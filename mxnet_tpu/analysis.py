@@ -528,8 +528,15 @@ _ALIAS_RE = _re.compile(
 # `{mhlo.sharding = "{devices=[4,1]<=[4]}", tf.aliasing_output = 0 :
 # i32}`, and a naive [^{}]* group would drop the donation marker of
 # exactly the sharded programs the audit exists to protect.
+# One char or one quoted string. No nested quantifier, so a dict the
+# pattern cannot close fails in linear time (`(?:[^{}"]+|...)*` took 2^n
+# on the first unquoted nested brace and hung the audit).
+_ATTR_ATOM = r"[^{}\"]|\"[^\"]*\""
 _STABLEHLO_ARG_RE = _re.compile(
-    r"%arg(\d+):\s*tensor<([^>]*)>\s*(\{(?:[^{}\"]+|\"[^\"]*\")*\})?")
+    r"%arg(\d+):\s*tensor<([^>]*)>\s*"
+    # the attr dict, with ONE level of nested braces: Shardy spells a
+    # sharding `#sdy.sharding<@mesh, [{"dp"}]>` inside it
+    r"(\{(?:" + _ATTR_ATOM + r"|\{(?:" + _ATTR_ATOM + r")*\})*\})?")
 
 _MLIR_DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2,
@@ -721,8 +728,7 @@ def program_summary(fn, avals):
     bare ``jax.jit`` callable; ``avals`` is ``(args, kwargs)`` of
     ``ShapeDtypeStruct``\\ s.
 
-    NOTE the lowering is a FULL recompile for donated entries (they are
-    deliberately excluded from jax's on-disk cache — PR 3), so this never
+    NOTE the lowering can be a FULL recompile, so this never
     runs on a step path: only the ``MXNET_HLOLINT_DUMP`` exit hook, the
     bench inventory stamp, and tests call it."""
     from . import compile_cache as _cc
@@ -732,11 +738,10 @@ def program_summary(fn, avals):
         return {"error": "unlowerable (no .lower on target)"}
     args, kwargs = avals
     with _cc.donation_warnings_suppressed():
-        with _cc._persistent_cache_paused():
-            lowered = target.lower(*args, **kwargs)
-            stablehlo_text = lowered.as_text()
-            compiled = lowered.compile()
-            hlo_text = compiled.as_text()
+        lowered = target.lower(*args, **kwargs)
+        stablehlo_text = lowered.as_text()
+        compiled = lowered.compile()
+        hlo_text = compiled.as_text()
     summary = summarize_hlo_text(stablehlo_text, hlo_text)
     shardings = None
     try:

@@ -22,15 +22,18 @@ Counters are recorded unconditionally (one lock-protected increment per
 step — noise next to a dispatch) so cache accounting works even when the
 wider telemetry plane is off.
 
-Persistent on-disk XLA cache: ``MXNET_COMPILE_CACHE_DIR=<dir>`` points
-jax's compilation cache at ``<dir>`` so a program compiled once (e.g. in a
-warm-up window) is deserialized, not re-built, by every later process —
-the `tools/compile_ladder.py` / bench `.jax_cache` mechanism promoted to a
-first-class framework knob.
+Persistent on-disk XLA cache: placed from OUTSIDE the program. When
+``JAX_COMPILATION_CACHE_DIR`` is set jax itself reads it and nothing here
+sets a directory; otherwise the cache lives at one fixed path inside the
+checkout (``.jax_cache/`` beside the package — the path is part of a cache
+key, so a directory that moves never hits). A program compiled once is
+deserialized, not re-built, by every later process; donated programs
+included.
 """
 from __future__ import annotations
 
 import contextlib
+import os
 import threading
 import time
 import warnings
@@ -41,14 +44,11 @@ from .base import getenv, register_env
 
 __all__ = ["CompileCache", "persistent_cache_dir", "stats", "named_stats",
            "name_totals", "all_caches", "donation_warnings_suppressed",
-           "trace_salt", "dump_audit", "audit_ledger"]
+           "dump_audit", "audit_ledger"]
 
 register_env("MXNET_FUSED_STEP", True,
              "fuse forward+backward+optimizer update into one jitted XLA "
              "computation per step (0 falls back to the eager per-op path)")
-register_env("MXNET_COMPILE_CACHE_DIR", "",
-             "directory for jax's persistent on-disk XLA compilation cache "
-             "(compile once per program across processes)")
 register_env("MXNET_HLOLINT_DUMP", "",
              "directory for compiled-program audit dumps: at process exit "
              "every audited cache entry's program summary (collective "
@@ -92,58 +92,6 @@ def _totals(name):
                                       "compile_seconds": 0.0}
         return t
 
-# Process-unique constant mixed into donated programs' HLO (trace_salt):
-# a donated-buffer executable deserialized from the on-disk cache by a
-# LATER process has broken input-output aliasing on XLA:CPU and corrupts
-# the heap when invoked ('corrupted double-linked list' — reproduced).
-# Salting makes such a program's cache key unique to this process, so no
-# other process can ever deserialize it, independent of jax-version
-# differences in how the persistent cache can be gated.
-import os as _os
-import time as _time
-
-_PROCESS_SALT = float(_os.getpid() * 4096 + (_time.time_ns() % 4096))
-
-
-def trace_salt(x):
-    """Mix the process-unique constant into a traced value without changing
-    it (``x + zeros_like(x) * salt`` — exact for any finite salt). Donated
-    programs call this on one traced argument so their HLO, and thus their
-    persistent-cache key, is unique to this process."""
-    import jax.numpy as jnp
-
-    return x + jnp.zeros_like(x) * _PROCESS_SALT
-
-
-def _persistent_cache_paused():
-    """Context: de-initialize jax's persistent compilation cache so the
-    next compile neither reads nor writes it (config-flag toggles alone do
-    not gate an already-initialized cache in jax 0.4.x). Best-effort — the
-    reset helper is a private jax API; trace_salt is the version-proof
-    backstop."""
-    import contextlib as _ctx
-
-    @_ctx.contextmanager
-    def scope():
-        import jax
-
-        try:
-            from jax._src import compilation_cache as _cc
-        except Exception:  # noqa: BLE001 — private API; salt still protects
-            _cc = None
-        old_dir = jax.config.jax_compilation_cache_dir
-        if _cc is not None and old_dir:
-            jax.config.update("jax_compilation_cache_dir", None)
-            _cc.reset_cache()
-        try:
-            yield
-        finally:
-            if _cc is not None and old_dir:
-                jax.config.update("jax_compilation_cache_dir", old_dir)
-                _cc.reset_cache()
-
-    return scope()
-
 
 @contextlib.contextmanager
 def donation_warnings_suppressed():
@@ -159,25 +107,41 @@ def donation_warnings_suppressed():
         yield
 
 
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
+
 def persistent_cache_dir():
-    """Apply ``MXNET_COMPILE_CACHE_DIR`` to jax's persistent compilation
-    cache (idempotent; called at import). Returns the directory or None."""
-    path = getenv("MXNET_COMPILE_CACHE_DIR")
-    if not path:
-        return None
-    try:
-        import os
+    """Place jax's persistent compilation cache (idempotent; called at
+    import) and return its directory. ``JAX_COMPILATION_CACHE_DIR`` set:
+    jax has already read it and this sets nothing. Unset: the one fixed
+    in-checkout path."""
+    external = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if external:
+        return external
+    import jax
 
-        import jax
+    os.makedirs(_CHECKOUT_CACHE_DIR, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", _CHECKOUT_CACHE_DIR)
+    return _CHECKOUT_CACHE_DIR
 
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        # small programs compile faster than they deserialize; only big
-        # compiles (the ones that hurt through a flaky relay) are persisted
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-        return path
-    except Exception:  # noqa: BLE001 — the on-disk cache is an optimisation
-        return None
+
+def _avals_of(args, kwargs):
+    """Shape/dtype/sharding skeleton of one call's arguments — enough to
+    AOT-lower the SAME program again (an array's sharding decides how the
+    program is partitioned and where it runs) without keeping a buffer
+    alive."""
+    import jax
+
+    def aval(x):
+        if isinstance(x, jax.Array):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
+        if hasattr(x, "shape") and hasattr(x, "dtype"):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype)
+        return x
+
+    return jax.tree_util.tree_map(aval, (tuple(args), dict(kwargs)))
 
 
 def _entries_gauge():
@@ -207,6 +171,9 @@ class CompileCache:
         self.compile_seconds = 0.0
         self._name_totals = _totals(name)
         self._entries = {}
+        # key -> seconds of the entry's first call (trace + XLA compile, or
+        # a persistent-cache read): what a cold run pays per program
+        self.first_call_seconds = {}
         # key -> {"avals": first-call abstract shapes, "memory": analysis}
         # (shape/dtype skeletons only — never holds buffers alive)
         self._entry_stats = {}
@@ -220,17 +187,10 @@ class CompileCache:
     def keys(self):
         return list(self._entries.keys())
 
-    def get_or_build(self, key, build, persistent=True, audit=None):
+    def get_or_build(self, key, build, audit=None):
         """The cached callable for ``key``; on miss, ``build()`` makes one
         (typically a ``jax.jit`` closure) and its first invocation is timed
         into ``compile.seconds``.
-
-        ``persistent=False`` keeps this program OUT of jax's on-disk
-        compilation cache: executables with donated (input-aliased) buffers
-        deserialize with broken aliasing on XLA:CPU and corrupt the heap on
-        invocation (reproduced: 'corrupted double-linked list' on the second
-        process reusing MXNET_COMPILE_CACHE_DIR). The fused train-step and
-        fused optimizer-update programs pass False; everything else persists.
 
         ``audit`` names the hlolint contract row this entry is audited
         under (``MXNET_HLOLINT_DUMP`` / ``tools/hlolint``); it defaults to
@@ -265,13 +225,14 @@ class CompileCache:
                 # so a new key means something about the workload changed —
                 # blame the axis instead of burning the budget silently
                 _blame_miss(self.name, key, self._entries)
-            fn = self._wrap_first_call(build(), persistent, key, audit)
+            fn = self._wrap_first_call(build(), key, audit)
             if self.maxsize is not None and len(self._entries) >= self.maxsize:
                 # drop the least-recently-used entry — executables are
                 # re-buildable, never precious
                 evicted = next(iter(self._entries))
                 self._entries.pop(evicted)
                 self._entry_stats.pop(evicted, None)
+                self.first_call_seconds.pop(evicted, None)
                 try:
                     from . import health
 
@@ -293,16 +254,8 @@ class CompileCache:
         program for XLA memory analysis (`memory_stats`) without keeping a
         single buffer alive."""
         try:
-            import jax
-
-            def aval(x):
-                if hasattr(x, "shape") and hasattr(x, "dtype"):
-                    return jax.ShapeDtypeStruct(x.shape, x.dtype)
-                return x
-
             self._entry_stats[key] = {
-                "avals": jax.tree_util.tree_map(aval, (tuple(args),
-                                                       dict(kwargs))),
+                "avals": _avals_of(args, kwargs),
                 "memory": None, "cost": None, "collectives": None}
         except Exception:  # noqa: BLE001 — stats are additive, never fatal
             pass
@@ -313,9 +266,8 @@ class CompileCache:
         an AOT `lower().compile()` pass over the recorded avals and
         memoized (failures too); never runs on the step path. NOTE the
         first computation can be a FULL recompile, not just a re-trace:
-        the AOT path bypasses jax's jit dispatch cache, and persistent=False
-        (donated) entries are deliberately kept out of the on-disk cache —
-        budget seconds per entry on the first scrape of a big cache."""
+        the AOT path bypasses jax's jit dispatch cache — budget seconds
+        per entry on the first scrape of a big cache."""
         st = self._entry_stats.get(key)
         if st is None:
             return None
@@ -418,15 +370,13 @@ class CompileCache:
                 rows.append(dict(mem, key=repr(key)))
         return rows
 
-    def _wrap_first_call(self, fn, persistent=True, key=None, audit=None):
+    def _wrap_first_call(self, fn, key=None, audit=None):
         cache = self
 
         class _Timed:
             """First call runs under a timer (trace + XLA compile happen
-            synchronously there), with the jax donation warning suppressed
-            and — for persistent=False programs — the on-disk compilation
-            cache disabled so the executable is neither written nor read
-            (see get_or_build); later calls go straight through."""
+            synchronously there) with the jax donation warning suppressed;
+            later calls go straight through."""
 
             __slots__ = ("_fn", "_first")
 
@@ -438,18 +388,10 @@ class CompileCache:
                 if self._first:
                     t0 = time.perf_counter()
                     with donation_warnings_suppressed():
-                        if persistent:
-                            out = self._fn(*args, **kwargs)
-                        else:
-                            # pause the on-disk cache for this one compile
-                            # (donated executables must never be persisted
-                            # — see get_or_build); compiles are rare and
-                            # the cache is restored immediately
-                            with _persistent_cache_paused():
-                                out = self._fn(*args, **kwargs)
+                        out = self._fn(*args, **kwargs)
                     # only now: a FAILED first call must retry with the
-                    # cache pause + accounting intact (another caller can
-                    # hit this shared entry after one caller's trace error)
+                    # accounting intact (another caller can hit this
+                    # shared entry after one caller's trace error)
                     self._first = False
                     if key is not None and cache.track_memory:
                         cache._record_avals(key, args, kwargs)
@@ -457,6 +399,8 @@ class CompileCache:
                         _audit_record(cache, audit or cache.name, key,
                                       self, args, kwargs)
                     dt = time.perf_counter() - t0
+                    if key is not None:
+                        cache.first_call_seconds[key] = dt
                     cache.compile_seconds += dt
                     cache._name_totals["compile_seconds"] += dt
                     telemetry.counter("compile.seconds").inc(dt)
@@ -708,14 +652,7 @@ def _audit_record(cache, tag, key, timed, args, kwargs):
         tags = _audit_tags()
         if tags and tag not in tags:
             return
-        import jax
-
-        def aval(x):
-            if hasattr(x, "shape") and hasattr(x, "dtype"):
-                return jax.ShapeDtypeStruct(x.shape, x.dtype)
-            return x
-
-        avals = jax.tree_util.tree_map(aval, (tuple(args), dict(kwargs)))
+        avals = _avals_of(args, kwargs)
         cap = int(getenv("MXNET_HLOLINT_MAX_ENTRIES"))
         with _audit_lock:
             lk = (tag, repr(key))
@@ -762,13 +699,13 @@ def dump_audit(dirpath):
                         "key": r["key"], "summary": summary})
     import json
 
-    _os.makedirs(dirpath, exist_ok=True)
-    path = _os.path.join(
-        dirpath, f"hlolint-{_os.getpid()}-{_time.time_ns() % 10**9}.json")
+    os.makedirs(dirpath, exist_ok=True)
+    path = os.path.join(
+        dirpath, f"hlolint-{os.getpid()}-{time.time_ns() % 10**9}.json")
     tmp = path + ".tmp"
     with open(tmp, "w") as f:
-        json.dump({"pid": _os.getpid(), "entries": entries}, f, indent=1)
-    _os.replace(tmp, path)
+        json.dump({"pid": os.getpid(), "entries": entries}, f, indent=1)
+    os.replace(tmp, path)
     return path
 
 

@@ -18,8 +18,8 @@ value simulation `tests/nightly/test_kvstore.py:33`
 
 TPU-native design: quantize/dequantize are pure jitted functions (fused by
 XLA into the push program) plus a Pallas kernel for the quantize hot path
-(`quantize_2bit_pallas`) — grid over 128-lane tiles, pack via a 16-step
-shift-or in registers. Dequantize(sum-over-workers) runs as one fused XLA
+(`quantize_2bit_pallas`) — grid over lane-aligned [16, words] blocks, pack
+via a 16-step shift-or in registers. Dequantize(sum-over-workers) runs as one fused XLA
 program on the allgathered packed words (`parallel/dist.py`).
 """
 from __future__ import annotations
@@ -82,51 +82,62 @@ def dequantize_2bit(packed, shape, threshold, dtype=jnp.float32):
     return out.reshape(shape)
 
 
-def quantize_2bit_pallas(grad, residual, threshold):
-    """Pallas TPU kernel for the quantize hot path (SURVEY §7's showcase):
-    one grid step packs a 2048-value tile (keeps lanes ×16 sublanes busy)
-    into 128 uint32 words with the shift-or tree in registers.
+_LANES = 128          # TPU vector lane count: the block's last dim tiles by it
+_MAX_WORD_BLOCK = 1024  # words per grid step (a 16 x 1024 f32 block = 64 KiB)
 
-    Falls back to interpret mode off-TPU so the same kernel is testable on
-    the CPU suite; numerics are identical to :func:`quantize_2bit`.
+
+def _quantize_kernel(g_ref, r_ref, packed_ref, res_ref, *, threshold):
+    """One (16, words) block: row j holds value j of every word, so packing is
+    a 16-step shift-or down the sublane axis and every load/store is a full
+    lane-aligned 32-bit vector."""
+    r = r_ref[...] + g_ref[...]
+    pos = r >= threshold
+    neg = r <= -threshold
+    res_ref[...] = jnp.where(pos, r - threshold,
+                             jnp.where(neg, r + threshold, r))
+    codes = jnp.where(pos, jnp.uint32(3),
+                      jnp.where(neg, jnp.uint32(2), jnp.uint32(0)))
+    packed = codes[0:1, :]
+    for j in range(1, _VALS_PER_WORD):
+        packed = packed | (codes[j:j + 1, :] << jnp.uint32(2 * j))
+    packed_ref[...] = packed
+
+
+def quantize_2bit_pallas(grad, residual, threshold, interpret=False):
+    """Pallas TPU kernel for the quantize hot path (SURVEY §7's showcase),
+    bit-equal to :func:`quantize_2bit`.
+
+    The flat values are laid out ``[16, words]`` (value j of word w at
+    ``[j, w]``) so one grid step packs up to 1024 words from 2-D,
+    lane-aligned 32-bit blocks — the shape Mosaic tiles. The kernel is
+    compiled for the TPU unless the caller asks for ``interpret=True`` (the
+    CPU test path).
     """
     from jax.experimental import pallas as pl
 
     n = grad.size
-    flat_g = grad.reshape(-1).astype(jnp.float32)
-    flat_r = residual.reshape(-1).astype(jnp.float32)
-    tile = 2048
-    padded = ((n + tile - 1) // tile) * tile
-    if padded != n:
-        flat_g = jnp.concatenate([flat_g, jnp.zeros((padded - n,), jnp.float32)])
-        flat_r = jnp.concatenate([flat_r, jnp.zeros((padded - n,), jnp.float32)])
-    n_tiles = padded // tile
-    words_per_tile = tile // _VALS_PER_WORD
+    words = compressed_size(n)
+    block = min(_MAX_WORD_BLOCK, -(-words // _LANES) * _LANES)
+    padded_words = -(-words // block) * block
 
-    def kernel(g_ref, r_ref, packed_ref, res_ref, *, threshold):
-        g = g_ref[...]
-        r = r_ref[...] + g
-        pos = r >= threshold
-        neg = r <= -threshold
-        res_ref[...] = jnp.where(pos, r - threshold, jnp.where(neg, r + threshold, r))
-        codes = jnp.where(pos, jnp.uint32(3), jnp.where(neg, jnp.uint32(2), jnp.uint32(0)))
-        blocks = codes.reshape(words_per_tile, _VALS_PER_WORD)
-        shifts = (jnp.arange(_VALS_PER_WORD, dtype=jnp.uint32) * 2)[None, :]
-        packed_ref[...] = jnp.bitwise_or.reduce(blocks << shifts, axis=1)
+    def lay_out(x):
+        flat = x.reshape(-1).astype(jnp.float32)
+        flat = jnp.pad(flat, (0, padded_words * _VALS_PER_WORD - n))
+        return flat.reshape(padded_words, _VALS_PER_WORD).T
 
-    interpret = jax.default_backend() != "tpu"
+    vals = pl.BlockSpec((_VALS_PER_WORD, block), lambda i: (0, i))
     packed, new_res = pl.pallas_call(
-        functools.partial(kernel, threshold=float(threshold)),
-        grid=(n_tiles,),
-        in_specs=[pl.BlockSpec((tile,), lambda i: (i,)),
-                  pl.BlockSpec((tile,), lambda i: (i,))],
-        out_specs=[pl.BlockSpec((words_per_tile,), lambda i: (i,)),
-                   pl.BlockSpec((tile,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((padded // _VALS_PER_WORD,), jnp.uint32),
-                   jax.ShapeDtypeStruct((padded,), jnp.float32)],
+        functools.partial(_quantize_kernel, threshold=float(threshold)),
+        grid=(padded_words // block,),
+        in_specs=[vals, vals],
+        out_specs=[pl.BlockSpec((1, block), lambda i: (0, i)), vals],
+        out_shape=[
+            jax.ShapeDtypeStruct((1, padded_words), jnp.uint32),
+            jax.ShapeDtypeStruct((_VALS_PER_WORD, padded_words), jnp.float32)],
         interpret=interpret,
-    )(flat_g, flat_r)
-    return packed[:compressed_size(n)], new_res[:n].reshape(residual.shape).astype(residual.dtype)
+    )(lay_out(grad), lay_out(residual))
+    new_res = new_res.T.reshape(-1)[:n].reshape(residual.shape)
+    return packed[0, :words], new_res.astype(residual.dtype)
 
 
 class GradientCompression:

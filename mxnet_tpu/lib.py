@@ -3,13 +3,17 @@
 The reference loads libmxnet.so via ctypes (`python/mxnet/base.py`); here the
 native library provides the host-side runtime only (dependency engine for
 IO/checkpoint ordering, RecordIO reader, shared-memory arena) — compute is
-XLA. Everything degrades gracefully to pure-python fallbacks when the .so
-has not been built (`make -C src`).
+XLA. The .so is a build product of the tracked sources in `src/`: it is
+(re)built with `make -C src` whenever it is missing or older than any of
+them, so nothing loaded here comes from a stale or foreign file. Without a
+toolchain, or when the build fails, the reason is printed and the pure-python
+engine is used.
 """
 from __future__ import annotations
 
 import ctypes
 import os
+import sys
 import threading
 
 _lib = None
@@ -17,50 +21,66 @@ _lib_tried = False
 _engine = None
 _lock = threading.Lock()
 
-_LIB_NAMES = ("librt_tpu.so",)
+_HERE = os.path.dirname(os.path.abspath(__file__))
+_SRC_DIR = os.path.join(os.path.dirname(_HERE), "src")
+_LIB_PATH = os.path.join(_HERE, "_native", "librt_tpu.so")
 
 
-def _find_lib():
-    here = os.path.dirname(os.path.abspath(__file__))
-    candidates = [
-        os.path.join(here, "_native"),
-        os.path.join(os.path.dirname(here), "build"),
-        os.path.join(os.path.dirname(here), "src"),
-    ]
-    for d in candidates:
-        for n in _LIB_NAMES:
-            p = os.path.join(d, n)
-            if os.path.exists(p):
-                return p
-    return None
+# files of src/ that go into OTHER build products (the C ABI, the native
+# self-test), not into librt_tpu.so
+_NOT_RT_SOURCES = ("capi.cc", "test_runtime.cc")
 
 
-def _try_build():
-    """Build librt_tpu.so from src/ if a toolchain is present (`make -C src`).
-    Failures are silent (everything has a pure-python fallback) and cached
-    via a marker file so forked workers / later processes don't each re-run
-    a doomed compile."""
+def _stale():
+    """True when librt_tpu.so is missing or one of its sources is newer."""
+    try:
+        built = os.path.getmtime(_LIB_PATH)
+    except OSError:
+        return True
+    if not os.path.isdir(_SRC_DIR):
+        return False  # installed without sources: the .so is all there is
+    return any(e.is_file() and e.name not in _NOT_RT_SOURCES
+               and e.stat().st_mtime > built for e in os.scandir(_SRC_DIR))
+
+
+def build_native(force=False):
+    """Build librt_tpu.so from src/ when stale (``force``: unconditionally).
+    Returns True when an up-to-date library is in place afterwards; a build
+    that cannot run or fails says why on stderr. Serialized across
+    processes by a lock file, so concurrent first imports build once."""
+    import fcntl
     import shutil
     import subprocess
 
-    here = os.path.dirname(os.path.abspath(__file__))
-    src = os.path.join(os.path.dirname(here), "src")
-    if not os.path.isdir(src) or shutil.which("make") is None:
-        return
-    marker = os.path.join(here, "_native", ".build_failed")
-    if os.path.exists(marker):
-        return
-    try:
-        subprocess.run(["make", "-C", src], capture_output=True, timeout=120)
-    except Exception:
-        pass
-    if _find_lib() is None:
+    if not force and not _stale():
+        return True
+    if not os.path.isdir(_SRC_DIR) or shutil.which("make") is None:
+        print("mxnet_tpu: native runtime not built (no src/ or no `make`); "
+              "using the python engine", file=sys.stderr)
+        return False
+    os.makedirs(os.path.dirname(_LIB_PATH), exist_ok=True)
+    with open(_LIB_PATH + ".lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if not force and not _stale():
+            return True  # another process built it while we waited
+        # stale: the default target (librt_tpu.so and, where python3-config
+        # exists, the C ABI). forced: librt_tpu.so alone, unconditionally
+        cmd = ["make", "-C", _SRC_DIR]
+        if force:
+            cmd += ["-B", os.path.relpath(_LIB_PATH, _SRC_DIR)]
         try:
-            os.makedirs(os.path.dirname(marker), exist_ok=True)
-            with open(marker, "w") as f:
-                f.write("native build failed; delete this file to retry\n")
-        except OSError:
-            pass
+            proc = subprocess.run(cmd, capture_output=True, text=True,
+                                  timeout=300)
+        except subprocess.TimeoutExpired:
+            print("mxnet_tpu: native runtime build timed out; using the "
+                  "python engine", file=sys.stderr)
+            return False
+    if proc.returncode != 0 or _stale():
+        print(f"mxnet_tpu: native runtime build failed (exit "
+              f"{proc.returncode}); using the python engine\n"
+              f"{proc.stdout}{proc.stderr}", file=sys.stderr)
+        return False
+    return True
 
 
 def get_lib():
@@ -68,15 +88,12 @@ def get_lib():
     with _lock:
         if not _lib_tried:
             _lib_tried = True
-            path = _find_lib()
-            if path is None and os.environ.get("MXNET_BUILD_NATIVE", "1") == "1":
-                _try_build()
-                path = _find_lib()
-            if path:
-                try:
-                    _lib = ctypes.CDLL(path)
-                except OSError:
-                    _lib = None
+            if os.environ.get("MXNET_BUILD_NATIVE", "1") == "1":
+                ok = build_native()
+            else:
+                ok = not _stale()
+            if ok:
+                _lib = ctypes.CDLL(_LIB_PATH)
     return _lib
 
 

@@ -325,7 +325,7 @@ def executable_stats():
     entry: ``{cache_name: [{key, argument_bytes, output_bytes, temp_bytes,
     peak_bytes}]}``. Lazy and memoized per entry, never on the step path —
     but the FIRST call after new compiles pays an AOT lowering pass per
-    new entry, which for donated (persistent=False) programs is a full
+    new entry, which can be a full
     recompile: expect the first ``/memory`` scrape of a freshly-warmed
     process to take seconds."""
     from . import compile_cache

@@ -55,21 +55,6 @@ class TransformerLMConfig:
     moe_aux_loss: float = 0.01
 
 
-def _use_pallas_attention():
-    """Fused flash kernel policy: ON by default on the TPU backend, OFF
-    elsewhere (the interpret path is a debugging tool, not a CPU win);
-    MXNET_PALLAS_ATTENTION=0/1 overrides either way."""
-    import os
-
-    flag = os.environ.get("MXNET_PALLAS_ATTENTION")
-    if flag is not None:
-        return flag == "1"
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # noqa: BLE001
-        return False
-
-
 def _spec(mesh, *axes):
     return NamedSharding(mesh, P(*[a if (a in mesh.shape and mesh.shape[a] > 1) else None
                                    for a in axes]))
@@ -197,43 +182,55 @@ class TransformerLM:
         out = (x32 - mu) * jax.lax.rsqrt(var + 1e-5)
         return (out * scale.astype(jnp.float32) + bias.astype(jnp.float32)).astype(x.dtype)
 
-    def _attention(self, q, k, v):
-        """Dispatch: ring attention if 'sp' is a real mesh axis, else local
-        blockwise attention (same math, zero hops)."""
-        mesh, c = self.mesh, self.cfg
-        sp = mesh.shape.get("sp", 1)
-        if sp > 1:
-            from ..parallel.collectives import shard_map
-            spec = P(("dp", "fsdp") if "fsdp" in mesh.shape else "dp", "sp", "tp", None)
-            spec = P(*[a if (isinstance(a, tuple) or (a in mesh.shape and mesh.shape[a] > 1)) else None
-                       for a in spec])
+    def _local_attention(self, q, k, v, blocks):
+        """One device's attention over [B, L, H, D]: the fused flash kernel
+        (ops/pallas_attention.py: QK^T -> streaming softmax -> PV without
+        the HBM round trip) with the `(block_q, block_k)` the caller's shape
+        test chose, else (`blocks` None) the XLA blockwise path. The choice
+        is made from shapes before the call, so a kernel the compiler
+        refuses raises instead of hiding."""
+        c = self.cfg
+        if blocks is not None:
+            from ..ops import pallas_attention as pa
 
-            def body(q, k, v):
-                return ring_attention(q, k, v, "sp", sp, causal=c.causal)
-
-            fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                           out_specs=spec, check_vma=False)
-            return fn(q, k, v)
-        if _use_pallas_attention():
-            # fused VMEM-resident flash kernel (ops/pallas_attention.py):
-            # QK^T -> streaming softmax -> PV without the HBM round trip.
-            # Falls through to the XLA blockwise path on shapes the kernel
-            # does not tile.
-            try:
-                import os
-
-                from ..ops.pallas_attention import flash_attention
-
-                return flash_attention(
-                    q, k, v, causal=c.causal,
-                    interpret=os.environ.get(
-                        "MXNET_PALLAS_INTERPRET") == "1")
-            except (ValueError, RuntimeError):
-                pass
+            return pa.flash_attention(q, k, v, causal=c.causal,
+                                      block_q=blocks[0], block_k=blocks[1],
+                                      interpret=pa.pallas_interpret())
         from ..parallel.ring_attention import _block_attn, _bhql_to_bqhl, _full_causal_bias
         bias = _full_causal_bias(q.shape[1], k.shape[1]) if c.causal else None
         o, m, l = _block_attn(q, k, v, bias)
         return o / _bhql_to_bqhl(l)
+
+    def _attention(self, q, k, v):
+        """Dispatch: ring attention if 'sp' is a real mesh axis, else local
+        attention (same math, zero hops). A Mosaic kernel cannot be
+        partitioned by GSPMD, so on a mesh of several devices the fused
+        kernel runs per shard inside `shard_map` (batch over dp/fsdp, heads
+        over tp — attention is independent across both)."""
+        from ..ops import pallas_attention as pa
+
+        mesh, c = self.mesh, self.cfg
+        sp = mesh.shape.get("sp", 1)
+        # blocks depend on L and D only, which dp/tp sharding leaves whole
+        blocks = None
+        if (sp == 1 and pa.pallas_enabled()
+                and c.n_heads % mesh.shape.get("tp", 1) == 0):
+            blocks = pa.flash_blocks(q.shape, k.shape, q.dtype, c.causal)
+        if sp == 1 and (blocks is None or mesh.size == 1):
+            return self._local_attention(q, k, v, blocks)
+        from ..parallel.collectives import shard_map
+        spec = P(("dp", "fsdp") if "fsdp" in mesh.shape else "dp", "sp", "tp", None)
+        spec = P(*[a if (isinstance(a, tuple) or (a in mesh.shape and mesh.shape[a] > 1)) else None
+                   for a in spec])
+
+        def body(q, k, v):
+            if sp > 1:
+                return ring_attention(q, k, v, "sp", sp, causal=c.causal)
+            return self._local_attention(q, k, v, blocks)
+
+        fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
+        return fn(q, k, v)
 
     def _moe_ffn(self, i, params, x):
         """Top-1 ("Switch") expert FFN — the GShard GROUPED dispatch/

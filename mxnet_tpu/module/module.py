@@ -8,8 +8,9 @@ TPU-native redesign: the reference binds one executor PER DEVICE and
 slices each batch across them (`executor_group.py:65`), reducing grads
 through KVStore. Here a single bound executor is one XLA program for the
 whole batch; multi-chip data parallelism is GSPMD sharding of that same
-program (`parallel.ShardedTrainer`), so there is no per-device executor
-list to manage — ctx lists are accepted for API parity.
+program (`MXNET_SPMD=dp=N`, `parallel/spmd.py`), so there is no per-device
+executor list to manage — a list of several distinct devices is refused
+with that advice, never bound on its first entry in silence.
 """
 from __future__ import annotations
 
@@ -47,6 +48,17 @@ class Module(BaseModule):
             self._context = list(self._context)
         else:
             self._context = [self._context]
+        if len(set(self._context)) > 1:
+            # the reference slices the batch over one executor per device;
+            # here ONE executor is bound on ONE device, so a device list
+            # would train on its first entry and say nothing
+            raise MXNetError(
+                f"Module(context={self._context}): a Module binds one "
+                f"executor on one device and does not split the batch over "
+                f"a device list. To train across {len(set(self._context))} "
+                f"devices pass a single context and set "
+                f"MXNET_SPMD=dp={len(set(self._context))} — one program "
+                f"sharded over one mesh (docs/faq/distributed_training.md)")
 
         arg_names = symbol.list_arguments()
         input_names = self._data_names + self._label_names

@@ -545,11 +545,21 @@ class NDArray:
             value = value._data
         elif isinstance(value, (_np.ndarray, list, tuple, float, int)):
             value = jnp.asarray(value, dtype=self.dtype)
+        # re-placed on THIS array's device: a value built from host data
+        # lands uncommitted on jax's default device, which would move a
+        # cpu()/tpu(1) array to chip 0 under an unchanged label — and, for a
+        # weight on chip 0, leave it uncommitted, so the second fused step
+        # (fed the first one's committed outputs) re-lowers and recompiles
         if isinstance(key, slice) and key == slice(None):
-            self._data = jnp.broadcast_to(value, self.shape).astype(self.dtype)
+            self._data = _on_device(
+                jnp.broadcast_to(value, self.shape).astype(self.dtype),
+                self._ctx)
             return
         key = _convert_index(key)
-        self._data = self._data.at[key].set(value.astype(self.dtype) if hasattr(value, "astype") else value)
+        self._data = _on_device(
+            self._data.at[key].set(value.astype(self.dtype)
+                                   if hasattr(value, "astype") else value),
+            self._ctx)
 
     def _lazy_basic_setitem(self, key, value):
         """The captured rendering of a basic write: `_slice_assign(_scalar)`
@@ -708,11 +718,23 @@ def _invoke(op_name, *args, **kwargs):
 # ---------------------------------------------------------------------------
 
 
+def _on_device(jarr, ctx):
+    """`jarr` committed to the device `ctx` names. On a CPU-only process a
+    cpu context leaves the array where jax put it (one device, nothing to
+    pin). A traced value, and an array sharded over several devices (an
+    SPMD-placed weight), is left alone: its placement is not the label's."""
+    if ctx.device_type in ("cpu", "cpu_pinned", "cpu_shared") and _default_is_cpu():
+        return jarr
+    if isinstance(jarr, jax.core.Tracer) or (
+            isinstance(jarr, jax.Array)
+            and len(jarr.sharding.device_set) > 1):
+        return jarr
+    return jax.device_put(jarr, ctx.jax_device)
+
+
 def _place(jarr, ctx):
     ctx = ctx if ctx is not None else current_context()
-    if ctx.device_type in ("cpu", "cpu_pinned", "cpu_shared") and _default_is_cpu():
-        return NDArray(jarr, ctx)
-    return NDArray(jax.device_put(jarr, ctx.jax_device), ctx)
+    return NDArray(_on_device(jarr, ctx), ctx)
 
 
 def _default_is_cpu():

@@ -15,28 +15,27 @@ the fused forward bit-for-bit up to reassociation). That is the standard
 XLA program.
 
 Availability: TPU (or `interpret=True` anywhere — the CPU test path).
-`flash_attention` raises on shapes not divisible by the block sizes;
-callers (transformer) fall back to the XLA blockwise path.
+Callers decide BEFORE the call, from the shapes alone, whether the kernel
+applies (`flash_blocks` / `partial_blocks`: blocks the Mosaic tiling accepts
+and K/V that fit the kernel's fast memory) and take the XLA blockwise path
+otherwise; once a caller has chosen the kernel, a compiler refusal raises.
 """
 from __future__ import annotations
 
 import functools
 import math
+import os
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:  # pallas ships with jax on TPU builds; guard for minimal CPU images
-    from jax.experimental import pallas as pl
-    HAVE_PALLAS = True
-except Exception:  # noqa: BLE001
-    pl = None
-    HAVE_PALLAS = False
+from jax.experimental import pallas as pl
 
 from ..compile_cache import CompileCache
 
-__all__ = ["flash_attention", "reference_attention", "HAVE_PALLAS"]
+__all__ = ["flash_attention", "reference_attention", "flash_blocks",
+           "partial_blocks", "pallas_enabled", "pallas_interpret"]
 
 # one custom_vjp-wrapped kernel per (config) — named so
 # `compile_cache.named_stats("pallas")` attributes kernel rebuilds the
@@ -46,6 +45,80 @@ __all__ = ["flash_attention", "reference_attention", "HAVE_PALLAS"]
 _pallas_cache = CompileCache("pallas", track_memory=False)
 
 _NEG_INF = -1e30
+
+_LANES = 128
+# Mosaic's default scoped-VMEM limit for one kernel on a TPU v5e is 16 MiB
+# (the compiler refuses "scoped vmem ... limit 16.00M"). Both kernels keep a
+# (batch*head)'s whole K and V resident per grid step, double-buffered by the
+# pipeline; 3/4 of the limit goes to them, the rest to the Q/O blocks and the
+# fp32 temporaries of one K step.
+_RESIDENT_BUDGET_BYTES = 12 * 2 ** 20
+
+
+def pallas_enabled():
+    """Fused-kernel policy: ON by default on the TPU backend, OFF elsewhere
+    (the interpret path is a debugging tool, not a CPU win);
+    MXNET_PALLAS_ATTENTION=0/1 overrides either way."""
+    flag = os.environ.get("MXNET_PALLAS_ATTENTION")
+    if flag is not None:
+        return flag == "1"
+    return jax.default_backend() == "tpu"
+
+
+def pallas_interpret():
+    """MXNET_PALLAS_INTERPRET=1 runs the kernels in the Pallas interpreter
+    (the CPU test path); otherwise they are compiled."""
+    return os.environ.get("MXNET_PALLAS_INTERPRET") == "1"
+
+
+def _divisor_block(n, target=128, multiple=8):
+    """Largest block <= target that divides n AND that the TPU tiling
+    accepts: a multiple of ``multiple`` (8 sublanes for a second-to-last
+    block dim, 128 lanes for a last one), or the full dimension. None when
+    no such block exists — the caller keeps the XLA path."""
+    if n <= target:
+        return n
+    for b in range(target - target % multiple, 0, -multiple):
+        if n % b == 0:
+            return b
+    return None
+
+
+def _resident_fits(lk, d, dtype, bias_lanes=0):
+    """Whether whole K and V (and the partials kernel's [Lk, block_q] bias
+    block) fit the fast-memory budget: the last dim pads to 128 lanes and
+    the pipeline holds two buffers of each."""
+    lanes = -(-d // _LANES) * _LANES
+    per_row = 2 * lanes * jnp.dtype(dtype).itemsize + 4 * bias_lanes
+    return 2 * lk * per_row <= _RESIDENT_BUDGET_BYTES
+
+
+def flash_blocks(q_shape, k_shape, dtype, causal, block_q=128, block_k=128):
+    """The shape test for :func:`flash_attention`: ``(block_q, block_k)``
+    when the fused forward applies to [B, L, H, D] operands of these shapes,
+    None when the caller should take the XLA path (cross-length causal
+    attention, lengths no accepted block divides, K/V beyond the resident
+    budget)."""
+    lq, lk, d = q_shape[1], k_shape[1], q_shape[-1]
+    if causal and lq != lk:
+        return None
+    bq, bk = _divisor_block(lq, block_q), _divisor_block(lk, block_k)
+    if bq is None or bk is None or not _resident_fits(lk, d, dtype):
+        return None
+    return bq, bk
+
+
+def partial_blocks(q_shape, k_shape, dtype, block_q=128, block_k=128):
+    """The shape test for :func:`block_partials_pallas` (the ring hop). The
+    q block is also the LAST dim of the kernel's transposed bias block, so
+    it tiles by 128 lanes, not 8 sublanes."""
+    lq, lk, d = q_shape[1], k_shape[1], q_shape[-1]
+    bq = _divisor_block(lq, block_q, multiple=_LANES)
+    bk = _divisor_block(lk, block_k)
+    if bq is None or bk is None or not _resident_fits(
+            lk, d, dtype, bias_lanes=-(-bq // _LANES) * _LANES):
+        return None
+    return bq, bk
 
 
 def reference_attention(q, k, v, causal=False, scale=None):
@@ -153,10 +226,9 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
     """Fused attention over [B, L, H, D] tensors.
 
     block sizes clamp to the sequence lengths; raises ValueError when the
-    lengths are not divisible by the (clamped) blocks — the caller keeps
-    the XLA blockwise path for such shapes."""
-    if not HAVE_PALLAS:
-        raise RuntimeError("pallas unavailable in this jax build")
+    lengths are not divisible by the (clamped) blocks — callers ask
+    :func:`flash_blocks` first and keep the XLA blockwise path for such
+    shapes."""
     lq, lk = q.shape[1], k.shape[1]
     if causal and lq != lk:
         # the kernel's causal mask assumes aligned self-attention
@@ -224,8 +296,6 @@ def flash_block_partials(q, k, v, bias=None, scale=None, block_q=128,
     (o = exp(s - m) @ v UNNORMALIZED, m row max, l row sum-exp; all
     fp32 stats, o in q.dtype; `bias` is the ring's additive [*, *, Lq, Lk]
     mask). Raises ValueError on shapes the kernel does not tile."""
-    if not HAVE_PALLAS:
-        raise RuntimeError("pallas unavailable in this jax build")
     b, lq, h, d = q.shape
     lk = k.shape[1]
     block_q = min(block_q, lq)
@@ -311,23 +381,17 @@ def _make_partials_vjp(scale, block_q, block_k, interpret):
         ("partials", scale, block_q, block_k, interpret), build)
 
 
-def _divisor_block(n, target=128):
-    """Largest block <= target that divides n (power-of-two seq lengths
-    get the full target; anything else still tiles exactly)."""
-    b = min(target, n)
-    while n % b:
-        b -= 1
-    return b
-
-
 def block_partials_pallas(q, k, v, bias, scale, block_q=128, block_k=128,
                           interpret=False):
     """Ring-hop entry point: `_block_attn`'s contract with the fused
-    kernel forward and an exact recomputed backward. `bias` may be None."""
+    kernel forward and an exact recomputed backward. `bias` may be None.
+    Raises ValueError on shapes :func:`partial_blocks` refuses."""
+    blocks = partial_blocks(q.shape, k.shape, q.dtype, block_q, block_k)
+    if blocks is None:
+        raise ValueError(
+            f"block_partials_pallas: no accepted tiling for q {q.shape} / "
+            f"k {k.shape} ({q.dtype}); use the XLA path")
     if bias is None:
         bias = jnp.zeros((1, 1, q.shape[1], k.shape[1]), jnp.float32)
-    fn = _make_partials_vjp(float(scale),
-                            _divisor_block(q.shape[1], block_q),
-                            _divisor_block(k.shape[1], block_k),
-                            bool(interpret))
+    fn = _make_partials_vjp(float(scale), *blocks, bool(interpret))
     return fn(q, k, v, bias)

@@ -1377,21 +1377,12 @@ class Updater:
                    tuple(_state_sig(s) for s in states))
 
             def build():
-                from ..compile_cache import trace_salt
-
                 def step(ws, gs, ss, lrs_, wds_, rescale):
-                    # salt the HLO: this donated program must never be
-                    # deserialized by another process
-                    # (compile_cache.trace_salt)
-                    return opt.fused_update(ws, gs, ss, lrs_, wds_,
-                                            trace_salt(rescale))
+                    return opt.fused_update(ws, gs, ss, lrs_, wds_, rescale)
 
                 return jax.jit(step, donate_argnums=(0, 2))
 
-            # persistent=False: donated programs must stay OUT of the
-            # on-disk XLA cache (deserialized aliasing corrupts the heap —
-            # see CompileCache.get_or_build)
-            fn = _updater_cache().get_or_build(key, build, persistent=False)
+            fn = _updater_cache().get_or_build(key, build)
             new_ws, new_ss = fn([w._data for w in weights],
                                 [g._data for g in grads],
                                 [_state_to_jax(s) for s in states],
@@ -1458,11 +1449,9 @@ class Updater:
             def build():
                 import jax
 
-                from ..compile_cache import trace_salt
-
                 def step(ws, gs, flat, lrs_, wds_, rescale):
                     return ctx.traced_update(opt, list(ws), list(gs), flat,
-                                             lrs_, wds_, trace_salt(rescale))
+                                             lrs_, wds_, rescale)
 
                 # donate only the flat sharded state: the updated weights
                 # are slices of one all-gathered bucket, which XLA cannot
@@ -1474,8 +1463,7 @@ class Updater:
             # audit="zero1": this is the gluon/aggregated rendering of the
             # sharded update — same reduce-scatter/all-gather contract row
             # as the executor-side fused step (tools/hlolint/contracts.py)
-            fn = _updater_cache().get_or_build(key, build, persistent=False,
-                                               audit="zero1")
+            fn = _updater_cache().get_or_build(key, build, audit="zero1")
             new_ws, new_flat = fn(
                 [ctx.put_replicated(w._data) for w in weights],
                 [ctx.put_replicated(g._data) for g in grads],

@@ -14,31 +14,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map  # noqa: F401 — shard_map is re-exported
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-try:  # newer jax exports it top-level
-    from jax import shard_map as _jax_shard_map
-except ImportError:  # older jax: experimental namespace only
-    from jax.experimental.shard_map import shard_map as _jax_shard_map
-
-
-def shard_map(f, **kwargs):
-    """Version-stable `shard_map`: jax renamed the replication-check kwarg
-    (`check_rep` -> `check_vma`) and moved the function out of
-    `jax.experimental`; route every in-repo use through this shim."""
-    import inspect
-
-    try:
-        params = inspect.signature(_jax_shard_map).parameters
-    except (TypeError, ValueError):
-        params = {}
-    if "check_vma" in kwargs and "check_vma" not in params:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    elif "check_rep" in kwargs and "check_rep" not in params:
-        kwargs["check_vma"] = kwargs.pop("check_rep")
-    return _jax_shard_map(f, **kwargs)
-
 
 from ..compile_cache import CompileCache
 from .mesh import default_mesh
@@ -71,13 +48,11 @@ psum_scatter = reduce_scatter
 
 
 def sharding_constraint(x, sharding):
-    """Version-stable `with_sharding_constraint` — the GSPMD annotation the
+    """`with_sharding_constraint` — the GSPMD annotation the
     sharded-weight-update paper (arXiv:2004.13336) is built on: a psum
     followed by a constraint to a sharded layout lowers to ReduceScatter,
     a constraint from sharded back to replicated lowers to AllGather."""
-    from jax import lax as _lax
-
-    return _lax.with_sharding_constraint(x, sharding)
+    return lax.with_sharding_constraint(x, sharding)
 
 
 def ppermute(x, axis_name, perm):

@@ -100,16 +100,7 @@ def init_process_group(coordinator=None, num_processes=None, process_id=None):
 
     timeout_s = int(getenv("MXNET_INIT_TIMEOUT_S"))
     if timeout_s:
-        # feature-detect instead of try/except TypeError: a TypeError from
-        # INSIDE initialize must not silently drop the user's timeout
-        import inspect
-
-        try:
-            params = inspect.signature(jax.distributed.initialize).parameters
-        except (TypeError, ValueError):
-            params = {}
-        if "initialization_timeout" in params:
-            kwargs["initialization_timeout"] = timeout_s
+        kwargs["initialization_timeout"] = timeout_s
     try:
         jax.distributed.initialize(**kwargs)
     except Exception as e:

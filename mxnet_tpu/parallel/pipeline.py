@@ -70,15 +70,6 @@ class PipelineFallback(Exception):
     Module logs it once."""
 
 
-def _pvary(x, axes):
-    """Varying-axis cast across jax versions (pcast / pvary / no-op)."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axes, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, axes)
-    return x
-
-
 # ---------------------------------------------------------------------------
 # Stage partition
 # ---------------------------------------------------------------------------
@@ -623,14 +614,16 @@ class PipelineContext:
                                            (0, max_flat - flat.shape[0]))
                         outs_t = tuple(jnp.zeros(shape, dtype)
                                        for shape, dtype in out_specs)
-                    return _pvary(flat, (axis,)), \
-                        tuple(_pvary(o, (axis,)) for o in outs_t)
+                    return flat, outs_t
 
                 return branch
 
             branches = [make_branch(i) for i in range(S)]
-            state0 = _pvary(jnp.zeros((max_flat,), jnp.float32), (axis,))
-            outs0 = tuple(_pvary(jnp.zeros((M,) + shape, dtype), (axis,))
+            # no varying-axis casts: this schedule's shard_map runs with
+            # check_vma=False, where a cast's transpose (a psum over an
+            # axis the untracked cotangent does not vary on) is refused
+            state0 = jnp.zeros((max_flat,), jnp.float32)
+            outs0 = tuple(jnp.zeros((M,) + shape, dtype)
                           for shape, dtype in out_specs)
 
             def tick(carry, t):
@@ -707,8 +700,9 @@ def pipeline_step(stage_fn, params_stack, x_microbatches, axis_name, axis_size):
     # up pp-varying params and x's data-axes on the first tick; fori_loop
     # needs a fixed carry type): inherit x's axes via a zero of x, then add pp
     zero = x_microbatches[0] * 0
-    state = _pvary(zero, (axis_name,))
-    outputs = _pvary(jnp.broadcast_to(zero, (m,) + h_shape), (axis_name,))
+    state = lax.pcast(zero, (axis_name,), to="varying")
+    outputs = lax.pcast(jnp.broadcast_to(zero, (m,) + h_shape), (axis_name,),
+                        to="varying")
 
     def tick(carry, t):
         state, outputs = carry

@@ -76,31 +76,26 @@ def _bhql_to_bqhl(x):
 
 
 def _hop_fn(scale):
-    """Per-hop block attention: the fused Pallas kernel on the TPU backend
-    (VMEM-resident QK^T/softmax/PV while K/V ride the ICI ring; exact
-    recomputed backward), the XLA blockwise path elsewhere. Same policy
-    knobs as the transformer's local attention (MXNET_PALLAS_ATTENTION /
-    MXNET_PALLAS_INTERPRET)."""
-    import os
+    """Per-hop block attention: the fused Pallas kernel where the policy
+    enables it (TPU backend by default; MXNET_PALLAS_ATTENTION /
+    MXNET_PALLAS_INTERPRET as for the transformer's local attention) AND the
+    hop's shapes pass the kernel's shape test — VMEM-resident QK^T/softmax/PV
+    while K/V ride the ICI ring, exact recomputed backward; the XLA blockwise
+    path otherwise. Decided from shapes before the call: a kernel the
+    compiler refuses raises."""
+    from ..ops import pallas_attention as pa
 
-    flag = os.environ.get("MXNET_PALLAS_ATTENTION")
-    if flag is not None:
-        enabled = flag == "1"
-    else:
-        try:
-            enabled = jax.default_backend() == "tpu"
-        except Exception:  # noqa: BLE001
-            enabled = False
-    if enabled:
-        try:
-            from ..ops.pallas_attention import block_partials_pallas
+    if not pa.pallas_enabled():
+        return lambda q, k, v, bias: _block_attn(q, k, v, bias, scale)
+    interpret = pa.pallas_interpret()
 
-            interpret = os.environ.get("MXNET_PALLAS_INTERPRET") == "1"
-            return lambda q, k, v, bias: block_partials_pallas(
-                q, k, v, bias, scale, interpret=interpret)
-        except Exception:  # noqa: BLE001 — pallas unavailable
-            pass
-    return lambda q, k, v, bias: _block_attn(q, k, v, bias, scale)
+    def hop(q, k, v, bias):
+        if pa.partial_blocks(q.shape, k.shape, q.dtype) is None:
+            return _block_attn(q, k, v, bias, scale)
+        return pa.block_partials_pallas(q, k, v, bias, scale,
+                                        interpret=interpret)
+
+    return hop
 
 
 def ring_attention(q, k, v, axis_name, axis_size, causal=False, scale=None,

@@ -422,12 +422,12 @@ class Zero1Context:
             # (tools/hlolint dumps of the compiled programs):
             # * SPMD composition: the bucket concatenates MIXED-sharded
             #   operands (tp/fsdp params next to replicated biases).
-            #   jax 0.4.x's SPMD partitioner miscompiles a concat of
+            #   jax 0.4.x's SPMD partitioner miscompiled a concat of
             #   mixed-sharded operands partitioned straight to the flat
-            #   dp layout — values interleave by shard stride (reproduced
-            #   on 0.4.37; canary-pinned in test_hlolint.py). Pinning the
-            #   concat result REPLICATED first, then sharding, is the
-            #   correct lowering the partitioner does handle.
+            #   dp layout — values interleaved by shard stride. The
+            #   installed jax 0.9.0 gets it right (canary-pinned in
+            #   test_hlolint.py), so this reason is history; the next
+            #   one alone keeps replicate-first.
             # * plain lane: partitioning the concat of REPLICATED
             #   operands straight to the dp layout lowers as
             #   dynamic-update-slice + a FULL-BUCKET all-reduce per pack

@@ -47,8 +47,7 @@ token, O(T²) per sequence). This engine is the token-level scheduler:
   prefill bucket plus exactly ONE decode (or verify) executable — and,
   per enabled feature, one fork entry, one suffix-prefill entry per
   bucket and the draft's own pinned set — all with the slab buffers
-  donated (``persistent=False``: donated programs stay out of the
-  on-disk XLA cache, the PR 3 aliasing rule). ``serving.warmup`` pins the
+  donated. ``serving.warmup`` pins the
   exact count ahead of traffic; steady state compiles nothing.
 
 Telemetry rides ``serving.generation.*`` (live-slot gauge, tokens/s,
@@ -836,7 +835,7 @@ class GenerationEngine:
             return jax.jit(fn, donate_argnums=(1, 2))
 
         key = ("prefill", bucket, self._total_slots, self._slab_len)
-        return cache.get_or_build(key, build, persistent=False)
+        return cache.get_or_build(key, build)
 
     def _decode_fn(self):
         """THE decode executable — one fused step over the whole slab,
@@ -856,7 +855,7 @@ class GenerationEngine:
             return jax.jit(fn, donate_argnums=(1, 2))
 
         key = ("decode", self._total_slots, self._slab_len)
-        return cache.get_or_build(key, build, persistent=False)
+        return cache.get_or_build(key, build)
 
     def _fork_fn(self):
         """THE prefix-fork executable: copy one slot's slab rows (both K
@@ -880,7 +879,7 @@ class GenerationEngine:
             return jax.jit(fn, donate_argnums=(0, 1))
 
         key = ("fork", self._total_slots, self._slab_len)
-        return cache.get_or_build(key, build, persistent=False)
+        return cache.get_or_build(key, build)
 
     def _suffix_prefill_fn(self, bucket):
         """The bucket's suffix-prefill executable: the prompt tail after
@@ -900,7 +899,7 @@ class GenerationEngine:
             return jax.jit(fn, donate_argnums=(1, 2))
 
         key = ("suffix_prefill", bucket, self._total_slots, self._slab_len)
-        return cache.get_or_build(key, build, persistent=False)
+        return cache.get_or_build(key, build)
 
     def _verify_fn(self):
         """THE speculative verify executable — k+1 unrolled decode graphs
@@ -922,7 +921,7 @@ class GenerationEngine:
             return jax.jit(fn, donate_argnums=(1, 2))
 
         key = ("verify", self._spec_k, self._total_slots, self._slab_len)
-        return cache.get_or_build(key, build, persistent=False)
+        return cache.get_or_build(key, build)
 
     # -- scheduler -----------------------------------------------------------
 

@@ -209,8 +209,7 @@ class CheckpointDraft(Draft):
                self._slab_len)
         # audit="generation": the draft slab programs live in the engine's
         # "generation" cache (passed in) — same hlolint contract row
-        return cache.get_or_build(key, build, persistent=False,
-                                  audit="generation")
+        return cache.get_or_build(key, build, audit="generation")
 
     def _step_fn(self, k):
         model, cache = self._model, self._eng.cache
@@ -248,8 +247,7 @@ class CheckpointDraft(Draft):
             return jax.jit(fn, donate_argnums=(1, 2))
 
         key = ("draft_step", k, self._eng.total_slots, self._slab_len)
-        return cache.get_or_build(key, build, persistent=False,
-                                  audit="generation")
+        return cache.get_or_build(key, build, audit="generation")
 
     # -- lifecycle -----------------------------------------------------------
 

@@ -6,9 +6,9 @@ look like an outage. Warmup runs a zeros batch through every bucket at
 startup, so the ``"serving"`` compile cache is fully populated before the
 first real request and steady state pays ZERO compiles (pinned by
 test_serving.py the way the fused-step PR pinned its padded-batch miss
-count). With ``MXNET_COMPILE_CACHE_DIR`` set, later processes deserialize
-these programs instead of rebuilding them — warmup then costs disk reads,
-not compiles.
+count). Later processes on the same persistent compile cache
+(``compile_cache.persistent_cache_dir``) deserialize these programs instead
+of rebuilding them — warmup then costs disk reads, not compiles.
 """
 from __future__ import annotations
 

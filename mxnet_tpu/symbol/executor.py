@@ -467,12 +467,6 @@ class Executor:
             n_args = len(self._arg_names)
 
             def step(key, params, others, auxs, ss, lrs_, wds_, rescale):
-                from ..compile_cache import trace_salt
-
-                # salt the HLO: this donated program must never be
-                # deserialized by another process (compile_cache.trace_salt)
-                rescale = trace_salt(rescale)
-
                 def f(*ps):
                     full = [None] * n_args
                     for p, i in zip(ps, param_pos):
@@ -534,9 +528,7 @@ class Executor:
             donate = (3, 4) if zero1 is not None else (1, 3, 4)
             return jax.jit(step, donate_argnums=donate)
 
-        # persistent=False: donated programs must stay OUT of the on-disk
-        # XLA cache (deserialized aliasing corrupts the heap — see
-        # CompileCache.get_or_build). Pipelined steps compile under the
+        # Pipelined steps compile under the
         # named "pipeline" cache, sharded ones under "spmd" (spmd wins
         # when both compose), so per-config accounting is assertable.
         # The audit tag names the hlolint contract row for the
@@ -551,8 +543,7 @@ class Executor:
             cache, audit = self._cache, "zero1"
         else:
             cache, audit = self._cache, "fused_step"
-        fn = cache.get_or_build(("fused_step", sig), build,
-                                persistent=False, audit=audit)
+        fn = cache.get_or_build(("fused_step", sig), build, audit=audit)
         call_args = [key, params, others, auxs, states_arg,
                      jnp.asarray(lrs, jnp.float32),
                      jnp.asarray(wds, jnp.float32),

@@ -1,0 +1,157 @@
+"""The Pallas kernels of the main path, compiled at real widths for a
+DESCRIBED TPU v5e (no chip attached): what interpret mode cannot show — a
+slice the Mosaic tiling refuses, more fast memory than a kernel may use — is
+refused here, at no chip time (on-chip-measurement guide, section 2,
+rehearsal 3). A compile that passes is not a chip run; `chip_smoke.py` is.
+
+Also pins the shape tests that decide, before the call, whether a kernel
+applies: lengths no accepted block divides and K/V beyond the fast-memory
+budget go to the XLA path instead of to a compiler refusal.
+"""
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
+# no chip is opened here, only the compiler: several test processes (xdist
+# workers) may load libtpu at once instead of queueing on its lockfile
+os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from mxnet_tpu.gradient_compression import quantize_2bit_pallas
+from mxnet_tpu.ops import pallas_attention as pa
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One device of a described v5e:2x2. The persistent compile cache is
+    off around these compiles: an entry for a described chip cannot be read
+    back without one."""
+    try:
+        from jax.experimental import topologies
+
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"the v5e topology cannot be described here: {e!r}")
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compiled_text(fn, *avals):
+    return jax.jit(fn).lower(*avals).compile().as_text()
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((8, 1024, 12, 64), jnp.bfloat16),     # GPT-2 small, the serve width
+    ((8, 1024, 12, 64), jnp.float32),
+    ((4, 2048, 16, 128), jnp.bfloat16),
+], ids=["gpt2s-bf16", "gpt2s-f32", "L2048-d128-bf16"])
+def test_flash_forward_compiles_for_v5e(one_chip, shape, dtype):
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    blocks = pa.flash_blocks(shape, shape, dtype, causal=True)
+    assert blocks == (128, 128)
+    text = _compiled_text(
+        lambda q, k, v: pa.flash_attention(q, k, v, causal=True,
+                                           block_q=blocks[0],
+                                           block_k=blocks[1]), x, x, x)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("with_bias", [True, False], ids=["bias", "nobias"])
+def test_block_partials_compile_for_v5e(one_chip, with_bias):
+    """The ring hop at the sp=2 shard of the serve width."""
+    shape = (2, 512, 12, 64)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    scale = 1.0 / np.sqrt(shape[-1])
+    assert pa.partial_blocks(shape, shape, jnp.bfloat16) == (128, 128)
+    if with_bias:
+        bias = jax.ShapeDtypeStruct((1, 1, 512, 512), jnp.float32,
+                                    sharding=one_chip)
+        text = _compiled_text(
+            lambda q, k, v, b: pa.block_partials_pallas(q, k, v, b, scale),
+            x, x, x, bias)
+    else:
+        text = _compiled_text(
+            lambda q, k, v: pa.block_partials_pallas(q, k, v, None, scale),
+            x, x, x)
+    assert "tpu_custom_call" in text
+
+
+def test_quantize_2bit_compiles_for_v5e(one_chip):
+    """2^20 values, compiled (not interpreted): Mosaic accepts the 2-D
+    lane-aligned blocks and the 32-bit stores. Bit-equality with
+    `quantize_2bit` is test_gradient_compression.py's."""
+    x = jax.ShapeDtypeStruct((1 << 20,), jnp.float32, sharding=one_chip)
+    text = _compiled_text(lambda g, r: quantize_2bit_pallas(g, r, 0.5), x, x)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("length,why", [
+    (1000, "no multiple-of-8 block <= 128 divides it for the ring's q block"),
+    (16384, "whole K/V would exceed the kernel's fast-memory budget"),
+], ids=["L1000", "L16384"])
+def test_shape_test_routes_to_xla(length, why):
+    """Decided by shape, before any call: these lengths never reach the
+    compiler (it refused them: block 125 is not a multiple of 8; 16.16M of
+    scoped vmem against a 16.00M limit)."""
+    shape = (1, length, 16, 128)
+    if length == 1000:
+        # 1000 = 8 x 125: the flash forward still tiles it with block 40,
+        # the ring hop (q block is a LANE dim: multiple of 128) cannot
+        assert pa.flash_blocks(shape, shape, jnp.bfloat16, True) == (40, 40)
+        assert pa.partial_blocks(shape, shape, jnp.bfloat16) is None, why
+        with pytest.raises(ValueError):
+            pa.block_partials_pallas(
+                jnp.zeros((1, length, 1, 8)), jnp.zeros((1, length, 1, 8)),
+                jnp.zeros((1, length, 1, 8)), None, 1.0, interpret=True)
+    else:
+        assert pa.flash_blocks(shape, shape, jnp.bfloat16, True) is None, why
+        assert pa.partial_blocks(shape, shape, jnp.bfloat16) is None, why
+
+
+def test_shape_test_accepts_only_blocks_the_tiling_accepts():
+    assert pa._divisor_block(1024) == 128
+    assert pa._divisor_block(100) == 100            # the full dimension
+    assert pa._divisor_block(320) == 80             # multiple of 8
+    assert pa._divisor_block(1000) == 40            # not 125
+    assert pa._divisor_block(1009) is None          # prime: no block at all
+    assert pa._divisor_block(320, multiple=128) is None
+    assert pa._divisor_block(512, multiple=128) == 128
+    # cross-length causal attention is the XLA path's (sequence ENDS align)
+    assert pa.flash_blocks((1, 32, 4, 64), (1, 64, 4, 64), jnp.float32,
+                           True) is None
+
+
+def test_flash_forward_odd_block_compiles_for_v5e(one_chip):
+    """The largest accepted block that is not 128: L=1000 tiles with 40."""
+    shape = (2, 1000, 4, 64)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    bq, bk = pa.flash_blocks(shape, shape, jnp.bfloat16, True)
+    text = _compiled_text(
+        lambda q, k, v: pa.flash_attention(q, k, v, causal=True, block_q=bq,
+                                           block_k=bk), x, x, x)
+    assert "tpu_custom_call" in text
+
+
+def test_resident_budget_boundary_compiles_for_v5e(one_chip):
+    """The longest K/V the shape test lets through (12 MiB resident of the
+    16 MiB the compiler allows) does compile."""
+    shape = (1, 12288, 8, 128)
+    assert pa.flash_blocks(shape, shape, jnp.bfloat16, True) == (128, 128)
+    assert pa.flash_blocks((1, 12416, 8, 128), (1, 12416, 8, 128),
+                           jnp.bfloat16, True) is None
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    text = _compiled_text(
+        lambda q, k, v: pa.flash_attention(q, k, v, causal=True), x, x, x)
+    assert "tpu_custom_call" in text

@@ -604,14 +604,14 @@ def test_blame_report_line(tmp_path, capsys):
 # the jax mixed-sharded-concat miscompile canary
 # ---------------------------------------------------------------------------
 
-# True = the installed jax (0.4.37) still MISCOMPILES a concat of
-# mixed-sharded operands partitioned straight to a 1-D dp layout (values
-# interleave by shard stride), so zero1's replicate-first pack stays
-# REQUIRED. When a jax upgrade fixes the partitioner this pin flips the
-# test red on purpose: flip it to False and consider retiring the
-# replicate-first constraint in parallel/zero1.py (Zero1Context
-# .traced_update pack()) — do NOT let the workaround fossilize silently.
-JAX_MIXED_SHARDED_CONCAT_MISCOMPILES = True
+# jax 0.4.37 MISCOMPILED a concat of mixed-sharded operands partitioned
+# straight to a 1-D dp layout (values interleaved by shard stride), which
+# is one of the two reasons for zero1's replicate-first pack. The installed
+# jax (0.9.0) partitions it correctly, so the pin is False; replicate-first
+# stays for its other reason (no full-bucket all-reduce per pack —
+# parallel/zero1.py Zero1Context.traced_update pack()). If this test goes
+# red the partitioner regressed: keep the workaround.
+JAX_MIXED_SHARDED_CONCAT_MISCOMPILES = False
 
 
 @pytest.mark.skipif(

@@ -253,3 +253,27 @@ def test_record_inside_pause():
         z = y * 2
     z.backward()
     assert_close(x.grad, np.array([6.0]))
+
+
+def test_setitem_keeps_the_array_on_its_device(monkeypatch):
+    """`arr[:] = host_value` builds the value on jax's DEFAULT device; the
+    array must stay on — and committed to — the device its context names.
+    (On a machine with a chip this moved cpu()/tpu(1) arrays to chip 0 under
+    an unchanged label, and left chip-0 weights uncommitted, so the second
+    fused step recompiled.) The CPU-only placement policy pins nothing, so
+    the test switches it to the accelerator-machine policy."""
+    import jax
+
+    from mxnet_tpu.ndarray import ndarray as nd_mod
+
+    if len(jax.devices()) < 2:
+        pytest.skip("needs 2 virtual devices")
+    monkeypatch.setattr(nd_mod, "_default_is_cpu", lambda: False)
+    dev1 = jax.devices()[1]
+    a = mx.nd.zeros((4, 3), ctx=mx.cpu(1))
+    assert a._data.devices() == {dev1}
+    a[:] = np.arange(12, dtype=np.float32).reshape(4, 3)
+    assert a._data.devices() == {dev1} and a._data.committed
+    a[1:3] = 7.0
+    assert a._data.devices() == {dev1} and a._data.committed
+    np.testing.assert_array_equal(a.asnumpy()[:, 0], [0, 7, 7, 9])

@@ -81,10 +81,11 @@ def _fit(stages=0, micro=0, optimizer="sgd", batch=8, epochs=2, sym=None,
         mx.random.seed(7)
         X, Y = _data()
         it = mx.io.NDArrayIter(X, Y, batch_size=batch, shuffle=False)
-        ctx = [mx.cpu(0), mx.cpu(1)] if kvstore else mx.cpu()
-        m = mx.mod.Module(sym or _mlp(), context=ctx)
+        m = mx.mod.Module(sym or _mlp(), context=mx.cpu())
+        # a kvstore OBJECT: with one device a kvstore NAME creates no store
+        # (and a list of several devices is refused by Module)
         m.fit(it, num_epoch=epochs, optimizer=optimizer,
-              kvstore=kvstore or "local",
+              kvstore=mx.kv.create(kvstore) if kvstore else "local",
               optimizer_params=(("learning_rate", 0.1),),
               initializer=mx.init.Xavier(rnd_type="gaussian", magnitude=2))
         if expect_pipeline is None:

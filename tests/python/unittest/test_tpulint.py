@@ -124,10 +124,43 @@ def test_disable_comment_requires_reason():
 
 
 # ---------------------------------------------------------------------------
-# donation-persistence
+# cache-memory-tracking
 # ---------------------------------------------------------------------------
 
-_DONATE_BAD = """
+_TRACK_BAD = """
+from mxnet_tpu.compile_cache import CompileCache
+_c = CompileCache("ops", maxsize=1024)
+"""
+
+_TRACK_BAD_ENV_SIZED = """
+from mxnet_tpu.base import getenv
+from mxnet_tpu.compile_cache import CompileCache
+_c = CompileCache("ops", maxsize=int(getenv("MXNET_OP_CACHE_SIZE")))
+"""
+
+_TRACK_GOOD = """
+from mxnet_tpu.compile_cache import CompileCache
+_small = CompileCache("steps", maxsize=64)
+_unbounded = CompileCache("executor")
+_big = CompileCache("ops", maxsize=1024, track_memory=False)
+"""
+
+
+def test_cache_memory_tracking_positive():
+    assert rules_of(lint_text(_TRACK_BAD, {"cache-memory-tracking"})) \
+        == ["cache-memory-tracking"]
+    # a size the linter cannot read (env-sized) counts as big
+    assert rules_of(lint_text(_TRACK_BAD_ENV_SIZED,
+                              {"cache-memory-tracking"})) \
+        == ["cache-memory-tracking"]
+
+
+def test_cache_memory_tracking_negative():
+    # small bounded and unbounded caches keep per-entry memory tracking
+    assert lint_text(_TRACK_GOOD, {"cache-memory-tracking"}) == []
+    # a donating builder needs no cache flag: donated executables persist
+    # in jax's on-disk cache like any other program
+    donating = """
 import jax
 
 def step_fn(cache, sig):
@@ -135,49 +168,7 @@ def step_fn(cache, sig):
         return jax.jit(lambda w, g: w - g, donate_argnums=(0,))
     return cache.get_or_build(sig, build)
 """
-
-_DONATE_GOOD = _DONATE_BAD.replace(
-    "cache.get_or_build(sig, build)",
-    "cache.get_or_build(sig, build, persistent=False)")
-
-_TRACK_BAD = """
-from mxnet_tpu.compile_cache import CompileCache
-_c = CompileCache("ops", maxsize=1024)
-"""
-
-_TRACK_GOOD = """
-from mxnet_tpu.compile_cache import CompileCache
-_small = CompileCache("steps", maxsize=64)
-_big = CompileCache("ops", maxsize=1024, track_memory=False)
-"""
-
-
-def test_donation_persistence_positive():
-    assert rules_of(lint_text(_DONATE_BAD, {"donation-persistence"})) \
-        == ["donation-persistence"]
-    assert rules_of(lint_text(_TRACK_BAD, {"donation-persistence"})) \
-        == ["donation-persistence"]
-
-
-def test_donation_persistence_negative():
-    assert lint_text(_DONATE_GOOD, {"donation-persistence"}) == []
-    # small bounded caches keep per-entry memory tracking; a donating
-    # builder in one scope must not taint a clean builder elsewhere
-    assert lint_text(_TRACK_GOOD, {"donation-persistence"}) == []
-    scoped = """
-import jax
-
-def donating(cache, sig):
-    def build():
-        return jax.jit(lambda w: w, donate_argnums=(0,))
-    return cache.get_or_build(sig, build, persistent=False)
-
-def clean(cache, sig):
-    def build():
-        return jax.jit(lambda x: x + 1)
-    return cache.get_or_build(sig, build)
-"""
-    assert lint_text(scoped, {"donation-persistence"}) == []
+    assert lint_text(donating, {"cache-memory-tracking"}) == []
 
 
 # ---------------------------------------------------------------------------
@@ -199,7 +190,7 @@ _cache = CompileCache("no-such-contract-row")
 def run(sig):
     def build():
         return jax.jit(lambda w: w * 2, donate_argnums=(0,))
-    return _cache.get_or_build(sig, build, persistent=False)
+    return _cache.get_or_build(sig, build)
 """
 
 _ALIAS_BAD_TAG = """
@@ -208,7 +199,7 @@ import jax
 def run(cache, sig):
     def build():
         return jax.jit(lambda w: w * 2, donate_argnums=(0,))
-    return cache.get_or_build(sig, build, persistent=False,
+    return cache.get_or_build(sig, build,
                               audit="no-such-contract-row")
 """
 
@@ -218,7 +209,7 @@ import jax
 def run(cache, sig):
     def build():
         return jax.jit(lambda w: w * 2, donate_argnums=(0,))
-    return cache.get_or_build(sig, build, persistent=False)
+    return cache.get_or_build(sig, build)
 """
 
 _ALIAS_GOOD_TAG = """
@@ -227,7 +218,7 @@ import jax
 def run(cache, sig):
     def build():
         return jax.jit(lambda w: w * 2, donate_argnums=(0,))
-    return cache.get_or_build(sig, build, persistent=False,
+    return cache.get_or_build(sig, build,
                               audit="zero1")
 """
 
@@ -240,7 +231,7 @@ _cache = CompileCache("generation")
 def run(sig):
     def build():
         return jax.jit(lambda w: w * 2, donate_argnums=(0,))
-    return _cache.get_or_build(sig, build, persistent=False)
+    return _cache.get_or_build(sig, build)
 """
 
 
@@ -470,7 +461,7 @@ def _run_cli(args, cwd=REPO):
 def test_cli_nonzero_on_each_rule_fixture(tmp_path):
     fixtures = {
         "executable-cache": _EXEC_BAD,
-        "donation-persistence": _DONATE_BAD,
+        "cache-memory-tracking": _TRACK_BAD,
         "gate-discipline": _GATE_BAD_THREAD,
         "tracer-hygiene": _TRACER_BAD_DECORATED,
     }

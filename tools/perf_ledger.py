@@ -25,7 +25,9 @@ Commands::
     python -m tools.perf_ledger show
         Render the ledger as one line per record.
 
-The ledger path defaults to ``PERF_LEDGER.jsonl`` at the repo root;
+The ledger path defaults to the git-ignored ``bench_ledger.jsonl`` at the
+repo root (``PERF_LEDGER.jsonl`` there is the driver's record of every PR —
+nothing in this repository writes it);
 ``MXNET_PERF_LEDGER`` overrides it (``0`` disables stamping from
 bench.py). Records are append-only: `ingest` and bench.py never rewrite
 history, and `check` never writes at all.
@@ -41,7 +43,7 @@ import time
 
 SCHEMA_VERSION = 1
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-DEFAULT_LEDGER = os.path.join(_REPO, "PERF_LEDGER.jsonl")
+DEFAULT_LEDGER = os.path.join(_REPO, "bench_ledger.jsonl")
 
 # (lane.metric, direction). "up" = bigger is better. The roofline
 # utilisation rows (mfu/mbu) are first-class regression metrics: a
@@ -441,7 +443,7 @@ def main(argv=None):
     ap = argparse.ArgumentParser(prog="perf_ledger", description=__doc__,
                                  formatter_class=argparse.RawDescriptionHelpFormatter)
     ap.add_argument("--ledger", default=None,
-                    help="ledger path (default PERF_LEDGER.jsonl at repo "
+                    help="ledger path (default bench_ledger.jsonl at repo "
                          "root; env MXNET_PERF_LEDGER overrides)")
     sub = ap.add_subparsers(dest="cmd", required=True)
     p_in = sub.add_parser("ingest", help="backfill sidecar files")

@@ -2,9 +2,8 @@
 
 Generic linters know Python; they do not know that in THIS codebase a
 ``functools.lru_cache`` holding a ``jax.jit`` executable is a silent-
-recompile bug (the BENCH_r05 failure class), that a donated-buffer
-program persisted to the on-disk XLA cache corrupts the heap of the next
-process (the PR 3 XLA:CPU incident), or that a module that parses env
+recompile bug, that a donation the hlolint audit cannot see is how
+"it silently stopped aliasing" survives review, or that a module that parses env
 vars at import breaks the "gates cost one attribute read when off"
 discipline every perf PR has leaned on since PR 7. Those rules lived in
 reviewer memory; tpulint turns them into a blocking CI gate
@@ -17,8 +16,8 @@ Rules (see :mod:`tools.tpulint.rules` for the exact semantics, and
 * ``executable-cache``    — compiled executables live in named
   :class:`~mxnet_tpu.compile_cache.CompileCache`\\ s, never
   ``lru_cache``/dict memos.
-* ``donation-persistence`` — builders that donate buffers pass
-  ``persistent=False``; big bounded caches pass ``track_memory=False``.
+* ``cache-memory-tracking`` — big bounded caches pass
+  ``track_memory=False``.
 * ``gate-discipline``     — no import-time side effects (thread starts,
   raw env parsing, device touches) outside the lazy gate helpers.
 * ``tracer-hygiene``      — no wall-clock / np.random / env reads
