@@ -29,6 +29,7 @@ import jax
 
 from . import autograd
 from . import random as _random
+from . import tracing
 from .compile_cache import CompileCache
 
 __all__ = ["CachedOp"]
@@ -94,39 +95,43 @@ class CachedOp:
     def __call__(self, *inputs, default_ctx=None):
         from .ndarray.ndarray import NDArray
 
-        arrays = []
-        nd_inputs = []
-        for a in inputs:
-            if isinstance(a, NDArray):
-                arrays.append(a._data)
-                nd_inputs.append(a)
+        with tracing.span("cached_op.dispatch", cat="gluon"):
+            arrays = []
+            nd_inputs = []
+            for a in inputs:
+                if isinstance(a, NDArray):
+                    arrays.append(a._data)
+                    nd_inputs.append(a)
+                else:
+                    arrays.append(a)
+                    nd_inputs.append(None)
+
+            train = bool(autograd.is_training())
+            recording = autograd.is_recording()
+            key = _random.next_key()
+
+            ctx = next((a._ctx for a in nd_inputs if a is not None),
+                       default_ctx)
+            # hashable dtype objects, not strings — this runs on every call.
+            # Non-array inputs key by TYPE only: a python scalar is a traced
+            # argument of the shared jit object (weak-typed), so a changing
+            # value re-specializes inside jax, never in this cache — keying
+            # on the value would compile one executable per distinct scalar
+            sig = tuple((a.shape, a.dtype) if hasattr(a, "shape")
+                        else (None, type(a).__name__) for a in arrays)
+
+            if recording:
+                outs, vjp = self._jit_fwd_vjp(train, sig)(key, *arrays)
             else:
-                arrays.append(a)
-                nd_inputs.append(None)
-
-        train = bool(autograd.is_training())
-        recording = autograd.is_recording()
-        key = _random.next_key()
-
-        ctx = next((a._ctx for a in nd_inputs if a is not None), default_ctx)
-        # hashable dtype objects, not strings — this runs on every call.
-        # Non-array inputs key by TYPE only: a python scalar is a traced
-        # argument of the shared jit object (weak-typed), so a changing
-        # value re-specializes inside jax, never in this cache — keying on
-        # the value would compile one executable per distinct scalar
-        sig = tuple((a.shape, a.dtype) if hasattr(a, "shape")
-                    else (None, type(a).__name__) for a in arrays)
-
+                outs = self._jit_fwd(train, sig)(key, *arrays)
+        outs_t = outs if isinstance(outs, tuple) else (outs,)
         if recording:
-            outs, vjp = self._jit_fwd_vjp(train, sig)(key, *arrays)
-            outs_t = outs if isinstance(outs, tuple) else (outs,)
-            out_nds = [NDArray(o, ctx) for o in outs_t]
-            autograd._record_node(
-                vjp, nd_inputs, out_nds,
-                [jax.ShapeDtypeStruct(o.shape, o.dtype) for o in outs_t])
+            with tracing.span("cached_op.record", cat="gluon"):
+                out_nds = [NDArray(o, ctx) for o in outs_t]
+                autograd._record_node(
+                    vjp, nd_inputs, out_nds,
+                    [jax.ShapeDtypeStruct(o.shape, o.dtype) for o in outs_t])
         else:
-            outs = self._jit_fwd(train, sig)(key, *arrays)
-            outs_t = outs if isinstance(outs, tuple) else (outs,)
             out_nds = [NDArray(o, ctx) for o in outs_t]
 
         self._n_out = len(out_nds)

@@ -21,6 +21,7 @@ import numpy as _np
 import jax
 import jax.numpy as jnp
 
+from . import tracing
 from .base import MXNetError
 
 __all__ = [
@@ -220,6 +221,14 @@ def _zero_ct(aval):
 
 def _run_backward(heads, head_grads, retain_graph, deposit=True):
     tape = _st().tape
+    # one span a backward (child: the deposit loop) — never one a node
+    with tracing.span("autograd.backward", cat="autograd",
+                      nodes=len(tape)):
+        return _backward_pass(tape, heads, head_grads, retain_graph,
+                              deposit)
+
+
+def _backward_pass(tape, heads, head_grads, retain_graph, deposit):
     grad_map = {}  # id(NDArray) -> jnp cotangent
 
     for h, hg in zip(heads, head_grads):
@@ -260,11 +269,12 @@ def _run_backward(heads, head_grads, retain_graph, deposit=True):
 
     # deposit into marked variables honoring grad_req
     if deposit:
-        for node in tape:
-            for nd_in in node.inputs:
-                _deposit(nd_in, grad_map)
-        for h in heads:
-            _deposit(h, grad_map)
+        with tracing.span("autograd.deposit", cat="autograd"):
+            for node in tape:
+                for nd_in in node.inputs:
+                    _deposit(nd_in, grad_map)
+            for h in heads:
+                _deposit(h, grad_map)
 
     if not retain_graph:
         _clear_tape()

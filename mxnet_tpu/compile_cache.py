@@ -22,6 +22,16 @@ Counters are recorded unconditionally (one lock-protected increment per
 step — noise next to a dispatch) so cache accounting works even when the
 wider telemetry plane is off.
 
+What jax compiles on its own — a re-trace of a cached callable whose
+argument changed layout, commitment or weak type, an eager op's program —
+never passes through ``get_or_build``, so the ledger above cannot see it
+(PR 21: 40.7 s at step 2, unseen). ``jax.monitoring`` duration listeners,
+registered once at import, count those too: ``compile.jax_traces`` (every
+jaxpr trace, including a re-trace that then hits jax's executable cache),
+``compile.jax_backend_compiles`` (XLA compiles and persistent-cache loads)
+and ``compile.jax_seconds``; :func:`jax_events` keeps the last few
+thousand with their end times, so a stall can be matched to a re-trace.
+
 Persistent on-disk XLA cache: placed from OUTSIDE the program. When
 ``JAX_COMPILATION_CACHE_DIR`` is set jax itself reads it and nothing here
 sets a directory; otherwise the cache lives at one fixed path inside the
@@ -32,6 +42,7 @@ included.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import os
 import threading
@@ -44,7 +55,7 @@ from .base import getenv, register_env
 
 __all__ = ["CompileCache", "persistent_cache_dir", "stats", "named_stats",
            "name_totals", "all_caches", "donation_warnings_suppressed",
-           "dump_audit", "audit_ledger"]
+           "dump_audit", "audit_ledger", "jax_events"]
 
 register_env("MXNET_FUSED_STEP", True,
              "fuse forward+backward+optimizer update into one jitted XLA "
@@ -82,6 +93,32 @@ _audit_hooked = [False]
 # happen to still be alive (a collected Predictor would silently subtract
 # its history and break delta-based zero-compile assertions)
 _name_totals = {}
+
+
+# jax's own trace/compile events (jax.monitoring), newest last
+_JAX_EVENT_KINDS = {
+    "/jax/core/compile/jaxpr_trace_duration":
+        ("jaxpr_trace", "compile.jax_traces"),
+    "/jax/core/compile/backend_compile_duration":
+        ("backend_compile", "compile.jax_backend_compiles"),
+}
+_jax_events = collections.deque(maxlen=16384)
+
+
+def _on_jax_duration(event, duration, **_):
+    kind = _JAX_EVENT_KINDS.get(event)
+    if kind is None:
+        return
+    telemetry.counter(kind[1]).inc()
+    telemetry.counter("compile.jax_seconds").inc(duration)
+    _jax_events.append((time.perf_counter(), kind[0], duration))
+
+
+def jax_events():
+    """``(perf_counter at end, kind, seconds)`` of jax's own recent traces
+    (``"jaxpr_trace"``) and XLA compiles or persistent-cache loads
+    (``"backend_compile"``), oldest first, bounded."""
+    return list(_jax_events)
 
 
 def _totals(name):
@@ -718,4 +755,11 @@ def _dump_audit_atexit():
         pass
 
 
+def _listen_to_jax():
+    from jax import monitoring
+
+    monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
 persistent_cache_dir()
+_listen_to_jax()

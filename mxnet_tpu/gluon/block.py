@@ -25,6 +25,7 @@ from ..context import Context, cpu, current_context
 from .. import ndarray as nd
 from ..ndarray import NDArray
 from .. import name as _name
+from .. import tracing
 from .._cached_op import CachedOp
 from .parameter import Parameter, ParameterDict, DeferredInitializationError
 
@@ -544,18 +545,22 @@ class HybridBlock(Block):
                 self._build_cache()
                 flat_args, _ = _flatten(args, "input")
         params = self._cached_graph_params
-        try:
-            param_nds = [p.data() for p in params]
-        except DeferredInitializationError:
-            self._deferred_infer_shape(*args)
-            for p in params:
-                if p._deferred_init:
-                    p._finish_deferred_init()
-            param_nds = [p.data() for p in params]
-        out = self._cached_op(*(param_nds + list(flat_args)))
-        if isinstance(out, NDArray):
-            out = [out]
-        ret, _ = _regroup(list(out), self._out_fmt)
+        # one span a call (children: gather here, dispatch and record in
+        # CachedOp.__call__): where the host's share of a gluon step goes
+        with tracing.span("cached_op.call", cat="gluon", params=len(params)):
+            with tracing.span("cached_op.gather", cat="gluon"):
+                try:
+                    param_nds = [p.data() for p in params]
+                except DeferredInitializationError:
+                    self._deferred_infer_shape(*args)
+                    for p in params:
+                        if p._deferred_init:
+                            p._finish_deferred_init()
+                    param_nds = [p.data() for p in params]
+            out = self._cached_op(*(param_nds + list(flat_args)))
+            if isinstance(out, NDArray):
+                out = [out]
+            ret, _ = _regroup(list(out), self._out_fmt)
         return ret
 
     def _deferred_infer_shape(self, *args):

@@ -22,6 +22,7 @@ into ordinary per-parameter states before pickling and re-shards on load.
 from __future__ import annotations
 
 from .. import optimizer as opt
+from .. import tracing
 from ..model import _create_kvstore
 from .parameter import ParameterDict, Parameter
 
@@ -172,14 +173,18 @@ class Trainer:
     def step(self, batch_size, ignore_stale_grad=False):
         """Make one parameter-update step: rescale by 1/batch_size, allreduce
         grads, update (parity trainer.py:298)."""
-        rescale_grad = self._scale / batch_size
-        self._check_and_rescale_grad(rescale_grad)
-        if not self._kv_initialized:
-            self._init_kvstore()
-        if self._params_to_init:
-            self._init_params()
-        self._allreduce_grads()
-        self._update(ignore_stale_grad)
+        with tracing.span("trainer.step", cat="gluon",
+                          params=len(self._params)):
+            rescale_grad = self._scale / batch_size
+            self._check_and_rescale_grad(rescale_grad)
+            if not self._kv_initialized:
+                self._init_kvstore()
+            if self._params_to_init:
+                self._init_params()
+            with tracing.span("trainer.allreduce", cat="gluon"):
+                self._allreduce_grads()
+            with tracing.span("trainer.update", cat="gluon"):
+                self._update(ignore_stale_grad)
 
     def _check_and_rescale_grad(self, scale):
         if self._update_on_kvstore and self._distributed and self._kv_initialized:
@@ -202,7 +207,8 @@ class Trainer:
             "allreduce_grads() when parameters are updated on kvstore " \
             "is not supported. Try setting `update_on_kvstore` " \
             "to False when creating trainer."
-        self._allreduce_grads()
+        with tracing.span("trainer.allreduce", cat="gluon"):
+            self._allreduce_grads()
 
     def _allreduce_grads(self):
         """Bucketed by default (`parallel/grad_sync.py`): dense grads ride
@@ -292,7 +298,8 @@ class Trainer:
             "is not supported. Try setting `update_on_kvstore` " \
             "to False when creating trainer."
         self._check_and_rescale_grad(self._scale / batch_size)
-        self._update(ignore_stale_grad)
+        with tracing.span("trainer.update", cat="gluon"):
+            self._update(ignore_stale_grad)
 
     def _update(self, ignore_stale_grad=False):
         updates = [[] for _ in self._updaters]

@@ -187,6 +187,23 @@ class BaseModule:
             return output_list2
         return output_list
 
+    def _fetch_next_batch(self, data_iter, sparse_row_id_fn, stage):
+        """`fit`'s data phase as one live span: ``step.data`` holding
+        ``step.data.next`` (the iterator) and ``step.data.stage``
+        (``prepare``, and ``stage_batch`` where the overlap order stages
+        the batch while the step runs). None when the epoch is over."""
+        with tracing.span("step.data", cat="train"):
+            with tracing.span("step.data.next", cat="train"):
+                try:
+                    batch = next(data_iter)
+                except StopIteration:
+                    return None
+            with tracing.span("step.data.stage", cat="train"):
+                self.prepare(batch, sparse_row_id_fn=sparse_row_id_fn)
+                if stage:
+                    self.stage_batch(batch)
+        return batch
+
     def fit(self, train_data, eval_data=None, eval_metric="acc",
             epoch_end_callback=None, batch_end_callback=None, kvstore="local",
             optimizer="sgd", optimizer_params=(("learning_rate", 0.01),),
@@ -252,32 +269,37 @@ class BaseModule:
                     # wall time actually goes (data wait / fwd-bwd dispatch /
                     # optimizer update / metric sync). The metric update fetches
                     # values, so it doubles as the device sync segment.
-                    # tracing: the same boundaries become a span tree under one
+                    # tracing: the same boundaries are live spans under one
                     # "step" root whose trace id is DETERMINISTIC in
                     # (epoch, step) — every dist worker labels the same step
                     # identically, so tools/trace_merge.py can join their
                     # dumps. Nested spans (grad_sync issue/drain, fused
-                    # dispatch, zero1 phases) parent to the root through the
-                    # context var; the finished tree feeds the slow-step
-                    # flight recorder.
+                    # dispatch, zero1 phases) parent to the phase they run
+                    # in through the context var; the finished tree feeds
+                    # the slow-step flight recorder.
                     tele = telemetry._enabled
                     trc = tracing._enabled
-                    timed = tele or trc or observatory._enabled
+                    timed = tele or observatory._enabled
                     step_span = tracing.span(
                         "step", cat="train",
                         trace_id=(tracing.deterministic_trace_id(
                             "fit", epoch, nbatch) if trc else None),
                         epoch=epoch, step=nbatch)
                     with step_span:
+                        # the four phase children are LIVE spans (also
+                        # `mx:step.*` in any jax.profiler trace); the perf
+                        # marks beside them feed the step.*_us histograms
                         t0 = time.perf_counter() if timed else 0.0
-                        # fused path: fwd+bwd+update as one XLA computation
-                        # (its whole cost lands in the fwdbwd segment)
-                        fused = self.fused_step(data_batch)
-                        if not fused:
-                            self.forward_backward(data_batch)
+                        with tracing.span("step.fwdbwd", cat="train"):
+                            # fused path: fwd+bwd+update as one XLA
+                            # computation (its whole cost lands here)
+                            fused = self.fused_step(data_batch)
+                            if not fused:
+                                self.forward_backward(data_batch)
                         t_fb = time.perf_counter() if timed else 0.0
-                        if not fused:
-                            self.update()
+                        with tracing.span("step.update", cat="train"):
+                            if not fused:
+                                self.update()
                         t_up = time.perf_counter() if timed else 0.0
                         if tele:
                             telemetry.gauge("step.fused").set(1 if fused else 0)
@@ -292,27 +314,29 @@ class BaseModule:
                             capture = self.capture_metric_update(
                                 data_batch.label)
                         if capture is None:
-                            if pending_metric is not None:
-                                # mixed-mode seam: settle the deferred step
-                                # before the eager one updates the metric
-                                pending_metric(eval_metric)
-                                pending_metric = None
-                                self.retire_staged()
-                            if isinstance(data_batch, list):
-                                self.update_metric(
-                                    eval_metric,
-                                    [db.label for db in data_batch],
-                                    pre_sliced=True)
-                            else:
-                                self.update_metric(eval_metric,
-                                                   data_batch.label)
+                            with tracing.span("step.sync", cat="train"):
+                                if pending_metric is not None:
+                                    # mixed-mode seam: settle the deferred
+                                    # step before the eager one updates the
+                                    # metric
+                                    pending_metric(eval_metric)
+                                    pending_metric = None
+                                    self.retire_staged()
+                                if isinstance(data_batch, list):
+                                    self.update_metric(
+                                        eval_metric,
+                                        [db.label for db in data_batch],
+                                        pre_sliced=True)
+                                else:
+                                    self.update_metric(eval_metric,
+                                                       data_batch.label)
                             t_sync = time.perf_counter() if timed else 0.0
-                            try:
-                                next_data_batch = next(data_iter)
-                                self.prepare(next_data_batch,
-                                             sparse_row_id_fn=sparse_row_id_fn)
-                            except StopIteration:
+                            fetched = self._fetch_next_batch(
+                                data_iter, sparse_row_id_fn, stage=False)
+                            if fetched is None:
                                 end_of_batch = True
+                            else:
+                                next_data_batch = fetched
                             t_end = t_data = time.perf_counter() if timed \
                                 else 0.0
                             marks = (("fwdbwd", t0, t_fb),
@@ -324,24 +348,25 @@ class BaseModule:
                             # batch t+1 while step t executes, then apply
                             # step t-1's metric thunk (its outputs finished
                             # at least one step ago, so this rarely blocks)
-                            try:
-                                next_data_batch = next(data_iter)
-                                self.prepare(next_data_batch,
-                                             sparse_row_id_fn=sparse_row_id_fn)
-                                self.stage_batch(next_data_batch)
-                            except StopIteration:
+                            fetched = self._fetch_next_batch(
+                                data_iter, sparse_row_id_fn, stage=True)
+                            if fetched is None:
                                 end_of_batch = True
+                            else:
+                                next_data_batch = fetched
                             t_data = time.perf_counter() if timed else 0.0
-                            if pending_metric is not None:
-                                pending_metric(eval_metric)
-                                self.retire_staged()
-                            pending_metric = capture
-                            if end_of_batch:
-                                # epoch boundary is a sync point: flush so
-                                # epoch-end metrics match lockstep bit-exact
-                                pending_metric(eval_metric)
-                                pending_metric = None
-                                self.retire_staged()
+                            with tracing.span("step.sync", cat="train"):
+                                if pending_metric is not None:
+                                    pending_metric(eval_metric)
+                                    self.retire_staged()
+                                pending_metric = capture
+                                if end_of_batch:
+                                    # epoch boundary is a sync point: flush
+                                    # so epoch-end metrics match lockstep
+                                    # bit-exact
+                                    pending_metric(eval_metric)
+                                    pending_metric = None
+                                    self.retire_staged()
                             t_end = t_sync = time.perf_counter() if timed \
                                 else 0.0
                             marks = (("fwdbwd", t0, t_fb),
@@ -350,17 +375,7 @@ class BaseModule:
                                      ("sync", t_data, t_sync))
                             if tele:
                                 telemetry.counter("overlap.steps").inc()
-                        if trc:
-                            # the phase children, reconstructed from the perf
-                            # marks (one wall-clock read anchors them all)
-                            end_us = tracing.now_us()
-                            for seg, a, b in marks:
-                                tracing.emit_span(
-                                    "step." + seg,
-                                    end_us - (t_end - a) * 1e6,
-                                    (b - a) * 1e6, cat="train",
-                                    parent=step_span)
-                            step_span.set(fused=fused)
+                        step_span.set(fused=fused)
                     if trc:
                         tracing.flight_recorder.observe(step_span.tree())
                     if observatory._enabled:

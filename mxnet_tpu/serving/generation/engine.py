@@ -54,9 +54,18 @@ Telemetry rides ``serving.generation.*`` (live-slot gauge, tokens/s,
 TTFT/tick histograms, per-reason eviction counters, derived
 ``slot_fill_ratio``, plus ``prefix.{hits,misses,forks,inserts,
 evictions}``/``prefix.cached_tokens`` and ``spec.{proposed,accepted,
-rolled_back,committed}`` with derived ``spec.acceptance_ratio``); tracing
-builds one span tree per session (root → queued → fork/prefill → decode
-ticks → evict); the slab (and the checkpoint draft's slab) registers
+rolled_back,committed}`` with derived ``spec.acceptance_ratio``).
+``queue_wait_us`` is submit → start of the admission (host events at both
+ends). ``prefill_us`` is prefill dispatch → first token on the HOST: under
+``MXNET_OVERLAP=1`` (the default) the tick dispatches its decode first and
+the prefill queues behind it on the device, so it reads one decode plus
+the prefill — the prefill's own device time is only in a device trace.
+Tracing: every tick is one live span tree (``generation.tick`` → sweep /
+decode / admit → prefill → prefill.fetch / commit → commit.fetch, each
+also ``mx:<name>`` in any ``jax.profiler`` trace), nothing per session per
+tick; with ``MXNET_TRACING=1`` each session also gets a root
+(``generation.session``, ``tokens=<n>`` at finish) with queued / prefill /
+evict children. The slab (and the checkpoint draft's slab) registers
 under the ``kv_cache`` memory-census category — forked rows live inside
 the same slab buffers, so the census never double-counts them.
 """
@@ -969,10 +978,12 @@ class GenerationEngine:
         decoded = False
         dec_s = None
         t0 = time.perf_counter()
-        # the tick's own span tree (admit/decode children via the context
+        # the tick's own span tree (sweep / decode / admit > prefill >
+        # prefill.fetch / commit > commit.fetch, children via the context
         # var; per-SESSION spans keep their explicit session parents) —
-        # observed into tracing.tick_recorder, the generation analog of
-        # the slow-step flight recorder (/trace serves it as worst_tick)
+        # `mx:generation.*` in any jax.profiler trace, and observed into
+        # tracing.tick_recorder, the generation analog of the slow-step
+        # flight recorder (/trace serves it as worst_tick)
         tick_span = tracing.span("generation.tick", cat="generation",
                                  live=self._live, queued=len(self._queue))
         with tick_span:
@@ -994,17 +1005,7 @@ class GenerationEngine:
                     decoded = self._live > 0
                     t_dec = time.perf_counter()
                     pending = self._decode_dispatch()
-                    now = time.monotonic()
-                    for req in self._queue.expire(now):
-                        self._fail_queued(req.payload, now)
-                    for slot, sess in enumerate(self._sessions):
-                        if (sess is not None and sess.deadline is not None
-                                and now >= sess.deadline):
-                            self._evict(
-                                slot, "deadline", DeadlineExceededError(
-                                    f"session deadline passed after "
-                                    f"{sess.generated} generated token(s)"))
-                    self._sweep_parked(now)
+                    self._sweep()
                     self._admit()
                     if pending is not None:
                         self._decode_commit(pending)
@@ -1014,17 +1015,7 @@ class GenerationEngine:
                     # order still leaves outside device time
                     dec_s = time.perf_counter() - t_dec
                 else:
-                    now = time.monotonic()
-                    for req in self._queue.expire(now):
-                        self._fail_queued(req.payload, now)
-                    for slot, sess in enumerate(self._sessions):
-                        if (sess is not None and sess.deadline is not None
-                                and now >= sess.deadline):
-                            self._evict(
-                                slot, "deadline", DeadlineExceededError(
-                                    f"session deadline passed after "
-                                    f"{sess.generated} generated token(s)"))
-                    self._sweep_parked(now)
+                    self._sweep()
                     self._admit()
                     decoded = self._live > 0
                     t_dec = time.perf_counter()
@@ -1103,6 +1094,22 @@ class GenerationEngine:
                     self._tokens_window / (now - self._rate_t0))
                 self._tokens_window = 0
                 self._rate_t0 = now
+
+    def _sweep(self):
+        """The tick's deadline sweep: queued requests past their deadline,
+        live sessions past theirs, and the park region."""
+        with tracing.span("generation.sweep", cat="generation"):
+            now = time.monotonic()
+            for req in self._queue.expire(now):
+                self._fail_queued(req.payload, now)
+            for slot, sess in enumerate(self._sessions):
+                if (sess is not None and sess.deadline is not None
+                        and now >= sess.deadline):
+                    self._evict(
+                        slot, "deadline", DeadlineExceededError(
+                            f"session deadline passed after "
+                            f"{sess.generated} generated token(s)"))
+            self._sweep_parked(now)
 
     def _free_slots(self):
         """Session slots holding neither a live session nor a cached
@@ -1229,30 +1236,42 @@ class GenerationEngine:
                     # capacity prompts fall back to the always-in-bounds
                     # full prefill instead
                     node = None
+            # queue wait: submit -> start of this admission. Both ends are
+            # host events, so it is sound under the overlap order (the
+            # prefill_us histogram below is not: see its record site)
+            waited_us = (now - sess.stream.submitted_at) * 1e6
+            bucket = self.bucket_for(n - m if node is not None else n)
             t_pf = time.perf_counter()
             trc = tracing._enabled and sess.span is not None
             if trc:
                 # queue-wait child reconstructed from the submit instant
-                tracing.emit_span("generation.queued", sess.span.t0,
-                                  tracing.now_us() - sess.span.t0,
-                                  cat="generation", parent=sess.span)
                 t_pf_us = tracing.now_us()
+                tracing.emit_span("generation.queued", sess.span.t0,
+                                  t_pf_us - sess.span.t0,
+                                  cat="generation", parent=sess.span)
             try:
-                if node is not None:
-                    tok = self._fork_admit(sess, slot, node, m)
-                else:
-                    bucket = self.bucket_for(n)
-                    padded = np.zeros(bucket, np.int32)
-                    padded[:n] = sess.prompt
-                    fn = self._prefill_fn(bucket)
-                    tok, self._ck, self._cv = fn(
-                        self._params, self._ck, self._cv,
-                        jnp.asarray(padded), jnp.asarray(n, jnp.int32),
-                        jnp.asarray(slot, jnp.int32))
-                    tok = int(tok)
-                    if tele and self._prefix is not None:
-                        telemetry.counter(
-                            "serving.generation.prefix.misses").inc()
+                with tracing.span("generation.prefill", cat="generation",
+                                  bucket=bucket, slot=slot,
+                                  waited_us=int(waited_us)):
+                    if node is not None:
+                        tok = self._fork_admit(sess, slot, node, m, bucket)
+                    else:
+                        padded = np.zeros(bucket, np.int32)
+                        padded[:n] = sess.prompt
+                        fn = self._prefill_fn(bucket)
+                        tok, self._ck, self._cv = fn(
+                            self._params, self._ck, self._cv,
+                            jnp.asarray(padded), jnp.asarray(n, jnp.int32),
+                            jnp.asarray(slot, jnp.int32))
+                        if tele and self._prefix is not None:
+                            telemetry.counter(
+                                "serving.generation.prefix.misses").inc()
+                    with tracing.span("generation.prefill.fetch",
+                                      cat="generation"):
+                        # blocks until the prefill ran: under the overlap
+                        # order that is behind the decode this tick
+                        # dispatched first
+                        tok = int(tok)
             except Exception as e:
                 # the popped session is in neither the queue nor a slot —
                 # the tick handler only evicts ADMITTED sessions, so fail
@@ -1266,11 +1285,12 @@ class GenerationEngine:
                     sess.span.set(error=repr(e), reason="error").finish()
                 raise
             if trc:
+                # the same instants as the live span, under the session root
                 tracing.emit_span("generation.prefill", t_pf_us,
                                   tracing.now_us() - t_pf_us,
                                   cat="generation", parent=sess.span,
-                                  bucket=self.bucket_for(n - sess.prefix_len),
-                                  slot=slot, cached_prefix=sess.prefix_len)
+                                  bucket=bucket, slot=slot,
+                                  cached_prefix=sess.prefix_len)
             sess.slot = slot
             # pinned for the session's whole life: after a swap the tick
             # keeps decoding this session under these exact weights
@@ -1286,6 +1306,14 @@ class GenerationEngine:
             self._deliver(sess, tok, first=True)
             if tele:
                 telemetry.counter("serving.generation.prefills").inc()
+                telemetry.histogram(
+                    "serving.generation.queue_wait_us").record(waited_us)
+                # prefill dispatch -> first token on the host. Under
+                # MXNET_OVERLAP=1 (the default) the tick dispatched its
+                # decode first and the prefill queues behind it on the
+                # device, so this reads ONE DECODE PLUS the prefill; the
+                # prefill's own device time is the trace's (the
+                # benchmark's prefill_ms_p50)
                 telemetry.histogram("serving.generation.prefill_us").record(
                     (time.perf_counter() - t_pf) * 1e6)
             # cache the full prompt's KV for future sessions while a free
@@ -1311,15 +1339,15 @@ class GenerationEngine:
             if time.perf_counter() - t0 > self._tick_budget_s:
                 return
 
-    def _fork_admit(self, sess, slot, node, m):
+    def _fork_admit(self, sess, slot, node, m, bucket):
         """Cache-hit admission: pin the entry, fork its slot onto the
-        session's, suffix-prefill the unmatched tail at offset ``m``.
-        Returns the first sampled token."""
+        session's, suffix-prefill the unmatched tail (padded to
+        ``bucket``) at offset ``m``. Returns the first sampled token,
+        still on the device (the caller fetches it)."""
         import jax.numpy as jnp
 
         suffix = sess.prompt[m:]
         ns = int(suffix.size)
-        bucket = self.bucket_for(ns)
         padded = np.zeros(bucket, np.int32)
         padded[:ns] = suffix
         self._prefix.acquire(node)
@@ -1342,7 +1370,7 @@ class GenerationEngine:
             telemetry.counter("serving.generation.prefix.forks").inc()
             telemetry.counter(
                 "serving.generation.prefix.cached_tokens_served").inc(m)
-        return int(tok)
+        return tok
 
     def _decode(self):
         """ONE fused step over the whole slab; every live session
@@ -1407,15 +1435,17 @@ class GenerationEngine:
         (overlap-window evict/re-admit) is skipped — its slab write is
         masked garbage the next prefill overwrites."""
         kind, pending = state
-        if kind == "spec":
-            self._spec_commit(pending)
-            return
-        trc = tracing._enabled
+        with tracing.span("generation.commit", cat="generation", kind=kind):
+            if kind == "spec":
+                self._spec_commit(pending)
+            else:
+                self._plain_commit(pending)
+
+    def _plain_commit(self, pending):
         live = 0
         for slots, snap, toks in pending:
-            toks = np.asarray(toks)
-            if trc:
-                t_us = tracing.now_us()
+            with tracing.span("generation.commit.fetch", cat="generation"):
+                toks = np.asarray(toks)     # blocks until the decode ran
             for slot, dispatched in zip(slots, snap):
                 sess = self._sessions[slot]
                 if sess is None or sess is not dispatched:
@@ -1425,10 +1455,6 @@ class GenerationEngine:
                 self._lengths[slot] += 1
                 tok = int(toks[slot])
                 self._last_tok[slot] = tok
-                if trc and sess.span is not None:
-                    tracing.emit_span("generation.decode_tick", t_us, 0.0,
-                                      cat="generation", parent=sess.span,
-                                      position=int(self._lengths[slot]))
                 self._deliver(sess, tok)
                 self._maybe_finish(slot)
             if telemetry._enabled:
@@ -1478,12 +1504,10 @@ class GenerationEngine:
         props, pending = state
         k = self._spec_k
         tele = telemetry._enabled
-        trc = tracing._enabled
         live = accepted = committed_total = 0
         for slots, snap, toks in pending:
-            toks = np.asarray(toks)                             # [S, k+1]
-            if trc:
-                t_us = tracing.now_us()
+            with tracing.span("generation.commit.fetch", cat="generation"):
+                toks = np.asarray(toks)                         # [S, k+1]
             for slot, dispatched in zip(slots, snap):
                 sess = self._sessions[slot]
                 if sess is None or sess is not dispatched:
@@ -1507,11 +1531,6 @@ class GenerationEngine:
                     self._maybe_finish(slot)
                     if self._sessions[slot] is None:
                         break
-                if trc and sess.span is not None:
-                    tracing.emit_span("generation.decode_tick", t_us, 0.0,
-                                      cat="generation", parent=sess.span,
-                                      position=int(self._lengths[slot]),
-                                      committed=len(committed), accepted=a)
                 if (self._sessions[slot] is not None
                         and self._draft is not None):
                     self._draft.on_commit(slot, committed)

@@ -12,6 +12,7 @@ Covers the fused-step PR end to end:
   per-epoch recompile churn.
 """
 import os
+import time
 
 import numpy as np
 import pytest
@@ -508,3 +509,46 @@ def test_compile_cache_stats_aggregate():
     s = compile_cache.stats()
     assert set(s) == {"entries", "hits", "misses", "compile_seconds", "caches"}
     assert s["entries"] == sum(p["entries"] for p in s["caches"])
+
+
+def test_jax_retrace_is_counted_where_the_ledger_is_blind():
+    """A cached callable that jax re-traces (another shape here; a changed
+    layout or commitment in PR 21) is a HIT for the CompileCache ledger —
+    compile.jax_traces / compile.jax_backend_compiles / jax_events() see
+    it. A call that re-uses the executable moves none of them."""
+    import jax
+
+    from mxnet_tpu import compile_cache
+    from mxnet_tpu.compile_cache import CompileCache
+
+    def value(name):
+        m = telemetry.get(name)
+        return m.value if m is not None else 0
+
+    def counts():
+        return (value("compile.jax_traces"),
+                value("compile.jax_backend_compiles"),
+                sum(1 for _, kind, _ in compile_cache.jax_events()
+                    if kind == "jaxpr_trace"))
+
+    c = CompileCache("test_retrace")
+    # a lax primitive: a jnp function is a nested jit with a trace event
+    # of its own
+    fn = c.get_or_build("k", lambda: jax.jit(lambda x: jax.lax.add(x, x)))
+    x3, x4 = np.ones(3, np.float32), np.ones(4, np.float32)
+    fn(x3)                                  # first call: trace + compile
+    base = counts()
+    s0 = value("compile.jax_seconds")
+    assert np.asarray(c.get_or_build("k", None)(x3))[0] == 2.0  # cached
+    assert counts() == base
+    t_before = time.perf_counter()
+    assert c.get_or_build("k", None)(x4).shape == (4,)   # forced re-trace
+    t_after = time.perf_counter()
+    traces, compiles, logged = counts()
+    assert (traces - base[0], compiles - base[1], logged - base[2]) \
+        == (1, 1, 1)
+    assert c.misses == 1 and c.hits == 2    # the ledger saw only hits
+    assert value("compile.jax_seconds") > s0
+    t, kind, seconds = compile_cache.jax_events()[-1]
+    assert kind == "backend_compile" and seconds > 0
+    assert t_before <= t <= t_after         # perf_counter at its end

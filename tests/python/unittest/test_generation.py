@@ -23,6 +23,9 @@ Covers the generation PR end to end:
 * router — occupancy-balanced placement across engine replicas;
 * observability — serving.generation.* telemetry, the kv_cache memory
   census category, and the tools/telemetry_report.py summary line;
+* tracing — one tick's live span tree under both overlap orders (no
+  per-session-per-tick span), the session root's token count, and the
+  queue-wait histogram recorded once per prefill;
 * acceptance — 1k concurrent ragged streaming sessions complete with
   zero steady-state compiles and sampled bit-exact parity vs sequential.
 """
@@ -35,7 +38,7 @@ import pytest
 
 import jax
 
-from mxnet_tpu import memory, serving, telemetry
+from mxnet_tpu import memory, serving, telemetry, tracing
 from mxnet_tpu import parallel as par
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.models import TransformerLM, TransformerLMConfig
@@ -464,6 +467,90 @@ def test_generation_telemetry_and_report(lm48, tele, tmp_path, capsys):
 # ---------------------------------------------------------------------------
 # acceptance: 1k concurrent ragged streaming sessions
 # ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("overlap", ["0", "1"], ids=["lockstep", "overlap"])
+def test_tick_span_tree(lm48, monkeypatch, overlap):
+    """A tick that decodes a live session and admits a queued one is ONE
+    live span tree: tick > sweep, decode, admit > prefill > prefill.fetch,
+    commit > commit.fetch, in the order the tick did them. Nothing is
+    emitted per session per tick (generation.decode_tick is gone); the
+    session root carries its token count instead."""
+    monkeypatch.setenv("MXNET_OVERLAP", overlap)
+    lm, params = lm48
+    a, b = _prompts(2, seed=6)
+    prev = tracing.enabled()
+    tracing.enable()
+    tracing.reset()
+    try:
+        with GenerationEngine(lm, params, max_slots=2, max_len=48,
+                              buckets=(16,)) as eng:
+            sa = eng.submit(a, max_new_tokens=30)
+            next(iter(sa))                  # A is live and decoding
+            sb = eng.submit(b, max_new_tokens=4)
+            assert len(sa.result(timeout=60)) == 30
+            assert len(sb.result(timeout=60)) == 4
+        spans = [e for e in tracing.peek_events() if e.get("ph") == "X"]
+        worst = tracing.tick_recorder.worst()
+    finally:
+        tracing.reset()
+        tracing.enable(prev)
+    assert not [e for e in spans if e["name"] == "generation.decode_tick"]
+
+    def kids(parent):
+        return sorted((e for e in spans if e["args"].get("parent_id")
+                       == parent["args"]["span_id"]), key=lambda e: e["ts"])
+
+    def names(events):
+        return [e["name"] for e in events]
+
+    ticks = [e for e in spans if e["name"] == "generation.tick"]
+    both = [t for t in ticks
+            if "generation.decode" in names(kids(t))
+            and any(names(kids(k)) == ["generation.prefill"]
+                    for k in kids(t) if k["name"] == "generation.admit")]
+    assert both, [names(kids(t)) for t in ticks]
+    tick = both[0]
+    order = (["generation.sweep", "generation.admit", "generation.decode",
+              "generation.commit"] if overlap == "0" else
+             ["generation.decode", "generation.sweep", "generation.admit",
+              "generation.commit"])
+    assert names(kids(tick)) == order
+    by = {e["name"]: e for e in kids(tick)}
+    (prefill,) = kids(by["generation.admit"])
+    assert {"bucket", "slot", "waited_us"} <= set(prefill["args"])
+    assert prefill["args"]["bucket"] == 16 and prefill["args"]["waited_us"] >= 0
+    assert names(kids(prefill)) == ["generation.prefill.fetch"]
+    assert names(kids(by["generation.commit"])) == ["generation.commit.fetch"]
+    assert by["generation.commit"]["args"]["kind"] == "plain"
+    # every tick: a handful of spans, whatever the number of live sessions
+    assert max(len(kids(t)) for t in ticks) <= 4
+    assert worst is not None and worst["name"] == "generation.tick"
+    # the per-session trees: queued + prefill + evict under a root that
+    # carries the session's token count
+    roots = [e for e in spans if e["name"] == "generation.session"]
+    assert sorted(r["args"]["tokens"] for r in roots) == [4, 30]
+    for r in roots:
+        assert names(kids(r)) == ["generation.queued", "generation.prefill",
+                                  "generation.evict"]
+
+
+def test_queue_wait_recorded_once_per_prefill(lm48, tele):
+    """serving.generation.queue_wait_us (submit -> start of the admission)
+    is recorded beside the prefills counter: same count, and a session
+    that waited for a slot shows a longer wait than one that did not."""
+    lm, params = lm48
+    hist = telemetry.histogram("serving.generation.queue_wait_us")
+    n0, p0 = hist.count, _counter("serving.generation.prefills")
+    with GenerationEngine(lm, params, max_slots=1, max_len=48,
+                          buckets=(16,)) as eng:
+        streams = [eng.submit(p, max_new_tokens=6)
+                   for p in _prompts(5, seed=7)]
+        for s in streams:
+            assert len(s.result(timeout=60)) == 6
+    assert _counter("serving.generation.prefills") - p0 == 5
+    assert hist.count - n0 == 5
+    assert hist.snapshot()["max"] > 0
 
 
 def test_1k_sessions_acceptance(tele):

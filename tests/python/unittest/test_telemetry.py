@@ -110,7 +110,11 @@ def test_disabled_paths_record_nothing(tmp_path):
     engine.wait_all()
     mx.nd.load(str(tmp_path / "off.params"))
     snap = telemetry.snapshot()
-    assert snap["counters"] == {} and snap["histograms"] == {}
+    # compile.* counters are unconditional by design (compile_cache.py):
+    # the first nd.ones of a process is a jax trace + compile, which
+    # compile.jax_* counts with the telemetry plane off
+    gated = {k for k in snap["counters"] if not k.startswith("compile.")}
+    assert gated == set() and snap["histograms"] == {}
 
 
 # ---------------------------------------------------------------------------

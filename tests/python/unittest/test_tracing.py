@@ -9,8 +9,12 @@ Covers:
   cross-request leakage;
 * the fit path — per-step trees with phase children, fused dispatch
   nesting, flight-recorder worst-step capture, Speedometer surfacing;
-* zero overhead when off — the disabled path allocates nothing and
-  emits nothing;
+* the second sink — a span is a ``jax.profiler`` annotation ``mx:<name>``
+  (args as stats, nested under its parent) with tracing off and on;
+* near-zero overhead when off — the disabled path is only that
+  annotation: nothing reaches the buffer or the recorders;
+* live span trees — the gluon step, both branches of ``fit``'s loop and
+  one engine tick under both overlap orders;
 * memory census — category totals vs KNOWN allocations, buffer-level
   dedup of shared weights, provider sweeping;
 * exports — prom_text format, the /metrics +/trace +/memory HTTP
@@ -178,15 +182,21 @@ def test_buffer_cap_counts_drops(trc, monkeypatch):
 def test_disabled_emits_nothing_and_allocates_nothing():
     assert not tracing.enabled()
     tracing.reset()
-    # the disabled fast path returns ONE shared singleton — no Span
-    # object, no timestamp, no event
+    # the disabled path is ONLY the profiler annotation: no Span object,
+    # no ids, no timestamp, no event, nothing for the flight recorders
     s1 = tracing.span("x", cat="y", foo=1)
-    s2 = tracing.span("z")
-    assert s1 is s2
+    assert not isinstance(s1, tracing.Span)
+    assert s1.trace_id is None and s1.span_id is None
     with s1 as s:
+        assert s is s1
         assert s.set(a=1) is s
-        assert s.child("c") is s
+        c = s.child("c", args={"k": 1})
+        assert not isinstance(c, tracing.Span)
+        with c:
+            pass
         assert s.tree() is None and s.finish() is None
+    # cross-thread roots and reconstructed spans are nothing at all
+    assert tracing.begin("b") is tracing.begin("b2")
     assert tracing.inject() is None
     with tracing.attach(None) as ctx:
         assert ctx is None
@@ -195,6 +205,140 @@ def test_disabled_emits_nothing_and_allocates_nothing():
     assert tracing.emit_span("e", 0.0, 1.0) is None
     events, dropped = tracing.take_events()
     assert events == [] and dropped == 0
+    assert tracing.flight_recorder.worst() is None
+    assert tracing.tick_recorder.worst() is None
+    assert tracing.flight_recorder.observed == 0
+    assert tracing.tick_recorder.observed == 0
+
+
+def test_now_us_survives_a_wall_clock_step(monkeypatch):
+    """now_us() is a wall-clock anchor taken once plus the monotonic
+    clock: stepping the wall clock back an hour bends no duration."""
+    wall = time.time() * 1e6
+    assert abs(tracing.now_us() - wall) < 60e6      # epoch timebase
+    a = tracing.now_us()
+    monkeypatch.setattr(time, "time", lambda: 0.0)
+    b = tracing.now_us()
+    assert 0 <= b - a < 1e6
+
+
+# ---------------------------------------------------------------------------
+# the second sink: the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+def _profiled_host_events(tmp_path, body):
+    """Run ``body`` inside a jax.profiler session; the ``mx:`` events of
+    the trace's /host:CPU plane as (name, start_ns, end_ns, stats, line)."""
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for i, line in enumerate(plane.lines):
+            out += [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                     dict(ev.stats), i) for ev in line.events
+                    if ev.name.startswith("mx:")]
+    return out
+
+
+@pytest.mark.parametrize("on", [False, True], ids=["tracing_off",
+                                                   "tracing_on"])
+def test_span_is_a_profiler_annotation(tmp_path, on):
+    """With no flag set (and with MXNET_TRACING on) a span opened during a
+    jax.profiler session is in the .xplane.pb as ``mx:<name>`` with its
+    args, nested under its parent; begin()/emit_span() write none."""
+    prev = tracing.enabled()
+    tracing.enable(on)
+    tracing.reset()
+    try:
+        def body():
+            with tracing.span("outer", cat="t", n=3, label="abc") as outer:
+                with tracing.span("inner", flag=True):
+                    time.sleep(0.002)
+                with outer.child("kid", args={"k": 2}):
+                    pass
+                tracing.emit_span("rebuilt", tracing.now_us(), 5.0)
+            tracing.begin("xthread").finish()
+
+        events = _profiled_host_events(tmp_path, body)
+        buffered = {e["name"] for e in _spans()}
+    finally:
+        tracing.reset()
+        tracing.enable(prev)
+    by_name = {e[0]: e for e in events}
+    assert set(by_name) == {"mx:outer", "mx:inner", "mx:kid"}
+    _, o0, o1, ostats, oline = by_name["mx:outer"]
+    assert ostats == {"n": 3, "label": "abc"}
+    assert by_name["mx:inner"][3] == {"flag": 1}
+    assert by_name["mx:kid"][3] == {"k": 2}
+    for child in ("mx:inner", "mx:kid"):
+        _, c0, c1, _, cline = by_name[child]
+        assert cline == oline and o0 <= c0 and c1 <= o1
+    assert by_name["mx:inner"][2] - by_name["mx:inner"][1] >= 2e6   # ns
+    # the first sink is the flag's alone
+    assert buffered == ({"outer", "inner", "kid", "rebuilt", "xthread"}
+                        if on else set())
+
+
+def _tree_names(node):
+    """A span tree (flight-recorder dict) as nested (name, [children])."""
+    return (node["name"], [_tree_names(c) for c in node.get("children", [])])
+
+
+def test_gluon_step_span_tree(trc):
+    """One hybridized gluon step: cached_op.call > gather/dispatch/record,
+    autograd.backward > deposit, trainer.step > allreduce/update — nine
+    spans, none per parameter or per tape node."""
+    from mxnet_tpu import autograd, gluon, nd
+
+    net = gluon.nn.HybridSequential()
+    net.add(gluon.nn.Dense(8, activation="relu"), gluon.nn.Dense(3))
+    net.initialize()
+    net.hybridize()
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.1})
+    x = nd.array(_x(4)[:, :5])
+    y = nd.array([0, 1, 2, 0])
+    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
+
+    def step():
+        with tracing.span("user.step") as root:
+            with autograd.record():
+                loss = loss_fn(net(x), y)
+            loss.backward()
+            trainer.step(4)
+        return root
+
+    step()                      # deferred init + compile
+    tracing.reset()
+    root = step()
+    assert _tree_names(root.tree()) == ("user.step", [
+        ("cached_op.call", [("cached_op.gather", []),
+                            ("cached_op.dispatch", []),
+                            ("cached_op.record", [])]),
+        ("autograd.backward", [("autograd.deposit", [])]),
+        ("trainer.step", [("trainer.allreduce", []),
+                          ("trainer.update", [])])])
+    call = root.tree()["children"][0]
+    assert call["args"]["params"] == 4
+    assert len(_spans()) == 10
+    _assert_connected(_spans())
 
 
 # ---------------------------------------------------------------------------
@@ -362,6 +506,57 @@ def test_fit_step_span_trees(trc):
         names = {e["name"] for e in _by_trace(spans)[tid]}
         assert STEP_PHASES <= names, names
         assert "fused.dispatch" in names  # nested through the contextvar
+
+
+@pytest.mark.parametrize("overlap", ["0", "1"], ids=["lockstep", "overlap"])
+def test_fit_live_children_keep_the_marks_order(trc, monkeypatch, overlap):
+    """The four phase children are live spans in the order the perf marks
+    had in each branch of the loop (lockstep: sync before data; overlap:
+    data before sync), fused.dispatch nests under step.fwdbwd, step.data
+    holds next + stage, and the step.*_us histograms still record."""
+    monkeypatch.setenv("MXNET_OVERLAP", overlap)
+    prev = telemetry.enabled()
+    telemetry.enable()
+    names = ("fwdbwd", "update", "data", "sync", "total")
+    before = {n: telemetry.histogram(f"step.{n}_us").count for n in names}
+    try:
+        _fit(steps=5)
+        counts = {n: telemetry.histogram(f"step.{n}_us").count - before[n]
+                  for n in names}
+    finally:
+        telemetry.enable(prev)
+    assert counts == dict.fromkeys(names, 5)
+    spans = _spans()
+    _assert_connected(spans)
+    steps = sorted((e for e in spans if e["name"] == "step"),
+                   key=lambda e: e["args"]["step"])
+    assert len(steps) == 5
+
+    def kids(parent):
+        return [e["name"] for e in sorted(
+            (e for e in spans
+             if e["args"].get("parent_id") == parent["args"]["span_id"]),
+            key=lambda e: e["ts"])]
+
+    order = (["step.fwdbwd", "step.update", "step.sync", "step.data"]
+             if overlap == "0" else
+             ["step.fwdbwd", "step.update", "step.data", "step.sync"])
+    for i, root in enumerate(steps):
+        assert kids(root) == order
+        assert root["args"]["fused"] is True
+        by = {e["name"]: e for e in spans
+              if e["args"]["trace_id"] == root["args"]["trace_id"]}
+        assert kids(by["step.fwdbwd"]) == ["fused.dispatch"]
+        # the epoch's last step finds the iterator empty: nothing to stage
+        assert kids(by["step.data"]) == (
+            ["step.data.next", "step.data.stage"] if i < 4
+            else ["step.data.next"])
+        assert "error" not in by["step.data.next"]["args"]
+        # live spans: children lie inside their parent on the clock
+        for name in order:
+            c = by[name]
+            assert root["ts"] <= c["ts"]
+            assert c["ts"] + c["dur"] <= root["ts"] + root["dur"] + 1.0
 
 
 def test_flight_recorder_keeps_worst_step(trc):
