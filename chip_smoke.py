@@ -502,6 +502,16 @@ def phase_serve(seed, events, seen):
                 path = attention_path(eng.cache._entries[key]._fn, st["avals"])
                 log(f"[{name}] program generation:{'/'.join(map(str, key))} "
                     f"attention={path}")
+                if key[0] == "decode":
+                    # the decode holds the slab kernel exactly when the
+                    # model's shape test says so (on the chip: yes, block
+                    # logged); the greedy parity below is its on-chip check
+                    block = eng._slab_block
+                    log(f"[{name}] decode slab access: "
+                        f"{'kernel, block ' + str(block) if block else 'xla'}")
+                    assert path == ("pallas" if block else "xla"), (
+                        f"{name}: decode program holds attention={path}, "
+                        f"the model's decode_block says {block}")
         misses0 = compile_cache.named_stats("generation")["misses"]
         xla0 = events.backend_compiles
 

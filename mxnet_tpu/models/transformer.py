@@ -60,6 +60,52 @@ def _spec(mesh, *axes):
                                    for a in axes]))
 
 
+def _table_rows(table, rows):
+    """``table[rows]`` as one dynamic_slice a row. The tied embedding lies
+    vocabulary-minor on a TPU (less tile padding; the head's matmul reads
+    it so), and a gather makes the compiler copy the whole table into the
+    other layout every call; a slice reads it where it lies."""
+    return jnp.concatenate([lax.dynamic_slice_in_dim(table, rows[s], 1, 0)
+                            for s in range(rows.shape[0])], axis=0)
+
+
+def _write_rows(slab, layer, positions, rows):
+    """``slab[s, layer, :, positions[s], :] = rows[s]`` for every slot with
+    a position >= 0, in place on a donated slab: one dynamic_update_slice
+    of ``[H, 1, hd]`` per slot, which XLA performs in the slab's own layout
+    (a scatter makes the TPU compiler copy the whole slab into the layout
+    it prefers and back). A dead slot (negative position) writes back what
+    its row 0 held."""
+    for s in range(slab.shape[0]):
+        at = (s, layer, 0, jnp.maximum(positions[s], 0), 0)
+        new = rows[s][None, None, :, None, :]
+        old = lax.dynamic_slice(slab, at, new.shape)
+        slab = lax.dynamic_update_slice(
+            slab, jnp.where(positions[s] >= 0, new, old), at)
+    return slab
+
+
+def _attend_rows(q, cache_k, cache_v, layer, positions):
+    """Layer ``layer``'s decode attention in plain XLA: the layer's page is
+    read where it lies in the slab (the slice fuses into the two
+    reductions), all ``L`` rows of every slot, masked to
+    ``j <= positions[s]`` (<=: the token just written attends itself).
+    Scores are masked large-negative, not -inf: a dead slot masks every row
+    and must give finite garbage, not NaN. V rows past the position are
+    selected away, not multiplied by a zero weight, so whatever a previous
+    occupant left there (inf, nan) cannot reach the output; a dead slot's
+    attention is 0."""
+    dt = q.dtype
+    L, hd = cache_k.shape[3], cache_k.shape[4]
+    live = jnp.arange(L)[None, :] <= positions[:, None]              # [S,L]
+    s = jnp.einsum("shd,shld->shl", q, cache_k[:, layer].astype(dt),
+                   preferred_element_type=jnp.float32) * (1.0 / np.sqrt(hd))
+    p = jax.nn.softmax(jnp.where(live[:, None, :], s, -1e9),
+                       axis=-1).astype(dt)
+    v = jnp.where(live[:, None, :, None], cache_v[:, layer].astype(dt), 0)
+    return jnp.einsum("shl,shld->shd", p, v)
+
+
 class TransformerLM:
     """Functional transformer LM bound to a mesh.
 
@@ -326,8 +372,8 @@ class TransformerLM:
     # preallocated KV slab of FIXED shape holds every live session's keys
     # and values, `prefill` fills a slot's rows [0, L) from the prompt in
     # one full-length pass, and `decode_step` extends every live slot by
-    # exactly one token — a dynamic_update_slice write plus attention over
-    # the (masked) slab row, never a recompile, never O(T) recomputation.
+    # exactly one token — one K/V row written in place plus attention over
+    # the slot's own live rows, never a recompile, never O(T) recomputation.
     # Both are pure functions of (params, cache, ...) so the serving engine
     # can jit them once per shape with the cache buffers donated.
 
@@ -523,64 +569,76 @@ class TransformerLM:
         return (logits[0], sharding_constraint(cache_k, sh),
                 sharding_constraint(cache_v, sh))
 
+    def decode_block(self, slab_shape, dtype):
+        """How :meth:`decode_step` touches a slab of this shape: the block
+        over ``L`` of the Pallas decode kernel (``ops/pallas_decode.py``:
+        rows are written and only each live slot's live blocks are read,
+        where the slab lies), or None for the XLA formulation, which reads
+        every row of every slot. Decided from what can be seen before the
+        call, as `flash_blocks` is: the kernel policy, the slab's shape and
+        dtype, and the mesh — a Mosaic kernel is not partitioned by GSPMD,
+        so a model on several devices keeps the XLA formulation, which
+        is."""
+        from ..ops import pallas_attention as pa
+        from ..ops import pallas_decode as pd
+
+        if self.mesh.size > 1 or not pa.pallas_enabled():
+            return None
+        return pd.decode_block(slab_shape, dtype)
+
     def decode_step(self, params, cache_k, cache_v, tokens, positions):
-        """One fused incremental step over the WHOLE slot slab: each slot
-        consumes one token, writes its K/V at ``positions[s]`` and attends
-        over rows ``[0, positions[s]]`` — O(1) work per token in generated
-        length, every slot in one XLA program.
+        """One fused incremental step over the WHOLE slot slab: each live
+        slot consumes one token, writes its K/V at ``positions[s]`` — one
+        row, in place on the donated slab — and attends over rows
+        ``[0, positions[s]]``: O(1) work per token in generated length,
+        every slot in one XLA program.
 
         tokens    : int32 [S] the token extending each slot (dead slots:
                     anything — their output is discarded by the engine)
         positions : int32 [S] the index each token occupies (== the slot's
-                    current length; dead slots: 0 — their garbage write
-                    lands in a row the length mask hides from any future
-                    occupant, because a new session's prefill rewrites
-                    [0, Lb) first)
+                    current length). A NEGATIVE position marks a dead
+                    slot: nothing of it is written or attended and its
+                    logits are garbage, so the slab rows of a slot nobody
+                    decodes (free, parked, holding a cached prefix, or of
+                    another weight cohort) stay exactly as they were. A
+                    position past the slab is clamped to its last row.
 
         Returns ``(logits [S, V] fp32, cache_k, cache_v)``. Pure; jit with
         the cache operands donated. One executable serves every admission/
-        eviction pattern — continuous batching never recompiles.
+        eviction pattern — continuous batching never recompiles. How the
+        slab is touched (:meth:`decode_block`): the Pallas kernel where it
+        applies, else per-slot dynamic_update_slice writes and attention
+        over the whole page in XLA — same rows written, same mathematics.
         """
+        from ..ops import pallas_attention as pa
+        from ..ops import pallas_decode as pd
+
         c = self.cfg
         dt = jnp.dtype(c.dtype)
         S = tokens.shape[0]
-        L = cache_k.shape[3]
         hd = c.d_model // c.n_heads
-        scale = 1.0 / np.sqrt(hd)
-        h = jnp.take(params["embed"], tokens, axis=0).astype(dt)      # [S,D]
-        h = h + jnp.take(params["pos_embed"], positions, axis=0).astype(dt)
-        # per-slot length mask over the slab row: attend j <= positions[s]
-        # (<=: the token just written attends to itself). Large-negative,
-        # not -inf — a dead slot masks everything and must produce finite
-        # garbage, not NaN.
-        mask = jnp.where(jnp.arange(L)[None, None, :]
-                         <= positions[:, None, None], 0.0, -1e9)    # [S,1,L]
+        block = self.decode_block(cache_k.shape, cache_k.dtype)
+        positions = jnp.minimum(positions, cache_k.shape[3] - 1)
+        h = _table_rows(params["embed"], tokens).astype(dt)          # [S,D]
+        h = h + jnp.take(params["pos_embed"], jnp.maximum(positions, 0),
+                         axis=0).astype(dt)
         for i in range(c.n_layers):
             ln1 = self._ln(h, params[f"l{i}.ln1_scale"],
                            params[f"l{i}.ln1_bias"])
             qkv = ln1 @ params[f"l{i}.wqkv"]
             q, k, v = jnp.split(qkv, 3, axis=-1)
             q = q.reshape(S, c.n_heads, hd)
-            k = k.reshape(S, c.n_heads, hd)
-            v = v.reshape(S, c.n_heads, hd)
-
-            def write(slab, new):
-                # per-slot dynamic_update_slice at that slot's position:
-                # [H, 1, hd] block into the slot's [H, L, hd] layer page
-                return jax.vmap(lambda page, u, p: lax.dynamic_update_slice(
-                    page, u, (0, p, 0)))(
-                        slab[:, i], new[:, :, None, :].astype(slab.dtype),
-                        positions)
-
-            ck_i = write(cache_k, k)                           # [S,H,L,hd]
-            cv_i = write(cache_v, v)
-            cache_k = cache_k.at[:, i].set(ck_i)
-            cache_v = cache_v.at[:, i].set(cv_i)
-            s = jnp.einsum("shd,shld->shl", q, ck_i.astype(dt),
-                           preferred_element_type=jnp.float32) * scale
-            p = jax.nn.softmax(s + mask, axis=-1).astype(dt)
-            attn = jnp.einsum("shl,shld->shd", p,
-                              cv_i.astype(dt)).reshape(S, c.d_model)
+            k = k.reshape(S, c.n_heads, hd).astype(cache_k.dtype)
+            v = v.reshape(S, c.n_heads, hd).astype(cache_v.dtype)
+            if block is not None:
+                attn, cache_k, cache_v = pd.decode_update_attend(
+                    q, k, v, cache_k, cache_v, jnp.int32(i), positions,
+                    block=block, interpret=pa.pallas_interpret())
+            else:
+                cache_k = _write_rows(cache_k, i, positions, k)
+                cache_v = _write_rows(cache_v, i, positions, v)
+                attn = _attend_rows(q, cache_k, cache_v, i, positions)
+            attn = attn.astype(dt).reshape(S, c.d_model)
             h = h + attn @ params[f"l{i}.wo"]
             ln2 = self._ln(h, params[f"l{i}.ln2_scale"],
                            params[f"l{i}.ln2_bias"])
@@ -630,8 +688,10 @@ class TransformerLM:
         """
         steps = []
         for i in range(tokens.shape[1]):
+            # a dead slot (negative position) stays dead through the block
             logits, cache_k, cache_v = self.decode_step(
-                params, cache_k, cache_v, tokens[:, i], positions + i)
+                params, cache_k, cache_v, tokens[:, i],
+                jnp.where(positions < 0, positions, positions + i))
             steps.append(logits)
         return jnp.stack(steps, axis=1), cache_k, cache_v
 

@@ -15,8 +15,14 @@ token, O(T²) per sequence). This engine is the token-level scheduler:
   clearing host-side metadata — continuous batching without a recompile,
   ever (the arXiv:2603.09555 compile-once O(1)-cache discipline).
 * **continuous scheduling** — every engine tick runs ONE fused
-  ``decode_step`` over the whole slab (all live sessions advance one
-  token together), evicts finished/EOS/deadline-expired sessions, and
+  ``decode_step`` over the slab: every live session advances one token
+  together, each writing ONE K/V row in place and attending only its
+  own rows ``[0, length]``; a slot without a live session rides along
+  at position -1, neither read nor written
+  (:meth:`TransformerLM.decode_step`; on one TPU chip the slab kernel of
+  ``ops/pallas_decode.py`` reads just the live blocks, elsewhere the XLA
+  formulation masks the whole page). The tick then evicts
+  finished/EOS/deadline-expired sessions, and
   admits queued prefills into the freed slots mid-stream. The intake is
   PR 5's :class:`~mxnet_tpu.serving.admission.AdmissionQueue`
   (``QueueFullError`` backpressure, ``ServerClosedError`` after close,
@@ -52,7 +58,8 @@ token, O(T²) per sequence). This engine is the token-level scheduler:
 
 Telemetry rides ``serving.generation.*`` (live-slot gauge, tokens/s,
 TTFT/tick histograms, per-reason eviction counters, derived
-``slot_fill_ratio``, plus ``prefix.{hits,misses,forks,inserts,
+``slot_fill_ratio``, ``slab_blocks_live``/``slab_blocks_total`` — the
+share of the slab's blocks each dispatch had to read — plus ``prefix.{hits,misses,forks,inserts,
 evictions}``/``prefix.cached_tokens`` and ``spec.{proposed,accepted,
 rolled_back,committed}`` with derived ``spec.acceptance_ratio``).
 ``queue_wait_us`` is submit → start of the admission (host events at both
@@ -266,6 +273,9 @@ class GenerationEngine:
         self._admit_seq = 0
         self._ck, self._cv = model.init_cache(self._total_slots,
                                               self._slab_len)
+        # the decode kernel's block over the slab's rows, None when the
+        # model keeps the XLA formulation (telemetry only)
+        self._slab_block = model.decode_block(self._ck.shape, self._ck.dtype)
         # host-side slot metadata — only the tick loop (under _tick_lock)
         # mutates these
         self._sessions = [None] * self._total_slots
@@ -545,10 +555,10 @@ class GenerationEngine:
         (speculative). Prefill/suffix warms write garbage into a FREE
         slot (skipped, with a log, for buckets that cannot get one on an
         already-full slab — they were compiled by real traffic anyway);
-        the decode/verify warm runs only while no session is live, and
-        its garbage K/V writes are steered to the slab's last row
-        (:meth:`_tick_positions`), so warming a serving engine never
-        perturbs a session or a cached prefix entry. Returns
+        the decode/verify warm runs only while no session is live, with
+        every slot dead (:meth:`_tick_positions`: nothing is written), so
+        warming a serving engine never perturbs a session or a cached
+        prefix entry. Returns
         ``{"buckets", "compiles", "seconds", "cache_entries"}``."""
         import jax.numpy as jnp
 
@@ -665,7 +675,7 @@ class GenerationEngine:
         weights; sessions already live keep decoding — bit-exact — under
         the version they were admitted with until they finish (the tick
         runs one executable dispatch per live version, same programs,
-        positions of other cohorts steered to the slab's safe row). The
+        the slots of other cohorts marked dead). The
         KV slab, the radix prefix cache structure and the speculative
         draft slab all survive the flip; prefix entries stamped with
         other versions are evicted (their KV would splice old-weight
@@ -1120,30 +1130,43 @@ class GenerationEngine:
                 if self._sessions[i] is None and i not in held]
 
     def _tick_positions(self, active=None):
-        """Write positions for the fixed-shape decode/verify executables:
-        a live slot's length, and the slab's LAST row for every other
-        slot. Dead and — critically — CACHE-HELD slots still get a K/V
-        row written every tick (the fixed shape computes all slots); row
-        0 would silently corrupt a cached prefix entry's first tokens,
-        so the garbage is steered to row ``slab_len - 1``, which no
-        entry can own (a cached prompt is at most ``max_len - 1`` tokens
-        — submit requires >= 1 generated token — and the speculative
-        slab adds scratch rows past that). A verify block's clamped
-        writes pile onto the same last row, equally harmless.
+        """Positions for the fixed-shape decode/verify executables: a live
+        slot's length, and -1 — dead, by ``decode_step``'s contract — for
+        every other slot: free, parked, or holding a cached prefix. A dead
+        slot is neither written nor read, so the rows a cached entry or a
+        parked session owns stay exactly as they were, and the tick reads
+        the slab's live part only.
 
-        ``active`` (an iterable of slot indices) additionally steers
-        every LIVE slot outside it to the same safe row — the per-version
-        cohort dispatch during a weight-swap transition: each cohort's
-        executable call must advance only its own slots, and a slot only
-        ever attends its own rows, so co-resident garbage writes cannot
-        perturb another cohort's (bit-exact) output."""
+        ``active`` (an iterable of slot indices) also marks every LIVE
+        slot outside it dead — the per-version cohort dispatch during a
+        weight-swap transition: each cohort's executable call advances
+        only its own slots."""
         pos = self._lengths.copy()
-        safe = self._slab_len - 1
         act = None if active is None else set(active)
         for i, s in enumerate(self._sessions):
             if s is None or (act is not None and i not in act):
-                pos[i] = safe
+                pos[i] = -1
         return pos
+
+    def _count_slab_blocks(self, positions, steps=1):
+        """Telemetry: how much of the slab this dispatch reads.
+        ``slab_blocks_live`` over ``slab_blocks_total`` is the share of
+        the slab's blocks the decode program had to read (1 when the model
+        keeps the XLA formulation, which reads every row). Host arithmetic
+        on positions the tick already holds."""
+        from ...ops.pallas_decode import live_blocks
+
+        block = self._slab_block
+        total = steps * self._total_slots * (
+            self._slab_len // block if block else 1)
+        if block:
+            live = sum(int(live_blocks(
+                np.where(positions >= 0, positions + i, -1), block).sum())
+                for i in range(steps))
+        else:
+            live = total
+        telemetry.counter("serving.generation.slab_blocks_live").inc(live)
+        telemetry.counter("serving.generation.slab_blocks_total").inc(total)
 
     def _prefix_claimable(self):
         """Cache entries session pressure may evict: everything above the
@@ -1375,13 +1398,13 @@ class GenerationEngine:
     def _decode(self):
         """ONE fused step over the whole slab; every live session
         advances one token (plain) or up to ``spec_k + 1`` (speculative
-        verify). Dead slots ride along as masked garbage — that fixed
+        verify). Dead slots ride along unread and unwritten — that fixed
         shape is exactly what makes mid-stream admit/evict free.
 
         During a weight-swap transition (live sessions pinned to more
         than one version) the SAME executable runs once per version
         cohort with that cohort's pinned params, other cohorts' slots
-        steered to the safe row — N dispatches, zero new programs, and
+        marked dead — N dispatches, zero new programs, and
         every session's output stays bit-exact with an unswapped engine
         on its own weights.
 
@@ -1398,8 +1421,8 @@ class GenerationEngine:
         cohort WITHOUT materializing the token output. Cohort dispatch
         order and inputs are identical to the fused path: a later
         cohort's call only reads the earlier ones' cache outputs (pure
-        lazy dataflow) and every non-member slot is steered to the safe
-        row, so committing before or after the remaining dispatches is
+        lazy dataflow) and every non-member slot is dead to it, so
+        committing before or after the remaining dispatches is
         bit-equivalent. Returns the pending state for
         :meth:`_decode_commit`, or None when no slot is live."""
         import jax.numpy as jnp
@@ -1414,13 +1437,14 @@ class GenerationEngine:
         pending = []
         for version in sorted(cohorts):
             slots = cohorts[version]
+            positions = self._tick_positions(slots if mixed else None)
             with tracing.span("generation.decode", cat="generation",
                               live=len(slots), version=version):
                 toks, self._ck, self._cv = fn(
                     self._version_params(version), self._ck, self._cv,
-                    jnp.asarray(self._last_tok),
-                    jnp.asarray(self._tick_positions(
-                        slots if mixed else None)))
+                    jnp.asarray(self._last_tok), jnp.asarray(positions))
+            if telemetry._enabled:
+                self._count_slab_blocks(positions)
             # snapshot the cohort's sessions: a slot evicted or re-
             # admitted between dispatch and commit fails the identity
             # check and its token is discarded
@@ -1483,13 +1507,14 @@ class GenerationEngine:
         pending = []
         for version in sorted(cohorts):
             slots = cohorts[version]
+            positions = self._tick_positions(slots if mixed else None)
             with tracing.span("generation.verify", cat="generation",
                               live=len(slots), k=k, version=version):
                 toks, self._ck, self._cv = fn(
                     self._version_params(version), self._ck, self._cv,
-                    jnp.asarray(tokens),
-                    jnp.asarray(self._tick_positions(
-                        slots if mixed else None)))
+                    jnp.asarray(tokens), jnp.asarray(positions))
+            if telemetry._enabled:
+                self._count_slab_blocks(positions, steps=k + 1)
             pending.append((slots, [self._sessions[s] for s in slots],
                             toks))
         return ("spec", (props, pending))
@@ -1742,8 +1767,8 @@ class GenerationEngine:
                               "last_tok": int(self._last_tok[victim]),
                               "parked_at": time.monotonic()}
         # host metadata moves aside WITHOUT failing the stream — the
-        # session is paused, not dead; its slot row becomes masked
-        # garbage steered to the safe row by _tick_positions
+        # session is paused, not dead; its slot goes dead to the decode
+        # (_tick_positions), so nothing touches its rows
         self._sessions[victim] = None
         self._lengths[victim] = 0
         self._last_tok[victim] = 0

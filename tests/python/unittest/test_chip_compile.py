@@ -9,6 +9,7 @@ applies: lengths no accepted block divides and K/V beyond the fast-memory
 budget go to the XLA path instead of to a compiler refusal.
 """
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
 # no chip is opened here, only the compiler: several test processes (xdist
@@ -155,3 +156,82 @@ def test_resident_budget_boundary_compiles_for_v5e(one_chip):
     text = _compiled_text(
         lambda q, k, v: pa.flash_attention(q, k, v, causal=True), x, x, x)
     assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------------
+# the decode program at the serving cells' real widths (ISSUE 24)
+# ---------------------------------------------------------------------------
+
+_HLO_RESULT = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+                         r"([\w\-]+)\(")
+
+
+def _page_sized_ops(text, page):
+    """HLO instructions that move a slab page or more: a copy, slice,
+    dynamic-(update-)slice, scatter or transpose whose result is a K/V-shaped
+    array (rank >= 4) of at least `page` elements."""
+    moved = []
+    for line in text.splitlines():
+        m = _HLO_RESULT.match(line)
+        if not m or m.group(2) not in (
+                "copy", "slice", "dynamic-slice", "dynamic-update-slice",
+                "scatter", "transpose", "copy-start"):
+            continue
+        dims = [int(d) for d in m.group(1).split(",")]
+        if len(dims) >= 4 and int(np.prod(dims)) >= page:
+            moved.append(line.strip()[:160])
+    return moved
+
+
+@pytest.mark.parametrize("slots,cfg", [
+    (32, dict(vocab_size=50257, d_model=1600, n_heads=25, d_ff=6400)),
+    (8, dict(vocab_size=50257, d_model=768, n_heads=12, d_ff=3072)),
+], ids=["gpt2xl-32x25x64", "gpt2s-8x12x64"])
+def test_decode_program_touches_no_slab_page_on_v5e(one_chip, monkeypatch,
+                                                    slots, cfg):
+    """The engine's decode program at GPT-2 XL's and GPT-2 small's widths
+    (1,024 rows, bf16) but 2 layers, so that it compiles in seconds: the
+    slab kernel is in it, both slabs are aliased input to output, and no
+    XLA op copies, slices, scatters into or re-lays a slab page. (The page
+    is the full size; the full depth's memory figure is
+    `benchmark/rehearse_compile.py`'s.)"""
+    from mxnet_tpu import parallel as par
+    from mxnet_tpu.models import TransformerLM, TransformerLMConfig
+
+    # code that asks jax.default_backend() sees "cpu" here
+    monkeypatch.setenv("MXNET_PALLAS_ATTENTION", "1")
+    monkeypatch.delenv("MXNET_PALLAS_INTERPRET", raising=False)
+    config = TransformerLMConfig(n_layers=2, max_len=1024, dtype="bfloat16",
+                                 **cfg)
+    dev = next(iter(one_chip.device_set))
+    lm = TransformerLM(config, par.create_mesh(devices=[dev], dp=1))
+    hd = config.d_model // config.n_heads
+    slab_shape = (slots, 2, config.n_heads, 1024, hd)
+    assert lm.decode_block(slab_shape, jnp.bfloat16) == 256
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    host_lm = TransformerLM(config, par.create_mesh(
+        devices=jax.devices()[:1], dp=1))
+    params = {k: sds(v.shape, v.dtype) for k, v in jax.eval_shape(
+        host_lm.init_params, jax.random.PRNGKey(0)).items()}
+    slab = sds(slab_shape, jnp.bfloat16)
+
+    def fn(params, ck, cv, tokens, positions):      # the engine's wrapper
+        logits, ck, cv = lm.decode_step(params, ck, cv, tokens, positions)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), ck, cv
+
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
+        params, slab, slab, sds((slots,), jnp.int32),
+        sds((slots,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2        # one kernel a layer
+    page = slots * config.n_heads * 1024 * hd
+    assert _page_sized_ops(text, page) == []
+    ma = compiled.memory_analysis()
+    slab_bytes = 2 * 2 * page * 2                    # K and V, 2 layers, bf16
+    assert ma.alias_size_in_bytes >= slab_bytes      # donated and aliased
+    # no temporary of a page's size (2 bytes an element), nor of the
+    # embedding table's: its rows are sliced where the table lies
+    assert ma.temp_size_in_bytes < page

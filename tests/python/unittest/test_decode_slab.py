@@ -1,0 +1,231 @@
+"""How `TransformerLM.decode_step` touches the KV slab (ISSUE 24): one row
+per live slot written in place, attention over rows ``[0, positions[s]]``
+only — through the Pallas kernel (`ops/pallas_decode.py`, interpret mode
+here) and through the XLA formulation it falls back to. The reference is a
+plain `jax.numpy` re-statement of the page-copying step both replaced.
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from mxnet_tpu import parallel as par
+from mxnet_tpu import telemetry
+from mxnet_tpu.models import TransformerLM, TransformerLMConfig
+from mxnet_tpu.models import transformer as tfm
+from mxnet_tpu.ops import pallas_decode as pd
+from mxnet_tpu.serving import GenerationEngine
+
+S, NL, H, L, LAYER = 6, 2, 2, 512, 1
+BLOCK = 256                      # what decode_block gives for L=512
+
+
+def old_step(q, k_new, v_new, ck, cv, layer, pos):
+    """The step before ISSUE 24, in plain jax.numpy at fp32: slice the
+    layer's page of every slot, write the row into it, set it back, and
+    attend the whole page under an additive length mask."""
+    f32 = jnp.float32
+    q, ck, cv = q.astype(f32), ck.astype(f32), cv.astype(f32)
+    page_k, page_v = ck[:, layer], cv[:, layer]                # [S,H,L,hd]
+    rows = jnp.arange(ck.shape[0])
+    page_k = page_k.at[rows, :, pos].set(k_new.astype(f32))
+    page_v = page_v.at[rows, :, pos].set(v_new.astype(f32))
+    ck, cv = ck.at[:, layer].set(page_k), cv.at[:, layer].set(page_v)
+    mask = jnp.where(jnp.arange(ck.shape[3])[None, None, :]
+                     <= pos[:, None, None], 0.0, -1e9)
+    s = jnp.einsum("shd,shld->shl", q, page_k) / np.sqrt(q.shape[-1])
+    p = jax.nn.softmax(s + mask, axis=-1)
+    return jnp.einsum("shl,shld->shd", p, page_v), ck, cv
+
+
+def new_step(path, q, k_new, v_new, ck, cv, layer, pos):
+    if path == "kernel":
+        block = pd.decode_block(ck.shape, ck.dtype)
+        assert block == BLOCK
+        return pd.decode_update_attend(q, k_new, v_new, ck, cv, layer, pos,
+                                       block=block, interpret=True)
+    ck = tfm._write_rows(ck, layer, pos, k_new)
+    cv = tfm._write_rows(cv, layer, pos, v_new)
+    return tfm._attend_rows(q, ck, cv, layer, pos), ck, cv
+
+
+POSITIONS = {
+    "all0": [0] * S,
+    "group-1": [127] * S,
+    "block-1": [BLOCK - 1] * S,
+    "block": [BLOCK] * S,
+    "block+1": [BLOCK + 1] * S,
+    "last": [L - 1] * S,
+    "mix": [0, 100, BLOCK - 1, BLOCK, 300, L - 1],
+    "mix-dead": [-1, 129, -1, 0, L - 1, -1],
+    "dead": [-1] * S,
+}
+# the kernel takes the L-minor slabs (hd not a multiple of 128); hd = 128
+# lies hd-minor on the chip and keeps the XLA formulation
+CASES = [(path, dt, hd, name)
+         for path, hds in (("kernel", (64,)), ("xla", (64, 128)))
+         for dt in ("float32", "bfloat16") for hd in hds for name in POSITIONS]
+
+
+def _operands(dt, hd, seed=0):
+    rng = np.random.default_rng(seed)
+    dt = jnp.dtype(dt)
+    slab = lambda: jnp.asarray(rng.standard_normal((S, NL, H, L, hd)), dt)
+    row = lambda: jnp.asarray(rng.standard_normal((S, H, hd)), dt)
+    return row(), row(), row(), slab(), slab()
+
+
+@pytest.mark.parametrize("path,dt,hd,name", CASES,
+                         ids=["-".join(map(str, c)) for c in CASES])
+def test_slab_access_matches_the_old_step(path, dt, hd, name):
+    """Attention and both slabs against the re-stated old step, on a slab
+    full of random rows. Tolerance from the dtype: fp32 differs by the order
+    of the sums; bf16 by the XLA path's bf16 softmax weights (2^-8)."""
+    if path == "kernel":
+        assert pd.decode_block((S, NL, H, L, 128), dt) is None
+    q, k_new, v_new, ck, cv = _operands(dt, hd)
+    pos = jnp.asarray(POSITIONS[name], jnp.int32)
+    alive = np.asarray(pos) >= 0
+    want, want_k, want_v = old_step(q, k_new, v_new, ck, cv, LAYER,
+                                    jnp.maximum(pos, 0))
+    got, got_k, got_v = new_step(path, q, k_new, v_new, ck, cv, LAYER, pos)
+    tol = 2e-5 if dt == "float32" else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32)[alive],
+                               np.asarray(want)[alive], rtol=tol, atol=tol)
+    # a dead slot attends nothing and writes nothing
+    assert not np.asarray(got, np.float32)[~alive].any()
+    for got_slab, want_slab, old in ((got_k, want_k, ck), (got_v, want_v, cv)):
+        got_slab = np.asarray(got_slab, np.float32)
+        assert np.array_equal(got_slab[alive], np.asarray(want_slab)[alive])
+        assert np.array_equal(got_slab[~alive],
+                              np.asarray(old, np.float32)[~alive])
+
+
+@pytest.mark.parametrize("path", ["kernel", "xla"])
+@pytest.mark.parametrize("junk", [np.inf, np.nan])
+def test_rows_past_the_position_never_reach_the_output(path, junk):
+    """What a previous occupant left beyond a slot's position — inf, nan —
+    is skipped or selected away, never multiplied by a zero weight."""
+    q, k_new, v_new, ck, cv = _operands("float32", 64, seed=1)
+    pos_list = [0, 100, BLOCK - 1, BLOCK, 300, L - 2]
+    pos = jnp.asarray(pos_list, jnp.int32)
+    beyond = jnp.arange(L)[None, None, None, :, None] \
+        > pos[:, None, None, None, None]
+    want, _, _ = old_step(q, k_new, v_new, ck, cv, LAYER, pos)
+    got, _, _ = new_step(path, q, k_new, v_new, jnp.where(beyond, junk, ck),
+                         jnp.where(beyond, junk, cv), LAYER, pos)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.fixture
+def tiny_lm():
+    cfg = TransformerLMConfig(vocab_size=61, d_model=128, n_heads=2, d_ff=128,
+                              n_layers=2, max_len=256, dtype="float32")
+    # one device: on a mesh of several the model keeps the XLA formulation
+    lm = TransformerLM(cfg, par.create_mesh(devices=jax.devices()[:1], dp=1))
+    return lm, lm.init_params(jax.random.PRNGKey(0))
+
+
+def _decode(lm, params, positions, seed=2):
+    rng = np.random.default_rng(seed)
+    n = len(positions)
+    shape = (n, lm.cfg.n_layers, lm.cfg.n_heads, 256, 64)
+    ck = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    cv = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    tokens = jnp.asarray(rng.integers(0, 61, n), jnp.int32)
+    logits, nk, nv = jax.jit(lm.decode_step)(
+        params, ck, cv, tokens, jnp.asarray(positions, jnp.int32))
+    return logits, (ck, cv), (nk, nv)
+
+
+@pytest.mark.parametrize("path", ["kernel", "xla"])
+def test_decode_step_writes_exactly_one_row_a_live_slot(tiny_lm, monkeypatch,
+                                                        path):
+    """After one decode_step each slab differs from its input in exactly
+    the rows (s, i, :, positions[s], :) of the live slots, all layers."""
+    monkeypatch.setenv("MXNET_PALLAS_ATTENTION",
+                       "1" if path == "kernel" else "0")
+    monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
+    lm, params = tiny_lm
+    positions = [0, 127, 128, -1, 255]
+    assert (lm.decode_block((5, 2, 2, 256, 64), jnp.float32)
+            == (256 if path == "kernel" else None))
+    _, olds, news = _decode(lm, params, positions)
+    for old, new in zip(olds, news):
+        changed = np.asarray(old != new).any(axis=(2, 4))        # [S,NL,L]
+        want = np.zeros_like(changed)
+        for s, p in enumerate(positions):
+            if p >= 0:
+                want[s, :, p] = True
+        assert np.array_equal(changed, want)
+
+
+def test_decode_step_kernel_and_xla_agree(tiny_lm, monkeypatch):
+    lm, params = tiny_lm
+    positions = [3, 127, 128, -1, 255]
+    monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
+    out = {}
+    for flag in ("1", "0"):
+        monkeypatch.setenv("MXNET_PALLAS_ATTENTION", flag)
+        out[flag] = _decode(lm, params, positions)
+    live = np.asarray(positions) >= 0
+    np.testing.assert_allclose(np.asarray(out["1"][0])[live],
+                               np.asarray(out["0"][0])[live],
+                               rtol=1e-4, atol=1e-4)
+    for a, b in zip(out["1"][2], out["0"][2]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_live_blocks_counts_what_the_kernel_reads():
+    pos = np.array([-1, 0, 255, 256, 511, -7])
+    assert pd.live_blocks(pos, 256).tolist() == [0, 1, 1, 2, 2, 0]
+    assert pd.live_blocks(pos, 128).tolist() == [0, 1, 2, 3, 4, 0]
+
+
+@pytest.mark.parametrize("shape,dtype,want", [
+    ((32, 48, 25, 1024, 64), "bfloat16", 256),    # GPT-2 XL, the chat cell
+    ((8, 12, 12, 1024, 64), "bfloat16", 256),     # GPT-2 small, chip_smoke
+    ((3, 2, 4, 48, 12), "float32", None),         # hd not a sublane multiple
+    ((3, 2, 4, 64, 16), "float32", None),         # L below a lane row
+    ((4, 2, 8, 1024, 128), "bfloat16", None),     # hd-minor on the chip
+    ((4, 2, 100, 1024, 64), "bfloat16", 128),     # the block budget halves it
+    ((4, 2, 200, 1024, 64), "float32", None),     # ... and gives up
+    ((4, 2, 8, 1000, 64), "bfloat16", None),      # no lane-aligned block
+], ids=["xl", "small", "hd12", "L64", "hd128", "wide", "wider", "L1000"])
+def test_decode_block_shape_test(shape, dtype, want):
+    assert pd.decode_block(shape, dtype) == want
+
+
+def test_engine_marks_dead_slots_and_counts_slab_blocks(tiny_lm, monkeypatch):
+    """The engine hands every slot without a live session position -1, and
+    with telemetry on counts the blocks each dispatch reads."""
+    lm, params = tiny_lm
+    monkeypatch.setenv("MXNET_PALLAS_ATTENTION", "1")
+    monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
+    was = telemetry._enabled
+    telemetry.enable()
+    try:
+        live0 = telemetry.counter("serving.generation.slab_blocks_live").value
+        total0 = telemetry.counter(
+            "serving.generation.slab_blocks_total").value
+        with GenerationEngine(lm, params, max_slots=4, max_len=256,
+                              buckets=(16,), prefix_cache=False) as eng:
+            assert eng._slab_block == 256
+            assert eng._tick_positions().tolist() == [-1] * 4
+            toks = eng.generate(np.arange(1, 6, dtype=np.int32),
+                                max_new_tokens=4)
+            assert len(toks) == 4
+        live = telemetry.counter(
+            "serving.generation.slab_blocks_live").value - live0
+        total = telemetry.counter(
+            "serving.generation.slab_blocks_total").value - total0
+    finally:
+        if not was:
+            telemetry.disable()
+    # 3 decode ticks (the first token is the prefill's), one live slot of
+    # four, one 256-row block a slot
+    assert (live, total) == (3, 12)
