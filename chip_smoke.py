@@ -9,7 +9,10 @@ calls, at the full width of the models the repo supports, in ONE process:
   (`multi_precision`) in bfloat16;
 * serve — `TransformerLM` at GPT-2-small width behind a `GenerationEngine`
   (continuous batching, prefix cache), 4 client threads x 3 streamed prompts,
-  plus the Pallas flash kernel in the compiled full forward.
+  plus the Pallas flash kernel in the compiled full forward; then a small
+  `HybridLM` (one period of the Granite 4.0-H pattern: 9 Mamba-2 layers and a
+  grouped-query attention layer) behind the same engine, whose cache holds a
+  recurrent state beside the K/V slab.
 
 `--multichip` runs ONLY the four-device phase and what it is compared with
 (transformer train step on sp=2,tp=2 and dp=4 meshes vs one device; the
@@ -48,6 +51,17 @@ LM = dict(vocab_size=50304, d_model=768, n_heads=12, d_ff=3072, n_layers=12,
 SERVE = dict(max_slots=8, buckets=(64, 256, 1024), clients=4,
              prompts_per_client=3, min_prompt=16, max_prompt=900,
              shared_prefix=256, max_new_tokens=32)
+# One period of the granite-4.0-h pattern at a small width: head sizes, state
+# size and chunk as published (64, 128, 256), so both kernels take their case.
+HYBRID = dict(vocab_size=50304, hidden_size=512, shared_intermediate_size=2048,
+              layer_types=("mamba",) * 5 + ("attention",) + ("mamba",) * 4,
+              num_attention_heads=8, num_key_value_heads=2,
+              attention_multiplier=0.015625, mamba_n_heads=16, mamba_d_head=64,
+              mamba_d_state=128, mamba_chunk_size=256, max_len=1024,
+              dtype="bfloat16")
+HYBRID_SERVE = dict(max_slots=4, buckets=(64, 256), prompts=(20, 64, 150, 255,
+                                                             90, 33),
+                    max_new_tokens=16)
 LM_TRAIN = dict(batch=8, seq=1024, steps=2)
 # SGD(momentum 0.9, wd 1e-4) on ONE repeated batch: half the usual
 # 0.1 x batch/256 so the loss falls step over step in bfloat16 too.
@@ -593,6 +607,87 @@ def phase_serve(seed, events, seen):
     log(f"[{name}] phase {time.perf_counter() - t_phase:.1f}s")
 
 
+def phase_serve_hybrid(seed, events, seen):
+    """A small hybrid model through the same engine: more requests than
+    slots, zero compiles after warm-up, which path the decode program holds
+    for each kind of state, and greedy parity with the plain re-forward."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu import compile_cache, parallel as par, serving
+    from mxnet_tpu.models import HybridLM, HybridLMConfig
+    from mxnet_tpu.serving import GenerationEngine
+
+    name = "serve/hybrid"
+    ev0 = events.snapshot()
+    t_phase = time.perf_counter()
+    dev = device_context(0).jax_device
+    cfg = HybridLMConfig(**HYBRID)
+    lm = HybridLM(cfg, par.create_mesh(devices=[dev], dp=1))
+    params = jax.jit(lm.init_params)(jax.random.PRNGKey(seed))
+    n_params = sum(int(np.prod(v.shape)) for v in params.values())
+    s = HYBRID_SERVE
+    eng = GenerationEngine(lm, params, max_slots=s["max_slots"],
+                           max_len=cfg.max_len, buckets=s["buckets"],
+                           prefix_cache=False, spec_k=0)
+    try:
+        warm = serving.warmup(eng)
+        kv, _, ssm, _ = eng._kv
+        block = eng._slab_block
+        state_kernel = lm.state_kernel(ssm.shape, ssm.dtype)
+        log(f"[{name}] {n_params / 1e6:.1f}M params {cfg.dtype}, "
+            f"{lm.n_mamba} mamba + {lm.n_attention} attention layers; cache "
+            f"K/V {tuple(kv.shape)} state {tuple(ssm.shape)} "
+            f"{eng.kv_slab_bytes() / 2**20:.0f} MiB; warmup compiled "
+            f"{warm['compiles']} programs in {warm['seconds']:.1f}s")
+        log(f"[{name}] decode K/V access: "
+            f"{'kernel, block ' + str(block) if block else 'xla'}; "
+            f"state update: {'kernel' if state_kernel else 'xla'}")
+        report_programs(name, seen)
+        key = next(k for k in eng.cache.keys() if k[0] == "decode")
+        args, kwargs = eng.cache._entry_stats[key]["avals"]
+        text = eng.cache._entries[key]._fn.lower(*args, **kwargs).as_text()
+        if block or state_kernel:
+            assert_kernel_in(text, f"{name}: decode program")
+        misses0 = compile_cache.named_stats("generation")["misses"]
+        rng = np.random.RandomState(seed)
+        prompts = [rng.randint(0, cfg.vocab_size, (n,)).astype(np.int32)
+                   for n in s["prompts"]]
+        t0 = time.perf_counter()
+        streams = [eng.submit(p, max_new_tokens=s["max_new_tokens"])
+                   for p in prompts]
+        got = [st.result(timeout=600) for st in streams]
+        t_traffic = time.perf_counter() - t0
+        misses1 = compile_cache.named_stats("generation")["misses"]
+        assert misses1 == misses0, (
+            f"{name}: {misses1 - misses0} compiles after warm-up")
+        log(f"[{name}] {len(got)} streamed requests over {s['max_slots']} "
+            f"slots ({min(s['prompts'])}-{max(s['prompts'])} prompt tokens) x"
+            f" {s['max_new_tokens']} new tokens in {t_traffic:.2f}s; "
+            f"compiles after warm-up: engine {misses1 - misses0}")
+        # greedy parity with the plain re-forward, teacher-forced
+        worst, exact, total = 0.0, 0, 0
+        for p, g in zip(prompts, got):
+            seq = np.concatenate([p, g[:-1]])
+            rows = np.asarray(jax.jit(lm.forward)(
+                params, jnp.asarray(seq[None])))[0, len(p) - 1:]
+            scale = np.abs(rows).max()
+            gaps = rows.max(-1) - rows[np.arange(len(g)), np.asarray(g)]
+            worst = max(worst, float(gaps.max() / scale))
+            exact += int((rows.argmax(-1) == np.asarray(g)).sum())
+            total += len(g)
+        log(f"[{name}] greedy parity: {exact}/{total} tokens equal the "
+            f"re-forward argmax; worst logit gap of a generated token "
+            f"{worst:.2e} of max|logit| (tol {LM_LOGIT_RTOL:.1e})")
+        assert worst <= LM_LOGIT_RTOL, (
+            f"{name}: generated tokens differ from the re-forward by "
+            f"{worst:.3e} of the logit scale")
+    finally:
+        eng.close()
+    events.report(name, ev0)
+    log(f"[{name}] phase {time.perf_counter() - t_phase:.1f}s")
+
+
 def compiled_forward(lm, params, tokens, kernel):
     """lm.forward compiled with the flash kernel on (the default on the TPU
     backend) or forced off. Returns (logits fp32 numpy, compiled text,
@@ -846,6 +941,7 @@ def main(argv=None):
         phase_train_module(args.seed, events, seen)
         phase_train_gluon(args.seed, events, seen)
         phase_serve(args.seed, events, seen)
+        phase_serve_hybrid(args.seed, events, seen)
     log(f"[total] {time.perf_counter() - t_start:.1f}s wall; jax compiled or "
         f"loaded {events.backend_compiles} programs in "
         f"{events.backend_compile_s:.1f}s; persistent cache hits="

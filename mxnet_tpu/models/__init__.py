@@ -8,8 +8,11 @@ exists for.
 """
 from . import transformer
 from .transformer import TransformerLMConfig, TransformerLM
+from . import hybrid
+from .hybrid import HybridLMConfig, HybridLM
 from . import resnet
 from .resnet import resnet50_symbol
 
 __all__ = ["transformer", "TransformerLMConfig", "TransformerLM",
+           "hybrid", "HybridLMConfig", "HybridLM",
            "resnet", "resnet50_symbol"]

@@ -85,7 +85,7 @@ def _write_rows(slab, layer, positions, rows):
     return slab
 
 
-def _attend_rows(q, cache_k, cache_v, layer, positions):
+def _attend_rows(q, cache_k, cache_v, layer, positions, scale=None):
     """Layer ``layer``'s decode attention in plain XLA: the layer's page is
     read where it lies in the slab (the slice fuses into the two
     reductions), all ``L`` rows of every slot, masked to
@@ -94,12 +94,24 @@ def _attend_rows(q, cache_k, cache_v, layer, positions):
     and must give finite garbage, not NaN. V rows past the position are
     selected away, not multiplied by a zero weight, so whatever a previous
     occupant left there (inf, nan) cannot reach the output; a dead slot's
-    attention is 0."""
+    attention is 0. ``q`` may carry a multiple of the slab's heads
+    (grouped-query attention: query head ``i`` reads slab head ``i //
+    group``); ``scale`` multiplies the scores (None: ``1/sqrt(hd)``)."""
     dt = q.dtype
-    L, hd = cache_k.shape[3], cache_k.shape[4]
+    heads, L, hd = cache_k.shape[2:]
+    scale = 1.0 / np.sqrt(hd) if scale is None else scale
     live = jnp.arange(L)[None, :] <= positions[:, None]              # [S,L]
+    if q.shape[1] != heads:
+        q = q.reshape(q.shape[0], heads, q.shape[1] // heads, hd)
+        s = jnp.einsum("shgd,shld->shgl", q, cache_k[:, layer].astype(dt),
+                       preferred_element_type=jnp.float32) * scale
+        p = jax.nn.softmax(jnp.where(live[:, None, None, :], s, -1e9),
+                           axis=-1).astype(dt)
+        v = jnp.where(live[:, None, :, None], cache_v[:, layer].astype(dt), 0)
+        return jnp.einsum("shgl,shld->shgd", p, v).reshape(
+            q.shape[0], -1, hd)
     s = jnp.einsum("shd,shld->shl", q, cache_k[:, layer].astype(dt),
-                   preferred_element_type=jnp.float32) * (1.0 / np.sqrt(hd))
+                   preferred_element_type=jnp.float32) * scale
     p = jax.nn.softmax(jnp.where(live[:, None, :], s, -1e9),
                        axis=-1).astype(dt)
     v = jnp.where(live[:, None, :, None], cache_v[:, layer].astype(dt), 0)
@@ -585,6 +597,17 @@ class TransformerLM:
         if self.mesh.size > 1 or not pa.pallas_enabled():
             return None
         return pd.decode_block(slab_shape, dtype)
+
+    def cache_traits(self, cache):
+        """What a serving engine may ask about a cache it otherwise only
+        carries (docs/faq/perf.md, "The cache protocol"): the decode
+        kernel's ``block`` over the slab's rows (None: the XLA formulation),
+        the per-slot bytes of state that is not rows (none here), and
+        whether every member is a range of rows, so that a slot can be
+        extended from an offset (:meth:`prefill_at`) and rolled back by not
+        advancing its position (:meth:`verify_step`)."""
+        return {"block": self.decode_block(cache[0].shape, cache[0].dtype),
+                "state_bytes_per_slot": 0, "rewindable": True}
 
     def decode_step(self, params, cache_k, cache_v, tokens, positions):
         """One fused incremental step over the WHOLE slot slab: each live

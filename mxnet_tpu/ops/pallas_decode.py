@@ -86,9 +86,11 @@ def _padded_heads(h, hd):
 
 def _kernel(n_ref, slot_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref, k_ref,
             v_ref, o_ref, ko_ref, vo_ref, m_sc, l_sc, acc_sc, q_sc, kn_sc,
-            vn_sc, *, scale, heads, hd, block):
-    """One (slot, L-block) grid step: every head's ``[hd, block]`` K and V
-    tiles against the slot's query, in groups of 128 positions; the group
+            vn_sc, *, scale, heads, group, hd, block):
+    """One (slot, L-block) grid step: every slab head's ``[hd, block]`` K
+    and V tiles against the slot's ``group`` queries of that head (grouped-
+    query attention: query head ``h * group + g`` reads slab head ``h``;
+    ``group`` 1 is one query a head), in groups of 128 positions; the group
     that holds the slot's position takes the new row and is written back.
     Heads run in a ``fori_loop`` that is unrolled when LOWERED: the body is
     traced once (unrolled in Python it cost seconds of tracing at every
@@ -100,8 +102,8 @@ def _kernel(n_ref, slot_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref, k_ref,
     pos = pos_ref[slot_ref[j]]
     live = j < n_ref[0]
 
-    def per_head(body):
-        lax.fori_loop(0, heads, lambda h, carry: body(h) or carry, 0,
+    def per_head(body, n=heads):
+        lax.fori_loop(0, n, lambda h, carry: body(h) or carry, 0,
                       unroll=True)
 
     @pl.when(jnp.logical_and(live, b == 0))
@@ -110,13 +112,21 @@ def _kernel(n_ref, slot_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref, k_ref,
         l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
         acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
         # a head's column of the query and of the new rows, along lanes
+        for h in range(heads * group):
+            q_sc[h] = jnp.broadcast_to(q_ref[0][:, h:h + 1], (hd, _LANES))
         for h in range(heads):
-            for src, dst in ((q_ref, q_sc), (kn_ref, kn_sc), (vn_ref, vn_sc)):
+            for src, dst in ((kn_ref, kn_sc), (vn_ref, vn_sc)):
                 dst[h] = jnp.broadcast_to(src[0][:, h:h + 1], (hd, _LANES))
 
     def attend(h, k, v, seen):
-        """Head ``h``'s ``[hd, 128]`` K and V tiles (fp32) into its running
-        softmax, which streams PER LANE: 128 running maxima, sums and PV
+        """Slab head ``h``'s ``[hd, 128]`` K and V tiles (fp32) into the
+        running softmax of each of its queries."""
+        for g in range(group):
+            attend_query(h if group == 1 else h * group + g, k, v, seen)
+
+    def attend_query(h, k, v, seen):
+        """Query head ``h``'s running softmax takes one ``[hd, 128]`` K and
+        V tile. It streams PER LANE: 128 running maxima, sums and PV
         columns a head, all elementwise — no reduction across lanes until
         the slot's last step. ``seen`` masks the lanes at or below the
         position; None when the whole group is."""
@@ -180,7 +190,7 @@ def _kernel(n_ref, slot_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref, k_ref,
             total = jnp.sum(l_sc[h] * w, axis=-1, keepdims=True)
             acc_sc[rows, :] = acc_sc[rows, :] * (w / total)
 
-        per_head(fold)
+        per_head(fold, heads * group)
         # the sum over lanes of every row, as one lane-dense row
         ones = jnp.ones((8, _LANES), jnp.float32)
         o = lax.dot_general(ones, acc_sc[...], (((1,), (1,)), ((), ())),
@@ -195,17 +205,20 @@ def _kernel(n_ref, slot_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref, k_ref,
         vo_ref[0, 0] = v_ref[0, 0, :, :, pl.ds(0, _LANES)]
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block", "scale", "interpret"))
 def decode_update_attend(q, k_new, v_new, slab_k, slab_v, layer, positions,
-                         *, block, interpret=False):
+                         *, block, scale=None, interpret=False):
     """One decode tick of layer ``layer`` on the slab
     (``[S, n_layers, H, L, hd]``, donated): for every slot with
     ``positions[s] >= 0`` store ``k_new[s]``/``v_new[s]`` ([S, H, hd]) at
     row ``positions[s]`` and attend ``q[s]`` over rows ``[0, positions[s]]``;
     a slot with a negative position is dead — nothing of it is read or
-    written, and its attention is 0. Returns ``(attention [S, H, hd] fp32,
-    slab_k, slab_v)``. ``block`` comes from :func:`decode_block`; positions
-    lie below ``L``.
+    written, and its attention is 0. ``q`` is ``[S, Hq, hd]`` with ``Hq`` a
+    multiple of ``H``: query head ``i`` reads slab head ``i // (Hq // H)``
+    (grouped-query attention; ``Hq == H`` is one query a head), decided from
+    the shapes. ``scale`` multiplies the scores (None: ``1/sqrt(hd)``).
+    Returns ``(attention [S, Hq, hd] fp32, slab_k, slab_v)``. ``block``
+    comes from :func:`decode_block`; positions lie below ``L``.
 
     ``layer`` is an int32 scalar and TRACED, and the function is jitted: a
     model calls it once a layer inside its own program, and every call
@@ -216,7 +229,11 @@ def decode_update_attend(q, k_new, v_new, slab_k, slab_v, layer, positions,
     if length % block or block % _LANES:
         raise ValueError(f"decode_update_attend: block {block} does not "
                          f"tile L={length} by whole lane rows")
-    padded = _padded_heads(heads, hd)
+    q_heads = q.shape[1]
+    if q_heads % heads:
+        raise ValueError(f"decode_update_attend: {q_heads} query heads do "
+                         f"not group over {heads} slab heads")
+    padded = _padded_heads(q_heads, hd)
     positions = positions.astype(jnp.int32)
     alive = positions >= 0
     # live slots first, in slot order; the steps past them stay on the last
@@ -241,8 +258,9 @@ def decode_update_attend(q, k_new, v_new, slab_k, slab_v, layer, positions,
     def columns(x):                               # [S, H, hd] -> [S, hd, H]
         return jnp.swapaxes(x.astype(jnp.float32), 1, 2)
 
-    kernel = functools.partial(_kernel, scale=1.0 / math.sqrt(hd),
-                               heads=heads, hd=hd, block=block)
+    kernel = functools.partial(
+        _kernel, scale=1.0 / math.sqrt(hd) if scale is None else scale,
+        heads=heads, group=q_heads // heads, hd=hd, block=block)
     view = (n_slots, slab_k.shape[1], heads, hd, length)
     out, slab_k, slab_v = pl.pallas_call(
         kernel,
@@ -250,7 +268,7 @@ def decode_update_attend(q, k_new, v_new, slab_k, slab_v, layer, positions,
             num_scalar_prefetch=4,
             grid=(n_slots, length // block),
             in_specs=[
-                pl.BlockSpec((1, hd, heads), row),
+                pl.BlockSpec((1, hd, q_heads), row),
                 pl.BlockSpec((1, hd, heads), row),
                 pl.BlockSpec((1, hd, heads), row),
                 pl.BlockSpec((1, 1, heads, hd, block), page),
@@ -262,10 +280,10 @@ def decode_update_attend(q, k_new, v_new, slab_k, slab_v, layer, positions,
                 pl.BlockSpec((1, 1, heads, hd, _LANES), written),
             ],
             scratch_shapes=[
-                pltpu.VMEM((heads, 1, _LANES), jnp.float32),     # max by lane
-                pltpu.VMEM((heads, 1, _LANES), jnp.float32),     # sum-exp
+                pltpu.VMEM((q_heads, 1, _LANES), jnp.float32),   # max by lane
+                pltpu.VMEM((q_heads, 1, _LANES), jnp.float32),   # sum-exp
                 pltpu.VMEM((padded * hd, _LANES), jnp.float32),  # PV
-                pltpu.VMEM((heads, hd, _LANES), jnp.float32),    # q by lane
+                pltpu.VMEM((q_heads, hd, _LANES), jnp.float32),  # q by lane
                 pltpu.VMEM((heads, hd, _LANES), jnp.float32),    # new K row
                 pltpu.VMEM((heads, hd, _LANES), jnp.float32),    # new V row
             ]),
@@ -282,6 +300,6 @@ def decode_update_attend(q, k_new, v_new, slab_k, slab_v, layer, positions,
     )(n_live[None], slot_of, positions,
       jnp.asarray(layer, jnp.int32).reshape(1), columns(q), columns(k_new),
       columns(v_new), jnp.swapaxes(slab_k, 3, 4), jnp.swapaxes(slab_v, 3, 4))
-    attn = out[:, 0, :heads * hd].reshape(n_slots, heads, hd)
+    attn = out[:, 0, :q_heads * hd].reshape(n_slots, q_heads, hd)
     return (jnp.where(alive[:, None, None], attn, 0.0),
             jnp.swapaxes(slab_k, 3, 4), jnp.swapaxes(slab_v, 3, 4))

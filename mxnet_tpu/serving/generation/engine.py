@@ -1,4 +1,6 @@
-"""GenerationEngine — token-level continuous batching over a KV slot slab.
+"""GenerationEngine — token-level continuous batching over a slot-major
+serving cache that the MODEL defines (a KV slot slab; a recurrent model's
+per-slot state beside it).
 
 PR 5's :class:`~mxnet_tpu.serving.batcher.DynamicBatcher` schedules at
 REQUEST granularity: a batch forms, computes once, and every member leaves
@@ -8,9 +10,15 @@ request-level batching would hold every finished sequence hostage to the
 longest one (and re-running the full forward per token would cost O(T) per
 token, O(T²) per sequence). This engine is the token-level scheduler:
 
-* **slot-based session store** — a preallocated KV slab
-  ``[max_slots, layers, heads, max_len, head_dim]``
-  (:meth:`TransformerLM.init_cache`) whose shape NEVER changes: admitting
+* **slot-based session store** — a preallocated cache the model defines
+  (``model.init_cache``: a tuple of arrays, each slot-major; for
+  :class:`TransformerLM` the K and V slabs ``[max_slots, layers, heads,
+  max_len, head_dim]``, for :class:`HybridLM` those of its attention
+  layers plus a recurrent and a convolution state per slot). The engine
+  carries it as one value — donated whole to every program, forked and
+  parked one slot of every member at a time — and asks the model what it
+  must know (``cache_traits``; docs/faq/perf.md, "The cache protocol").
+  Its shape NEVER changes: admitting
   a session is a prefill write into a free slot index, evicting is
   clearing host-side metadata — continuous batching without a recompile,
   ever (the arXiv:2603.09555 compile-once O(1)-cache discipline).
@@ -21,8 +29,11 @@ token, O(T²) per sequence). This engine is the token-level scheduler:
   at position -1, neither read nor written
   (:meth:`TransformerLM.decode_step`; on one TPU chip the slab kernel of
   ``ops/pallas_decode.py`` reads just the live blocks, elsewhere the XLA
-  formulation masks the whole page). The tick then evicts
-  finished/EOS/deadline-expired sessions, and
+  formulation masks the whole page). Where no live session can end on a
+  token's value the NEXT decode is dispatched before the current one's
+  tokens are fetched (:meth:`_dispatch_ahead`), so the device runs decodes
+  back to back and the host's share of a tick hides behind them. The tick
+  then evicts finished/EOS/deadline-expired sessions, and
   admits queued prefills into the freed slots mid-stream. The intake is
   PR 5's :class:`~mxnet_tpu.serving.admission.AdmissionQueue`
   (``QueueFullError`` backpressure, ``ServerClosedError`` after close,
@@ -59,7 +70,9 @@ token, O(T²) per sequence). This engine is the token-level scheduler:
 Telemetry rides ``serving.generation.*`` (live-slot gauge, tokens/s,
 TTFT/tick histograms, per-reason eviction counters, derived
 ``slot_fill_ratio``, ``slab_blocks_live``/``slab_blocks_total`` — the
-share of the slab's blocks each dispatch had to read — plus ``prefix.{hits,misses,forks,inserts,
+share of the slab's blocks each dispatch had to read —
+``state_slots_live``/``state_bytes_touched``/``state_bytes_resident`` for a
+model with per-slot state that is not rows, ``prefill_tokens``, plus ``prefix.{hits,misses,forks,inserts,
 evictions}``/``prefix.cached_tokens`` and ``spec.{proposed,accepted,
 rolled_back,committed}`` with derived ``spec.acceptance_ratio``).
 ``queue_wait_us`` is submit → start of the admission (host events at both
@@ -78,6 +91,7 @@ the same slab buffers, so the census never double-counts them.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 
@@ -182,9 +196,11 @@ class GenerationEngine:
 
     Parameters
     ----------
-    model : TransformerLM
-        Functional model providing ``init_cache`` / ``prefill`` /
-        ``decode_step`` (pure, jit-able, cache-donating).
+    model : a model offering the cache protocol
+        (:class:`TransformerLM`, :class:`HybridLM`): ``cfg.max_len``,
+        ``mesh``, ``param_specs``, ``init_cache`` / ``cache_traits`` /
+        ``prefill`` / ``decode_step`` (pure, jit-able, cache-donating),
+        and ``prefill_at`` / ``verify_step`` when its cache is rewindable.
     params : dict[str, jax.Array]
         The model's parameters (``init_params`` placement).
     max_slots / max_len / buckets / tick_budget_ms :
@@ -200,6 +216,8 @@ class GenerationEngine:
         Overrides of ``MXNET_GENERATION_PREFIX_CACHE`` /
         ``_PREFIX_MIN_TOKENS`` — cache prompt-prefix KV in free slab
         slots and admit matching prompts via fork + suffix prefill.
+        Refused (``MXNetError``) for a model whose cache is not
+        rewindable (a recurrent state), as is ``spec_k > 0``.
     spec_k : int, optional
         Override of ``MXNET_GENERATION_SPEC_K`` — draft length for the
         speculative verify lane (0 = plain one-token decode). The slab
@@ -271,14 +289,31 @@ class GenerationEngine:
         #                              parked_at}
         self._park_free = list(range(self._slots, self._total_slots))
         self._admit_seq = 0
-        self._ck, self._cv = model.init_cache(self._total_slots,
-                                              self._slab_len)
+        # the cache belongs to the model: a tuple of arrays, slot-major,
+        # that the engine carries whole — donated to every program, forked
+        # and parked one slot of every member at a time — and otherwise
+        # only asks the model about (cache_traits)
+        self._kv = tuple(model.init_cache(self._total_slots,
+                                          self._slab_len))
+        traits = model.cache_traits(self._kv)
         # the decode kernel's block over the slab's rows, None when the
         # model keeps the XLA formulation (telemetry only)
-        self._slab_block = model.decode_block(self._ck.shape, self._ck.dtype)
+        self._slab_block = traits["block"]
+        # per-slot bytes of state that is not a range of rows (a recurrent
+        # layer's): read and written whole by every tick a slot is live
+        self._state_bytes = int(traits["state_bytes_per_slot"])
         # host-side slot metadata — only the tick loop (under _tick_lock)
         # mutates these
         self._sessions = [None] * self._total_slots
+        # a decode dispatched one tick AHEAD of its commit (_dispatch_ahead):
+        # the pending state the next tick commits, None when none is out
+        self._ahead = None
+        # where the decode's token argument lives: placed like the token
+        # OUTPUT of the decode before it, which _dispatch_ahead feeds back
+        # — a host array left to jax's default placement would make the
+        # same program compile a second time for the fed-back kind
+        from jax.sharding import NamedSharding, PartitionSpec
+        self._token_sharding = NamedSharding(model.mesh, PartitionSpec())
         self._lengths = np.zeros(self._total_slots, np.int32)
         self._last_tok = np.zeros(self._total_slots, np.int32)
         self._live = 0
@@ -306,6 +341,14 @@ class GenerationEngine:
 
         use_prefix = (bool(getenv("MXNET_GENERATION_PREFIX_CACHE"))
                       if prefix_cache is None else bool(prefix_cache))
+        if (use_prefix or self._spec_k) and not traits["rewindable"]:
+            # prefix reuse extends a slot from a row offset and speculation
+            # rolls it back by not advancing a position: both need every
+            # member of the cache to be a range of rows
+            raise MXNetError(
+                f"{'prefix cache' if use_prefix else 'speculative decoding'}"
+                f" (prefix_cache={use_prefix}, spec_k={self._spec_k}) cannot"
+                f" serve this model: {traits['why_not_rewindable']}")
         if use_prefix and getattr(model.cfg, "moe_experts", 0) > 0:
             # MoE expert capacity is computed over the forward's input
             # length, so a suffix-only prefill can capacity-drop
@@ -332,8 +375,7 @@ class GenerationEngine:
 
         # the slab is device state the engine REPLACES every tick, so the
         # census needs a live view, not a snapshot weakref
-        memory.register_provider("kv_cache", self,
-                                 lambda e: [e._ck, e._cv])
+        memory.register_provider("kv_cache", self, lambda e: list(e._kv))
 
         self._worker = None
         if start:
@@ -470,10 +512,29 @@ class GenerationEngine:
         return queue_ready(self._queue)
 
     def kv_slab_bytes(self):
-        """Total device bytes the KV slab pins (both key and value
-        arrays) — the number ``docs/faq/perf.md`` "Sizing the KV slab"
-        budgets."""
-        return int(self._ck.nbytes) + int(self._cv.nbytes)
+        """Total device bytes the serving cache pins (every member: key and
+        value slabs, and a recurrent model's state) — the number
+        ``docs/faq/perf.md`` "Sizing the KV slab" budgets."""
+        return sum(int(leaf.nbytes) for leaf in self._kv)
+
+    def slot_snapshot(self, slot):
+        """Host copies of one slot of every member of the cache, in the
+        cache's order, taken between ticks — for tests and for comparing
+        what a session left (its K/V rows, a recurrent model's state) with
+        a reference. A slot keeps a finished session's contents until the
+        next occupant's prefill."""
+        with self._tick_lock:
+            return tuple(np.asarray(leaf[int(slot)]) for leaf in self._kv)
+
+    # the K and V slabs of a cache that leads with them (both models'
+    # do): what tests and debuggers index; the engine itself does not
+    @property
+    def _ck(self):
+        return self._kv[0]
+
+    @property
+    def _cv(self):
+        return self._kv[1]
 
     def bucket_for(self, n):
         for b in self._buckets:
@@ -579,14 +640,14 @@ class GenerationEngine:
                         "warm for bucket %d", b)
                     continue
                 fn = self._prefill_fn(b)
-                _, self._ck, self._cv = fn(
-                    self._params, self._ck, self._cv,
+                _, self._kv = fn(
+                    self._params, self._kv,
                     jnp.zeros((b,), jnp.int32), jnp.asarray(1, jnp.int32),
                     jnp.asarray(free, jnp.int32))
                 if self._prefix is not None:
                     fn = self._suffix_prefill_fn(b)
-                    _, self._ck, self._cv = fn(
-                        self._params, self._ck, self._cv,
+                    _, self._kv = fn(
+                        self._params, self._kv,
                         jnp.zeros((b,), jnp.int32),
                         jnp.asarray(1, jnp.int32),
                         jnp.asarray(free, jnp.int32),
@@ -596,16 +657,13 @@ class GenerationEngine:
                 # (the prefix cache's admission fork AND the QoS
                 # preempt/park/resume path share this one executable —
                 # warming it here is what keeps preemption compile-free)
-                fn = self._fork_fn()
-                self._ck, self._cv = fn(self._ck, self._cv,
-                                        jnp.asarray(free, jnp.int32),
-                                        jnp.asarray(free, jnp.int32))
+                self._fork(free, free)
             idle = self._live == 0
             if self._spec_k:
                 if idle:
                     fn = self._verify_fn()
-                    _, self._ck, self._cv = fn(
-                        self._params, self._ck, self._cv,
+                    _, self._kv = fn(
+                        self._params, self._kv,
                         jnp.zeros((self._total_slots, self._spec_k + 1),
                                   jnp.int32),
                         jnp.asarray(self._tick_positions()))
@@ -616,10 +674,15 @@ class GenerationEngine:
                         "verify/draft warm")
             elif idle:
                 fn = self._decode_fn()
-                _, self._ck, self._cv = fn(
-                    self._params, self._ck, self._cv,
-                    jnp.asarray(self._last_tok),
+                toks, self._kv = fn(
+                    self._params, self._kv,
+                    self._tokens_on_device(self._last_tok),
                     jnp.asarray(self._tick_positions()))
+                # the token select of _dispatch_ahead (an eager op: jax's
+                # own cache holds it, not this engine's)
+                jnp.where(self._tokens_on_device(
+                    np.ones(self._total_slots, bool)),
+                    self._tokens_on_device(self._last_tok), toks)
         compiles = self._cache.misses - misses0
         seconds = time.perf_counter() - t0
         self._warmed = True           # readiness: warmup complete
@@ -846,12 +909,12 @@ class GenerationEngine:
             import jax
             import jax.numpy as jnp
 
-            def fn(params, ck, cv, toks, length, slot):
-                logits, ck, cv = model.prefill(params, ck, cv, toks,
+            def fn(params, cache, toks, length, slot):
+                logits, *cache = model.prefill(params, *cache, toks,
                                                length, slot)
-                return jnp.argmax(logits).astype(jnp.int32), ck, cv
+                return jnp.argmax(logits).astype(jnp.int32), tuple(cache)
 
-            return jax.jit(fn, donate_argnums=(1, 2))
+            return jax.jit(fn, donate_argnums=(1,))
 
         key = ("prefill", bucket, self._total_slots, self._slab_len)
         return cache.get_or_build(key, build)
@@ -866,39 +929,59 @@ class GenerationEngine:
             import jax
             import jax.numpy as jnp
 
-            def fn(params, ck, cv, tokens, positions):
-                logits, ck, cv = model.decode_step(params, ck, cv, tokens,
+            def fn(params, cache, tokens, positions):
+                logits, *cache = model.decode_step(params, *cache, tokens,
                                                    positions)
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32), ck, cv
+                return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                        tuple(cache))
 
-            return jax.jit(fn, donate_argnums=(1, 2))
+            return jax.jit(fn, donate_argnums=(1,))
 
         key = ("decode", self._total_slots, self._slab_len)
         return cache.get_or_build(key, build)
 
     def _fork_fn(self):
-        """THE prefix-fork executable: copy one slot's slab rows (both K
-        and V, all layers) onto another slot, src/dst traced — one
-        program serves every (cached entry, session slot) pair. Slab
-        donated; a cache hit costs one dispatch plus the suffix prefill."""
+        """THE fork executable: copy one slot of EVERY member of the cache
+        (K and V rows of all layers; a recurrent model's state too) onto
+        another slot, src/dst traced — one program serves every (cached
+        entry, session slot) pair and the QoS park/resume. Cache donated;
+        a prefix hit costs one dispatch plus the suffix prefill."""
         cache = self._cache
 
         def build():
             import jax
             from jax import lax
 
-            def fn(ck, cv, src, dst):
-                rk = lax.dynamic_slice(ck, (src, 0, 0, 0, 0),
-                                       (1,) + ck.shape[1:])
-                rv = lax.dynamic_slice(cv, (src, 0, 0, 0, 0),
-                                       (1,) + cv.shape[1:])
-                return (lax.dynamic_update_slice(ck, rk, (dst, 0, 0, 0, 0)),
-                        lax.dynamic_update_slice(cv, rv, (dst, 0, 0, 0, 0)))
+            def fn(kv, src, dst):
+                def copy(leaf):
+                    rest = (0,) * (leaf.ndim - 1)
+                    row = lax.dynamic_slice(leaf, (src,) + rest,
+                                            (1,) + leaf.shape[1:])
+                    return lax.dynamic_update_slice(leaf, row, (dst,) + rest)
 
-            return jax.jit(fn, donate_argnums=(0, 1))
+                return tuple(copy(leaf) for leaf in kv)
+
+            return jax.jit(fn, donate_argnums=(0,))
 
         key = ("fork", self._total_slots, self._slab_len)
         return cache.get_or_build(key, build)
+
+    def _tokens_on_device(self, host):
+        """A per-slot host array as the decode's token argument."""
+        import jax
+
+        return jax.device_put(host, self._token_sharding)
+
+    def _fork(self, src, dst):
+        """Run the fork executable: slot ``src`` of the cache onto slot
+        ``dst`` (prefix insert and hit, QoS park and resume)."""
+        import jax.numpy as jnp
+
+        with tracing.span("generation.fork", cat="generation", src=int(src),
+                          dst=int(dst)):
+            self._kv = self._fork_fn()(self._kv,
+                                       jnp.asarray(src, jnp.int32),
+                                       jnp.asarray(dst, jnp.int32))
 
     def _suffix_prefill_fn(self, bucket):
         """The bucket's suffix-prefill executable: the prompt tail after
@@ -910,12 +993,12 @@ class GenerationEngine:
             import jax
             import jax.numpy as jnp
 
-            def fn(params, ck, cv, toks, length, slot, offset):
-                logits, ck, cv = model.prefill_at(params, ck, cv, toks,
+            def fn(params, cache, toks, length, slot, offset):
+                logits, *cache = model.prefill_at(params, *cache, toks,
                                                   length, slot, offset)
-                return jnp.argmax(logits).astype(jnp.int32), ck, cv
+                return jnp.argmax(logits).astype(jnp.int32), tuple(cache)
 
-            return jax.jit(fn, donate_argnums=(1, 2))
+            return jax.jit(fn, donate_argnums=(1,))
 
         key = ("suffix_prefill", bucket, self._total_slots, self._slab_len)
         return cache.get_or_build(key, build)
@@ -931,13 +1014,13 @@ class GenerationEngine:
             import jax
             import jax.numpy as jnp
 
-            def fn(params, ck, cv, tokens, positions):
-                logits, ck, cv = model.verify_step(params, ck, cv, tokens,
+            def fn(params, cache, tokens, positions):
+                logits, *cache = model.verify_step(params, *cache, tokens,
                                                    positions)
                 return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
-                        ck, cv)
+                        tuple(cache))
 
-            return jax.jit(fn, donate_argnums=(1, 2))
+            return jax.jit(fn, donate_argnums=(1,))
 
         key = ("verify", self._spec_k, self._total_slots, self._slab_len)
         return cache.get_or_build(key, build)
@@ -946,7 +1029,7 @@ class GenerationEngine:
 
     def _has_work(self):
         return (self._live > 0 or len(self._queue) > 0
-                or len(self._parked) > 0)
+                or len(self._parked) > 0 or self._ahead is not None)
 
     def _loop(self):
         while True:
@@ -1012,13 +1095,33 @@ class GenerationEngine:
                     # outputs, so they join the NEXT tick's decode —
                     # per-session token streams stay bit-exact with the
                     # lockstep order below.
-                    decoded = self._live > 0
                     t_dec = time.perf_counter()
-                    pending = self._decode_dispatch()
+                    # the decode this tick commits is the one the last
+                    # tick dispatched AHEAD, else one dispatched now; where
+                    # it may (_lookahead_ok) the decode after it goes out
+                    # before the commit, so the device has its next program
+                    # queued while the host fetches, commits and dispatches
+                    pending, self._ahead = self._ahead, None
+                    first = pending is None
+                    if first:
+                        pending = self._decode_dispatch()
+                    if pending is not None and self._lookahead_ok(pending):
+                        # (a tick that dispatched its own decode above has
+                        # its span already: a tick shows one decode child)
+                        self._ahead = self._dispatch_ahead(pending,
+                                                           span=not first)
+                    decoded = pending is not None
                     self._sweep()
-                    self._admit()
+                    # a live slot is parked only BETWEEN decodes: the one in
+                    # flight advances its slot, and a recurrent state is
+                    # not advanced twice by the same token the way a K/V
+                    # row is rewritten (the parked copy would hold a token
+                    # that the identity guard then discards at commit)
+                    self._admit(preempt=pending is None)
                     if pending is not None:
                         self._decode_commit(pending)
+                        if self._qos is not None:
+                            self._admit()
                     # the dispatch→commit window: the swept bookkeeping
                     # rides INSIDE it, so wall − dec_s (the lane's
                     # host_gap_us) is exactly the host work the overlap
@@ -1045,9 +1148,10 @@ class GenerationEngine:
                 # lived in the same donated buffers) — never-strand
                 for park, rec in list(self._parked.items()):
                     self._fail_parked(park, rec, e)
+                self._ahead = None
                 # the failed executable may have consumed the donated slab
-                self._ck, self._cv = self._model.init_cache(
-                    self._total_slots, self._slab_len)
+                self._kv = tuple(self._model.init_cache(
+                    self._total_slots, self._slab_len))
                 if self._prefix is not None:
                     # the cached rows died with the donated buffers
                     self._prefix.clear("slab_reset")
@@ -1167,6 +1271,19 @@ class GenerationEngine:
             live = total
         telemetry.counter("serving.generation.slab_blocks_live").inc(live)
         telemetry.counter("serving.generation.slab_blocks_total").inc(total)
+        if self._state_bytes:
+            self._count_state(steps * int((positions >= 0).sum()))
+
+    def _count_state(self, live):
+        """Telemetry of a model whose cache holds per-slot state that is not
+        rows: the live slots whose state this dispatch advances, the bytes
+        that moves at the least (each live slot's state read once and
+        written once), and what the state pins. Host arithmetic."""
+        telemetry.counter("serving.generation.state_slots_live").inc(live)
+        telemetry.counter("serving.generation.state_bytes_touched").inc(
+            2 * live * self._state_bytes)
+        telemetry.gauge("serving.generation.state_bytes_resident").set(
+            self._total_slots * self._state_bytes)
 
     def _prefix_claimable(self):
         """Cache entries session pressure may evict: everything above the
@@ -1191,7 +1308,7 @@ class GenerationEngine:
             return self._prefix.evict_lru("slot_pressure")
         return None
 
-    def _admit(self):
+    def _admit(self, preempt=True):
         """Move queued sessions into free slots (prefill), oldest first
         (QoS active: class/deadline order), until the slab is full, the
         queue is empty, or the tick budget is spent — at least one
@@ -1199,9 +1316,10 @@ class GenerationEngine:
         a cached prefix), so backlog always drains even under a tiny
         budget. Under QoS, a full slab with a higher-class request at
         the queue head first PARKS the youngest batch session (one per
-        tick — bounded churn) to free its slot."""
+        tick — bounded churn) to free its slot; ``preempt`` False (a
+        decode is in flight) leaves that to the pass after its commit."""
         free = self._free_slots()
-        if self._qos is not None and not free:
+        if self._qos is not None and not free and preempt:
             freed = self._preempt_for_priority()
             if freed is not None:
                 free = [freed]
@@ -1282,8 +1400,8 @@ class GenerationEngine:
                         padded = np.zeros(bucket, np.int32)
                         padded[:n] = sess.prompt
                         fn = self._prefill_fn(bucket)
-                        tok, self._ck, self._cv = fn(
-                            self._params, self._ck, self._cv,
+                        tok, self._kv = fn(
+                            self._params, self._kv,
                             jnp.asarray(padded), jnp.asarray(n, jnp.int32),
                             jnp.asarray(slot, jnp.int32))
                         if tele and self._prefix is not None:
@@ -1314,7 +1432,7 @@ class GenerationEngine:
                                   cat="generation", parent=sess.span,
                                   bucket=bucket, slot=slot,
                                   cached_prefix=sess.prefix_len)
-            sess.slot = slot
+            sess.slot = sess.stream.slot = slot
             # pinned for the session's whole life: after a swap the tick
             # keeps decoding this session under these exact weights
             sess.version = self._weights_version
@@ -1329,6 +1447,8 @@ class GenerationEngine:
             self._deliver(sess, tok, first=True)
             if tele:
                 telemetry.counter("serving.generation.prefills").inc()
+                telemetry.counter("serving.generation.prefill_tokens").inc(
+                    n - sess.prefix_len)
                 telemetry.histogram(
                     "serving.generation.queue_wait_us").record(waited_us)
                 # prefill dispatch -> first token on the host. Under
@@ -1349,10 +1469,7 @@ class GenerationEngine:
                 if self._prefix.insert(sess.prompt, cslot,
                                        version=sess.version) is not None:
                     free.pop(0)
-                    fn = self._fork_fn()
-                    self._ck, self._cv = fn(
-                        self._ck, self._cv, jnp.asarray(slot, jnp.int32),
-                        jnp.asarray(cslot, jnp.int32))
+                    self._fork(slot, cslot)
             # the prompt's last token may already end the session; a slot
             # freed that way goes straight back on the free list so a
             # burst of first-token-EOS sessions drains within the tick
@@ -1375,13 +1492,10 @@ class GenerationEngine:
         padded[:ns] = suffix
         self._prefix.acquire(node)
         try:
-            fk = self._fork_fn()
-            self._ck, self._cv = fk(self._ck, self._cv,
-                                    jnp.asarray(node.slot, jnp.int32),
-                                    jnp.asarray(slot, jnp.int32))
+            self._fork(node.slot, slot)
             fn = self._suffix_prefill_fn(bucket)
-            tok, self._ck, self._cv = fn(
-                self._params, self._ck, self._cv, jnp.asarray(padded),
+            tok, self._kv = fn(
+                self._params, self._kv, jnp.asarray(padded),
                 jnp.asarray(ns, jnp.int32), jnp.asarray(slot, jnp.int32),
                 jnp.asarray(m, jnp.int32))
         finally:
@@ -1440,9 +1554,10 @@ class GenerationEngine:
             positions = self._tick_positions(slots if mixed else None)
             with tracing.span("generation.decode", cat="generation",
                               live=len(slots), version=version):
-                toks, self._ck, self._cv = fn(
-                    self._version_params(version), self._ck, self._cv,
-                    jnp.asarray(self._last_tok), jnp.asarray(positions))
+                toks, self._kv = fn(
+                    self._version_params(version), self._kv,
+                    self._tokens_on_device(self._last_tok),
+                    jnp.asarray(positions))
             if telemetry._enabled:
                 self._count_slab_blocks(positions)
             # snapshot the cohort's sessions: a slot evicted or re-
@@ -1451,6 +1566,58 @@ class GenerationEngine:
             pending.append((slots, [self._sessions[s] for s in slots],
                             toks))
         return ("plain", pending)
+
+    def _lookahead_ok(self, pending):
+        """Whether the decode AFTER ``pending`` may be dispatched before
+        ``pending`` is committed: it then takes its tokens from
+        ``pending``'s device output, and which slots it advances must be
+        known without them — so no session may end on a token's value (an
+        ``eos_id``), and the plain one-cohort path only (no speculation, no
+        QoS parking, one weights version)."""
+        return (pending[0] == "plain" and len(pending[1]) == 1
+                and self._qos is None and len(self._param_sets) == 1
+                and all(s is None or s.eos_id is None
+                        for s in self._sessions))
+
+    def _dispatch_ahead(self, pending, span=True):
+        """Dispatch the decode that follows ``pending`` (dispatched, not yet
+        committed): a session ``pending`` advances and that does not end
+        with it (by its token count or the slab's capacity — what
+        :meth:`_maybe_finish` will find at the commit) goes on one position
+        further with the token ``pending`` leaves on the device; a session
+        admitted since starts from its prefill's token on the host; every
+        other slot is dead. Returns the new pending state, None when no
+        slot goes on."""
+        import jax.numpy as jnp
+
+        (slots, snap, toks), = pending[1]
+        positions = np.full(self._total_slots, -1, np.int32)
+        from_host = np.ones(self._total_slots, bool)
+        out = set()                 # slots whose session ``pending`` holds
+        for slot, sess in zip(slots, snap):
+            if self._sessions[slot] is not sess:
+                continue            # evicted or replaced since the dispatch
+            out.add(slot)
+            if (sess.generated + 1 < sess.max_new_tokens
+                    and self._lengths[slot] + 2 <= self._max_len):
+                positions[slot] = self._lengths[slot] + 1
+                from_host[slot] = False
+        for slot, sess in enumerate(self._sessions):
+            if sess is not None and slot not in out:
+                positions[slot] = self._lengths[slot]
+        live = [int(s) for s in np.nonzero(positions >= 0)[0]]
+        if not live:
+            return None
+        with (tracing.span("generation.decode", cat="generation",
+                           live=len(live), version=self._weights_version,
+                           ahead=True) if span else contextlib.nullcontext()):
+            tokens = jnp.where(self._tokens_on_device(from_host),
+                               self._tokens_on_device(self._last_tok), toks)
+            toks, self._kv = self._decode_fn()(
+                self._params, self._kv, tokens, jnp.asarray(positions))
+        if telemetry._enabled:
+            self._count_slab_blocks(positions)
+        return ("plain", [(live, [self._sessions[s] for s in live], toks)])
 
     def _decode_commit(self, state):
         """Block on the dispatched token outputs and commit them:
@@ -1510,8 +1677,8 @@ class GenerationEngine:
             positions = self._tick_positions(slots if mixed else None)
             with tracing.span("generation.verify", cat="generation",
                               live=len(slots), k=k, version=version):
-                toks, self._ck, self._cv = fn(
-                    self._version_params(version), self._ck, self._cv,
+                toks, self._kv = fn(
+                    self._version_params(version), self._kv,
                     jnp.asarray(tokens), jnp.asarray(positions))
             if telemetry._enabled:
                 self._count_slab_blocks(positions, steps=k + 1)
@@ -1732,8 +1899,6 @@ class GenerationEngine:
 
         Zero new executables: the fork program is the prefix cache's /
         warm()'s, keyed ``("fork", total_slots, slab_len)``."""
-        import jax.numpy as jnp
-
         if not self._park_free:
             return None
         head = self._queue.peek()
@@ -1753,10 +1918,7 @@ class GenerationEngine:
         sess = self._sessions[victim]
         park = self._park_free.pop()
         try:
-            fn = self._fork_fn()
-            self._ck, self._cv = fn(self._ck, self._cv,
-                                    jnp.asarray(victim, jnp.int32),
-                                    jnp.asarray(park, jnp.int32))
+            self._fork(victim, park)
         except Exception:
             # the victim is still live in its slot; the tick handler's
             # sweep will fail it with everyone else
@@ -1803,17 +1965,12 @@ class GenerationEngine:
         and greedy decode continues bit-exact with an uninterrupted run
         (fork is a bitwise row copy; decode is slot-index-independent).
         Returns True when a session was resumed."""
-        import jax.numpy as jnp
-
         park = min(self._parked,
                    key=lambda p: self._parked[p]["parked_at"])
         rec = self._parked.pop(park)
         sess = rec["sess"]
         try:
-            fn = self._fork_fn()
-            self._ck, self._cv = fn(self._ck, self._cv,
-                                    jnp.asarray(park, jnp.int32),
-                                    jnp.asarray(slot, jnp.int32))
+            self._fork(park, slot)
         except Exception as e:
             # never-strand: the session is now in neither _parked nor a
             # slot — fail its stream here, then let the tick handler
@@ -1824,7 +1981,7 @@ class GenerationEngine:
                 sess.span.set(error=repr(e), reason="error").finish()
             raise
         self._park_free.append(park)
-        sess.slot = slot
+        sess.slot = sess.stream.slot = slot
         self._sessions[slot] = sess
         self._lengths[slot] = rec["length"]
         self._last_tok[slot] = rec["last_tok"]

@@ -55,6 +55,10 @@ class GenerationStream:
         # whose K/V came from the prefix cache (0 = full prefill) — the
         # client-visible "why was my TTFT fast" signal
         self.cached_prefix_len = 0
+        # the cache slot the session was last admitted (or resumed) into;
+        # after it ends, the slot keeps what it held until the next
+        # occupant (GenerationEngine.slot_snapshot reads it)
+        self.slot = None
 
     # -- engine side ---------------------------------------------------------
 

@@ -235,3 +235,71 @@ def test_decode_program_touches_no_slab_page_on_v5e(one_chip, monkeypatch,
     # no temporary of a page's size (2 bytes an element), nor of the
     # embedding table's: its rows are sliced where the table lies
     assert ma.temp_size_in_bytes < page
+
+
+# ---------------------------------------------------------------------------
+# the hybrid model's decode program at granite-4.0-h-micro's widths (ISSUE 26)
+# ---------------------------------------------------------------------------
+
+def test_hybrid_decode_program_updates_state_in_place_on_v5e(one_chip,
+                                                             monkeypatch):
+    """The engine's decode program for one period (10 layers: 9 Mamba-2, 1
+    grouped-query attention) of granite-4.0-h-micro at its published widths,
+    32 slots x 4,096 rows: it compiles for the v5e; both kernels are in it
+    (the K/V slab's with 4 queries a head, the recurrent state's); every
+    member of the cache is aliased input to output; and no XLA op copies,
+    slices, updates or re-lays the recurrent-state slab — each live slot's
+    state is read once and written once, by the kernel, where it lies."""
+    import json
+    import os
+
+    from mxnet_tpu import parallel as par
+    from mxnet_tpu.models import HybridLM, HybridLMConfig
+
+    monkeypatch.setenv("MXNET_PALLAS_ATTENTION", "1")
+    monkeypatch.delenv("MXNET_PALLAS_INTERPRET", raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "granite_4_0_h_micro.json")) as f:
+        published = json.load(f)
+    published["layer_types"] = published["layer_types"][:10]     # a period
+    config = HybridLMConfig.from_config(published)
+    slots, rows = 32, 4096
+    dev = next(iter(one_chip.device_set))
+    lm = HybridLM(config, par.create_mesh(devices=[dev], dp=1))
+    host_lm = HybridLM(config, par.create_mesh(devices=jax.devices()[:1],
+                                               dp=1))
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = {k: sds(v) for k, v in jax.eval_shape(
+        host_lm.init_params, jax.random.PRNGKey(0)).items()}
+    cache = tuple(sds(v) for v in jax.eval_shape(
+        lambda: host_lm.init_cache(slots, rows)))
+    assert [c.shape for c in cache] == [
+        (32, 1, 8, 4096, 64), (32, 1, 8, 4096, 64), (32, 9, 64, 64, 128),
+        (32, 9, 3, 4352)]
+    assert lm.decode_block(cache[0].shape, cache[0].dtype) == 256
+    assert lm.state_kernel(cache[2].shape, cache[2].dtype)
+
+    def fn(params, cache, tokens, positions):       # the engine's wrapper
+        logits, *cache = lm.decode_step(params, *cache, tokens, positions)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), tuple(cache)
+
+    ints = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, ints, ints).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 10      # 9 state + 1 K/V kernel
+    assert len(re.findall(r"%mamba_state_update[.\d]* = ", text)) == 9
+    state = slots * 64 * 64 * 128                   # one layer's page
+    moved = [line for line in _page_sized_ops(text, state)
+             if "f32[" in line]
+    assert moved == []
+    ma = compiled.memory_analysis()
+    cache_bytes = sum(int(np.prod(c.shape)) * c.dtype.itemsize
+                      for c in cache)
+    assert ma.alias_size_in_bytes >= cache_bytes    # all four, whole
+    assert ma.temp_size_in_bytes < state * 4        # no temporary of a page

@@ -97,6 +97,14 @@ def _run_steered(argv):
                      n_layers=2, max_len=64, dtype="float32")
         cs.SERVE.update(buckets=(8, 16, 64), min_prompt=4, max_prompt=40,
                         shared_prefix=16, max_new_tokens=4)
+        cs.HYBRID.update(vocab_size=96, hidden_size=32,
+                         shared_intermediate_size=64,
+                         layer_types=("mamba", "mamba", "attention", "mamba"),
+                         num_attention_heads=4, num_key_value_heads=2,
+                         mamba_n_heads=4, mamba_d_head=16, mamba_d_state=128,
+                         mamba_chunk_size=8, max_len=128, dtype="float32")
+        cs.HYBRID_SERVE.update(buckets=(8, 32), prompts=(5, 8, 20, 31, 12, 3),
+                               max_new_tokens=4)
         cs.LM_TRAIN.update(batch=4, seq=64)
         buf = io.StringIO()
         with contextlib.redirect_stdout(buf):
@@ -125,9 +133,15 @@ def multichip_run():
     ("serve/gpt2-small", "12 streamed requests"),
     ("serve/gpt2-small", "compiles after warm-up: engine 0"),
     ("serve/gpt2-small", "greedy parity"),
+    ("serve/hybrid", "6 streamed requests over 4 slots"),
+    ("serve/hybrid", "compiles after warm-up: engine 0"),
+    ("serve/hybrid", "decode K/V access: kernel, block 128; state update: "
+                     "kernel"),
+    ("serve/hybrid", "greedy parity: 24/24"),
 ], ids=["module-steps", "module-logits-vs-cpu", "module-timing-sanity",
         "gluon-steps", "serve-streams", "serve-zero-compiles",
-        "serve-greedy-parity"])
+        "serve-greedy-parity", "hybrid-streams", "hybrid-zero-compiles",
+        "hybrid-decode-paths", "hybrid-greedy-parity"])
 def test_default_phases_run_on_cpu(default_run, phase, evidence):
     lines, _ = default_run
     assert any(l.startswith(f"[{phase}]") and evidence in l for l in lines), \
