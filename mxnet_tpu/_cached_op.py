@@ -14,9 +14,15 @@ Here capture *is* compilation: the wrapped python function is traced by
 `static_shape` are accepted for API compatibility and are no-ops because
 every CachedOp already gets a static memory plan from XLA.
 
-Autograd: when recording, the forward runs through ``jax.vjp`` (compiled
-with the forward) and ONE tape node is recorded whose pullback is the
-whole-graph backward — exactly CachedOp::Backward's role.
+Autograd: a call under ``autograd.record()`` dispatches nothing. It keeps
+its op, RNG key, train flag and input buffers (:class:`RecordedCall`), puts
+ONE node on the tape and returns NDArrays whose buffer is a
+:class:`PendingOutput` — shape and dtype from the function's jaxpr, traced
+once per signature. ``backward()`` then runs forward and pullback of the
+recorded calls as one program (:func:`backward_program`, differentiating
+those jaxprs): the residuals are temporaries of that program, never its
+outputs — CachedOp::Backward's role without a saved-tensor list. An output that is read first is forced through
+the forward-only program inference uses.
 
 RNG / train-mode: the compiled program takes a threefry base key as a
 traced argument (fresh randomness each call, zero recompiles) and the
@@ -26,13 +32,167 @@ OpContext::is_train and per-op PRNG resources.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
+import numpy as _np
+from jax.extend.core import jaxpr_as_fun
 
 from . import autograd
 from . import random as _random
+from . import telemetry
 from . import tracing
+from .base import MXNetError
 from .compile_cache import CompileCache
 
-__all__ = ["CachedOp"]
+__all__ = ["CachedOp", "PendingOutput", "RecordedCall", "backward_program"]
+
+
+class PendingOutput:
+    """One output of a recorded call whose program has not run yet: what an
+    NDArray holds as its buffer until ``backward()`` fills it or a read
+    forces it. Shares the pending-buffer protocol of
+    :class:`~mxnet_tpu.lazy.graph.LazyArray` (``shape``, ``dtype``,
+    ``force(reason)``), which is all ``NDArray._data`` asks of one."""
+
+    __slots__ = ("call", "shape", "dtype", "value")
+
+    def __init__(self, call, aval):
+        self.call = call      # dropped once the value is there
+        self.shape = tuple(aval.shape)
+        self.dtype = aval.dtype
+        self.value = None
+
+    def force(self, reason="value"):
+        if self.value is None:
+            self.call.run_forward()
+        return self.value
+
+    def __repr__(self):
+        state = "pending" if self.value is None else "realized"
+        return f"PendingOutput({state}, shape={self.shape}, dtype={self.dtype})"
+
+
+class RecordedCall:
+    """What one ``CachedOp`` call under ``autograd.record()`` keeps — the
+    tape node's payload. ``inputs`` are the buffers the arguments held at
+    call time (a later in-place write to a parameter swaps the NDArray's
+    buffer, not this one); an entry may be the :class:`PendingOutput` of an
+    earlier recorded call."""
+
+    __slots__ = ("op", "train", "sig", "trace", "key", "inputs", "outputs")
+
+    def __init__(self, op, train, sig, trace, key, inputs):
+        self.op = op
+        self.train = train
+        self.sig = sig
+        self.trace = trace
+        self.key = key
+        self.inputs = inputs
+        self.outputs = [PendingOutput(self, a) for a in trace.avals]
+
+    @property
+    def ran(self):
+        return self.outputs[0].value is not None
+
+    def fill(self, values):
+        for out, v in zip(self.outputs, values):
+            out.value = v
+            out.call = None
+
+    def run_forward(self):
+        """Force the outputs through the forward-only program."""
+        with tracing.span("cached_op.dispatch", cat="gluon"):
+            args = [a.force() if type(a) is PendingOutput else a
+                    for a in self.inputs]
+            try:
+                outs = self.op._jit_fwd(self.train, self.sig)(self.key, *args)
+            except RuntimeError as e:
+                explain_deleted_inputs(args, e)
+                raise
+        self.fill(outs if isinstance(outs, tuple) else (outs,))
+        telemetry.counter("autograd.forced_forward").inc()
+
+
+def explain_deleted_inputs(arrays, err):
+    """A recorded call runs when its output is read or at ``backward()``,
+    so a buffer it captured can have been donated meanwhile
+    (``Trainer.step`` donates the weights): say so instead of jax's bare
+    "Array has been deleted"."""
+    if any(isinstance(a, jax.Array) and a.is_deleted() for a in arrays):
+        raise MXNetError(
+            "a hybridized call recorded under autograd.record() runs when "
+            "its output is first read or at backward(), and an input buffer "
+            "it captured has been donated since (e.g. by Trainer.step): "
+            "read the output or call backward() before the update") from err
+
+
+def backward_program(jaxprs, wiring, wanted, heads, emit):
+    """Forward and pullback of recorded calls as ONE jitted function
+    ``(keys, leaves, cts) -> (emitted outputs, grads of the wanted leaves)``.
+
+    ``jaxprs``: the calls' closed jaxprs ``(key, *args) -> outputs`` in tape
+    order. ``wiring[c][i]`` says where argument ``i`` of call ``c`` comes
+    from: ``("l", slot)`` a leaf, or ``("o", c0, k)`` output ``k`` of the
+    earlier call ``c0``. ``wanted``: the leaf slots to differentiate.
+    ``heads``: ``(c, k, explicit)`` — the outputs cotangents enter at; an
+    ``explicit`` one takes the next entry of ``cts``, the others get ones.
+    ``emit``: the ``(c, k)`` outputs the program also returns (those nobody
+    has computed yet). Nothing else leaves the program: the residuals are
+    its temporaries."""
+    fns = [jaxpr_as_fun(j) for j in jaxprs]
+
+    def program(keys, leaves, cts):
+        def forward(diff):
+            vals = list(leaves)
+            for slot, v in zip(wanted, diff):
+                vals[slot] = v
+            outs = []
+            for fn, key, row in zip(fns, keys, wiring):
+                outs.append(fn(key, *(
+                    vals[s[1]] if s[0] == "l" else outs[s[1]][s[2]]
+                    for s in row)))
+            return (tuple(outs[c][k] for c, k, _ in heads),
+                    tuple(outs[c][k] for c, k in emit))
+
+        head_vals, pullback, emitted = jax.vjp(
+            forward, tuple(leaves[s] for s in wanted), has_aux=True)
+        explicit = iter(cts)
+        head_cts = tuple(next(explicit) if given else _ones_ct(h)
+                         for h, (_, _, given) in zip(head_vals, heads))
+        (grads,) = pullback(head_cts)
+        return emitted, grads
+
+    return jax.jit(program)
+
+
+def _ones_ct(x):
+    if jnp.issubdtype(x.dtype, jnp.inexact):
+        return jnp.ones(x.shape, x.dtype)
+    return _np.zeros(x.shape, jax.dtypes.float0)
+
+
+class _TraceOnce:
+    """The op's function as a closed jaxpr ``(key, *args) -> outputs`` and
+    the outputs' shapes, traced at the first call of a signature and kept:
+    what a recorded call reads its outputs' shapes from and what
+    ``backward()`` differentiates — the python function is not traced
+    again under ``jax.vjp``. (``HybridBlock`` reads the output format this
+    trace leaves behind; a steady loop only looks the entry up.)"""
+
+    __slots__ = ("_fn", "jaxpr", "avals")
+
+    def __init__(self, fn):
+        self._fn = fn
+        self.jaxpr = None
+
+    def __call__(self, key, *bufs):
+        if self.jaxpr is None:
+            jaxpr = jax.make_jaxpr(self._fn)(key, *(
+                jax.ShapeDtypeStruct(b.shape, b.dtype)
+                if type(b) is PendingOutput else b for b in bufs))
+            self.avals = [jax.ShapeDtypeStruct(a.shape, a.dtype)
+                          for a in jaxpr.out_avals]
+            self.jaxpr = jaxpr
+        return self
 
 
 class CachedOp:
@@ -67,8 +227,7 @@ class CachedOp:
                     outs = fn(*nds)
             if isinstance(outs, (list, tuple)):
                 res = tuple(o._data for o in outs)
-                # single output stays a bare leaf so the stored pullback's
-                # cotangent convention matches the per-op tape nodes
+                # a single output is a bare leaf, not a 1-tuple
                 return res[0] if len(res) == 1 else res
             return outs._data
 
@@ -78,17 +237,9 @@ class CachedOp:
         return self._cache.get_or_build(
             ("fwd", train, sig), lambda: jax.jit(self._traced(train)))
 
-    def _jit_fwd_vjp(self, train, sig):
-        def build():
-            base = self._traced(train)
-
-            def fwd(key, *arrays):
-                outs, vjp = jax.vjp(lambda *a: base(key, *a), *arrays)
-                return outs, vjp
-
-            return jax.jit(fwd)
-
-        return self._cache.get_or_build(("fwd_vjp", train, sig), build)
+    def _trace(self, train, sig):
+        return self._cache.get_or_build(
+            ("jaxpr", train, sig), lambda: _TraceOnce(self._traced(train)))
 
     # -- call ---------------------------------------------------------------
 
@@ -96,18 +247,25 @@ class CachedOp:
         from .ndarray.ndarray import NDArray
 
         with tracing.span("cached_op.dispatch", cat="gluon"):
+            train = bool(autograd.is_training())
+            recording = autograd.is_recording()
             arrays = []
             nd_inputs = []
             for a in inputs:
                 if isinstance(a, NDArray):
-                    arrays.append(a._data)
+                    buf = a._buf
+                    # a recorded call takes the still-pending output of an
+                    # earlier one as it is (`sce(net(x), y)`); any other
+                    # pending buffer materializes here
+                    if not (recording and type(buf) is PendingOutput
+                            and buf.value is None):
+                        buf = a._data
+                    arrays.append(buf)
                     nd_inputs.append(a)
                 else:
                     arrays.append(a)
                     nd_inputs.append(None)
 
-            train = bool(autograd.is_training())
-            recording = autograd.is_recording()
             key = _random.next_key()
 
             ctx = next((a._ctx for a in nd_inputs if a is not None),
@@ -121,17 +279,18 @@ class CachedOp:
                         else (None, type(a).__name__) for a in arrays)
 
             if recording:
-                outs, vjp = self._jit_fwd_vjp(train, sig)(key, *arrays)
+                # nothing is dispatched: backward() runs forward and
+                # pullback together, a read before it the forward alone
+                trace = self._trace(train, sig)(key, *arrays)
             else:
                 outs = self._jit_fwd(train, sig)(key, *arrays)
-        outs_t = outs if isinstance(outs, tuple) else (outs,)
         if recording:
             with tracing.span("cached_op.record", cat="gluon"):
-                out_nds = [NDArray(o, ctx) for o in outs_t]
-                autograd._record_node(
-                    vjp, nd_inputs, out_nds,
-                    [jax.ShapeDtypeStruct(o.shape, o.dtype) for o in outs_t])
+                call = RecordedCall(self, train, sig, trace, key, arrays)
+                out_nds = [NDArray(o, ctx) for o in call.outputs]
+                autograd._record_node(call, nd_inputs, out_nds, trace.avals)
         else:
+            outs_t = outs if isinstance(outs, tuple) else (outs,)
             out_nds = [NDArray(o, ctx) for o in outs_t]
 
         self._n_out = len(out_nds)

@@ -9,18 +9,24 @@ TPU-native design: instead of building an NNVM backward graph, every
 recorded op stores the **pullback** returned by `jax.vjp` (compiled together
 with the forward — see `ops.registry.invoke_with_vjp`). `backward()` walks
 the tape in reverse applying pullbacks; each pullback application is itself
-a jit-cached XLA program. Hybridized blocks record a single tape node whose
-pullback is the whole-graph backward — the analogue of CachedOp::Backward
-(`src/imperative/cached_op.cc:1160`).
+a jit-cached XLA program. A hybridized block records a single tape node that
+keeps the call itself (`_cached_op.RecordedCall`: op, key, input buffers),
+not a pullback: where the heads reach nothing but such calls, `backward()`
+runs their forward and pullback as ONE program (`_fused_backward`) — the
+analogue of CachedOp::Backward (`src/imperative/cached_op.cc:1160`) with
+the residuals as temporaries of that program; where eager nodes sit between
+them, the walk runs that program for one call at a time.
 """
 from __future__ import annotations
 
+import functools
 import threading
 
 import numpy as _np
 import jax
 import jax.numpy as jnp
 
+from . import telemetry
 from . import tracing
 from .base import MXNetError
 
@@ -165,7 +171,9 @@ class _TapeNode:
     __slots__ = ("vjp", "inputs", "outputs", "out_avals")
 
     def __init__(self, vjp, inputs, outputs, out_avals):
-        self.vjp = vjp            # tree_util.Partial pullback (device residuals)
+        # tree_util.Partial pullback (device residuals), a _PyPullback, or
+        # a hybridized call's RecordedCall (no residuals: see _run_calls)
+        self.vjp = vjp
         self.inputs = inputs      # list[NDArray|None] aligned with fn args
         self.outputs = outputs    # list[NDArray] (user outputs, prefix of avals)
         self.out_avals = out_avals  # ShapeDtypeStruct for ALL fn outputs
@@ -195,8 +203,16 @@ def _retarget(frm, to):
 
 
 def _clear_tape():
-    _st().tape = []
-    _st().tape_index = {}
+    from ._cached_op import RecordedCall
+
+    st = _st()
+    dropped = sum(1 for node in st.tape
+                  if type(node.vjp) is RecordedCall and not node.vjp.ran)
+    if dropped:
+        # recorded, never read, never differentiated: no program ran
+        telemetry.counter("autograd.recorded_calls_dropped").inc(dropped)
+    st.tape = []
+    st.tape_index = {}
 
 
 def mark_variables(variables, gradients, grad_reqs="write"):
@@ -219,37 +235,187 @@ def _zero_ct(aval):
     return _np.zeros(aval.shape, jax.dtypes.float0)
 
 
-def _run_backward(heads, head_grads, retain_graph, deposit=True):
+def _run_backward(heads, head_grads, retain_graph, deposit=True,
+                  variables=()):
     tape = _st().tape
-    # one span a backward (child: the deposit loop) — never one a node
+    # one span a backward (children: a recorded calls' program launch, the
+    # deposit loop) — never one a node
     with tracing.span("autograd.backward", cat="autograd",
                       nodes=len(tape)):
         return _backward_pass(tape, heads, head_grads, retain_graph,
-                              deposit)
+                              deposit, variables)
 
 
-def _backward_pass(tape, heads, head_grads, retain_graph, deposit):
-    grad_map = {}  # id(NDArray) -> jnp cotangent
+def _dense_ct(g, dtype):
+    """A cotangent as a pullback takes it: dense, of the output's dtype."""
+    if isinstance(g, _RowSparseCT):
+        g = g.densify()
+    if getattr(g, "dtype", None) != dtype:
+        g = jnp.asarray(g, dtype)  # else: already usable
+    return g
 
-    for h, hg in zip(heads, head_grads):
-        if hg is None:
-            hg = jnp.ones(h.shape, h.dtype)
-        else:
-            hg = hg._data if hasattr(hg, "_data") else jnp.asarray(hg)
-        grad_map[id(h)] = grad_map.get(id(h), 0) + hg
 
+def _accumulate(grad_map, nd, ct):
+    prev = grad_map.get(id(nd))
+    grad_map[id(nd)] = ct if prev is None else prev + ct
+
+
+@functools.lru_cache(maxsize=64)
+def _inexact(dtype):
+    return bool(jnp.issubdtype(dtype, jnp.inexact))
+
+
+def _run_calls(nodes, out_cts, also, grad_map):
+    """Forward and pullback of the recorded CachedOp calls ``nodes`` (tape
+    order) as ONE program, built and cached by
+    ``_cached_op.backward_program`` in the last call's op. ``out_cts``:
+    ``id(output NDArray) -> cotangent``, None standing for ones. Cotangents
+    go into ``grad_map`` for the leaf inputs that are marked or whose id is
+    in ``also``; outputs nobody has computed yet are filled from the
+    program's outputs. False, with nothing run, where the tape wires an
+    input to an earlier call's output but another value flowed."""
+    from ._cached_op import (PendingOutput, backward_program,
+                             explain_deleted_inputs)
+
+    where = {id(o): (c, k) for c, node in enumerate(nodes)
+             for k, o in enumerate(node.outputs)}
+    slots, leaves, leaf_nds, wiring = {}, [], [], []
+    for c, node in enumerate(nodes):
+        row = []
+        for nd_in, buf in zip(node.inputs, node.vjp.inputs):
+            src = where.get(id(nd_in)) if nd_in is not None else None
+            if src is not None:
+                out = nodes[src[0]].vjp.outputs[src[1]]
+                if src[0] >= c or (buf is not out and buf is not out.value):
+                    return False
+                row.append(("o",) + src)
+                continue
+            if type(buf) is PendingOutput:
+                # pending, yet not this tape's to differentiate through
+                # (a detach()ed view, a call of a tape since dropped)
+                buf = buf.force()
+            # one slot per (array, buffer): the program sums what several
+            # calls send back to it; an array rewritten between two calls
+            # is two leaves whose cotangents meet in grad_map
+            slot_key = (id(nd_in), id(buf)) if nd_in is not None else len(leaves)
+            slot = slots.get(slot_key)
+            if slot is None:
+                slot = slots[slot_key] = len(leaves)
+                leaves.append(buf)
+                leaf_nds.append(nd_in)
+            row.append(("l", slot))
+        wiring.append(tuple(row))
+    wanted = tuple(
+        s for s, nd_in in enumerate(leaf_nds)
+        if nd_in is not None and _inexact(leaves[s].dtype) and (
+            id(nd_in) in also or (nd_in._ag_marked and nd_in.grad is not None
+                                  and nd_in.grad_req != "null")))
+    heads, cts, emit = [], [], []
+    for c, node in enumerate(nodes):
+        for k, (o, aval) in enumerate(zip(node.outputs, node.out_avals)):
+            if id(o) not in out_cts:
+                continue
+            ct = out_cts[id(o)]
+            if ct is not None:
+                cts.append(_dense_ct(ct, aval.dtype))
+            heads.append((c, k, ct is not None))
+        if not node.vjp.ran:
+            emit.extend((c, k) for k in range(len(node.outputs)))
+
+    calls = [node.vjp for node in nodes]
+    key = ("bwd", tuple((call.op, call.train, call.sig) for call in calls),
+           tuple(wiring), wanted, tuple(heads), tuple(emit))
+    program = calls[-1].op._cache.get_or_build(
+        key, lambda: backward_program([call.trace.jaxpr for call in calls],
+                                      *key[2:]))
+    with tracing.span("autograd.dispatch", cat="autograd", calls=len(calls)):
+        try:
+            emitted, grads = program(tuple(call.key for call in calls),
+                                     tuple(leaves), tuple(cts))
+        except RuntimeError as e:
+            explain_deleted_inputs(leaves, e)
+            raise
+    values = iter(emitted)
+    for call in calls:
+        if not call.ran:
+            call.fill([next(values) for _ in call.outputs])
+    for s, g in zip(wanted, grads):
+        _accumulate(grad_map, leaf_nds[s], g)
+    return True
+
+
+def _fused_backward(tape, head_cts, variables, grad_map):
+    """The whole backward as one program, where every tape node the heads
+    reach is a recorded CachedOp call (the example loop: hybridized net,
+    hybridized loss). False, with nothing done, where it is not so."""
+    from ._cached_op import RecordedCall
+
+    needed = {id(h) for h, _ in head_cts}
+    nodes = []
     for node in reversed(tape):
+        if any(id(o) in needed for o in node.outputs):
+            if type(node.vjp) is not RecordedCall:
+                return False
+            nodes.append(node)
+            needed.update(id(a) for a in node.inputs if a is not None)
+    nodes.reverse()
+    produced = {id(o) for node in nodes for o in node.outputs}
+    # the program returns no intermediate's cotangent: autograd.grad with
+    # respect to a call's output takes the walk
+    if not nodes or any(id(v) in produced for v in variables):
+        return False
+    out_cts, rest = {}, []
+    for h, ct in head_cts:
+        if id(h) not in produced:
+            rest.append((h, ct))
+        elif id(h) in out_cts:  # a head given twice: the cotangents add
+            out_cts[id(h)] = _head_ct(h, out_cts[id(h)]) + _head_ct(h, ct)
+        else:
+            out_cts[id(h)] = ct
+    if not _run_calls(nodes, out_cts, {id(v) for v in variables}, grad_map):
+        return False
+    for h, ct in rest:
+        _accumulate(grad_map, h, _head_ct(h, ct))
+    telemetry.counter("autograd.fused_backward").inc()
+    return True
+
+
+def _head_ct(h, ct):
+    return jnp.ones(h.shape, h.dtype) if ct is None else ct
+
+
+def _backward_pass(tape, heads, head_grads, retain_graph, deposit, variables):
+    from ._cached_op import RecordedCall
+
+    grad_map = {}  # id(NDArray) -> jnp cotangent
+    head_cts = [(h, hg if hg is None else
+                 hg._data if hasattr(hg, "_data") else jnp.asarray(hg))
+                for h, hg in zip(heads, head_grads)]
+    if _fused_backward(tape, head_cts, variables, grad_map):
+        tape_walk = ()
+    else:
+        tape_walk = reversed(tape)
+        for h, ct in head_cts:
+            _accumulate(grad_map, h, _head_ct(h, ct))
+    also = None
+
+    for node in tape_walk:
         if not any(id(o) in grad_map for o in node.outputs):
+            continue
+        if type(node.vjp) is RecordedCall:
+            # a recorded call between eager nodes: its own program, forward
+            # recomputed inside, cotangents for what the walk goes on to
+            if also is None:
+                also = {id(o) for n in tape for o in n.outputs}
+                also.update(id(v) for v in variables)
+            _run_calls([node], {id(o): grad_map[id(o)] for o in node.outputs
+                                if id(o) in grad_map}, also, grad_map)
             continue
         cts = []
         for i, aval in enumerate(node.out_avals):
             if i < len(node.outputs) and id(node.outputs[i]) in grad_map:
-                g = grad_map[id(node.outputs[i])]
-                if isinstance(g, _RowSparseCT):
-                    g = g.densify()  # a pullback consumes dense cotangents
-                if getattr(g, "dtype", None) != aval.dtype:
-                    g = jnp.asarray(g, aval.dtype)  # else: already usable
-                cts.append(g)
+                cts.append(_dense_ct(grad_map[id(node.outputs[i])],
+                                     aval.dtype))
             else:
                 cts.append(_zero_ct(aval))
         cts = tuple(cts) if len(node.out_avals) > 1 else cts[0]
@@ -264,8 +430,7 @@ def _backward_pass(tape, heads, head_grads, retain_graph, deposit):
                 continue
             if hasattr(ct, "dtype") and ct.dtype == jax.dtypes.float0:
                 continue
-            prev = grad_map.get(id(nd_in))
-            grad_map[id(nd_in)] = ct if prev is None else prev + ct
+            _accumulate(grad_map, nd_in, ct)
 
     # deposit into marked variables honoring grad_req
     if deposit:
@@ -355,7 +520,8 @@ def grad(heads, variables, head_grads=None, retain_graph=None, create_graph=Fals
     if retain_graph is None:
         retain_graph = create_graph
 
-    grad_map = _run_backward(heads, head_grads, retain_graph=True, deposit=False)
+    grad_map = _run_backward(heads, head_grads, retain_graph=True,
+                             deposit=False, variables=variables)
     outs = []
     for v in variables:
         g = grad_map.get(id(v))

@@ -23,10 +23,17 @@ import jax.numpy as jnp
 
 from ..base import MXNetError, np_dtype, integer_types, numeric_types
 from ..context import Context, current_context, cpu
+from .._cached_op import PendingOutput as _PendingOutput
 from ..lazy.graph import LazyArray as _LazyArray
 from ..ops import registry as _reg
 
 __all__ = ["NDArray", "array", "zeros", "ones", "full", "empty", "arange", "concatenate", "waitall"]
+
+
+# What an NDArray may hold in place of a jax array: shape and dtype are
+# known, ``force(reason)`` gives the array (an op the lazy graph captured;
+# the output of a hybridized call recorded for autograd).
+_PENDING = (_LazyArray, _PendingOutput)
 
 
 def _dtype_name(dt):
@@ -56,18 +63,22 @@ class NDArray:
 
     @property
     def _data(self):
-        """The concrete jax array — THE materialization barrier. Under
-        ``MXNET_LAZY=1`` the buffer may be a pending
-        :class:`~mxnet_tpu.lazy.graph.LazyArray`; reading ``_data``
-        flushes the owning segment (one fused XLA program) and swaps the
-        realized buffer in. Every concrete-value escape in the codebase —
+        """The concrete jax array — THE materialization barrier. The
+        buffer may be pending: under ``MXNET_LAZY=1`` a
+        :class:`~mxnet_tpu.lazy.graph.LazyArray` (reading ``_data``
+        flushes the owning segment, one fused XLA program), or the
+        :class:`~mxnet_tpu._cached_op.PendingOutput` of a hybridized call
+        recorded under ``autograd.record()`` (``backward()`` fills it; a
+        read before that runs the forward-only program). Either way the
+        realized buffer is swapped in. Every concrete-value escape in the
+        codebase —
         ``asnumpy``, kvstore pushes, checkpoint writes, executor feeds —
         reads through here, which is what makes the barrier audit
         structural rather than a site-by-site hunt. Metadata queries
         (``shape``/``dtype``/``ndim``/``size``) read ``_buf`` and never
         flush."""
         buf = self._buf
-        if type(buf) is _LazyArray:
+        if type(buf) in _PENDING:
             buf = buf.force()
             self._buf = buf
         return buf
@@ -132,7 +143,7 @@ class NDArray:
     def asnumpy(self):
         """Blocking copy to host (reference `WaitToRead` + copy)."""
         buf = self._buf
-        if type(buf) is _LazyArray:
+        if type(buf) in _PENDING:
             self._buf = buf = buf.force("asnumpy")
         return _np.asarray(buf)
 
@@ -174,7 +185,7 @@ class NDArray:
 
     def wait_to_read(self):
         buf = self._buf
-        if type(buf) is _LazyArray:
+        if type(buf) in _PENDING:
             self._buf = buf = buf.force("wait")
         buf.block_until_ready()
 

@@ -156,7 +156,10 @@ def _invoke_nd_impl(op_name, *args, out=None, name=None, **kwargs):
             last_arr = i
     for i, a in enumerate(args):
         if isinstance(a, NDArray):
+            # the lazy graph takes its own pending buffers as they are;
+            # a recorded hybridized call's pending output materializes
             arrays.append(a._buf if lazy_graph is not None
+                          and type(a._buf) is _lazy.LazyArray
                           else _resolve_nd_data(a))
             nd_inputs.append(a)
         elif a is None:

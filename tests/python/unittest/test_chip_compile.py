@@ -303,3 +303,59 @@ def test_hybrid_decode_program_updates_state_in_place_on_v5e(one_chip,
                       for c in cache)
     assert ma.alias_size_in_bytes >= cache_bytes    # all four, whole
     assert ma.temp_size_in_bytes < state * 4        # no temporary of a page
+
+
+# ---------------------------------------------------------------------------
+# the gluon step's one backward program at ResNet-50's widths (ISSUE 27)
+# ---------------------------------------------------------------------------
+
+def test_recorded_call_backward_returns_no_residual_on_v5e(one_chip):
+    """Stage 2 of the model zoo's ResNet-50 v1 (four BottleneckV1 units of
+    width 512 on a [64, 256, 56, 56] input, bfloat16): forward and pullback
+    of the recorded call as the one program `backward()` runs. It compiles
+    for the v5e, and what it returns is the gradients and the stage's
+    output — the activations the pullback needs (0.45 GB) are temporaries
+    of the program, where the forward+vjp program returned them."""
+    from mxnet_tpu import nd
+    from mxnet_tpu._cached_op import backward_program
+    from mxnet_tpu.gluon.model_zoo.vision.resnet import BottleneckV1, ResNetV1
+
+    batch = 64
+    stage = ResNetV1(BottleneckV1, [3, 4, 6, 3],
+                     [64, 256, 512, 1024, 2048]).features[5]
+    stage.initialize()
+    stage.hybridize()
+    stage.cast("bfloat16")
+    stage(nd.zeros((1, 256, 56, 56), dtype="bfloat16"))  # shapes, the op
+    op = stage._cached_op
+    params = [p.data() for p in stage._cached_graph_params]
+    assert len(stage) == 4 and len(params) == 73
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    leaves = tuple(sds(p.shape, p.dtype) for p in params) \
+        + (sds((batch, 256, 56, 56), jnp.bfloat16),)
+    sig = tuple((a.shape, a.dtype) for a in leaves)
+    learned = tuple(i for i, p in enumerate(stage._cached_graph_params)
+                    if p.grad_req != "null")
+    key = sds((2,), jnp.uint32)
+    program = backward_program(
+        jaxprs=[op._trace(True, sig)(key, *leaves).jaxpr],
+        wiring=(tuple(("l", i) for i in range(len(leaves))),),
+        wanted=learned, heads=((0, 0, False),), emit=((0, 0),))
+    compiled = program.lower((key,), leaves, ()).compile()
+    (emitted,), grads = compiled.out_info
+    assert emitted.shape == (batch, 512, 28, 28) and len(grads) == 47
+
+    def nbytes(avals):
+        return sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in avals)
+
+    ma = compiled.memory_analysis()
+    exact = nbytes(grads) + nbytes([emitted])
+    # tiling pads the small per-channel vectors; an activation is 51 MB
+    assert exact <= ma.output_size_in_bytes <= exact + 2e6
+    assert ma.temp_size_in_bytes > 4 * nbytes([emitted])
+    live = ma.argument_size_in_bytes + ma.output_size_in_bytes \
+        + ma.temp_size_in_bytes
+    assert live < 4e9

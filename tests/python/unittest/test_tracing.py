@@ -301,10 +301,20 @@ def _tree_names(node):
     return (node["name"], [_tree_names(c) for c in node.get("children", [])])
 
 
-def test_gluon_step_span_tree(trc):
-    """One hybridized gluon step: cached_op.call > gather/dispatch/record,
-    autograd.backward > deposit, trainer.step > allreduce/update — nine
-    spans, none per parameter or per tape node."""
+_CALL_TREE = ("cached_op.call", [("cached_op.gather", []),
+                                 ("cached_op.dispatch", []),
+                                 ("cached_op.record", [])])
+
+
+@pytest.mark.parametrize("hybrid_loss", [True, False],
+                         ids=["hybridized_loss", "eager_loss"])
+def test_gluon_step_span_tree(trc, hybrid_loss):
+    """One hybridized gluon step. With a hybridized loss both calls only
+    capture (cached_op.call > gather/dispatch/record, twice) and
+    autograd.backward > dispatch/deposit launches the one program. An
+    eager loss reads the net's output first: that forces the forward-only
+    program under a cached_op.dispatch of its own, outside any call.
+    trainer.step > allreduce/update; no span per parameter or tape node."""
     from mxnet_tpu import autograd, gluon, nd
 
     net = gluon.nn.HybridSequential()
@@ -316,6 +326,8 @@ def test_gluon_step_span_tree(trc):
     x = nd.array(_x(4)[:, :5])
     y = nd.array([0, 1, 2, 0])
     loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
+    if hybrid_loss:
+        loss_fn.hybridize()
 
     def step():
         with tracing.span("user.step") as root:
@@ -328,16 +340,16 @@ def test_gluon_step_span_tree(trc):
     step()                      # deferred init + compile
     tracing.reset()
     root = step()
+    second = _CALL_TREE if hybrid_loss else ("cached_op.dispatch", [])
     assert _tree_names(root.tree()) == ("user.step", [
-        ("cached_op.call", [("cached_op.gather", []),
-                            ("cached_op.dispatch", []),
-                            ("cached_op.record", [])]),
-        ("autograd.backward", [("autograd.deposit", [])]),
+        _CALL_TREE, second,
+        ("autograd.backward", [("autograd.dispatch", []),
+                               ("autograd.deposit", [])]),
         ("trainer.step", [("trainer.allreduce", []),
                           ("trainer.update", [])])])
     call = root.tree()["children"][0]
     assert call["args"]["params"] == 4
-    assert len(_spans()) == 10
+    assert len(_spans()) == (15 if hybrid_loss else 12)
     _assert_connected(_spans())
 
 
