@@ -42,7 +42,7 @@ unit() {
   # test runs — cheaper to read one findings list than to bisect the
   # suite failure it would eventually cause
   log "tpulint gate (framework-invariant static analysis, blocking)"
-  python -m tools.tpulint mxnet_tpu tools bench.py --strict
+  python -m tools.tpulint mxnet_tpu tools --strict
   # hlolint dump dir: the suites below that warm the audited caches
   # (serving/generation/zero1/pipeline/lazy/spmd) run with
   # MXNET_HLOLINT_DUMP set, so each process writes its compiled-program
@@ -203,15 +203,6 @@ unit() {
   # attributed, not as a flaky assertion inside an unrelated suite
   log "health suite (SLO tracker, liveness/readiness, stall watchdog + capture, router drain, chaos acceptance)"
   python -m pytest tests/python/unittest/test_health.py -q
-  # observatory gate, standalone: these tests flip the process-global
-  # observatory state, run measured-peak probes (tiny shapes on CPU) and
-  # pin probe caching/provenance invalidation, roofline attribution math
-  # against hand-computed fixtures, bound classification (matmul=compute
-  # vs elementwise=bandwidth), per-lane MFU/MBU gauge publication, ledger
-  # ingest + regression flagging and the zero-overhead-off subprocess —
-  # a roofline or ledger regression fails HERE, attributed
-  log "observatory suite (measured-peak probes, roofline attribution, MFU/MBU gauges, perf ledger)"
-  python -m pytest tests/python/unittest/test_observatory.py -q
   # overlap gate, standalone: these tests flip MXNET_OVERLAP / the
   # telemetry registry, spin the DeviceStager staging thread and pin
   # N-step BIT-EXACT parameter parity vs the MXNET_OVERLAP=0 lockstep
@@ -375,26 +366,6 @@ print("spmd smoke OK:", {ax: {n: round(r["param_state_ratio"], 3)
                          for ax, runs in sweep.items()})
 PY
   rm -f /tmp/ci_spmd_bw.jsonl
-
-  log "bench smoke (CPU, reduced steps)"
-  # fresh compile cache: XLA:CPU AOT entries are machine-feature-pinned,
-  # and a cache written on another host can SIGILL here. The run appends
-  # its record to a scratch ledger so the advisory check below exercises
-  # the rolling-baseline path
-  bench_cache="$(mktemp -d)"
-  bench_ledger="$(mktemp)"
-  env BENCH_FORCE_CPU=1 BENCH_ITERS=2 \
-      JAX_COMPILATION_CACHE_DIR="$bench_cache" \
-      MXNET_PERF_LEDGER="$bench_ledger" timeout 900 python bench.py
-  rm -rf "$bench_cache"
-
-  log "perf-ledger trajectory check (tools/perf_ledger.py check, advisory)"
-  # ADVISORY: the smoke run above vs the median of recent same-backend
-  # ledger records (exit 2 = no baseline yet). A CPU smoke box is noisy,
-  # so a nonzero exit only logs
-  python -m tools.perf_ledger check --ledger "$bench_ledger" \
-      || log "perf_ledger: ADVISORY (no baseline, or regression vs rolling baseline — see above)"
-  rm -f "$bench_ledger"
 }
 
 case "$stage" in

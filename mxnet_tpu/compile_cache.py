@@ -293,7 +293,7 @@ class CompileCache:
         try:
             self._entry_stats[key] = {
                 "avals": _avals_of(args, kwargs),
-                "memory": None, "cost": None, "collectives": None}
+                "memory": None, "collectives": None}
         except Exception:  # noqa: BLE001 — stats are additive, never fatal
             pass
 
@@ -320,22 +320,11 @@ class CompileCache:
             with donation_warnings_suppressed():
                 compiled = target.lower(*args, **kwargs).compile()
             ma = compiled.memory_analysis()
-            # the same AOT pass also yields the cost analysis (FLOPs,
-            # bytes accessed — the observatory's roofline numerators) for
-            # free; the collective inventory needs the full
-            # post-optimization HLO TEXT, which is expensive to serialise
-            # and parse for big programs, so it is extracted only when
-            # entry_collectives asked for it (the /memory scrape sweeps
-            # every entry and must stay as cheap as plain memory_analysis)
-            try:
-                ca = compiled.cost_analysis()
-                if isinstance(ca, (list, tuple)):
-                    ca = ca[0] if ca else {}
-                st["cost"] = {
-                    "flops": float(ca.get("flops", 0.0)),
-                    "bytes_accessed": float(ca.get("bytes accessed", 0.0))}
-            except Exception:  # noqa: BLE001 — cost is best-effort
-                st["cost"] = False
+            # the collective inventory needs the full post-optimization
+            # HLO TEXT, which is expensive to serialise and parse for big
+            # programs, so it is extracted only when entry_collectives
+            # asked for it (the /memory scrape sweeps every entry and must
+            # stay as cheap as plain memory_analysis)
             if _want_collectives:
                 try:
                     from . import analysis
@@ -359,32 +348,16 @@ class CompileCache:
                                   - ma.alias_size_in_bytes)}
         except Exception:  # noqa: BLE001 — analysis is best-effort
             st["memory"] = False  # memoize the failure: the AOT lowering
-            st.setdefault("cost", None)
-            st["cost"] = st["cost"] or False
             st["collectives"] = st.get("collectives") or False
             return None           # is expensive and will not get better
         return st["memory"]
-
-    def entry_cost(self, key):
-        """XLA cost analysis for one entry: ``{flops, bytes_accessed}``
-        or None — computed in the SAME lazy AOT pass as
-        :meth:`entry_memory` (one lowering feeds memory, cost and
-        collective attribution), memoized including failures. The
-        observatory's roofline numerators."""
-        st = self._entry_stats.get(key)
-        if st is None:
-            return None
-        if st.get("cost") is None:
-            self.entry_memory(key)
-        return st.get("cost") or None
 
     def entry_collectives(self, key):
         """Collective inventory of one entry's COMPILED program
         (``{kind: {count, bytes}}``, bytes per participant) or None —
         recorded by the shared AOT pass on demand (an entry first scanned
-        by a plain memory scrape pays one extra lowering here); the
-        observatory's comm-bound attribution source, same parser as the
-        hlolint audit."""
+        by a plain memory scrape pays one extra lowering here); same
+        parser as the hlolint audit."""
         st = self._entry_stats.get(key)
         if st is None:
             return None

@@ -499,11 +499,6 @@ _DEFAULT_SPEC = ("serving.generation.ttft_us:p99<500ms;"
                  "serving.e2e_us:p99<250ms;"
                  "compile.cache_misses:rate<=0;"
                  "step.total_us:p99<8*p50;"
-                 # MFU collapse: achieved step FLOP/s under 0.1% of the
-                 # MEASURED matmul peak (observatory.summary publishes
-                 # step.mfu) means the step path stopped doing real work
-                 # per wall second — a bug, not a ceiling, on any backend
-                 "step.mfu:value>=0.001;"
                  # projected peak-HBM headroom went negative: resident
                  # census + the worst warmed executable's temp working
                  # set exceed device capacity (memory.census) — the next

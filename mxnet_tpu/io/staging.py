@@ -2,8 +2,7 @@
 dispatch pipeline.
 
 The reference's L2 dependency engine (`include/mxnet/engine.h`) exists to
-hide host work behind device compute; on the TPU-native port the same gap
-shows up as ``host_gap_us`` (wall − exec) in the observatory: every
+hide host work behind device compute; on the TPU-native port every
 lockstep step pays batch pad/cast/``device_put`` and metric reads on the
 critical path while the device sits idle.  :class:`DeviceStager` closes
 the input half of that gap: while step *t* executes, a staging thread

@@ -37,8 +37,6 @@ Three rule families, each individually disableable via
   fused step already gets. On a trivial (single-device / tp=1) mesh the
   constraint is a pure layout annotation and lowers to ZERO collectives
   (pinned by test_lazy_rewrite + the hlolint ``lazy`` contract row).
-* bench-in-the-loop tuning lives in ``tools/lazy_tune.py`` (bench.py is
-  the cost oracle; this module only honors the knobs it sweeps).
 
 Vjp nodes are never rewritten (their residual pytree structure is pinned
 by ``_LazyVjp``); they only *consume* rewritten forward values, which is

@@ -14,7 +14,6 @@ import time
 import numpy as _np
 
 from .. import health
-from .. import observatory
 from .. import telemetry
 from .. import tracing
 from ..base import MXNetError
@@ -279,7 +278,6 @@ class BaseModule:
                     # the slow-step flight recorder.
                     tele = telemetry._enabled
                     trc = tracing._enabled
-                    timed = tele or observatory._enabled
                     step_span = tracing.span(
                         "step", cat="train",
                         trace_id=(tracing.deterministic_trace_id(
@@ -289,18 +287,18 @@ class BaseModule:
                         # the four phase children are LIVE spans (also
                         # `mx:step.*` in any jax.profiler trace); the perf
                         # marks beside them feed the step.*_us histograms
-                        t0 = time.perf_counter() if timed else 0.0
+                        t0 = time.perf_counter() if tele else 0.0
                         with tracing.span("step.fwdbwd", cat="train"):
                             # fused path: fwd+bwd+update as one XLA
                             # computation (its whole cost lands here)
                             fused = self.fused_step(data_batch)
                             if not fused:
                                 self.forward_backward(data_batch)
-                        t_fb = time.perf_counter() if timed else 0.0
+                        t_fb = time.perf_counter() if tele else 0.0
                         with tracing.span("step.update", cat="train"):
                             if not fused:
                                 self.update()
-                        t_up = time.perf_counter() if timed else 0.0
+                        t_up = time.perf_counter() if tele else 0.0
                         if tele:
                             telemetry.gauge("step.fused").set(1 if fused else 0)
                         # deferred-metric capture: under overlap, step t's
@@ -330,14 +328,14 @@ class BaseModule:
                                 else:
                                     self.update_metric(eval_metric,
                                                        data_batch.label)
-                            t_sync = time.perf_counter() if timed else 0.0
+                            t_sync = time.perf_counter() if tele else 0.0
                             fetched = self._fetch_next_batch(
                                 data_iter, sparse_row_id_fn, stage=False)
                             if fetched is None:
                                 end_of_batch = True
                             else:
                                 next_data_batch = fetched
-                            t_end = t_data = time.perf_counter() if timed \
+                            t_end = t_data = time.perf_counter() if tele \
                                 else 0.0
                             marks = (("fwdbwd", t0, t_fb),
                                      ("update", t_fb, t_up),
@@ -354,7 +352,7 @@ class BaseModule:
                                 end_of_batch = True
                             else:
                                 next_data_batch = fetched
-                            t_data = time.perf_counter() if timed else 0.0
+                            t_data = time.perf_counter() if tele else 0.0
                             with tracing.span("step.sync", cat="train"):
                                 if pending_metric is not None:
                                     pending_metric(eval_metric)
@@ -367,7 +365,7 @@ class BaseModule:
                                     pending_metric(eval_metric)
                                     pending_metric = None
                                     self.retire_staged()
-                            t_end = t_sync = time.perf_counter() if timed \
+                            t_end = t_sync = time.perf_counter() if tele \
                                 else 0.0
                             marks = (("fwdbwd", t0, t_fb),
                                      ("update", t_fb, t_up),
@@ -378,11 +376,6 @@ class BaseModule:
                         step_span.set(fused=fused)
                     if trc:
                         tracing.flight_recorder.observe(step_span.tree())
-                    if observatory._enabled:
-                        # steady-state step wall for the roofline's
-                        # achieved MFU/MBU (the executable itself was
-                        # named by Executor.fused_step's exec_s sample)
-                        observatory.observe("step", wall_s=t_end - t0)
                     step_stats = None
                     if tele:
                         total_h = telemetry.histogram("step.total_us")

@@ -332,7 +332,14 @@ class ElasticRuntime:
                 with open(members_path) as f:
                     members = sorted(int(x) for x in f.read().split(",")
                                      if x.strip())
-                break
+                if members or time.monotonic() > read_deadline:
+                    break
+                # empty: the decider is between its O_EXCL create and its
+                # write (a decision always names the decider itself);
+                # past the deadline it died there, and the check below
+                # says so
+                time.sleep(poll)
+                continue
             except OSError:
                 pass
             joined = {int(n.split("-", 1)[1])

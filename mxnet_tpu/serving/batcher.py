@@ -37,7 +37,6 @@ from concurrent.futures import Future
 
 from .. import analysis
 from .. import ndarray as nd
-from .. import observatory
 from .. import telemetry
 from .. import tracing
 from ..io import staging as _staging
@@ -385,7 +384,7 @@ class DynamicBatcher:
                         if r.deadline is not None), default=None)
         return {"live": live, "reason": reason, "rows": rows,
                 "bucket": bucket, "feeds": feeds, "earliest": earliest,
-                "staged": staged, "t0": time.perf_counter()}
+                "staged": staged}
 
     def _resweep_staged(self, prep):
         """A staged prep sat out one flush: re-sweep its deadlines before
@@ -441,9 +440,6 @@ class DynamicBatcher:
         live, reason = prep["live"], prep["reason"]
         rows, bucket = prep["rows"], prep["bucket"]
         feeds, earliest = prep["feeds"], prep["earliest"]
-        # staged preps overlapped their prepare; their wall starts at
-        # dispatch. Lockstep walls include the prepare they paid inline.
-        t_wall0 = time.perf_counter() if prep["staged"] else prep["t0"]
         staged_box = [None]
         # the dispatch/drain split honors the `_run` seam: an instance
         # with `_run` patched over (test gates, wrappers) keeps the
@@ -522,10 +518,4 @@ class DynamicBatcher:
                 sliced = [o[off:off + r.rows] for o in outs]
                 off += r.rows
                 self._deliver(r, sliced, done_ts)
-            if observatory._enabled:
-                # the flush WALL (prep + dispatch + drain + deliver, minus
-                # whatever staging hid); the predictor observed exec_s —
-                # their gap is the serving lane's host_gap_us
-                observatory.observe(
-                    "serving", wall_s=time.perf_counter() - t_wall0)
         return staged_box[0]

@@ -100,7 +100,6 @@ import numpy as np
 from ... import analysis
 from ... import health
 from ... import memory
-from ... import observatory
 from ... import telemetry
 from ... import tracing
 from ...base import MXNetError, getenv, register_env
@@ -1067,9 +1066,6 @@ class GenerationEngine:
         failure fails the live sessions (never-strand, the batcher's
         guard) and reallocates the possibly-donated slab."""
         tele = telemetry._enabled
-        obs = observatory._enabled
-        decoded = False
-        dec_s = None
         t0 = time.perf_counter()
         # the tick's own span tree (sweep / decode / admit > prefill >
         # prefill.fetch / commit > commit.fetch, children via the context
@@ -1095,7 +1091,6 @@ class GenerationEngine:
                     # outputs, so they join the NEXT tick's decode —
                     # per-session token streams stay bit-exact with the
                     # lockstep order below.
-                    t_dec = time.perf_counter()
                     # the decode this tick commits is the one the last
                     # tick dispatched AHEAD, else one dispatched now; where
                     # it may (_lookahead_ok) the decode after it goes out
@@ -1110,7 +1105,6 @@ class GenerationEngine:
                         # its span already: a tick shows one decode child)
                         self._ahead = self._dispatch_ahead(pending,
                                                            span=not first)
-                    decoded = pending is not None
                     self._sweep()
                     # a live slot is parked only BETWEEN decodes: the one in
                     # flight advances its slot, and a recurrent state is
@@ -1122,18 +1116,10 @@ class GenerationEngine:
                         self._decode_commit(pending)
                         if self._qos is not None:
                             self._admit()
-                    # the dispatch→commit window: the swept bookkeeping
-                    # rides INSIDE it, so wall − dec_s (the lane's
-                    # host_gap_us) is exactly the host work the overlap
-                    # order still leaves outside device time
-                    dec_s = time.perf_counter() - t_dec
                 else:
                     self._sweep()
                     self._admit()
-                    decoded = self._live > 0
-                    t_dec = time.perf_counter()
                     self._decode()
-                    dec_s = time.perf_counter() - t_dec
                 if len(self._param_sets) > 1:
                     # a swap transition is draining: release versions
                     # whose last session just finished
@@ -1179,17 +1165,6 @@ class GenerationEngine:
             self._beacon.touch()
             if not self._has_work():
                 self._beacon.idle()
-        if obs and decoded:
-            # a decode (or verify) actually swept the slab this tick:
-            # the tick wall against THE decode executable's bytes is the
-            # per-tick MBU — the honest decode metric (arXiv:2603.09555),
-            # bandwidth-bound by construction at steady state
-            key = (("verify", self._spec_k, self._total_slots,
-                    self._slab_len) if self._spec_k else
-                   ("decode", self._total_slots, self._slab_len))
-            observatory.observe("generation.tick", self._cache, key,
-                                wall_s=time.perf_counter() - t0,
-                                exec_s=dec_s)
         if tele:
             dt = time.perf_counter() - t0
             telemetry.counter("serving.generation.ticks").inc()

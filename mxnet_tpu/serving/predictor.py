@@ -42,7 +42,6 @@ import numpy as np
 from .. import analysis
 from .. import memory
 from .. import ndarray as nd
-from .. import observatory
 from .. import telemetry
 from .. import tracing
 from ..base import MXNetError, getenv, register_env
@@ -373,34 +372,25 @@ class Predictor:
         with tracing.span("serving.pad", cat="serving", bucket=bucket):
             padded, _ = pad_arrays(list(arrays), bucket)
         feed = dict(zip(self._data_names, padded))
-        t0 = time.perf_counter() if telemetry._enabled \
-            or observatory._enabled else 0.0
+        t0 = time.perf_counter() if telemetry._enabled else 0.0
         with self._lock, tracing.span("serving.forward", cat="serving",
                                       bucket=bucket):
             outs = list(exec_.forward(is_train=False, **feed))
-        return outs, padded, exec_, t0
+        return outs, padded, t0
 
     def _run_wait(self, pending):
         """Drain a :meth:`_run_dispatch` handle: block on the outputs so
         an execution failure surfaces here (retryable), then account the
-        batch. ``exec_s`` spans dispatch->drained — the honest device
-        window; the flush WALL is the batcher's to observe, so the
-        serving lane's host gap reflects what staging actually hides."""
+        batch. ``serving.compute_us`` spans dispatch->drained."""
         import jax
 
-        outs, padded, exec_, t0 = pending
+        outs, padded, t0 = pending
         jax.block_until_ready([o._data for o in outs])
         # in-flight batch residency: weak refs, swept as batches retire
         memory.track_transient("serving_batches", padded + outs)
-        tele = telemetry._enabled
-        obs = observatory._enabled
-        dt = time.perf_counter() - t0 if tele or obs else 0.0
-        if tele:
-            telemetry.histogram("serving.compute_us").record(dt * 1e6)
-        if obs:
-            # the executor recorded which compiled entry this forward hit
-            observatory.observe("serving", self._cache, exec_._last_fwd_key,
-                                exec_s=dt)
+        if telemetry._enabled:
+            telemetry.histogram("serving.compute_us").record(
+                (time.perf_counter() - t0) * 1e6)
         return outs
 
     # -- weight rollout ------------------------------------------------------

@@ -15,24 +15,17 @@ is_train=True)` so backward never re-runs the forward.
 """
 from __future__ import annotations
 
-import time
-
 import numpy as _np
 
 import jax
 import jax.numpy as jnp
 
-from .. import observatory, tracing
+from .. import tracing
 from ..base import MXNetError
 from ..compile_cache import CompileCache
 from ..ops import registry as _reg
 
 __all__ = ["Executor"]
-
-# under MXNET_OVERLAP, only every Nth observed fused step drains for an
-# exec_s sample — the rest stay dispatch-only so the overlap lane keeps
-# its host work hidden behind the in-flight executable
-_OBS_PROBE_PERIOD = 8
 
 
 def _dispatch_node(node, env, key, train, nidx, gate=None):
@@ -162,7 +155,6 @@ class Executor:
         self._monitor_callback = None
 
         self._fns = {}
-        self._last_fwd_key = None
         # every compiled executable this executor holds, keyed by full shape
         # signature — shape churn (bucketing, unpadded partial batches) shows
         # up as compile.cache_misses instead of silently re-specializing.
@@ -294,9 +286,6 @@ class Executor:
         else:
             outputs, aux_new = self._jit_fwd(bool(is_train), sig)(key, args, auxs)
             self._vjp = None
-            # which compiled entry this forward ran — the serving plane's
-            # roofline attribution reads it back (observatory.observe)
-            self._last_fwd_key = ("fwd", bool(is_train), sig)
 
         if is_train:
             # aux write-back (moving stats) — reference mutable aux NDArrays
@@ -575,36 +564,12 @@ class Executor:
             # donation silently degrades to a copy
             put = pipeline.put_replicated
             call_args = [jax.tree_util.tree_map(put, a) for a in call_args]
-        obs = observatory._enabled
-        t_obs = time.perf_counter() if obs else 0.0
         try:
             with tracing.span("fused.dispatch", cat="train",
                               params=len(names),
                               zero1=zero1 is not None,
                               pipeline=pipeline is not None):
                 outputs, new_ws, new_ss, aux_new = fn(*call_args)
-            if obs:
-                # device-busy window for the roofline's host-gap: drain
-                # the step and name the executable that ran so attribution
-                # can pull its FLOPs/bytes lazily. Under the async overlap
-                # lane (MXNET_OVERLAP=1) a per-step drain would serialize
-                # exactly the host work the lane exists to hide, so only a
-                # PERIODIC probe step drains for an exec_s sample — the
-                # EWMA keeps the roofline's exec estimate fresh while the
-                # other steps stay dispatch-only (their wall comes from
-                # the fit loop's observe).
-                from ..io import staging as _staging
-
-                self._obs_probe = getattr(self, "_obs_probe", 0) + 1
-                if not _staging.overlap_enabled() or \
-                        self._obs_probe % _OBS_PROBE_PERIOD == 1:
-                    jax.block_until_ready(outputs)
-                    observatory.observe("step", cache,
-                                        ("fused_step", sig),
-                                        exec_s=time.perf_counter() - t_obs)
-                else:
-                    # keep the cache/key naming current without a sync
-                    observatory.observe("step", cache, ("fused_step", sig))
         except Exception as e:
             donated = [w._data for w in weights]
             if zero1 is not None:

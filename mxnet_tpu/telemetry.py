@@ -55,7 +55,6 @@ import atexit
 import json
 import os
 import random
-import sys
 import threading
 import time
 
@@ -424,17 +423,6 @@ def snapshot():
             out["compile_caches"] = totals
     except Exception:  # noqa: BLE001 — snapshot must never fail
         pass
-    try:
-        # last computed roofline summary, by reference only (sys.modules:
-        # a snapshot must never import — let alone probe — the
-        # observatory; tools/telemetry_report.py renders the section)
-        obs = sys.modules.get("mxnet_tpu.observatory")
-        if obs is not None:
-            cached = obs.cached_summary()
-            if cached:
-                out["observatory"] = cached
-    except Exception:  # noqa: BLE001 — snapshot must never fail
-        pass
     return out
 
 
@@ -682,10 +670,7 @@ def start_http_server(port=None, host=None):
     * ``/healthz`` / ``/readyz`` — liveness/readiness probe aggregation
       (HTTP 503 when any probe fails — a k8s-shaped contract);
     * ``/events`` — the health event journal (bounded ring of runtime
-      events: rejections, evictions, drains, watchdog firings);
-    * ``/roofline`` — the observatory's roofline report: measured device
-      peaks + per-lane MFU/MBU attribution
-      (:func:`mxnet_tpu.observatory.summary`).
+      events: rejections, evictions, drains, watchdog firings).
 
     Returns the server (its ``.server_address[1]`` is the bound port —
     pass port 0 for an ephemeral one in tests), or None when off."""
@@ -764,19 +749,10 @@ def start_http_server(port=None, host=None):
                     self._send(json.dumps(health.events(), indent=2,
                                           default=repr),
                                "application/json")
-                elif path == "/roofline":
-                    from . import observatory
-
-                    # summary() computes attribution for observed lanes —
-                    # the first scrape after new compiles pays the lazy
-                    # AOT cost pass (like /memory), never the step path
-                    self._send(json.dumps(observatory.summary(), indent=2,
-                                          default=repr),
-                               "application/json")
                 else:
                     self.send_error(404, "try /metrics, /trace, /memory, "
-                                         "/slo, /healthz, /readyz, "
-                                         "/events or /roofline")
+                                         "/slo, /healthz, /readyz "
+                                         "or /events")
             except Exception as e:  # noqa: BLE001 — a scrape must not crash
                 try:
                     self.send_error(500, repr(e))

@@ -222,6 +222,56 @@ def test_shrink_membership_and_coordinator(tmp_path):
             rt.stop()
 
 
+@pytest.mark.parametrize("decider_writes", [True, False],
+                         ids=["written_late", "never_written"])
+def test_shrink_reader_meets_an_empty_members_file(tmp_path, decider_writes):
+    """A survivor that opens `members` between the decider's O_EXCL create
+    and its write reads nothing. That is no decision yet: it waits for the
+    list (and adopts it) instead of failing as "published without rank 2";
+    a decider that died there is reported after the grace, not waited for
+    for ever."""
+    rt = _rt(tmp_path, 2, 3).start()
+    gen1 = os.path.join(str(tmp_path), "gen-1")
+    os.makedirs(gen1)
+    stop = threading.Event()
+    shrink_began = []
+
+    def fake_rank0():
+        """Alive, joined, has created `members`; writes it 0.15 s into the
+        survivor's rendezvous, or never."""
+        open(os.path.join(gen1, "join-0"), "w").close()
+        open(os.path.join(gen1, "members"), "w").close()
+        while not stop.is_set():
+            _beat(tmp_path, 0)
+            if decider_writes and shrink_began \
+                    and time.monotonic() > shrink_began[0] + 0.15:
+                with open(os.path.join(gen1, "members"), "w") as f:
+                    f.write("0,2")
+                with open(os.path.join(gen1, "coordinator"), "w") as f:
+                    f.write("127.0.0.1:1")
+                return
+            time.sleep(0.02)
+
+    th = threading.Thread(target=fake_rank0, daemon=True)
+    th.start()
+    try:
+        time.sleep(0.5)  # rank 1 never beats -> lost
+        assert rt.lost_peers() == [1]
+        shrink_began.append(time.monotonic())
+        if decider_writes:
+            assert rt.shrink() == {"generation": 1, "world": 2, "rank": 1,
+                                   "coordinator": "127.0.0.1:1"}
+        else:
+            t0 = time.monotonic()
+            with pytest.raises(WorkerLostError, match="without rank 2"):
+                rt.shrink()
+            assert time.monotonic() - t0 < 5
+    finally:
+        stop.set()
+        th.join(timeout=2)
+        rt.stop()
+
+
 def test_shrink_to_one_has_no_coordinator(tmp_path):
     rt = _rt(tmp_path, 0, 2).start()
     try:

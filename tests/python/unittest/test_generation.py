@@ -600,3 +600,91 @@ def test_1k_sessions_acceptance(tele):
         for i in range(0, 1000, 111):     # sampled sequential parity
             assert ref.generate(prompts[i],
                                 max_new_tokens=budgets[i]) == results[i]
+
+
+# ---------------------------------------------------------------------------
+# the names the benchmark takes from the program (benchmark/serve_programs.py
+# and the serving runners' telemetry tuples) that no other test holds
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def engine_programs(lm48):
+    """`{kind: lowered module text}` for every program kind the engine
+    compiles: a plain engine warms prefill and decode, one with the prefix
+    cache and speculation on warms fork, suffix_prefill and verify."""
+    lm, params = lm48
+    found = {}
+    for features in ({}, {"prefix_cache": True, "spec_k": 2}):
+        eng = GenerationEngine(lm, params, max_slots=2, max_len=48,
+                               buckets=(8,), start=False, **features)
+        eng.warm()
+        for key in eng.cache.keys():
+            args, kwargs = eng.cache._entry_stats[key]["avals"]
+            jitted = eng.cache._entries[key]._fn
+            found[key[0]] = jitted.lower(*args, **kwargs).as_text()
+        eng.close()
+    return found
+
+
+@pytest.mark.parametrize("kind", ["prefill", "decode", "fork",
+                                  "suffix_prefill", "verify"])
+def test_engine_program_is_named_jit_fn(engine_programs, kind):
+    """A device trace shows an execution under its module's name, and
+    `benchmark/serve_programs.py` finds the engine's programs by the one
+    pattern `^jit_fn\\(`: a builder whose inner function is renamed would
+    drop out of `decode_ms_p50`, `prefill_ms_p50`,
+    `prefill_share_of_tick_pct` and the roofline shares without an error
+    (PERF.md section 7 has the order in which a rename can be made)."""
+    assert engine_programs[kind].startswith("module @jit_fn ")
+
+
+@pytest.fixture(scope="module")
+def tiny_run_telemetry(lm48):
+    """Counter and histogram movements of 5 requests of 6 tokens each
+    through a 2-slot engine, telemetry on."""
+    lm, params = lm48
+    pre = "serving.generation."
+    counters = ("ticks", "tick_slots", "decode_tokens", "prefills", "tokens")
+    histograms = ("tick_us", "prefill_us")
+
+    def mark():
+        m = {c: telemetry.counter(pre + c).value for c in counters}
+        for h in histograms:
+            snap = telemetry.histogram(pre + h).snapshot()
+            m[h + ".count"], m[h + ".sum"] = snap["count"], snap["sum"]
+        return m
+
+    prev = telemetry.enabled()
+    telemetry.enable()
+    try:
+        before = mark()
+        with GenerationEngine(lm, params, max_slots=2, max_len=48,
+                              buckets=(16,)) as eng:
+            streams = [eng.submit(p, max_new_tokens=6)
+                       for p in _prompts(5, seed=21)]
+            for s in streams:
+                assert len(s.result(timeout=60)) == 6
+        after = mark()
+    finally:
+        telemetry.enable(prev)
+    return {k: after[k] - before[k] for k in after}
+
+
+@pytest.mark.parametrize("name", ["decode_tokens", "tick_us", "prefill_us"])
+def test_benchmark_telemetry_name_moves(tiny_run_telemetry, name):
+    """`batch_occupancy_pct` is `decode_tokens` over `tick_slots`, and the
+    runners difference `tick_us` and `prefill_us` over the window."""
+    moved = tiny_run_telemetry
+    assert moved["tokens"] == 30 and moved["prefills"] == 5
+    if name == "decode_tokens":
+        # a prefill yields a request's first token, the decodes the rest;
+        # no decode is counted for a slot that was swept dead
+        assert moved["decode_tokens"] == moved["tokens"] - moved["prefills"]
+        assert moved["decode_tokens"] <= moved["tick_slots"]
+    elif name == "tick_us":
+        assert moved["tick_us.count"] == moved["ticks"] > 0
+        assert moved["tick_us.sum"] > 0
+    else:
+        assert moved["prefill_us.count"] == moved["prefills"]
+        assert moved["prefill_us.sum"] > 0

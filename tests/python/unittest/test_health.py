@@ -19,8 +19,9 @@ Covers:
 * fit-step and lazy-flush progress beacons;
 * the autoscale signal (demand-driven desired_engines, change-driven
   callbacks);
-* tools/bench_compare.py (sidecar diff, direction-aware regressions,
-  the steady-state-compiles invariant);
+* ``memory.headroom_bytes`` (capacity − census − worst warmed
+  executable's temp bytes) and the default SLO row burning on negative
+  projected headroom;
 * the chaos acceptance run: one wedged engine in a 3-replica router —
   watchdog bundle, drain, zero drops on healthy engines, /readyz flip
   after recovery, SLO burn reported;
@@ -41,7 +42,7 @@ import pytest
 import jax
 
 import mxnet_tpu as mx
-from mxnet_tpu import health, serving, telemetry, tracing
+from mxnet_tpu import health, memory, serving, telemetry, tracing
 from mxnet_tpu import parallel as par
 from mxnet_tpu.models import TransformerLM, TransformerLMConfig
 from mxnet_tpu.serving.generation import GenerationEngine, GenerationRouter
@@ -149,10 +150,10 @@ def test_slo_spec_parsing():
     assert objs[4].threshold == 1.5e6          # s -> us
     assert objs[2].rel_stat == "p50" and objs[2].threshold == 8.0
     assert objs[3].stat == "value" and objs[3].op == ">="
-    # defaults exist and parse (incl. the roofline + headroom rows)
-    assert len(health.parse_spec("")) == 6
+    # defaults exist and parse (incl. the headroom row)
+    assert len(health.parse_spec("")) == 5
     keys = [o.metric for o in health.parse_spec("")]
-    assert "step.mfu" in keys and "memory.headroom_bytes" in keys
+    assert "memory.headroom_bytes" in keys
     for bad in ("nocolon", "m:p99<<1", "m:p99<abc", "m:weird<1"):
         with pytest.raises(ValueError):
             health.parse_spec(bad)
@@ -573,60 +574,47 @@ def test_autoscale_from_registered_fleet(lm32):
 
 
 # ---------------------------------------------------------------------------
-# tools/bench_compare.py
+# memory headroom + the default SLO row
 # ---------------------------------------------------------------------------
 
 
-def _write_bench(tmp_path, name, record, wrap=False):
-    path = tmp_path / name
-    doc = {"n": 1, "cmd": "bench", "rc": 0, "tail": "",
-           "parsed": record} if wrap else record
-    path.write_text(json.dumps(doc))
-    return str(path)
+def test_memory_headroom_and_negative_headroom_slo(monkeypatch):
+    snap = memory.census()
+    # CPU devices report no bytes_limit: headroom stays unpublished
+    # unless the capacity override is set
+    if "capacity_bytes" not in snap:
+        assert telemetry.get("memory.headroom_bytes") is None
+    monkeypatch.setenv("MXNET_DEVICE_HBM_BYTES", str(1 << 40))
+    snap = memory.census()
+    assert snap["capacity_bytes"] == 1 << 40
+    assert "worst_executable_temp_bytes" in snap
+    assert snap["headroom_bytes"] > 0                  # 1 TiB covers a test
+    assert telemetry.get("memory.headroom_bytes").value == \
+        snap["headroom_bytes"]
 
-
-def test_bench_compare_directions_and_invariant(tmp_path, capsys):
-    sys.path.insert(0, os.path.join(REPO, "tools"))
+    # negative projected headroom burns the default SLO row
+    monkeypatch.setenv("MXNET_DEVICE_HBM_BYTES", "1")
+    snap = memory.census()
+    assert snap["headroom_bytes"] < 0
+    was = health.enabled()
+    health.reset()
+    health.enable()
     try:
-        import bench_compare
+        tr = health.tracker()
+        rep = tr.evaluate()
+        obj = next(o for o in rep["objectives"]
+                   if o["spec"].startswith("memory.headroom_bytes:"))
+        assert not obj["ok"]
+        # and with a sane capacity the same row recovers
+        monkeypatch.setenv("MXNET_DEVICE_HBM_BYTES", str(1 << 40))
+        memory.census()
+        rep = tr.evaluate()
+        obj = next(o for o in rep["objectives"]
+                   if o["spec"].startswith("memory.headroom_bytes:"))
+        assert obj["ok"]
     finally:
-        sys.path.pop(0)
-    old = {"metric": "x", "backend": "cpu", "value": 10.0,
-           "serving": {"req_per_s": 100.0, "p99_ms": 5.0,
-                       "steady_state_compiles": 0},
-           "generation": {"tokens_per_s": 50.0, "ttft_p99_ms": 8.0,
-                          "steady_state_compiles": 0}}
-    # identical -> ok (wrapper form for NEW exercises the sidecar path)
-    ok_new = _write_bench(tmp_path, "new_ok.json", old, wrap=True)
-    assert bench_compare.main(
-        [_write_bench(tmp_path, "old.json", old), ok_new]) == 0
-    # throughput down 50% -> regression
-    worse = json.loads(json.dumps(old))
-    worse["serving"]["req_per_s"] = 50.0
-    assert bench_compare.main(
-        [_write_bench(tmp_path, "old2.json", old),
-         _write_bench(tmp_path, "worse.json", worse),
-         "--threshold", "0.2"]) == 1
-    out = capsys.readouterr().out
-    assert "REGRESSION" in out and "serving req/s" in out
-    # latency p99 UP is a regression; DOWN is an improvement
-    faster = json.loads(json.dumps(old))
-    faster["generation"]["ttft_p99_ms"] = 2.0
-    assert bench_compare.main(
-        [_write_bench(tmp_path, "old3.json", old),
-         _write_bench(tmp_path, "faster.json", faster)]) == 0
-    # the compile-once invariant: nonzero steady-state compiles in NEW
-    # fails REGARDLESS of old and of threshold
-    broken = json.loads(json.dumps(old))
-    broken["generation"]["steady_state_compiles"] = 2
-    assert bench_compare.main(
-        [_write_bench(tmp_path, "old4.json", old),
-         _write_bench(tmp_path, "broken.json", broken),
-         "--threshold", "100"]) == 1
-    # garbage input -> 2
-    bad = tmp_path / "bad.json"
-    bad.write_text("not json")
-    assert bench_compare.main([str(bad), ok_new]) == 2
+        health.reset()
+        health.enable(was)
 
 
 def test_report_tool_health_line(tmp_path, capsys):

@@ -554,36 +554,6 @@ def test_blamer_serving_bucket_ladder(tmp_path):
     assert "(8," in ev["nearest"]       # the nearest bucket, named
 
 
-def test_bench_compare_hlolint_rows(tmp_path, capsys):
-    """Per-step collective bytes from the hlolint inventory: growth >10%
-    at the SAME mesh spec is a hard regression; a mesh change is a
-    skipped row, not a false alarm."""
-    sys.path.insert(0, os.path.join(TOOLS_DIR, "tools"))
-    import bench_compare
-
-    def record(bytes_, mesh="tp=2,fsdp=2"):
-        return {"spmd": {"hlolint": {"mesh": mesh,
-                                     "collective_bytes": bytes_,
-                                     "collectives": {"all-gather": bytes_}}}}
-
-    def run(old, new):
-        po, pn = tmp_path / "old.json", tmp_path / "new.json"
-        po.write_text(json.dumps(old))
-        pn.write_text(json.dumps(new))
-        rc = bench_compare.main([str(po), str(pn)])
-        return rc, capsys.readouterr().out
-
-    rc, out = run(record(1000), record(1200))
-    assert rc == 1 and "REGRESSION (hard)" in out
-    assert "spmd collective bytes/step" in out
-    rc, out = run(record(1000), record(1050))       # +5% — under the bar
-    assert rc == 0 and "REGRESSION" not in out
-    rc, out = run(record(1000), record(5000, mesh="tp=4"))
-    assert rc == 0 and "skipped (mesh" in out       # different mesh
-    rc, out = run(record(1000), record(800))
-    assert rc == 0 and "improved" in out
-
-
 def test_blame_report_line(tmp_path, capsys):
     snap = {"counters": {"compile.blamed_misses": 3,
                          "compile.blame_axis.shape_batch": 2,

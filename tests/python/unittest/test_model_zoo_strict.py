@@ -100,17 +100,22 @@ def _fingerprint(name, size):
 # param-count + torchvision-anchor tests (above) independently pin the
 # architectures, so the fingerprints' job is regression detection FROM
 # CURRENT numerics — stale pins only mask real regressions behind
-# expected failures.
+# expected failures. Re-pinned again at PR 29: these four are the families
+# with a Dropout layer, which draws its mask in train mode, and the
+# installed jax (0.9.0) ships jax_threefry_partitionable=True, so a key
+# yields other bits than it did when the values were taken; with the flag
+# set to False every old value reproduces to 7 digits. The families
+# without Dropout never moved.
 FINGERPRINTS = [
     ("resnet18_v1", 64, -0.52433062, 20.012974),
     ("resnet50_v2", 64, -0.05805696, 9.278577),
-    ("vgg11", 64, -0.00027057, 0.152059),
-    ("alexnet", 224, -0.00932012, 0.647499),
+    ("vgg11", 64, -0.00120782, 0.122725),
+    ("alexnet", 224, -0.02187289, 0.729647),
     ("densenet121", 224, -0.11545076, 8.502438),
-    ("squeezenet1.1", 224, 0.00005404, 0.001081),
+    ("squeezenet1.1", 224, 0.00005458, 0.001092),
     ("mobilenet0.5", 64, 0.09610178, 11.040597),
     ("mobilenetv2_0.5", 64, 0.19661103, 9.270964),
-    ("inceptionv3", 299, -0.21313837, 14.120452),
+    ("inceptionv3", 299, -0.12100963, 13.699405),
 ]
 
 

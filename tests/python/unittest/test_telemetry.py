@@ -457,3 +457,96 @@ def test_prom_label_escaping_helper():
     assert telemetry._prom_label('a"b') == 'a\\"b'
     assert telemetry._prom_label("a\\b") == "a\\\\b"
     assert telemetry._prom_label("a\nb") == "a\\nb"
+
+
+# ---------------------------------------------------------------------------
+# CompileCache.entry_memory / entry_collectives: the lazy AOT analysis that
+# /memory, memory.census()'s worst_executable_temp_bytes and chip_smoke read
+# ---------------------------------------------------------------------------
+
+
+def test_entry_memory_of_a_donated_entry():
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.compile_cache import CompileCache
+
+    cache = CompileCache("entry_memory_test")
+    fn = cache.get_or_build("k", lambda: jax.jit(
+        lambda w, x: (w + x.sum(), jnp.tanh(x) @ x.T), donate_argnums=(0,)))
+    w = jnp.ones((256, 128), jnp.float32)
+    x = jnp.ones((64, 128), jnp.float32)
+    assert cache.memory_stats() == []          # nothing analysed before a call
+    w_bytes, x_bytes, out2_bytes = w.nbytes, x.nbytes, 64 * 64 * 4
+    fn(w, x)
+    assert cache.memory_stats() == []          # ... nor by the call itself
+    mem = cache.entry_memory("k")
+    assert mem["argument_bytes"] == w_bytes + x_bytes
+    # both outputs, plus the result tuple's own few bytes
+    assert 0 <= mem["output_bytes"] - (w_bytes + out2_bytes) <= 64
+    assert mem["alias_bytes"] == w_bytes       # the donated weight, in place
+    assert mem["temp_bytes"] >= x_bytes        # tanh(x) lives somewhere
+    assert mem["peak_bytes"] == (mem["argument_bytes"] + mem["output_bytes"]
+                                 + mem["temp_bytes"] - mem["alias_bytes"])
+    assert cache.entry_memory("k") is mem      # memoised: one AOT pass
+    assert cache.memory_stats() == [dict(mem, key="'k'")]
+    assert cache.entry_collectives("k") == {}  # one device: nothing to send
+    assert cache.entry_memory("never built") is None
+    assert cache.entry_collectives("never built") is None
+
+
+def test_entry_memory_failure_is_memoised():
+    import jax.numpy as jnp
+
+    from mxnet_tpu.compile_cache import CompileCache
+
+    class NoLower:
+        def __call__(self, x):
+            return x
+
+    class LowerRaises(NoLower):
+        lowered = 0
+
+        def lower(self, *args, **kwargs):
+            LowerRaises.lowered += 1
+            raise RuntimeError("cannot be lowered")
+
+    cache = CompileCache("entry_memory_failure_test")
+    cache.get_or_build("plain", NoLower)(jnp.ones(3))
+    cache.get_or_build("raises", LowerRaises)(jnp.ones(3))
+    # a callable that is not a jit has nothing to analyse, and costs nothing
+    assert cache.entry_memory("plain") is None
+    assert cache.entry_collectives("plain") is None
+    # a lowering that fails is tried once: the AOT pass is expensive and a
+    # second try would not go better
+    assert cache.entry_memory("raises") is None
+    assert cache.entry_memory("raises") is None
+    assert cache.entry_collectives("raises") is None
+    assert LowerRaises.lowered == 1
+    assert cache.memory_stats(compute=True) == []
+
+
+def test_entry_collectives_of_a_two_device_psum():
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from mxnet_tpu.compile_cache import CompileCache
+
+    if len(jax.devices()) < 2:
+        pytest.skip("needs two devices")
+    mesh = Mesh(np.array(jax.devices()[:2]), ("dp",))
+    cache = CompileCache("entry_collectives_test")
+    fn = cache.get_or_build("psum", lambda: jax.jit(jax.shard_map(
+        lambda a: jax.lax.psum(a, "dp"), mesh=mesh, in_specs=P("dp"),
+        out_specs=P())))
+    a = jax.device_put(jnp.ones((1024, 4), jnp.float32),
+                       NamedSharding(mesh, P("dp")))
+    assert fn(a).shape == (512, 4)
+    # scanned first by a plain memory scrape (no HLO text parsed), the
+    # inventory is still there when asked for: bytes per participant
+    mem = cache.entry_memory("psum")
+    assert mem["argument_bytes"] == 512 * 4 * 4
+    assert cache.entry_collectives("psum") == {
+        "all-reduce": {"count": 1, "bytes": 512 * 4 * 4}}
+    assert cache.entry_memory("psum") == mem

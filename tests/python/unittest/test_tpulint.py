@@ -439,10 +439,9 @@ def test_env_registry_repo_is_clean():
     from tools.tpulint import lint_paths
 
     # same scan set as the ci/run.sh gate — the doc-coverage direction
-    # needs tools/ and bench.py (they read the probe/test-seed knobs)
+    # needs tools/ (it reads the probe/test-seed knobs)
     findings = lint_paths(
-        [os.path.join(REPO, "mxnet_tpu"), os.path.join(REPO, "tools"),
-         os.path.join(REPO, "bench.py")],
+        [os.path.join(REPO, "mxnet_tpu"), os.path.join(REPO, "tools")],
         env_doc=os.path.join(REPO, "docs", "faq", "env_var.md"),
         select={"env-var-registry"})
     assert findings == [], "\n".join(map(str, findings))
@@ -487,10 +486,10 @@ def test_cli_nonzero_on_each_rule_fixture(tmp_path):
 
 @pytest.mark.slow
 def test_cli_repo_gate_is_clean():
-    """`python -m tools.tpulint mxnet_tpu tools bench.py --strict` exits
+    """`python -m tools.tpulint mxnet_tpu tools --strict` exits
     0 — every pre-existing violation is fixed or carries a reasoned
     disable (the ci/run.sh blocking gate)."""
-    r = _run_cli(["mxnet_tpu", "tools", "bench.py", "--strict"])
+    r = _run_cli(["mxnet_tpu", "tools", "--strict"])
     assert r.returncode == 0, r.stdout + r.stderr
     assert "clean" in r.stdout
 

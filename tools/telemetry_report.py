@@ -6,8 +6,7 @@ Usage::
     python tools/telemetry_report.py telemetry.json [--sort-by total|count|avg|min|max]
 
 The input is a ``mxnet_tpu.telemetry.dumps()`` JSON snapshot — written by
-``MXNET_TELEMETRY_DUMP=<path>`` at exit, ``telemetry.dump(path)``, or
-``bench.py`` (``BENCH_TELEMETRY.json`` next to its BENCH output). The
+``MXNET_TELEMETRY_DUMP=<path>`` at exit or ``telemetry.dump(path)``. The
 rendering is ``telemetry.dumps_table`` — the same visual format as
 ``profiler.dumps_aggregate``, so perf rounds read one table language for
 both planes.
@@ -296,47 +295,6 @@ def main(argv=None):
         line += ("\n  (ratio should track 1/N of the sharded axes - "
                  "docs/faq/perf.md \"One mesh, one program\")\n")
         sys.stdout.write(line)
-    obs = snap.get("observatory") or {}
-    if obs.get("enabled") and obs.get("lanes"):
-        pk = obs.get("peaks") or {}
-        mm = (pk.get("matmul_flops") or {})
-        best = max([v for v in mm.values()
-                    if isinstance(v, (int, float))] or [0])
-        line = "\nroofline (measured peaks"
-        if best:
-            line += f": matmul {best / 1e12:.2f} TFLOP/s"
-        hbm = pk.get("hbm_bytes_per_s")
-        if hbm:
-            line += f", hbm {hbm / 1e9:.1f} GB/s"
-        line += f", source {pk.get('source', '?')})"
-        verdict = obs.get("probe_verdict")
-        if verdict:
-            line += f" [{verdict}]"
-        sys.stdout.write(line + "\n")
-        # worst offenders first: each lane judged by utilisation against
-        # its BINDING roof (MBU when bandwidth-bound, MFU otherwise)
-        order = obs.get("worst") or sorted(obs["lanes"])
-        for name in order:
-            row = obs["lanes"].get(name) or {}
-            bound = row.get("roofline_bound", "?")
-            util = row.get("mbu" if bound == "bandwidth" else "mfu")
-            bits = [f"  {name:<18} bound={bound:<9}"]
-            if util is not None:
-                bits.append(f"util={util:.3f}")
-            if row.get("mfu") is not None:
-                bits.append(f"mfu={row['mfu']:.3f}")
-            if row.get("mbu") is not None:
-                bits.append(f"mbu={row['mbu']:.3f}")
-            if row.get("comm_fraction"):
-                bits.append(f"comm={row['comm_fraction']:.2f}")
-            if row.get("predicted_floor_s") is not None \
-                    and row.get("measured_s") is not None:
-                bits.append(f"floor={row['predicted_floor_s'] * 1e3:.3f}ms"
-                            f" measured={row['measured_s'] * 1e3:.3f}ms")
-            sys.stdout.write(" ".join(bits) + "\n")
-        sys.stdout.write("  (worst offender first - utilisation against "
-                         "the binding roof; docs/faq/perf.md \"Reading "
-                         "the roofline\")\n")
     gauges = snap.get("gauges", {})
     slo_keys = sorted({k[len("slo."):-len(".ok")]
                        for k in gauges if k.startswith("slo.")

@@ -7,8 +7,7 @@ recompile bug, that a donation the hlolint audit cannot see is how
 vars at import breaks the "gates cost one attribute read when off"
 discipline every perf PR has leaned on since PR 7. Those rules lived in
 reviewer memory; tpulint turns them into a blocking CI gate
-(``ci/run.sh``: ``python -m tools.tpulint mxnet_tpu tools bench.py
---strict``).
+(``ci/run.sh``: ``python -m tools.tpulint mxnet_tpu tools --strict``).
 
 Rules (see :mod:`tools.tpulint.rules` for the exact semantics, and
 ``docs/faq/perf.md`` "Machine-checked invariants" for the why):

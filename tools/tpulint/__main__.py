@@ -1,4 +1,4 @@
-"""CLI: ``python -m tools.tpulint mxnet_tpu tools bench.py --strict``.
+"""CLI: ``python -m tools.tpulint mxnet_tpu tools --strict``.
 
 Exit codes: 0 clean (or findings without --strict), 1 findings under
 --strict, 2 usage error. The ci/run.sh gate runs --strict; the
