@@ -5,19 +5,43 @@ Parity: `python/mxnet/metric.py` — EvalMetric base (:68), CompositeEvalMetric
 Perplexity (:960), MAE/MSE/RMSE (:1084-1213), CrossEntropy (:1278),
 NegativeLogLikelihood (:1350), PearsonCorrelation (:1422), Loss (:1610),
 CustomMetric (:1662), np()/create() helpers.
+
+An ``update`` whose labels or predictions hold an :class:`NDArray` is settled
+one call late. The call keeps the buffers those arrays hold (a later
+``out[:] = ...`` swaps the NDArray's buffer, not the kept one; the pending
+output of a recorded hybridized call is kept as it is), settles the update the
+previous call kept, and returns: the host fetches step *i - 1*, which finished
+before step *i* could start, instead of draining the step it has just
+launched, so the next batch's copy and launch run under it. Settling is the
+subclass's own ``update`` on the kept arrays, so the numbers are those of
+lockstep. Every read (``get``, ``get_name_value``, ``reset``, ``str``,
+``sum_metric``, ``num_inst``, pickling and copying) settles first. The lag is
+one call, never more. All-numpy arguments, and everything under
+``MXNET_OVERLAP=0``, settle at once.
+
+Counters ``metric.deferred`` (updates kept), ``metric.settled_late`` (settled
+by the next ``update``) and ``metric.settled_on_read``; the fetch is under the
+span ``metric.settle``.
 """
 from __future__ import annotations
 
+import contextlib
+import functools
 import math
+import threading
 
 import numpy
 
+from . import telemetry
+from . import tracing
 from .base import numeric_types, string_types
+from .io.staging import overlap_enabled
+from .ndarray.ndarray import NDArray
 
 __all__ = ["EvalMetric", "CompositeEvalMetric", "Accuracy", "TopKAccuracy", "F1", "MCC",
            "Perplexity", "MAE", "MSE", "RMSE", "CrossEntropy", "NegativeLogLikelihood",
            "PearsonCorrelation", "Loss", "Torch", "Caffe", "CustomMetric", "np", "create",
-           "check_label_shapes"]
+           "check_label_shapes", "immediate"]
 
 _METRIC_REGISTRY = {}
 
@@ -62,7 +86,99 @@ def _asnp(x):
     return x.asnumpy() if hasattr(x, "asnumpy") else numpy.asarray(x)
 
 
+class _Lockstep(threading.local):
+    depth = 0
+
+
+_lockstep = _Lockstep()
+
+
+@contextlib.contextmanager
+def immediate():
+    """Updates made inside are applied at once. For a caller that already
+    applies a step's update one step late itself (``fit``'s deferred lane):
+    deferring again would let the host run two steps ahead."""
+    _lockstep.depth += 1
+    try:
+        yield
+    finally:
+        _lockstep.depth -= 1
+
+
+def _holds_ndarray(x):
+    if isinstance(x, (list, tuple)):
+        return any(_holds_ndarray(v) for v in x)
+    return isinstance(x, NDArray)
+
+
+def _keep(x):
+    """`x` as it is now: an NDArray's buffer, a numpy array's values."""
+    if isinstance(x, (list, tuple)):
+        return [_keep(v) for v in x]
+    if isinstance(x, NDArray):
+        return x.detach()
+    if isinstance(x, numpy.ndarray):
+        return x.copy()
+    return x
+
+
+def _deferring(update):
+    @functools.wraps(update)
+    def deferred(self, labels, preds):
+        defer = (not _lockstep.depth and overlap_enabled()
+                 and (_holds_ndarray(labels) or _holds_ndarray(preds)))
+        kept = (_keep(labels), _keep(preds)) if defer else None
+        try:
+            self._settle("metric.settled_late")
+        finally:
+            self._pending = kept
+        if not defer:
+            return update(self, labels, preds)
+        telemetry.counter("metric.deferred").inc()
+
+    deferred._settles = True
+    return deferred
+
+
+def _settling_first(read):
+    @functools.wraps(read)
+    def settled(self, *args, **kwargs):
+        self._settle()
+        return read(self, *args, **kwargs)
+
+    settled._settles = True
+    return settled
+
+
+def _settled_attribute(name):
+    """A public accumulator: reading or writing it settles first."""
+
+    def read(self):
+        self._settle()
+        return getattr(self, name)
+
+    def write(self, value):
+        self._settle()
+        setattr(self, name, value)
+
+    return property(read, write)
+
+
 class EvalMetric:
+    # the one update not settled yet: (labels, preds) as `_keep` left them
+    _pending = None
+
+    def __init_subclass__(cls, defer=True, **kwargs):
+        super().__init_subclass__(**kwargs)
+        if not defer:
+            return
+        for name, wrap in (("update", _deferring), ("get", _settling_first),
+                           ("get_name_value", _settling_first),
+                           ("reset", _settling_first)):
+            method = cls.__dict__.get(name)
+            if callable(method) and not getattr(method, "_settles", False):
+                setattr(cls, name, wrap(method))
+
     def __init__(self, name, output_names=None, label_names=None, **kwargs):
         self.name = str(name)
         self.output_names = output_names
@@ -70,8 +186,24 @@ class EvalMetric:
         self._kwargs = kwargs
         self.reset()
 
+    def _settle(self, counter="metric.settled_on_read"):
+        kept = self._pending
+        if kept is None:
+            return
+        self._pending = None
+        telemetry.counter(counter).inc()
+        with immediate(), tracing.span("metric.settle"):
+            self.update(*kept)
+
+    sum_metric = _settled_attribute("_sum_metric")
+    num_inst = _settled_attribute("_num_inst")
+
+    def __getstate__(self):
+        self._settle()
+        return self.__dict__
+
     def __str__(self):
-        return f"EvalMetric: {dict(zip(*self.get()))}"
+        return f"EvalMetric: {dict(self.get_name_value())}"
 
     def get_config(self):
         config = self._kwargs.copy()
@@ -112,7 +244,9 @@ class EvalMetric:
 
 
 @register
-class CompositeEvalMetric(EvalMetric):
+class CompositeEvalMetric(EvalMetric, defer=False):
+    """Defers and settles through its children."""
+
     def __init__(self, metrics=None, name="composite", output_names=None, label_names=None):
         super().__init__(name, output_names, label_names)
         self.metrics = [create(m) for m in (metrics or [])]
@@ -158,10 +292,11 @@ class Accuracy(EvalMetric):
         labels, preds = check_label_shapes(labels, preds, True)
         for label, pred_label in zip(labels, preds):
             pred_np = _asnp(pred_label)
-            if pred_np.ndim > _asnp(label).ndim:
+            label_np = _asnp(label)
+            if pred_np.ndim > label_np.ndim:
                 pred_np = numpy.argmax(pred_np, axis=self.axis)
             pred_np = pred_np.astype("int32").reshape(-1)
-            label_np = _asnp(label).astype("int32").reshape(-1)
+            label_np = label_np.astype("int32").reshape(-1)
             check_label_shapes(label_np, pred_np)
             self.sum_metric += (pred_np == label_np).sum()
             self.num_inst += len(pred_np)
@@ -263,7 +398,8 @@ class Perplexity(EvalMetric):
         num = 0
         for label, pred in zip(labels, preds):
             label_np = _asnp(label).astype("int32").reshape(-1)
-            pred_np = _asnp(pred).reshape(-1, _asnp(pred).shape[-1])
+            pred_np = _asnp(pred)
+            pred_np = pred_np.reshape(-1, pred_np.shape[-1])
             probs = pred_np[numpy.arange(label_np.shape[0]), label_np]
             if self.ignore_label is not None:
                 ignore = (label_np == self.ignore_label).astype(pred_np.dtype)

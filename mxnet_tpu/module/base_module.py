@@ -312,7 +312,8 @@ class BaseModule:
                             capture = self.capture_metric_update(
                                 data_batch.label)
                         if capture is None:
-                            with tracing.span("step.sync", cat="train"):
+                            with tracing.span("step.sync", cat="train"), \
+                                    _metric.immediate():
                                 if pending_metric is not None:
                                     # mixed-mode seam: settle the deferred
                                     # step before the eager one updates the
@@ -353,7 +354,8 @@ class BaseModule:
                             else:
                                 next_data_batch = fetched
                             t_data = time.perf_counter() if tele else 0.0
-                            with tracing.span("step.sync", cat="train"):
+                            with tracing.span("step.sync", cat="train"), \
+                                    _metric.immediate():
                                 if pending_metric is not None:
                                     pending_metric(eval_metric)
                                     self.retire_staged()
@@ -410,7 +412,8 @@ class BaseModule:
                         # the step cadence from these
                         fit_beacon.touch()
                 if pending_metric is not None:  # pragma: no cover — safety
-                    pending_metric(eval_metric)
+                    with _metric.immediate():
+                        pending_metric(eval_metric)
                     pending_metric = None
                     self.retire_staged()
                 if fit_beacon is not None:
