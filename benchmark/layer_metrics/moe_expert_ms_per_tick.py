@@ -1,0 +1,15 @@
+"""`moe_expert_ms_per_tick` — layer: kernels. Device time of the expert
+layers' grouped product a decode execution: the grouped-matmul operations
+of the decode program (moe_ops.py: the Pallas `gmm` on the chip; two
+products an expert layer), summed over the traced window's decode executions and divided
+by their number. Should move `itl_p90_ms`.
+"""
+import moe_ops
+
+
+def read(obs, run):
+    found = moe_ops.grouped_product_seconds(obs, run)
+    if found is None:
+        return None
+    seconds, executions = found
+    return seconds / executions * 1e3

@@ -10,9 +10,12 @@ from . import transformer
 from .transformer import TransformerLMConfig, TransformerLM
 from . import hybrid
 from .hybrid import HybridLMConfig, HybridLM
+from . import latent_moe
+from .latent_moe import LatentMoELMConfig, LatentMoELM
 from . import resnet
 from .resnet import resnet50_symbol
 
 __all__ = ["transformer", "TransformerLMConfig", "TransformerLM",
            "hybrid", "HybridLMConfig", "HybridLM",
+           "latent_moe", "LatentMoELMConfig", "LatentMoELM",
            "resnet", "resnet50_symbol"]

@@ -301,6 +301,10 @@ class GenerationEngine:
         # per-slot bytes of state that is not a range of rows (a recurrent
         # layer's): read and written whole by every tick a slot is live
         self._state_bytes = int(traits["state_bytes_per_slot"])
+        # counters the MODEL defines and computes on the device from what a
+        # decode step left in the cache (an expert model's routing): names
+        # under "serving.generation.", or () for a model that has none
+        self._tick_counter_names = tuple(traits.get("tick_counters", ()))
         # host-side slot metadata — only the tick loop (under _tick_lock)
         # mutates these
         self._sessions = [None] * self._total_slots
@@ -682,6 +686,9 @@ class GenerationEngine:
                 jnp.where(self._tokens_on_device(
                     np.ones(self._total_slots, bool)),
                     self._tokens_on_device(self._last_tok), toks)
+                if self._tick_counter_names:
+                    self._tick_counters_fn()(
+                        self._kv, jnp.asarray(self._tick_positions()))
         compiles = self._cache.misses - misses0
         seconds = time.perf_counter() - t0
         self._warmed = True           # readiness: warmup complete
@@ -937,6 +944,26 @@ class GenerationEngine:
             return jax.jit(fn, donate_argnums=(1,))
 
         key = ("decode", self._total_slots, self._slab_len)
+        return cache.get_or_build(key, build)
+
+    def _tick_counters_fn(self):
+        """The model's own counters of one decode step
+        (``cache_traits(...)["tick_counters"]``): a few integers computed on
+        the device from the cache the decode just returned (not donated) and
+        its positions. Dispatched behind a decode only while telemetry is
+        on, fetched at that decode's commit; not named ``fn``, so a trace
+        tells it from the decode and prefill programs."""
+        model, cache = self._model, self._cache
+
+        def build():
+            import jax
+
+            def tick_counters(cache, positions):
+                return model.tick_counters(*cache, positions)
+
+            return jax.jit(tick_counters)
+
+        key = ("tick_counters", self._total_slots, self._slab_len)
         return cache.get_or_build(key, build)
 
     def _fork_fn(self):
@@ -1226,6 +1253,20 @@ class GenerationEngine:
             if s is None or (act is not None and i not in act):
                 pos[i] = -1
         return pos
+
+    def _count_dispatch(self, positions):
+        """Telemetry of one plain decode dispatch: the slab's blocks it
+        reads and the state it advances (host arithmetic), and — for a model
+        that defines counters of its own — their program, dispatched behind
+        the decode on the cache it returned. Returns that program's output,
+        still on the device (the commit fetches it with the tokens), or
+        None."""
+        import jax.numpy as jnp
+
+        self._count_slab_blocks(positions)
+        if not self._tick_counter_names:
+            return None
+        return self._tick_counters_fn()(self._kv, jnp.asarray(positions))
 
     def _count_slab_blocks(self, positions, steps=1):
         """Telemetry: how much of the slab this dispatch reads.
@@ -1533,13 +1574,14 @@ class GenerationEngine:
                     self._version_params(version), self._kv,
                     self._tokens_on_device(self._last_tok),
                     jnp.asarray(positions))
+            counted = None
             if telemetry._enabled:
-                self._count_slab_blocks(positions)
+                counted = self._count_dispatch(positions)
             # snapshot the cohort's sessions: a slot evicted or re-
             # admitted between dispatch and commit fails the identity
             # check and its token is discarded
             pending.append((slots, [self._sessions[s] for s in slots],
-                            toks))
+                            toks, counted))
         return ("plain", pending)
 
     def _lookahead_ok(self, pending):
@@ -1565,7 +1607,7 @@ class GenerationEngine:
         slot goes on."""
         import jax.numpy as jnp
 
-        (slots, snap, toks), = pending[1]
+        (slots, snap, toks, _), = pending[1]
         positions = np.full(self._total_slots, -1, np.int32)
         from_host = np.ones(self._total_slots, bool)
         out = set()                 # slots whose session ``pending`` holds
@@ -1590,9 +1632,11 @@ class GenerationEngine:
                                self._tokens_on_device(self._last_tok), toks)
             toks, self._kv = self._decode_fn()(
                 self._params, self._kv, tokens, jnp.asarray(positions))
+        counted = None
         if telemetry._enabled:
-            self._count_slab_blocks(positions)
-        return ("plain", [(live, [self._sessions[s] for s in live], toks)])
+            counted = self._count_dispatch(positions)
+        return ("plain", [(live, [self._sessions[s] for s in live], toks,
+                           counted)])
 
     def _decode_commit(self, state):
         """Block on the dispatched token outputs and commit them:
@@ -1609,9 +1653,14 @@ class GenerationEngine:
 
     def _plain_commit(self, pending):
         live = 0
-        for slots, snap, toks in pending:
+        for slots, snap, toks, counted in pending:
             with tracing.span("generation.commit.fetch", cat="generation"):
                 toks = np.asarray(toks)     # blocks until the decode ran
+            if counted is not None:
+                for name, n in zip(self._tick_counter_names,
+                                   np.asarray(counted)):
+                    telemetry.counter("serving.generation." + name).inc(
+                        int(n))
             for slot, dispatched in zip(slots, snap):
                 sess = self._sessions[slot]
                 if sess is None or sess is not dispatched:

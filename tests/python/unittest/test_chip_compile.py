@@ -359,3 +359,92 @@ def test_recorded_call_backward_returns_no_residual_on_v5e(one_chip):
     live = ma.argument_size_in_bytes + ma.output_size_in_bytes \
         + ma.temp_size_in_bytes
     assert live < 4e9
+
+
+# ---------------------------------------------------------------------------
+# the latent-attention expert model's decode program at sarvam-105b's widths
+# (ISSUE 31)
+# ---------------------------------------------------------------------------
+
+def test_latent_moe_decode_program_compiles_for_v5e(one_chip, monkeypatch):
+    """The engine's decode program for the dense layer and one expert layer
+    of `sarvam_105b_ep4` at its published widths, 32 slots x 16,384 rows: it
+    compiles for the v5e; the latent kernel is in it once a layer and the
+    grouped matmul `gmm` twice an expert layer; every member of the cache is
+    aliased input to output; and no XLA op copies or re-lays a latent slab
+    — a row is written where it lies and the live blocks are read by the
+    kernel."""
+    import json
+    import os
+
+    from mxnet_tpu import parallel as par
+    from mxnet_tpu.models import LatentMoELM, LatentMoELMConfig
+
+    monkeypatch.setenv("MXNET_PALLAS_ATTENTION", "1")
+    monkeypatch.delenv("MXNET_PALLAS_INTERPRET", raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "sarvam_105b_ep4.json")) as f:
+        published = json.load(f)
+    published["num_hidden_layers"] = 2          # the dense + one expert layer
+    slots, rows = 32, 16384
+    config = LatentMoELMConfig.from_config(published, max_len=rows)
+    assert (config.num_experts, config.experts_held, config.expert_first) \
+        == (128, 32, 0)
+    dev = next(iter(one_chip.device_set))
+    lm = LatentMoELM(config, par.create_mesh(devices=[dev], dp=1))
+    host_lm = LatentMoELM(config, par.create_mesh(devices=jax.devices()[:1],
+                                                  dp=1))
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = {k: sds(v) for k, v in jax.eval_shape(
+        host_lm.init_params, jax.random.PRNGKey(0)).items()}
+    cache = tuple(sds(v) for v in jax.eval_shape(
+        lambda: host_lm.init_cache(slots, rows)))
+    assert [c.shape for c in cache] == [
+        (32, 2, 16384, 512), (32, 2, 64, 16384), (32, 1, 8)]
+    assert lm.decode_block(cache[0].shape, cache[0].dtype) == 1024
+
+    def fn(params, cache, tokens, positions):       # the engine's wrapper
+        logits, *cache = lm.decode_step(params, *cache, tokens, positions)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), tuple(cache)
+
+    ints = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, ints, ints).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%latent_attend[.\d]* = ", text)) == 2
+    assert len(re.findall(r"%gmm[.\d]* = ", text)) == 2
+    page = slots * rows * 64                        # the smaller slab's page
+    # a row goes in by a dynamic-update-slice, in place on the donated slab
+    # (its result is the slab, its operand one row); nothing else moves one
+    assert [line for line in _page_sized_ops(text, page)
+            if "dynamic-update-slice" not in line] == []
+    ma = compiled.memory_analysis()
+    cache_bytes = sum(int(np.prod(c.shape)) * c.dtype.itemsize
+                      for c in cache)
+    assert ma.alias_size_in_bytes >= cache_bytes    # all three, whole
+    assert ma.temp_size_in_bytes < page * 2
+
+
+def test_latent_prefill_attention_compiles_for_v5e(one_chip):
+    """The prefill attention kernel at sarvam-105b's widths — 64 heads,
+    keys of 128 + a shared 64, values of 128 — over the 8,192 bucket."""
+    from mxnet_tpu.ops import pallas_latent
+
+    heads, length = 64, 8192
+    block = pallas_latent.prefill_block(length)
+    assert block == 1024
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    text = _compiled_text(
+        lambda qn, qr, kn, kr, v: pallas_latent.prefill_attend(
+            qn, qr, kn, kr, v, block=block, scale=0.1),
+        sds(heads, length, 128), sds(heads, length, 64),
+        sds(heads, length, 128), sds(length, 64), sds(heads, length, 128))
+    assert len(re.findall(r"%latent_prefill_attend[.\d]* = ", text)) == 1
