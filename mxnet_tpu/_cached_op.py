@@ -24,6 +24,13 @@ those jaxprs): the residuals are temporaries of that program, never its
 outputs — CachedOp::Backward's role without a saved-tensor list. An output that is read first is forced through
 the forward-only program inference uses.
 
+A plain ``backward()`` over such calls launches nothing either
+(``autograd._CallsBackward``): each wanted leaf's ``.grad`` holds a
+:class:`PendingGrad` until ``Trainer.step`` runs forward, pullback and the
+optimizer update as ONE donated program (:func:`backward_program` with an
+``update`` stage: no gradient leaves it), or until a gradient or an output
+is read, which runs the forward+pullback program as before.
+
 RNG / train-mode: the compiled program takes a threefry base key as a
 traced argument (fresh randomness each call, zero recompiles) and the
 train flag is a static cache key — the reference achieves the same with
@@ -43,7 +50,8 @@ from . import tracing
 from .base import MXNetError
 from .compile_cache import CompileCache
 
-__all__ = ["CachedOp", "PendingOutput", "RecordedCall", "backward_program"]
+__all__ = ["CachedOp", "PendingGrad", "PendingOutput", "RecordedCall",
+           "backward_program"]
 
 
 class PendingOutput:
@@ -63,12 +71,57 @@ class PendingOutput:
 
     def force(self, reason="value"):
         if self.value is None:
-            self.call.run_forward()
+            # a deferred backward that still owes gradients computes the
+            # outputs with them; a dead one leaves the forward-only program
+            deferred = self.call.deferred
+            if deferred is None or not deferred.run_if_owed():
+                self.call.run_forward()
         return self.value
 
     def __repr__(self):
         state = "pending" if self.value is None else "realized"
         return f"PendingOutput({state}, shape={self.shape}, dtype={self.dtype})"
+
+
+class PendingGrad:
+    """The gradient of one leaf of a deferred ``backward()``
+    (``autograd._CallsBackward``), in the leaf's ``.grad`` until
+    ``Trainer.step`` takes the backward whole or a read forces it. The
+    pending-buffer protocol of :class:`PendingOutput`. ``owner`` is dropped
+    with the backward: by the forward+pullback program, which leaves
+    ``value``, or by the one-program step, which leaves none — the
+    gradient was a temporary of that program."""
+
+    __slots__ = ("owner", "shape", "dtype", "value")
+
+    def __init__(self, owner, shape, dtype):
+        self.owner = owner
+        self.shape = tuple(shape)
+        self.dtype = dtype
+        self.value = None
+
+    @property
+    def consumed(self):
+        """Taken by the one-program step: there is no value and none can
+        be made."""
+        return self.value is None and self.owner is None
+
+    def force(self, reason="value"):
+        if self.value is None:
+            if self.owner is None:
+                raise MXNetError(
+                    "this gradient was never materialized: Trainer.step ran "
+                    "backward and the update as one program and the old "
+                    "weights were donated to it. Read a gradient between "
+                    "backward() and step() (the step then runs as two "
+                    "programs), not after")
+            self.owner.run()
+        return self.value
+
+    def __repr__(self):
+        state = "consumed" if self.consumed else \
+            "pending" if self.value is None else "realized"
+        return f"PendingGrad({state}, shape={self.shape}, dtype={self.dtype})"
 
 
 class RecordedCall:
@@ -78,9 +131,11 @@ class RecordedCall:
     buffer, not this one); an entry may be the :class:`PendingOutput` of an
     earlier recorded call."""
 
-    __slots__ = ("op", "train", "sig", "trace", "key", "inputs", "outputs")
+    __slots__ = ("op", "train", "sig", "trace", "key", "inputs", "outputs",
+                 "deferred")
 
     def __init__(self, op, train, sig, trace, key, inputs):
+        self.deferred = None  # the deferred backward that will fill outputs
         self.op = op
         self.train = train
         self.sig = sig
@@ -125,9 +180,10 @@ def explain_deleted_inputs(arrays, err):
             "read the output or call backward() before the update") from err
 
 
-def backward_program(jaxprs, wiring, wanted, heads, emit):
+def backward_program(jaxprs, wiring, wanted, heads, emit, update=None):
     """Forward and pullback of recorded calls as ONE jitted function
-    ``(keys, leaves, cts) -> (emitted outputs, grads of the wanted leaves)``.
+    ``(keys, leaves, cts) -> (emitted outputs, grads of the wanted leaves)``
+    — or, with ``update``, the whole training step.
 
     ``jaxprs``: the calls' closed jaxprs ``(key, *args) -> outputs`` in tape
     order. ``wiring[c][i]`` says where argument ``i`` of call ``c`` comes
@@ -137,7 +193,14 @@ def backward_program(jaxprs, wiring, wanted, heads, emit):
     ``explicit`` one takes the next entry of ``cts``, the others get ones.
     ``emit``: the ``(c, k)`` outputs the program also returns (those nobody
     has computed yet). Nothing else leaves the program: the residuals are
-    its temporaries."""
+    its temporaries.
+
+    ``update``: ``Optimizer.fused_update``. The function is then
+    ``(keys, weights, rest, states, lrs, wds, rescale) -> (emitted outputs,
+    new weights, new states' leaves)``: ``weights`` are the wanted leaves
+    and ``rest`` the others, each in slot order; the gradients go into
+    ``update`` as values and do not leave; ``weights`` and ``states`` are
+    donated, so every new weight and state is written over its old one."""
     fns = [jaxpr_as_fun(j) for j in jaxprs]
 
     def program(keys, leaves, cts):
@@ -161,7 +224,29 @@ def backward_program(jaxprs, wiring, wanted, heads, emit):
         (grads,) = pullback(head_cts)
         return emitted, grads
 
-    return jax.jit(program)
+    if update is None:
+        return jax.jit(program)
+
+    def step(keys, weights, rest, states, lrs, wds, rescale):
+        mine, others = iter(weights), iter(rest)
+        slots = set(wanted)
+        leaves = tuple(next(mine) if s in slots else next(others)
+                       for s in range(len(weights) + len(rest)))
+        emitted, grads = program(keys, leaves, ())
+        # the update reads the gradients as the pullback rounds them: with
+        # no barrier XLA carries them over in the pullback's wider
+        # precision, and the step differs from its two-program form
+        grads = jax.lax.optimization_barrier(grads)
+        new_ws, new_ss = update(list(weights), list(grads), list(states),
+                                lrs, wds, rescale)
+        if jax.tree_util.tree_structure(tuple(new_ss)) \
+                != jax.tree_util.tree_structure(tuple(states)):
+            raise MXNetError("fused_update changed the structure of the "
+                             "optimizer states")
+        return emitted, tuple(new_ws), tuple(jax.tree_util.tree_leaves(new_ss))
+
+    # tpulint: disable=donation-aliasing (autograd._CallsBackward.program builds it inside the op's CompileCache, audit="fused_step")
+    return jax.jit(step, donate_argnums=(1, 3))
 
 
 def _ones_ct(x):

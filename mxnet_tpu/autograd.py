@@ -16,6 +16,17 @@ runs their forward and pullback as ONE program (`_fused_backward`) — the
 analogue of CachedOp::Backward (`src/imperative/cached_op.cc:1160`) with
 the residuals as temporaries of that program; where eager nodes sit between
 them, the walk runs that program for one call at a time.
+
+The plain call — no head gradient, no ``retain_graph``, not ``grad()``,
+every wanted leaf a dense ``grad_req="write"`` buffer, ``MXNET_FUSED_STEP``
+on — does not even launch that program: it keeps its wiring
+(`_CallsBackward`) and puts a pending buffer into each leaf's ``.grad``
+(the protocol of `NDArray._buf`: `LazyArray`, `PendingOutput`,
+`PendingGrad`). ``gluon.Trainer.step`` then runs forward, pullback and the
+optimizer update as one donated program that returns no gradient; a read of
+a gradient or of an output first runs forward and pullback as above.
+Counters: ``autograd.backward_deferred``, ``autograd.deferred_forced``,
+``trainer.fused_step``.
 """
 from __future__ import annotations
 
@@ -28,7 +39,7 @@ import jax.numpy as jnp
 
 from . import telemetry
 from . import tracing
-from .base import MXNetError
+from .base import MXNetError, getenv
 
 __all__ = [
     "record", "pause", "train_mode", "predict_mode", "is_recording", "is_training",
@@ -207,7 +218,8 @@ def _clear_tape():
 
     st = _st()
     dropped = sum(1 for node in st.tape
-                  if type(node.vjp) is RecordedCall and not node.vjp.ran)
+                  if type(node.vjp) is RecordedCall and not node.vjp.ran
+                  and node.vjp.deferred is None)
     if dropped:
         # recorded, never read, never differentiated: no program ran
         telemetry.counter("autograd.recorded_calls_dropped").inc(dropped)
@@ -265,89 +277,214 @@ def _inexact(dtype):
     return bool(jnp.issubdtype(dtype, jnp.inexact))
 
 
+class _CallsBackward:
+    """Forward and pullback of recorded CachedOp calls (tape order) as ONE
+    program, built and cached by ``_cached_op.backward_program`` in the
+    last call's op: the wiring of one ``backward()``, run at once
+    (:meth:`launch`) or kept whole — the deferred backward. Deferred
+    (:meth:`defer`), it launches nothing: each wanted leaf's ``.grad``
+    holds a ``PendingGrad`` until ``Trainer.step`` takes the program with
+    its update stage (:meth:`consumed`), or a read of a gradient or of an
+    output runs it as it would have run (:meth:`run`). Once a later
+    ``backward()`` has overwritten every one of its gradients it is dead
+    and never runs: its outputs, if read, take the forward-only program."""
+
+    __slots__ = ("calls", "leaves", "leaf_nds", "wanted", "cts", "key",
+                 "pending")
+
+    def __init__(self, calls, leaves, leaf_nds, wanted, cts, key):
+        self.calls = calls
+        self.leaves = leaves        # the buffers the calls captured, by slot
+        self.leaf_nds = leaf_nds    # the NDArray of each slot, or None
+        self.wanted = wanted        # slots to differentiate
+        self.cts = cts              # the explicit head cotangents
+        self.key = key              # of the program, in the op's cache
+        self.pending = None         # deferred: a PendingGrad a wanted slot
+
+    @classmethod
+    def wire(cls, nodes, out_cts, also):
+        """``out_cts``: ``id(output NDArray) -> cotangent``, None standing
+        for ones. Differentiated are the leaf inputs that are marked or
+        whose id is in ``also``. None where the tape wires an input to an
+        earlier call's output but another value flowed."""
+        from ._cached_op import PendingOutput
+
+        where = {id(o): (c, k) for c, node in enumerate(nodes)
+                 for k, o in enumerate(node.outputs)}
+        slots, leaves, leaf_nds, wiring = {}, [], [], []
+        for c, node in enumerate(nodes):
+            row = []
+            for nd_in, buf in zip(node.inputs, node.vjp.inputs):
+                src = where.get(id(nd_in)) if nd_in is not None else None
+                if src is not None:
+                    out = nodes[src[0]].vjp.outputs[src[1]]
+                    if src[0] >= c or (buf is not out and buf is not out.value):
+                        return None
+                    row.append(("o",) + src)
+                    continue
+                if type(buf) is PendingOutput:
+                    # pending, yet not this tape's to differentiate through
+                    # (a detach()ed view, a call of a tape since dropped)
+                    buf = buf.force()
+                # one slot per (array, buffer): the program sums what several
+                # calls send back to it; an array rewritten between two calls
+                # is two leaves whose cotangents meet in grad_map
+                slot_key = (id(nd_in), id(buf)) if nd_in is not None \
+                    else len(leaves)
+                slot = slots.get(slot_key)
+                if slot is None:
+                    slot = slots[slot_key] = len(leaves)
+                    leaves.append(buf)
+                    leaf_nds.append(nd_in)
+                row.append(("l", slot))
+            wiring.append(tuple(row))
+        wanted = tuple(
+            s for s, nd_in in enumerate(leaf_nds)
+            if nd_in is not None and _inexact(leaves[s].dtype) and (
+                id(nd_in) in also or (nd_in._ag_marked
+                                      and nd_in.grad is not None
+                                      and nd_in.grad_req != "null")))
+        heads, cts, emit = [], [], []
+        for c, node in enumerate(nodes):
+            for k, (o, aval) in enumerate(zip(node.outputs, node.out_avals)):
+                if id(o) not in out_cts:
+                    continue
+                ct = out_cts[id(o)]
+                if ct is not None:
+                    cts.append(_dense_ct(ct, aval.dtype))
+                heads.append((c, k, ct is not None))
+            if not node.vjp.ran:
+                emit.extend((c, k) for k in range(len(node.outputs)))
+        calls = [node.vjp for node in nodes]
+        key = ("bwd", tuple((call.op, call.train, call.sig) for call in calls),
+               tuple(wiring), wanted, tuple(heads), tuple(emit))
+        return cls(calls, leaves, leaf_nds, wanted, cts, key)
+
+    def program(self, update=None, update_key=()):
+        """The program of this wiring from the last call's op's cache:
+        forward and pullback, or with ``update`` (``update_key``: what it
+        adds to the key) the whole step."""
+        from ._cached_op import backward_program
+
+        return self.calls[-1].op._cache.get_or_build(
+            self.key + update_key,
+            lambda: backward_program([call.trace.jaxpr for call in self.calls],
+                                     *self.key[2:], update=update),
+            audit="fused_step" if update else None)
+
+    def fill(self, emitted):
+        values = iter(emitted)
+        for call in self.calls:
+            if not call.ran:
+                call.fill([next(values) for _ in call.outputs])
+            call.deferred = None
+
+    def launch(self):
+        """Run forward and pullback; fill the outputs nobody had computed;
+        the gradients of the wanted leaves."""
+        from ._cached_op import explain_deleted_inputs
+
+        program = self.program()
+        with tracing.span("autograd.dispatch", cat="autograd",
+                          calls=len(self.calls)):
+            try:
+                emitted, grads = program(
+                    tuple(call.key for call in self.calls),
+                    tuple(self.leaves), tuple(self.cts))
+            except RuntimeError as e:
+                explain_deleted_inputs(self.leaves, e)
+                raise
+        self.fill(emitted)
+        return grads
+
+    def launch_into(self, grad_map):
+        for s, g in zip(self.wanted, self.launch()):
+            _accumulate(grad_map, self.leaf_nds[s], g)
+
+    # -- the deferred backward ----------------------------------------------
+
+    def defer(self):
+        """Leave the gradients pending where ``Trainer.step`` could take
+        the whole backward: every wanted leaf a distinct marked array whose
+        ``.grad`` is a dense buffer of its shape and dtype, written and not
+        added to, and no head gradient given. False, with nothing done,
+        where it is not so."""
+        from ._cached_op import PendingGrad
+        from .ndarray.ndarray import NDArray
+
+        nds = [self.leaf_nds[s] for s in self.wanted]
+        if self.cts or not nds or len({id(nd) for nd in nds}) != len(nds):
+            return False
+        for nd, s in zip(nds, self.wanted):
+            grad, leaf = nd.grad, self.leaves[s]
+            if not (nd._ag_marked and type(grad) is NDArray
+                    and nd.grad_req == "write"
+                    and grad._buf.dtype == leaf.dtype
+                    and tuple(grad._buf.shape) == tuple(leaf.shape)):
+                return False
+        self.pending = [PendingGrad(self, self.leaves[s].shape,
+                                    self.leaves[s].dtype)
+                        for s in self.wanted]
+        for nd, grad in zip(nds, self.pending):
+            nd.grad._buf = grad
+            nd._fresh_grad = True
+        for call in self.calls:
+            call.deferred = self
+        telemetry.counter("autograd.backward_deferred").inc()
+        return True
+
+    def run(self):
+        """A read came before ``Trainer.step``: forward and pullback now,
+        as ``backward()`` would have run them."""
+        with tracing.span("autograd.backward", cat="autograd",
+                          nodes=len(self.calls)):
+            grads = self.launch()
+        for grad, value in zip(self.pending, grads):
+            grad.value = value
+            grad.owner = None
+        telemetry.counter("autograd.fused_backward").inc()
+        telemetry.counter("autograd.deferred_forced").inc()
+
+    def run_if_owed(self):
+        """Run, where a leaf's ``.grad`` still holds one of the pending
+        gradients; where a later ``backward()`` has overwritten them all,
+        let go of the calls (False: their outputs are the forward's)."""
+        owed = any(self.leaf_nds[s].grad is not None
+                   and self.leaf_nds[s].grad._buf is grad
+                   for s, grad in zip(self.wanted, self.pending))
+        if owed:
+            self.run()
+        else:
+            for call in self.calls:
+                call.deferred = None
+        return owed
+
+    def consumed(self, emitted):
+        """``Trainer.step`` ran the program with its update stage: the
+        outputs are there, the gradients were its temporaries."""
+        self.fill(emitted)
+        for grad in self.pending:
+            grad.owner = None
+        telemetry.counter("autograd.fused_backward").inc()
+
+
 def _run_calls(nodes, out_cts, also, grad_map):
-    """Forward and pullback of the recorded CachedOp calls ``nodes`` (tape
-    order) as ONE program, built and cached by
-    ``_cached_op.backward_program`` in the last call's op. ``out_cts``:
-    ``id(output NDArray) -> cotangent``, None standing for ones. Cotangents
-    go into ``grad_map`` for the leaf inputs that are marked or whose id is
-    in ``also``; outputs nobody has computed yet are filled from the
-    program's outputs. False, with nothing run, where the tape wires an
-    input to an earlier call's output but another value flowed."""
-    from ._cached_op import (PendingOutput, backward_program,
-                             explain_deleted_inputs)
-
-    where = {id(o): (c, k) for c, node in enumerate(nodes)
-             for k, o in enumerate(node.outputs)}
-    slots, leaves, leaf_nds, wiring = {}, [], [], []
-    for c, node in enumerate(nodes):
-        row = []
-        for nd_in, buf in zip(node.inputs, node.vjp.inputs):
-            src = where.get(id(nd_in)) if nd_in is not None else None
-            if src is not None:
-                out = nodes[src[0]].vjp.outputs[src[1]]
-                if src[0] >= c or (buf is not out and buf is not out.value):
-                    return False
-                row.append(("o",) + src)
-                continue
-            if type(buf) is PendingOutput:
-                # pending, yet not this tape's to differentiate through
-                # (a detach()ed view, a call of a tape since dropped)
-                buf = buf.force()
-            # one slot per (array, buffer): the program sums what several
-            # calls send back to it; an array rewritten between two calls
-            # is two leaves whose cotangents meet in grad_map
-            slot_key = (id(nd_in), id(buf)) if nd_in is not None else len(leaves)
-            slot = slots.get(slot_key)
-            if slot is None:
-                slot = slots[slot_key] = len(leaves)
-                leaves.append(buf)
-                leaf_nds.append(nd_in)
-            row.append(("l", slot))
-        wiring.append(tuple(row))
-    wanted = tuple(
-        s for s, nd_in in enumerate(leaf_nds)
-        if nd_in is not None and _inexact(leaves[s].dtype) and (
-            id(nd_in) in also or (nd_in._ag_marked and nd_in.grad is not None
-                                  and nd_in.grad_req != "null")))
-    heads, cts, emit = [], [], []
-    for c, node in enumerate(nodes):
-        for k, (o, aval) in enumerate(zip(node.outputs, node.out_avals)):
-            if id(o) not in out_cts:
-                continue
-            ct = out_cts[id(o)]
-            if ct is not None:
-                cts.append(_dense_ct(ct, aval.dtype))
-            heads.append((c, k, ct is not None))
-        if not node.vjp.ran:
-            emit.extend((c, k) for k in range(len(node.outputs)))
-
-    calls = [node.vjp for node in nodes]
-    key = ("bwd", tuple((call.op, call.train, call.sig) for call in calls),
-           tuple(wiring), wanted, tuple(heads), tuple(emit))
-    program = calls[-1].op._cache.get_or_build(
-        key, lambda: backward_program([call.trace.jaxpr for call in calls],
-                                      *key[2:]))
-    with tracing.span("autograd.dispatch", cat="autograd", calls=len(calls)):
-        try:
-            emitted, grads = program(tuple(call.key for call in calls),
-                                     tuple(leaves), tuple(cts))
-        except RuntimeError as e:
-            explain_deleted_inputs(leaves, e)
-            raise
-    values = iter(emitted)
-    for call in calls:
-        if not call.ran:
-            call.fill([next(values) for _ in call.outputs])
-    for s, g in zip(wanted, grads):
-        _accumulate(grad_map, leaf_nds[s], g)
+    """Forward and pullback of the recorded calls ``nodes`` as one program
+    now; cotangents go into ``grad_map``. False, with nothing run, where
+    the tape does not wire so (:meth:`_CallsBackward.wire`)."""
+    calls = _CallsBackward.wire(nodes, out_cts, also)
+    if calls is None:
+        return False
+    calls.launch_into(grad_map)
     return True
 
 
-def _fused_backward(tape, head_cts, variables, grad_map):
+def _fused_backward(tape, head_cts, variables, grad_map, may_defer):
     """The whole backward as one program, where every tape node the heads
     reach is a recorded CachedOp call (the example loop: hybridized net,
-    hybridized loss). False, with nothing done, where it is not so."""
+    hybridized loss). False, with nothing done, where it is not so. With
+    ``may_defer`` the program is left pending where it can be
+    (:meth:`_CallsBackward.defer`)."""
     from ._cached_op import RecordedCall
 
     needed = {id(h) for h, _ in head_cts}
@@ -372,8 +509,12 @@ def _fused_backward(tape, head_cts, variables, grad_map):
             out_cts[id(h)] = _head_ct(h, out_cts[id(h)]) + _head_ct(h, ct)
         else:
             out_cts[id(h)] = ct
-    if not _run_calls(nodes, out_cts, {id(v) for v in variables}, grad_map):
+    calls = _CallsBackward.wire(nodes, out_cts, {id(v) for v in variables})
+    if calls is None:
         return False
+    if may_defer and not rest and calls.defer():
+        return True
+    calls.launch_into(grad_map)
     for h, ct in rest:
         _accumulate(grad_map, h, _head_ct(h, ct))
     telemetry.counter("autograd.fused_backward").inc()
@@ -391,7 +532,12 @@ def _backward_pass(tape, heads, head_grads, retain_graph, deposit, variables):
     head_cts = [(h, hg if hg is None else
                  hg._data if hasattr(hg, "_data") else jnp.asarray(hg))
                 for h, hg in zip(heads, head_grads)]
-    if _fused_backward(tape, head_cts, variables, grad_map):
+    # the plain call may leave its gradients pending for Trainer.step,
+    # which the switch of the fused update gates too
+    may_defer = deposit and not retain_graph and not variables \
+        and all(ct is None for _, ct in head_cts) \
+        and getenv("MXNET_FUSED_STEP")
+    if _fused_backward(tape, head_cts, variables, grad_map, may_defer):
         tape_walk = ()
     else:
         tape_walk = reversed(tape)
@@ -433,7 +579,7 @@ def _backward_pass(tape, heads, head_grads, retain_graph, deposit, variables):
             _accumulate(grad_map, nd_in, ct)
 
     # deposit into marked variables honoring grad_req
-    if deposit:
+    if deposit and grad_map:
         with tracing.span("autograd.deposit", cat="autograd"):
             for node in tape:
                 for nd_in in node.inputs:

@@ -16,6 +16,7 @@ from __future__ import annotations
 import numpy as _np
 
 from ..base import MXNetError
+from .._cached_op import PendingGrad
 from ..context import Context, cpu, current_context
 from .. import ndarray as nd
 from ..ndarray import NDArray
@@ -297,6 +298,11 @@ class Parameter:
         from .. import autograd
         with autograd.pause():
             for g in self._grad.values():
+                buf = g._buf
+                if type(buf) is PendingGrad and buf.owner is not None:
+                    # the deferred backward runs now: its outputs must
+                    # not wait for an update that donates what they need
+                    buf.force("zero_grad")
                 g[:] = 0
 
     def reset_ctx(self, ctx):
@@ -324,7 +330,13 @@ class Parameter:
                 self._data[k] = self._data[k].astype(dtype)
             if self._grad is not None:
                 for k in list(self._grad):
-                    self._grad[k] = self._grad[k].astype(dtype)
+                    g = self._grad[k]
+                    if type(g._buf) is PendingGrad and g._buf.consumed:
+                        # the one-program step left no value to cast
+                        self._grad[k] = nd.zeros(g.shape, dtype=dtype,
+                                                 ctx=g.context)
+                    else:
+                        self._grad[k] = g.astype(dtype)
                     autograd.mark_variables(self._data[k], self._grad[k], self._grad_req)
 
     def var(self):

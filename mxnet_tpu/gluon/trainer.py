@@ -18,15 +18,73 @@ in flat buckets (1/N per replica, `parallel/zero1.py`) and the update runs
 on each replica's shard, allgathered back into the full weights.
 `save_states`/`load_states` stay transparent: the updater gathers shards
 into ordinary per-parameter states before pickling and re-shards on load.
+
+The step as one launch: a plain `backward()` over hybridized calls leaves
+its gradients pending (`autograd._CallsBackward`), and where they are all
+this Trainer's to apply — one context, no kvstore, dense gradients, no
+ZeRO-1, an optimizer with `fused_update`, `MXNET_FUSED_STEP` on —
+`_update` runs forward, pullback and `fused_update` as ONE program with
+weights and optimizer states donated (`_fused_step`). Anything else reads
+the gradients, which runs the forward+pullback program, and updates as
+before.
 """
 from __future__ import annotations
 
+import logging
+
+import numpy as _np
+from jax import tree_util as _jtu
+
 from .. import optimizer as opt
+from .. import telemetry
 from .. import tracing
+from .._cached_op import PendingGrad
 from ..model import _create_kvstore
+from ..ndarray import NDArray
+from ..optimizer.optimizer import (_raise_if_donated_consumed,
+                                   _restore_counts, _snapshot_counts,
+                                   _state_sig, _state_to_jax)
 from .parameter import ParameterDict, Parameter
 
 __all__ = ["Trainer"]
+
+
+class _StepPlan:
+    """Where a deferred backward's wanted leaves sit among a Trainer's
+    parameters: found once per program (`Trainer._plan_step`), checked by
+    identity every step (`holds`)."""
+
+    __slots__ = ("indices", "weights", "grads", "others", "states",
+                 "state_nds", "update_key")
+
+    def __init__(self, indices, weights, grads, others):
+        self.indices = indices    # the optimizer's index of each wanted leaf
+        self.weights = weights    # its weight array (the leaf's NDArray)
+        self.grads = grads        # and that array's .grad
+        self.others = others      # trainable arrays the backward left out
+        self.states = None        # the updater's state trees, by identity;
+        # with them (take_states): state_nds, update_key (the key's tail)
+
+    def holds(self, backward, ignore_stale_grad):
+        """Every gradient to apply is still pending in ``backward``, for
+        the weight buffers it differentiated, and nothing else is due."""
+        if self.others and not (ignore_stale_grad and not any(
+                arr._fresh_grad for arr in self.others)):
+            return False
+        leaf_nds, leaves = backward.leaf_nds, backward.leaves
+        for slot, arr, grad, pending in zip(backward.wanted, self.weights,
+                                            self.grads, backward.pending):
+            if leaf_nds[slot] is not arr or arr._buf is not leaves[slot] \
+                    or grad._buf is not pending:
+                return False
+        return True
+
+    def take_states(self, states, opt_key):
+        self.states = states
+        # NDArray leaves, in the order the program returns their new values
+        self.state_nds = _jtu.tree_leaves(states)
+        self.update_key = (("update", opt_key,
+                            tuple(_state_sig(s) for s in states)),)
 
 
 class Trainer:
@@ -73,7 +131,17 @@ class Trainer:
         self._update_on_kvstore = None
         self._distributed = None
         self._params_to_init = []
+        # program key of a deferred backward -> _StepPlan; None once the
+        # one-program step failed to build
+        self._step_plans = {}
         self._reset_kvstore()
+
+    def __getstate__(self):
+        # the optimizer's param_dict pickles its Parameters' Trainer with
+        # save_states: the plans name compiled ops and are found again
+        state = self.__dict__.copy()
+        state["_step_plans"] = None if self._step_plans is None else {}
+        return state
 
     def _check_contexts(self):
         contexts = None
@@ -302,6 +370,8 @@ class Trainer:
             self._update(ignore_stale_grad)
 
     def _update(self, ignore_stale_grad=False):
+        if self._fused_step(ignore_stale_grad):
+            return
         updates = [[] for _ in self._updaters]
 
         for i, param in enumerate(self._params):
@@ -334,6 +404,99 @@ class Trainer:
                 if upd:
                     i, g, w = zip(*upd)
                     updater(list(i), list(g), list(w))
+
+    def _plan_step(self, backward):
+        """The `_StepPlan` of ``backward``'s program, or None where its
+        wanted leaves are not exactly dense weights of this Trainer."""
+        by_array = {}
+        for i, param in enumerate(self._params):
+            if param.grad_req == "null":
+                continue
+            if param._stype != "default" or param._grad_stype != "default":
+                return None
+            by_array[id(param.list_data()[0])] = (i, param.list_grad()[0])
+        indices, weights, grads = [], [], []
+        for slot in backward.wanted:
+            arr = backward.leaf_nds[slot]
+            i, grad = by_array.pop(id(arr), (None, None))
+            if grad is None or grad is not arr.grad:
+                return None
+            indices.append(i)
+            weights.append(arr)
+            grads.append(grad)
+        others = [self._params[i].list_data()[0] for i, _ in by_array.values()]
+        if len(self._step_plans) >= 16:  # shapes that churn: start over
+            self._step_plans.clear()
+        plan = self._step_plans[backward.key] = _StepPlan(
+            indices, weights, grads, others)
+        return plan
+
+    def _fused_step(self, ignore_stale_grad):
+        """Forward, pullback and the optimizer update as ONE program with
+        weights and states donated, where the gradients to apply are the
+        pending ones of one deferred `backward()` and all of them. False,
+        with nothing touched, where they are not: `_update` then reads the
+        gradients (the forward+pullback program runs) and updates."""
+        from ..parallel.zero1 import zero1_enabled  # not at import time
+
+        updater = self._updaters[0]
+        if self._step_plans is None or self._kvstore \
+                or len(self._updaters) != 1 or not updater.fused_ready() \
+                or zero1_enabled():
+            return False
+        grad = next((g for p in self._params if p._grad
+                     for g in p._grad.values()), None)
+        # (a row_sparse gradient densifies when asked for its buffer)
+        pending = grad._buf if type(grad) is NDArray else None
+        if type(pending) is not PendingGrad or pending.owner is None:
+            return False
+        backward = pending.owner
+        plan = self._step_plans.get(backward.key) or self._plan_step(backward)
+        if plan is None or not plan.holds(backward, ignore_stale_grad):
+            return False
+
+        optimizer, indices = updater.optimizer, plan.indices
+        updater.ensure_states(indices, plan.weights)
+        states = [updater.states[i] for i in indices]
+        if plan.states is None or any(
+                a is not b for a, b in zip(states, plan.states)):
+            plan.take_states(states, optimizer._fused_static_key())
+        wanted = set(backward.wanted)
+        weights = [backward.leaves[s] for s in backward.wanted]
+        rest = [leaf for s, leaf in enumerate(backward.leaves)
+                if s not in wanted]
+        count_snap = _snapshot_counts(optimizer, indices)
+        optimizer._update_count(indices)
+        try:
+            lrs, wds = optimizer._fused_hyperparams(indices)
+            program = backward.program(optimizer.fused_update,
+                                       plan.update_key)
+            with tracing.span("trainer.dispatch", cat="gluon"):
+                emitted, new_weights, new_states = program(
+                    tuple(call.key for call in backward.calls), weights, rest,
+                    [_state_to_jax(s) for s in states],
+                    _np.asarray(lrs, _np.float32),
+                    _np.asarray(wds, _np.float32),
+                    _np.float32(optimizer.rescale_grad))
+        except Exception as e:
+            _raise_if_donated_consumed(weights, e)
+            # the build failed before any buffer was consumed: the
+            # gradients stay pending, and reading them takes the two
+            # programs from now on
+            _restore_counts(optimizer, count_snap)
+            self._step_plans = None
+            logging.getLogger("mxnet_tpu.gluon").warning(
+                "the one-program training step failed to build (%r); "
+                "backward and update run as two programs", e)
+            return False
+        backward.consumed(emitted)
+        for arr, value in zip(plan.weights, new_weights):
+            arr._buf = value
+            arr._fresh_grad = False
+        for state, value in zip(plan.state_nds, new_states):
+            state._buf = value
+        telemetry.counter("trainer.fused_step").inc()
+        return True
 
     def _row_sparse_pull(self, parameter, row_id, full_idx=False):
         """Refresh the requested rows of a sparse parameter from the kvstore

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from ..base import MXNetError, np_dtype, integer_types, numeric_types
 from ..context import Context, current_context, cpu
+from .._cached_op import PendingGrad as _PendingGrad
 from .._cached_op import PendingOutput as _PendingOutput
 from ..lazy.graph import LazyArray as _LazyArray
 from ..ops import registry as _reg
@@ -33,7 +34,7 @@ __all__ = ["NDArray", "array", "zeros", "ones", "full", "empty", "arange", "conc
 # What an NDArray may hold in place of a jax array: shape and dtype are
 # known, ``force(reason)`` gives the array (an op the lazy graph captured;
 # the output of a hybridized call recorded for autograd).
-_PENDING = (_LazyArray, _PendingOutput)
+_PENDING = (_LazyArray, _PendingOutput, _PendingGrad)
 
 
 def _dtype_name(dt):
@@ -69,7 +70,9 @@ class NDArray:
         flushes the owning segment, one fused XLA program), or the
         :class:`~mxnet_tpu._cached_op.PendingOutput` of a hybridized call
         recorded under ``autograd.record()`` (``backward()`` fills it; a
-        read before that runs the forward-only program). Either way the
+        read before that runs the forward-only program), or the
+        :class:`~mxnet_tpu._cached_op.PendingGrad` a deferred
+        ``backward()`` left in a ``.grad``. Either way the
         realized buffer is swapped in. Every concrete-value escape in the
         codebase —
         ``asnumpy``, kvstore pushes, checkpoint writes, executor feeds —
@@ -668,7 +671,11 @@ class NDArray:
     def __getstate__(self):
         import copyreg
 
-        self._data  # materialize: a pending lazy buffer must not pickle
+        buf = self._buf
+        if not (type(buf) is _PendingGrad and buf.consumed):
+            # materialize: a pending lazy buffer must not pickle (a gradient
+            # the one-program step consumed has no value: it goes as it is)
+            self._data
         names = copyreg._slotnames(type(self))
         return (None, {n: getattr(self, n) for n in names
                        if n != "__weakref__" and hasattr(self, n)})

@@ -1253,6 +1253,17 @@ def _any_donated_deleted(arrays):
     return out
 
 
+def _raise_if_donated_consumed(arrays, err):
+    """After a fused update raised: execution consumed donated inputs
+    before failing — weights/states are unrecoverable in-process."""
+    if _any_donated_deleted(arrays):
+        raise MXNetError(
+            "fused optimizer update failed mid-execution; weight/"
+            "state buffers were donated and may be invalidated — "
+            "restore from the last checkpoint before continuing "
+            f"({err!r})") from err
+
+
 # one executable per (optimizer fingerprint, weight shapes/dtypes, state
 # structure) — shared across Updater instances (gluon Trainer keeps one
 # Updater per context; all hit the same cache). Bounded: each entry's build
@@ -1336,6 +1347,14 @@ class Updater:
             self.optimizer.update_multi_precision(idx, weights[i], grads[i],
                                                   self.states[idx])
 
+    def fused_ready(self):
+        """Whether ``Optimizer.fused_update`` may run inside a program: the
+        optimizer has one, no earlier build of it failed, and
+        ``MXNET_FUSED_STEP`` is on."""
+        return not self._fused_disabled \
+            and self.optimizer.fused_update_supported \
+            and bool(getenv("MXNET_FUSED_STEP"))
+
     def _fused_call(self, indices, grads, weights):
         """One jitted Optimizer.fused_update over the whole parameter group
         with weight and state buffers donated — the entire optimizer step is
@@ -1344,8 +1363,7 @@ class Updater:
         optimizers without a fused path, sparse grads, or MXNET_FUSED_STEP=0
         — the eager loop remains the correctness reference."""
         opt = self.optimizer
-        if self._fused_disabled or not opt.fused_update_supported \
-                or not getenv("MXNET_FUSED_STEP"):
+        if not self.fused_ready():
             return False
         from ..ndarray.sparse import RowSparseNDArray
 
@@ -1390,14 +1408,7 @@ class Updater:
                                 jnp.asarray(wds, jnp.float32),
                                 jnp.float32(opt.rescale_grad))
         except Exception as e:
-            if _any_donated_deleted(w._data for w in weights):
-                # execution consumed donated inputs before failing —
-                # weights/states are unrecoverable in-process
-                raise MXNetError(
-                    "fused optimizer update failed mid-execution; weight/"
-                    "state buffers were donated and may be invalidated — "
-                    "restore from the last checkpoint before continuing "
-                    f"({e!r})") from e
+            _raise_if_donated_consumed((w._data for w in weights), e)
             # trace/compile failed BEFORE any buffer was consumed (e.g. an
             # Optimizer subclass whose states the fused path can't unpack):
             # weights are intact — undo the count bump and stay eager

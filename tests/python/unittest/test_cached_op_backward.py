@@ -266,6 +266,7 @@ def test_no_residual_is_an_output():
     with autograd.record():
         loss = job.sce(job.net(job.x), job.y)
     loss.backward()
+    job.params[0].grad().asnumpy()  # the plain backward() waits for a read
     shapes = sorted(o.shape for o in _backward_outputs(job.sce))
     want = [p.shape for p in job.params] + [(BATCH, CLASSES), (BATCH,)]
     assert shapes == sorted(want)

@@ -310,11 +310,13 @@ _CALL_TREE = ("cached_op.call", [("cached_op.gather", []),
                          ids=["hybridized_loss", "eager_loss"])
 def test_gluon_step_span_tree(trc, hybrid_loss):
     """One hybridized gluon step. With a hybridized loss both calls only
-    capture (cached_op.call > gather/dispatch/record, twice) and
-    autograd.backward > dispatch/deposit launches the one program. An
-    eager loss reads the net's output first: that forces the forward-only
-    program under a cached_op.dispatch of its own, outside any call.
-    trainer.step > allreduce/update; no span per parameter or tape node."""
+    capture (cached_op.call > gather/dispatch/record, twice), the plain
+    autograd.backward only wires, and trainer.step > update > dispatch
+    launches the one program of the whole step. An eager loss reads the
+    net's output first: that forces the forward-only program under a
+    cached_op.dispatch of its own, outside any call, autograd.backward >
+    dispatch/deposit launches the call's program and trainer.update the
+    optimizer's. No span per parameter or tape node."""
     from mxnet_tpu import autograd, gluon, nd
 
     net = gluon.nn.HybridSequential()
@@ -340,16 +342,19 @@ def test_gluon_step_span_tree(trc, hybrid_loss):
     step()                      # deferred init + compile
     tracing.reset()
     root = step()
-    second = _CALL_TREE if hybrid_loss else ("cached_op.dispatch", [])
+    if hybrid_loss:
+        second, backward, update = _CALL_TREE, [], [("trainer.dispatch", [])]
+    else:
+        second, update = ("cached_op.dispatch", []), []
+        backward = [("autograd.dispatch", []), ("autograd.deposit", [])]
     assert _tree_names(root.tree()) == ("user.step", [
         _CALL_TREE, second,
-        ("autograd.backward", [("autograd.dispatch", []),
-                               ("autograd.deposit", [])]),
+        ("autograd.backward", backward),
         ("trainer.step", [("trainer.allreduce", []),
-                          ("trainer.update", [])])])
+                          ("trainer.update", update)])])
     call = root.tree()["children"][0]
     assert call["args"]["params"] == 4
-    assert len(_spans()) == (15 if hybrid_loss else 12)
+    assert len(_spans()) == (14 if hybrid_loss else 12)
     _assert_connected(_spans())
 
 
