@@ -12,10 +12,13 @@ from . import hybrid
 from .hybrid import HybridLMConfig, HybridLM
 from . import latent_moe
 from .latent_moe import LatentMoELMConfig, LatentMoELM
+from . import window_moe
+from .window_moe import WindowMoELMConfig, WindowMoELM
 from . import resnet
 from .resnet import resnet50_symbol
 
 __all__ = ["transformer", "TransformerLMConfig", "TransformerLM",
            "hybrid", "HybridLMConfig", "HybridLM",
            "latent_moe", "LatentMoELMConfig", "LatentMoELM",
+           "window_moe", "WindowMoELMConfig", "WindowMoELM",
            "resnet", "resnet50_symbol"]

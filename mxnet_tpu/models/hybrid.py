@@ -80,7 +80,9 @@ class HybridLMConfig:
     @classmethod
     def from_config(cls, config, max_len=None, dtype=None):
         """From a published `config.json` (a dict). What the block cannot
-        express is refused by name rather than ignored."""
+        express is refused by name rather than ignored (experts live in
+        `models/experts.py`, which `LatentMoELM` and `WindowMoELM` call: a
+        state-space model with experts would call it from here)."""
         for key, want in (("mamba_n_groups", 1), ("num_local_experts", 0),
                           ("position_embedding_type", "nope"),
                           ("attention_bias", False),

@@ -50,21 +50,17 @@ from __future__ import annotations
 import dataclasses
 import math
 
-import numpy as np
-
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..parallel.spmd import model_mesh
+from . import experts, rotary
 from .transformer import _table_rows
 
 __all__ = ["LatentMoELMConfig", "LatentMoELM"]
 
-# tokens an expert layer groups at once in a prefill: bounds the sorted
-# copies (top_k rows a token) whatever the bucket
-_EXPERT_CHUNK = 4096
 # rows of one blockwise-attention step in a prefill
 _ATTN_BLOCK = 1024
 
@@ -168,27 +164,10 @@ class LatentMoELMConfig:
         return self._mscale("mscale") / self._mscale("mscale_all_dim")
 
     def inv_freq(self):
-        """`deepseek_yarn` inverse frequencies of the rotary entries: as
-        published where a frequency turns more than `beta_fast` times over
-        the original context, divided by `factor` where fewer than
-        `beta_slow`, a linear blend between."""
-        dim = self.qk_rope_head_dim
-        extra = self.rope_theta ** (-np.arange(0, dim, 2, dtype=np.float64)
-                                    / dim)
-        s = dict(self.rope_scaling)
-        if not s:
-            return extra
-
-        def correction_dim(rotations):
-            return dim * math.log(s["original_max_position_embeddings"]
-                                  / (rotations * 2 * math.pi)) \
-                / (2 * math.log(self.rope_theta))
-
-        low = max(math.floor(correction_dim(s["beta_fast"])), 0)
-        high = min(math.ceil(correction_dim(s["beta_slow"])), dim - 1)
-        ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3),
-                       0, 1)
-        return extra / s["factor"] * ramp + extra * (1 - ramp)
+        """`deepseek_yarn` inverse frequencies of the rotary entries
+        (`rotary.yarn_inv_freq`)."""
+        return rotary.yarn_inv_freq(self.qk_rope_head_dim, self.rope_theta,
+                                    dict(self.rope_scaling))
 
 
 class LatentMoELM:
@@ -291,17 +270,8 @@ class LatentMoELM:
     def _rotate(self, x, positions):
         """Half-split rotary embedding of the last axis of `x` [T, ...,
         rope] at `positions` [T], computed in float32."""
-        c = self.cfg
-        half = c.qk_rope_head_dim // 2
-        angle = positions[:, None].astype(jnp.float32) \
-            * jnp.asarray(c.inv_freq(), jnp.float32)[None, :]
-        shape = (x.shape[0],) + (1,) * (x.ndim - 2) + (half,)
-        cos = (jnp.cos(angle) * c.rope_amplitude).reshape(shape)
-        sin = (jnp.sin(angle) * c.rope_amplitude).reshape(shape)
-        a, b = x[..., :half].astype(jnp.float32), \
-            x[..., half:].astype(jnp.float32)
-        return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                               axis=-1).astype(x.dtype)
+        return rotary.rotate_half(x, positions, self.cfg.inv_freq(),
+                                  self.cfg.rope_amplitude)
 
     def _project(self, params, i, u, positions):
         """`u` [T, D] at `positions` [T] -> `(q_nope [T, H, nope], q_rope
@@ -413,66 +383,15 @@ class LatentMoELM:
                 * c.routed_scaling_factor
         return chosen, weights
 
-    def _grouped(self, rows, weights, sizes):
-        """The grouped product `rows[group g] @ weights[g]`: `rows` [M, K]
-        sorted by group, `weights` [G, K, N], `sizes` [G]; rows past the
-        groups' total hold nothing that was computed. On one TPU chip the
-        Pallas grouped matmul (jax's megablox `gmm`: empty groups cost
-        nothing, a weight tile of `[512, N]` is one DMA — at a few rows a
-        group the product is the weights' bytes, and XLA's own lowering of
-        `lax.ragged_dot` moved them in 512 x 512 tiles at 52% of the HBM
-        rate where this reads 86%: PERF.md section 6, PR 31); elsewhere
-        `lax.ragged_dot`. Decided as :meth:`decode_block` is."""
-        from ..ops import pallas_attention as pa
-
-        m, n = rows.shape[0], weights.shape[2]
-        # few rows (a decode tick): the smallest row tile; many (a prefill
-        # chunk): a row tile that reuses a weight tile over more rows
-        tile = 256 if m > 1024 and m % 256 == 0 else 128
-        if self.mesh.size > 1 or not pa.pallas_enabled() or m % tile:
-            return lax.ragged_dot(rows, weights, sizes)
-        from jax.experimental.pallas.ops.tpu.megablox import gmm
-
-        # [K tile, N tile] of a weight is one DMA; with the accumulator and
-        # the double buffers it stays under Mosaic's 16 MiB of scoped VMEM
-        tiling = (128, 512, min(n, 4096)) if tile == 128 \
-            else (256, 1024, min(n, 1024))
-        return gmm(rows, weights, sizes, preferred_element_type=rows.dtype,
-                   tiling=tiling, interpret=pa.pallas_interpret())
-
     def _experts(self, params, i, x, real):
         """The held experts' part of the expert layer for `x` [T, D]:
-        `(y [T, D], local [T, k])`. `real` [T] marks the tokens that exist
-        (padding is routed nowhere). Dropless: every (token, chosen held
-        expert) pair is computed, grouped by expert."""
-        c = self.cfg
-        t, k = x.shape[0], c.num_experts_per_tok
-        chosen, weights = self._route(params, i, x)
-        with jax.named_scope("moe.group"):
-            local = chosen - c.expert_first
-            held = (local >= 0) & (local < c.experts_held) & real[:, None]
-            local = jnp.where(held, local, -1)
-            # the pairs held elsewhere sort past the last group
-            key = jnp.where(held, local, c.experts_held).reshape(-1)
-            order = jnp.argsort(key, stable=True)
-            sizes = jnp.sum(key[:, None] == jnp.arange(c.experts_held),
-                            axis=0, dtype=jnp.int32)
-            rows = jnp.take(x, order // k, axis=0)              # [T k, D]
-        with jax.named_scope("moe.experts"):
-            hid = self._grouped(rows, params[f"l{i}.experts_in"], sizes)
-            g, v = jnp.split(hid, 2, axis=-1)
-            out = self._grouped(jax.nn.silu(g) * v,
-                                params[f"l{i}.experts_out"], sizes)
-        with jax.named_scope("moe.group"):
-            # back to (token, choice) order; a row past the groups holds
-            # nothing that was computed
-            w = jnp.where(held, weights, 0.0).reshape(-1)
-            back = jnp.argsort(order)
-            out = jnp.where((jnp.arange(t * k) < sizes.sum())[:, None],
-                            out, 0)
-            y = (jnp.take(out, back, axis=0).astype(jnp.float32)
-                 * w[:, None]).reshape(t, k, -1).sum(1)
-        return y.astype(x.dtype), local
+        `(y [T, D], local [T, k])`; `real` [T] marks the tokens that exist.
+        The dropless grouping and the grouped products are
+        `experts.expert_layer`'s, under this model's router."""
+        return experts.expert_layer(
+            x, real, lambda xs: self._route(params, i, xs),
+            params[f"l{i}.experts_in"], params[f"l{i}.experts_out"],
+            expert_first=self.cfg.expert_first, mesh=self.mesh)
 
     def _mlp(self, params, i, h, real=None):
         """The MLP sub-layer with its norm and residual: `(h, local)`;
@@ -483,15 +402,7 @@ class LatentMoELM:
             return h + self._gated(x, params[f"l{i}.w_in"],
                                    params[f"l{i}.w_out"]), None
         real = jnp.ones(x.shape[0], bool) if real is None else real
-        if x.shape[0] > _EXPERT_CHUNK and x.shape[0] % _EXPERT_CHUNK == 0:
-            n = x.shape[0] // _EXPERT_CHUNK
-            y, local = lax.map(
-                lambda xs: self._experts(params, i, *xs),
-                (x.reshape(n, _EXPERT_CHUNK, -1),
-                 real.reshape(n, _EXPERT_CHUNK)))
-            y, local = y.reshape(x.shape), local.reshape(x.shape[0], -1)
-        else:
-            y, local = self._experts(params, i, x, real)
+        y, local = self._experts(params, i, x, real)
         with jax.named_scope("moe.shared"):
             y = y + self._gated(x, params[f"l{i}.shared_in"],
                                 params[f"l{i}.shared_out"])
@@ -596,12 +507,9 @@ class LatentMoELM:
         (a layer)."""
         del cache_c, cache_kr
         alive = positions >= 0
-        hit = (routed[..., None] == jnp.arange(self.cfg.experts_held)) \
-            & alive[:, None, None, None]                    # [S, Lx, k, E]
-        per = hit.sum((0, 2), dtype=jnp.int32)              # [Lx, E]
-        return jnp.stack([
-            per.sum(), (per > 0).sum(dtype=jnp.int32), per.max(-1).sum(),
-            jnp.where(alive, positions + 1, 0).sum(dtype=jnp.int32)])
+        return jnp.concatenate([
+            experts.routing_counters(routed, alive, self.cfg.experts_held),
+            jnp.where(alive, positions + 1, 0).sum(dtype=jnp.int32)[None]])
 
     def prefill(self, params, cache_c, cache_kr, routed, tokens, length,
                 slot):

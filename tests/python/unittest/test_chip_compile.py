@@ -448,3 +448,115 @@ def test_latent_prefill_attention_compiles_for_v5e(one_chip):
         sds(heads, length, 128), sds(heads, length, 64),
         sds(heads, length, 128), sds(length, 64), sds(heads, length, 128))
     assert len(re.findall(r"%latent_prefill_attend[.\d]* = ", text)) == 1
+
+
+# ---------------------------------------------------------------------------
+# the window/full-attention expert model's programs at Mellum2-12B's widths
+# (ISSUE 33): one period — three window layers and a full one
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def mellum_period(one_chip):
+    """`(lm, params, cache)` of one period of `mellum2_12b_l8` at its
+    published widths, 32 slots x 16,384 positions, as shapes on the
+    described chip."""
+    import json
+
+    from mxnet_tpu import parallel as par
+    from mxnet_tpu.models import WindowMoELM, WindowMoELMConfig
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mellum2_12b_l8.json")) as f:
+        published = json.load(f)
+    assert len(published["layer_types"]) == 28      # carried whole
+    published["num_hidden_layers"] = 4
+    config = WindowMoELMConfig.from_config(published, max_len=16384)
+    assert config.layer_types == ("sliding_attention",) * 3 \
+        + ("full_attention",)
+    assert (config.num_experts, config.experts_held, config.expert_first) \
+        == (64, 64, 0)
+    dev = next(iter(one_chip.device_set))
+    lm = WindowMoELM(config, par.create_mesh(devices=[dev], dp=1))
+    host_lm = WindowMoELM(config, par.create_mesh(devices=jax.devices()[:1],
+                                                  dp=1))
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = {k: sds(v) for k, v in jax.eval_shape(
+        host_lm.init_params, jax.random.PRNGKey(0)).items()}
+    cache = tuple(sds(v) for v in jax.eval_shape(
+        lambda: host_lm.init_cache(32, 16384)))
+    assert [c.shape for c in cache] == [
+        (32, 1, 4, 16384, 128), (32, 1, 4, 16384, 128),
+        (32, 3, 4, 1024, 128), (32, 3, 4, 1024, 128), (32, 4, 8)]
+    return lm, params, cache
+
+
+def test_window_moe_decode_program_compiles_for_v5e(one_chip, monkeypatch,
+                                                    mellum_period):
+    """The engine's decode program: it compiles for the v5e; the `hd`-minor
+    slab kernel is in it once a layer (ring members and the full one alike)
+    and the grouped matmul `gmm` twice a layer — K = 2304 and 896 are not
+    multiples of 512 and must not fall to `lax.ragged_dot`; every member of
+    the cache is aliased input to output; no XLA op copies, slices,
+    scatters into or re-lays a page of either kind (the new row is merged
+    inside the kernel)."""
+    lm, params, cache = mellum_period
+    monkeypatch.setenv("MXNET_PALLAS_ATTENTION", "1")
+    monkeypatch.delenv("MXNET_PALLAS_INTERPRET", raising=False)
+    assert lm.decode_block(cache[0].shape, cache[0].dtype) == 1024
+    assert lm.decode_block(cache[2].shape, cache[2].dtype) == 1024
+
+    def fn(params, cache, tokens, positions):       # the engine's wrapper
+        logits, *cache = lm.decode_step(params, *cache, tokens, positions)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), tuple(cache)
+
+    ints = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, ints, ints).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%kv128_attend[.\d]* = ", text)) == 4
+    assert len(re.findall(r"%gmm[.\d]* = ", text)) == 8
+    assert "ragged-dot" not in text
+    ring_page = 32 * 4 * 1024 * 128                 # the smaller page
+    assert _page_sized_ops(text, ring_page) == []
+    ma = compiled.memory_analysis()
+    cache_bytes = sum(int(np.prod(c.shape)) * c.dtype.itemsize
+                      for c in cache)
+    assert ma.alias_size_in_bytes >= cache_bytes    # all five, whole
+    assert ma.temp_size_in_bytes < ring_page * 2
+
+
+def test_window_moe_prefill_program_compiles_for_v5e(one_chip, monkeypatch,
+                                                     mellum_period):
+    """The engine's 16,384-token prefill program: the band kernel once a
+    layer — a window layer's grid holds 3 key blocks of 512 a query block,
+    the full layer's 16 of 1,024 — and the grouped matmul over chunks of
+    4,096 tokens; it fits the chip beside the weights and the cache."""
+    from mxnet_tpu.ops import pallas_window
+
+    lm, params, cache = mellum_period
+    monkeypatch.setenv("MXNET_PALLAS_ATTENTION", "1")
+    monkeypatch.delenv("MXNET_PALLAS_INTERPRET", raising=False)
+    assert lm.prefill_block(16384, 1024) == 512
+    assert pallas_window.band_steps(16384, 512, 1024) == 3
+    assert lm.prefill_block(16384) == 1024
+
+    def fn(params, cache, toks, length, slot):      # the engine's wrapper
+        logits, *cache = lm.prefill(params, *cache, toks, length, slot)
+        return jnp.argmax(logits).astype(jnp.int32), tuple(cache)
+
+    toks = jax.ShapeDtypeStruct((16384,), jnp.int32, sharding=one_chip)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, toks, scalar, scalar).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%swa_prefill_attend[.\d]* = ", text)) == 4
+    assert "%gmm" in text and "ragged-dot" not in text
+    ma = compiled.memory_analysis()
+    live = ma.argument_size_in_bytes + ma.temp_size_in_bytes \
+        + ma.output_size_in_bytes - ma.alias_size_in_bytes
+    assert live < 9e9, live         # one period of two: 5.5 GB resident

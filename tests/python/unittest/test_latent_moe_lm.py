@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from mxnet_tpu import parallel as par
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.models import LatentMoELM, LatentMoELMConfig
-from mxnet_tpu.models import latent_moe
+from mxnet_tpu.models import experts, latent_moe
 from mxnet_tpu.ops import pallas_latent
 from mxnet_tpu.serving import GenerationEngine, qos
 
@@ -143,7 +143,7 @@ def test_chunked_expert_layer_is_the_whole_one(tiny, monkeypatch):
     h = jnp.asarray(np.random.default_rng(1).normal(size=(32, 64)),
                     jnp.float32)
     want, routing = lm._mlp(params, 1, h)
-    monkeypatch.setattr(latent_moe, "_EXPERT_CHUNK", 8)
+    monkeypatch.setattr(experts, "EXPERT_CHUNK", 8)
     got, chunked = lm._mlp(params, 1, h)
     _close(got, want, "chunked experts")
     assert np.array_equal(routing, chunked)
@@ -311,33 +311,83 @@ def test_selection_uses_the_bias_and_weights_do_not(tiny):
     _close(weights, picked / picked.sum(-1, keepdims=True) * 2.5, "weights")
 
 
-def test_the_shares_add_up(tiny):
+def _softmax_family():
+    """The other model that calls `experts.expert_layer`: a softmax router
+    with normalised weights, no bias, no shared expert (`WindowMoELM`), at
+    16 experts of which 4 are chosen."""
+    from mxnet_tpu.models import WindowMoELM, WindowMoELMConfig
+    from reference import mellum_swa_moe
+    from runners import serve_swa_moe
+
+    kinds = ["sliding_attention", "full_attention"]
+    config = dict(
+        vocab_size=VOCAB, hidden_size=64, num_hidden_layers=2,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        moe_intermediate_size=32, num_experts=16, num_experts_per_tok=4,
+        norm_topk_prob=True, rms_norm_eps=1e-6, sliding_window=8,
+        layer_types=kinds, mlp_layer_types=["sparse"] * 2,
+        rope_parameters={k: {"rope_type": "default", "rope_theta": 10000}
+                         for k in kinds},
+        max_position_embeddings=64, dtype="float32")
+
+    def model(cfg):
+        return WindowMoELM(WindowMoELMConfig.from_config(cfg),
+                           par.create_mesh(devices=jax.devices()[:1], dp=1))
+
+    def reference(cfg, h, weights):
+        return mellum_swa_moe.expert_mlp(
+            h, weights, mellum_swa_moe._static(cfg, kinds[1]))[0]
+
+    return config, model, reference, \
+        lambda params, cfg: serve_swa_moe.published(params, cfg), False
+
+
+def _sigmoid_family():
+    return CONFIG, _model, \
+        lambda cfg, h, weights: ref.expert_mlp(h, weights,
+                                               ref._static(cfg))[0], \
+        lambda params, cfg: published(params), True
+
+
+@pytest.mark.parametrize("family", [_sigmoid_family, _softmax_family],
+                         ids=["sigmoid+bias", "softmax"])
+def test_the_shares_add_up(family):
     """Four chips hold 4 of the 16 experts each. Each computes its own
-    experts' part and the shared expert; the routed parts of the four and
-    the shared expert ONCE are the uncut layer of the reference."""
-    lm, params, weights = tiny
+    experts' part (and the shared expert, where the model has one); the
+    routed parts of the four and the shared expert ONCE are the uncut layer
+    of the reference — through the one expert function both models call,
+    under either router."""
+    config, model, reference, names, has_shared = family()
+    lm = model(config)
+    params = lm.init_params(jax.random.PRNGKey(0))
     h = jnp.asarray(np.random.default_rng(11).normal(size=(24, 64)),
                     jnp.float32)
-    layer = {k[len("layers.1."):]: v for k, v in weights.items()
-             if k.startswith("layers.1.")}
-    want, _ = ref.expert_mlp(h, layer, ref._static(CONFIG))
+
+    def layer(weights):
+        return {k[len("layers.1."):]: v for k, v in weights.items()
+                if k.startswith("layers.1.")}
+
+    want = reference(config, h, layer(names(params, config)))
     x = lm._rms(h, params["l1.norm2"])
-    shared = lm._gated(x, params["l1.shared_in"], params["l1.shared_out"])
-    total = h + shared
+    total = h
+    if has_shared:
+        total = total + lm._gated(x, params["l1.shared_in"],
+                                  params["l1.shared_out"])
     for first in range(0, 16, 4):
-        cut, held = _share(CONFIG, params, first, 4)
-        chip = _model(cut)
+        cut, held = _share(config, params, first, 4)
+        chip = model(cut)
         assert (chip.cfg.num_experts, chip.cfg.experts_held,
                 chip.cfg.expert_first) == (16, 4, first)
-        routed, local = chip._experts(held, 1, x, jnp.ones(24, bool))
+        routed, local = experts.expert_layer(
+            x, jnp.ones(24, bool), lambda xs: chip._route(held, 1, xs),
+            held["l1.experts_in"], held["l1.experts_out"],
+            expert_first=first, mesh=chip.mesh)
         assert ((np.asarray(local) >= -1) & (np.asarray(local) < 4)).all()
         total = total + routed
         # the chip's whole layer is the reference's, given the same share
-        part, _ = ref.expert_mlp(
-            h, {k[len("layers.1."):]: v for k, v in published(held).items()
-                if k.startswith("layers.1.")}, ref._static(cut))
+        part = reference(cut, h, layer(names(held, cut)))
         _close(chip._mlp(held, 1, h)[0], part, f"share from {first}")
-    _close(total, want, "the four shares and the shared expert once")
+    _close(total, want, "the four shares (and the shared expert once)")
 
 
 def test_no_token_is_dropped_when_all_choose_one_expert(tiny):

@@ -1,0 +1,641 @@
+"""`WindowMoELM` (window and full attention layers mixed, a K/V cache whose
+window layers are rings, a softmax-routed dropless expert layer; the `mellum`
+block) against the plain reference `benchmark/reference/mellum_swa_moe.py`,
+at a tiny size with the published structure: two periods of three window
+layers and a full one, a window of 8 under sequences of 40, 8 experts of
+which 2 are chosen, YaRN-corrected rotary positions on the full layers. The
+model is float32 here, so it agrees with the float32 reference to rounding:
+every tolerance is 1e-4 of the compared quantity's scale. The bfloat16 model
+at the published widths is compared on the chip
+(benchmark/runners/serve_swa_moe.py).
+"""
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from mxnet_tpu import parallel as par
+from mxnet_tpu.base import MXNetError
+from mxnet_tpu.models import WindowMoELM, WindowMoELMConfig
+from mxnet_tpu.models import experts, window_moe
+from mxnet_tpu.models.transformer import _write_rows
+from mxnet_tpu.ops import pallas_window
+from mxnet_tpu.serving import GenerationEngine, qos
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))))
+sys.path[:0] = [os.path.join(REPO, "benchmark")]
+from reference import mellum_swa_moe as ref  # noqa: E402
+from runners import serve_swa_moe  # noqa: E402
+
+ref.PAD_TO = ref.BLOCK = 16     # the chip's sizes would spend these tiny tests on padding
+
+TOL = 1e-4
+VOCAB = 211
+FULL, WINDOW = "full_attention", "sliding_attention"
+CONFIG = dict(
+    vocab_size=VOCAB, hidden_size=64, num_hidden_layers=8,
+    num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+    intermediate_size=128, moe_intermediate_size=32, num_experts=8,
+    num_experts_per_tok=2, norm_topk_prob=True, rms_norm_eps=1e-6,
+    sliding_window=8, use_sliding_window=True, max_window_layers=0,
+    layer_types=[WINDOW, WINDOW, WINDOW, FULL] * 2,
+    mlp_layer_types=["sparse"] * 8,
+    rope_parameters={
+        FULL: {"rope_type": "yarn", "rope_theta": 10000, "factor": 16,
+               "original_max_position_embeddings": 16, "beta_fast": 32,
+               "beta_slow": 1, "attention_factor": 1.2772588722239782},
+        WINDOW: {"rope_type": "default", "rope_theta": 10000}},
+    max_position_embeddings=256, dtype="float32", hidden_act="silu",
+    attention_bias=False, tie_word_embeddings=False, model_type="mellum")
+# what the decode kernel takes: heads of 128, a ring of whole lane rows
+KERNEL = dict(CONFIG, num_hidden_layers=4, head_dim=128, sliding_window=128,
+              layer_types=[WINDOW, WINDOW, WINDOW, FULL],
+              mlp_layer_types=["sparse"] * 4, max_position_embeddings=512)
+
+
+def _model(config):
+    return WindowMoELM(WindowMoELMConfig.from_config(config),
+                       par.create_mesh(devices=jax.devices()[:1], dp=1))
+
+
+def _built(config):
+    lm = _model(config)
+    params = lm.init_params(jax.random.PRNGKey(0))
+    return lm, params, serve_swa_moe.published(params, config)
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    return _built(CONFIG)
+
+
+@pytest.fixture(scope="module")
+def wide():
+    return _built(KERNEL)
+
+
+def _tokens(n, seed=0):
+    return np.random.default_rng(seed).integers(0, VOCAB, n).astype(np.int32)
+
+
+def _err(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return np.abs(got - want).max() / np.abs(want).max()
+
+
+def _close(got, want, what, tol=TOL):
+    err = _err(got, want)
+    assert np.isfinite(np.asarray(got)).all() and err <= tol, (what, err)
+
+
+def _poisoned(lm, slots, max_len):
+    """A cache whose K/V members are NaN: what a careless previous occupant
+    may leave in a slot."""
+    return tuple(c if c.dtype == jnp.int32 else
+                 jnp.full(c.shape, jnp.nan, c.dtype)
+                 for c in lm.init_cache(slots, max_len))
+
+
+_JITTED = {}
+
+
+def _jit(lm, method):
+    """One jit wrapper a (model, method, kernel policy): a fresh wrapper a
+    call would trace and compile every decode step again."""
+    key = (id(lm), method, os.environ.get("MXNET_PALLAS_ATTENTION"))
+    if key not in _JITTED:
+        _JITTED[key] = jax.jit(getattr(lm, method))
+    return _JITTED[key]
+
+
+def _prefill(lm, params, cache, prompt, bucket, slot):
+    padded = np.full(bucket, 7, np.int32)       # padded "with anything"
+    padded[:len(prompt)] = prompt
+    out = _jit(lm, "prefill")(params, *cache, jnp.asarray(padded),
+                              jnp.asarray(len(prompt), jnp.int32),
+                              jnp.asarray(slot, jnp.int32))
+    return out[0], tuple(out[1:])
+
+
+def _decode(lm, params, cache, slot, token, position):
+    slots = cache[0].shape[0]
+    tokens = np.zeros(slots, np.int32)
+    positions = np.full(slots, -1, np.int32)
+    tokens[slot], positions[slot] = token, position
+    out = _jit(lm, "decode_step")(params, *cache, jnp.asarray(tokens),
+                                  jnp.asarray(positions))
+    return out[0][slot], tuple(out[1:])
+
+
+def _check_rows(lm, cache, slot, n, want):
+    """What the slot holds after `n` positions against the reference's
+    rotated keys and values: a full member's rows `[0, n)`, a ring
+    unrolled."""
+    members = [np.asarray(m)[slot] for m in cache[:4]]
+    for i, kv in enumerate(want):
+        if i in lm.full_layers:
+            (k, v), page = members[:2], lm.full_layers.index(i)
+        else:
+            (k, v), page = members[2:], lm.window_layers.index(i)
+        (k, first), (v, _) = (serve_swa_moe.unrolled(m, page, n)
+                              for m in (k, v))
+        assert first == (0 if i in lm.full_layers
+                         else max(0, n - lm.cfg.sliding_window))
+        _close(np.stack([k, v], 1), kv[first:n], f"layer {i} rows")
+
+
+# -- the model against the reference ------------------------------------------
+
+@pytest.mark.parametrize("length", [7, 16, 29, 40])
+def test_forward_matches_reference(tiny, length):
+    lm, params, weights = tiny
+    seq = _tokens(length)
+    want = ref.logits(CONFIG, weights, seq, np.arange(length))
+    _close(lm.forward(params, seq[None])[0], want, "logits")
+
+
+@pytest.mark.parametrize("change,what", [
+    (dict(sliding_window=7), "a window of one fewer"),
+    (dict(sliding_window=9), "a window of one more"),
+    (dict(rope_parameters=dict(CONFIG["rope_parameters"], **{FULL: dict(
+        CONFIG["rope_parameters"][FULL], attention_factor=1.0)})),
+     "YaRN's attention factor left out"),
+    (dict(rope_parameters=dict(CONFIG["rope_parameters"], **{
+        FULL: CONFIG["rope_parameters"][WINDOW]})),
+     "YaRN's blended frequencies left out"),
+])
+def test_the_comparison_sees_a_wrong_window_and_missing_yarn(tiny, change,
+                                                             what):
+    """The comparison this file and the benchmark make is tight enough to
+    fail for a window off by one and for YaRN left out."""
+    _, params, weights = tiny
+    seq = _tokens(40)
+    want = ref.logits(CONFIG, weights, seq, np.arange(40))
+    wrong = _model(dict(CONFIG, **change))
+    assert _err(wrong.forward(params, seq[None])[0], want) > 100 * TOL, what
+
+
+def test_blockwise_band_attention_is_the_plain_one(monkeypatch):
+    """A sequence longer than one attention block is attended blockwise,
+    each query block over the key blocks of its band only; the result is
+    the one-block formulation's."""
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.normal(size=(64, 4, 16)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(2, 64, 16)), jnp.float32)
+            for _ in range(2))
+    for window in (None, 8, 16, 21):
+        monkeypatch.setattr(window_moe, "_ATTN_BLOCK", 1024)
+        want = window_moe._band_attention(q, k, v, 0.3, window)
+        monkeypatch.setattr(window_moe, "_ATTN_BLOCK", 16)
+        _close(window_moe._band_attention(q, k, v, 0.3, window), want,
+               f"blockwise, window {window}")
+
+
+CASES = [("xla", 5, 8), ("xla", 8, 8), ("xla", 13, 16), ("xla", 29, 32),
+         ("kernel", 50, 64), ("kernel", 200, 256), ("kernel", 300, 512)]
+
+
+@pytest.mark.parametrize("path,prompt_len,bucket", CASES)
+def test_prefill_then_decode_matches_full_forward(tiny, wide, monkeypatch,
+                                                  path, prompt_len, bucket):
+    """Prefill into a slot whose previous occupant left NaN everywhere,
+    then decode through the cache until the rings have wrapped again: every
+    logit row, every row of the full members and the unrolled rings are the
+    reference's full forward's; the other slots stay NaN. Prompts shorter
+    than the window, equal to it, and bucket-padded past it (the padding's
+    rows must not wrap over real ones)."""
+    lm, params, weights = tiny if path == "xla" else wide
+    config = CONFIG if path == "xla" else KERNEL
+    steps, max_len = (20, 64) if path == "xla" else (6, 512)
+    if path == "kernel":
+        monkeypatch.setenv("MXNET_PALLAS_ATTENTION", "1")
+        monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
+        assert lm.decode_block((3, 1, 2, 512, 128), jnp.float32) == 512
+        assert lm.decode_block((3, 3, 2, 128, 128), jnp.float32) == 128
+    seq = _tokens(prompt_len + steps, seed=prompt_len)
+    want, kv, _ = ref.forward(config, weights, seq,
+                              np.arange(prompt_len - 1, len(seq)))
+    logits, cache = _prefill(lm, params, _poisoned(lm, 3, max_len),
+                             seq[:prompt_len], bucket, slot=1)
+    _close(logits, want[0], "prefill logits")
+    _check_rows(lm, cache, 1, prompt_len, kv)
+    for t in range(prompt_len, len(seq)):
+        logits, cache = _decode(lm, params, cache, 1, seq[t], t)
+        _close(logits, want[t - prompt_len + 1], f"decode logits at {t}")
+    _check_rows(lm, cache, 1, len(seq), kv)
+    for member in cache[:4]:
+        assert member.shape[3] == (max_len if member is cache[0]
+                                   or member is cache[1]
+                                   else config["sliding_window"])
+        assert np.isnan(np.asarray(member)[[0, 2]]).all(), \
+            "a dead slot's rows were touched"
+    assert (np.asarray(cache[4])[[0, 2]] == 0).all()
+
+
+def test_a_short_prompt_after_a_long_occupant(tiny):
+    """A prompt shorter than the window into a slot whose previous occupant
+    was longer: the decode at `p < window - 1` attends rows `[0, p]` of the
+    ring and nothing stale."""
+    lm, params, weights = tiny
+    old = _tokens(29, seed=1)
+    _, cache = _prefill(lm, params, lm.init_cache(2, 64), old, 32, slot=1)
+    for t in range(3):
+        _, cache = _decode(lm, params, cache, 1, 5 + t, 29 + t)
+    seq = _tokens(3 + 12, seed=2)
+    want = ref.logits(CONFIG, weights, seq, np.arange(2, len(seq)))
+    logits, cache = _prefill(lm, params, cache, seq[:3], 8, slot=1)
+    _close(logits, want[0], "prefill logits")
+    for t in range(3, len(seq)):
+        logits, cache = _decode(lm, params, cache, 1, seq[t], t)
+        _close(logits, want[t - 2], f"decode logits at {t}")
+
+
+def test_a_ring_that_the_padding_wrapped_fails(tiny):
+    """What the prefill must NOT do: write every row of the padded bucket
+    at `p mod window`. The rows of the padding then lie over real ones and
+    the next token's logits are not the reference's."""
+    lm, params, weights = tiny
+    seq = _tokens(14, seed=4)
+    want = ref.logits(CONFIG, weights, seq, [13])
+    _, cache = _prefill(lm, params, lm.init_cache(2, 64), seq[:13], 16, 1)
+    logits, _ = _decode(lm, params, cache, 1, seq[13], 13)
+    _close(logits, want[0], "the right ring")
+    padded = np.full(16, 7, np.int32)
+    padded[:13] = seq[:13]
+    _, kept = lm._sequence(params, jnp.asarray(padded), 13)
+    k_ring, v_ring = cache[2], cache[3]
+    for page, i in enumerate(lm.window_layers):
+        k, v = kept[i]                  # positions 8..15 at rows 0..7
+        k_ring = k_ring.at[1, page].set(k[:, 8:16])
+        v_ring = v_ring.at[1, page].set(v[:, 8:16])
+    wrapped = cache[:2] + (k_ring, v_ring) + cache[4:]
+    logits, _ = _decode(lm, params, wrapped, 1, seq[13], 13)
+    assert _err(logits, want[0]) > 100 * TOL
+
+
+# -- kernels against their restatements in XLA ---------------------------------
+
+@pytest.mark.parametrize("alive", [[1, 1, 1, 1], [0, 1, 0, 1], [0, 0, 0, 0]])
+@pytest.mark.parametrize("rows", [256, 128])
+def test_decode_kernel_matches_restatement(alive, rows):
+    """The Pallas kernel (interpreted) against the XLA formulation (write
+    one row a slot, attend the member masked) on a member of `rows` rows
+    under positions below its length (a full member, a ring not yet
+    wrapped) and past it (a ring wrapped once and several times), the rows
+    past the live ones NaN, with dead slots. 4 queries a K/V head."""
+    rng = np.random.default_rng(5)
+    slots, layers, heads, group, hd = 4, 2, 2, 4, 128
+    block = pallas_window.kv_block((slots, layers, heads, rows, hd),
+                                   jnp.float32, target=128)
+    assert block == 128
+    assert pallas_window.kv_block((4, 2, 2, 256, 64), jnp.float32) is None
+    if rows == 256:
+        positions = [127, 128, 5, 255]
+    else:                               # a ring of 128 under 512 positions
+        positions = [127, 128, 5, 389]
+    positions = np.where(alive, positions, -1).astype(np.int32)
+    slab_k, slab_v = (rng.normal(size=(slots, layers, heads, rows, hd))
+                      .astype("f4") for _ in range(2))
+    for s, p in enumerate(positions):
+        for slab in (slab_k, slab_v):
+            slab[s, :, :, min(max(p, 0) + 1, rows):] = np.nan
+            slab[s, :, :, max(p, 0) % rows] = np.nan    # the row to write
+    q = jnp.asarray(rng.normal(size=(slots, heads * group, hd)), jnp.float32)
+    k_new, v_new = (jnp.asarray(rng.normal(size=(slots, heads, hd)),
+                                jnp.float32) for _ in range(2))
+    at = jnp.asarray(np.where(positions >= 0, positions % rows, -1))
+    want_k = _write_rows(jnp.asarray(slab_k), 1, at, k_new)
+    want_v = _write_rows(jnp.asarray(slab_v), 1, at, v_new)
+    want = window_moe._attend_member(q, want_k[:, 1], want_v[:, 1],
+                                     jnp.asarray(positions), 0.2)
+    got, got_k, got_v = pallas_window.kv_update_attend(
+        q, k_new, v_new, jnp.asarray(slab_k), jnp.asarray(slab_v),
+        jnp.int32(1), jnp.asarray(positions), block=block, scale=0.2,
+        interpret=True)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got_k), np.asarray(want_k))
+    np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v))
+    if any(alive):
+        _close(got, want, "decode kernel")
+    else:
+        assert not np.asarray(got).any()
+
+
+@pytest.mark.parametrize("window", [None, 128, 100, 300])
+def test_prefill_kernel_matches_restatement(window):
+    """The prefill attention kernel (interpreted) — grouped queries, key
+    blocks streamed through the grid, blocks outside the band skipped —
+    against the XLA formulation, for windows of one block, of less, and of
+    more than two."""
+    rng = np.random.default_rng(6)
+    heads, group, length, hd = 2, 2, 512, 128
+    q = jnp.asarray(rng.normal(size=(heads * group, length, hd)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(heads, length, hd)), jnp.float32)
+            for _ in range(2))
+    assert pallas_window.band_block(length, None, target=128) == 128
+    assert pallas_window.band_block(16384, 1024) == 512
+    assert pallas_window.band_block(16384) == 1024
+    assert pallas_window.band_block(200) is None
+    steps = pallas_window.band_steps(length, 128, window)
+    assert steps == {None: 4, 128: 2, 100: 2, 300: 4}[window]
+    assert pallas_window.band_steps(16384, 512, 1024) == 3
+    got = pallas_window.band_prefill_attend(q, k, v, block=128, scale=0.3,
+                                            window=window, interpret=True)
+    want = window_moe._band_attention(q.transpose(1, 0, 2), k, v, 0.3, window)
+    _close(got.transpose(1, 0, 2), want, "prefill kernel")
+
+
+# -- the expert layer ------------------------------------------------------------
+
+def test_router_is_a_softmax_with_normalised_top_k(tiny):
+    lm, params, _ = tiny
+    x = jnp.asarray(np.random.default_rng(7).normal(size=(40, 64)),
+                    jnp.float32)
+    p = np.asarray(jax.nn.softmax(x @ params["l1.router"], -1), np.float64)
+    chosen, weights = lm._route(params, 1, x)
+    want = np.argsort(-p, axis=-1)[:, :2]
+    assert np.array_equal(np.sort(np.asarray(chosen), -1), np.sort(want, -1))
+    picked = np.take_along_axis(p, np.asarray(chosen), -1)
+    _close(weights, picked / picked.sum(-1, keepdims=True), "weights")
+    assert np.allclose(np.asarray(weights).sum(-1), 1, atol=1e-6)
+    plain = _model(dict(CONFIG, norm_topk_prob=False))
+    _close(plain._route(params, 1, x)[1], picked, "unnormalised weights")
+
+
+def test_grouped_kernel_is_the_plain_grouped_product(tiny, monkeypatch):
+    """The expert layer through the Pallas grouped matmul (interpreted),
+    tiled from its shapes, is the one through `lax.ragged_dot`."""
+    lm, params, _ = tiny
+    h = jnp.asarray(np.random.default_rng(17).normal(size=(64, 64)),
+                    jnp.float32)           # 64 tokens x 2: one 128-row tile
+    want, routing = lm._mlp(params, 1, h)
+    monkeypatch.setenv("MXNET_PALLAS_ATTENTION", "1")
+    monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
+    got, kernel = lm._mlp(params, 1, h)
+    _close(got, want, "grouped kernel")
+    assert np.array_equal(routing, kernel)
+
+
+@pytest.mark.parametrize("m,k,n,want", [
+    (256, 4096, 4096, (128, 512, 4096)),        # PR 31's constants ...
+    (256, 2048, 4096, (128, 512, 4096)),
+    (32768, 4096, 4096, (256, 1024, 1024)),
+    (32768, 2048, 4096, (256, 1024, 1024)),
+    (256, 2304, 1792, (128, 1152, 1792)),       # ... and K = 2304 / 896
+    (256, 896, 2304, (128, 896, 2304)),
+    (32768, 2304, 1792, (256, 1152, 896)),
+    (32768, 896, 2304, (256, 896, 768)),
+    (100, 64, 64, None),                        # no whole row tile
+])
+def test_grouped_tiles_follow_the_shapes(m, k, n, want):
+    got = experts.gmm_tiling(m, k, n, 2)
+    assert got == want
+    if want:
+        tm, tk, tn = want
+        assert m % tm == 0 and k % tk == 0 and n % tn == 0
+        # the weight tile, double-buffered, beside the fp32 accumulator and
+        # the row and output tiles: under Mosaic's 16 MiB
+        assert 2 * tk * tn * 2 + tm * tn * 4 + 2 * tm * (tk + tn) * 2 \
+            < 14 * 2 ** 20
+
+
+def test_from_config_refuses_what_the_block_cannot_express():
+    for key, value in (("hidden_act", "gelu"), ("tie_word_embeddings", True),
+                       ("attention_bias", True),
+                       ("use_sliding_window", False)):
+        with pytest.raises(ValueError, match=key):
+            WindowMoELMConfig.from_config(dict(CONFIG, **{key: value}))
+    with pytest.raises(ValueError, match="mlp_layer_types"):
+        WindowMoELMConfig.from_config(dict(
+            CONFIG, mlp_layer_types=["dense"] + ["sparse"] * 7))
+    with pytest.raises(ValueError, match="layer_types"):
+        WindowMoELMConfig.from_config(dict(
+            CONFIG, layer_types=["chunked_attention"] * 8))
+    with pytest.raises(ValueError, match="held experts"):
+        _model(dict(CONFIG, num_experts=4, published={"num_experts": 8},
+                    share={"expert_first": 6}))
+
+
+# -- through GenerationEngine ------------------------------------------------
+
+def _greedy_reference(weights, prompt, n):
+    """The reference's own greedy continuation, one full forward a token."""
+    seq = list(prompt)
+    for _ in range(n):
+        row = ref.logits(CONFIG, weights, np.asarray(seq), [len(seq) - 1])
+        seq.append(int(np.asarray(row)[0].argmax()))
+    return seq[len(prompt):]
+
+
+def _engine(lm, params, **kw):
+    kw.setdefault("max_slots", 3)
+    return GenerationEngine(lm, params, max_len=64, prefix_cache=False,
+                            spec_k=0, **kw)
+
+
+def test_a_batch_of_mixed_lengths_equals_each_request_alone(tiny):
+    """7 requests over 3 slots: every stream is the reference's greedy
+    continuation of its own prompt (slots are reused, so a prefill really
+    replaces what its slot held; the rings wrap), and nothing compiles
+    after the first pass."""
+    lm, params, weights = tiny
+    prompts = [_tokens(n, seed=n) for n in (3, 8, 9, 17, 5, 30, 12)]
+    with _engine(lm, params, buckets=(8, 32)) as eng:
+        assert [m.shape[1:] for m in eng._kv] == [
+            (2, 2, 64, 16), (2, 2, 64, 16), (6, 2, 8, 16), (6, 2, 8, 16),
+            (8, 2)]
+        assert eng.kv_slab_bytes() == sum(int(m.nbytes) for m in eng._kv)
+        streams = [eng.submit(p, max_new_tokens=6) for p in prompts]
+        got = [s.result(timeout=120) for s in streams]
+        misses = eng.cache.misses
+        again = eng.generate(prompts[3], max_new_tokens=6)
+        assert eng.cache.misses == misses
+    for p, g in zip(prompts, got):
+        assert g == _greedy_reference(weights, p, 6)
+    assert again == got[3]
+
+
+def test_fork_is_a_bitwise_copy_of_one_slot(tiny):
+    lm, params, _ = tiny
+    eng = _engine(lm, params, buckets=(16,), start=False)
+    try:
+        s = eng.submit(_tokens(11, seed=9), max_new_tokens=5)
+        for _ in range(3):
+            eng._tick_once()
+        src = eng.slot_snapshot(s.slot)
+        others = eng.slot_snapshot((s.slot + 1) % 3)
+        eng._fork(s.slot, (s.slot + 2) % 3)
+        assert [m.shape for m in src] == [
+            (2, 2, 64, 16), (2, 2, 64, 16), (6, 2, 8, 16), (6, 2, 8, 16),
+            (8, 2)]
+        for a, b in zip(src, eng.slot_snapshot((s.slot + 2) % 3)):
+            assert np.array_equal(a, b) and np.abs(a).sum() > 0
+        for a, b in zip(others, eng.slot_snapshot((s.slot + 1) % 3)):
+            assert np.array_equal(a, b)
+    finally:
+        eng.close()
+
+
+def test_park_copies_every_member_and_resume_is_bit_equal(tiny):
+    """QoS park and resume go through the fork executable, which copies one
+    slot of EVERY member of the cache — full rows, rings and the routing —
+    and the preempted stream resumes bit-equal to an uncontended run."""
+    lm, params, _ = tiny
+    qos.install(qos.TenantRegistry(qos.parse_spec(
+        "lat:interactive;bulk:batch")))
+    try:
+        bp = [_tokens(9, seed=40), _tokens(14, seed=41)]
+        ip = _tokens(6, seed=42)
+        with _engine(lm, params, max_slots=2, buckets=(16,)) as base:
+            want = [base.generate(p, max_new_tokens=20) for p in bp]
+            iwant = base.generate(ip, max_new_tokens=4)
+        eng = _engine(lm, params, max_slots=2, buckets=(16,), start=False)
+        try:
+            assert eng.total_slots == 3
+            bs = [eng.submit(p, max_new_tokens=20, tenant="bulk")
+                  for p in bp]
+            for _ in range(50):
+                if eng.live_slots == 2:
+                    break
+                eng._tick_once()
+            eng._tick_once()
+            istream = eng.submit(ip, max_new_tokens=4, tenant="lat")
+            for _ in range(3):
+                eng._tick_once()                # parks the youngest
+                if eng.parked_count:
+                    break
+            assert eng.parked_count == 1
+            parked = eng.slot_snapshot(2)
+            assert all(np.abs(m).sum() > 0 for m in parked[:4])
+            for _ in range(400):
+                if all(s._future.done() for s in bs + [istream]):
+                    break
+                eng._tick_once()
+            assert [s.result(1) for s in bs] == want
+            assert istream.result(1) == iwant
+        finally:
+            eng.close()
+    finally:
+        qos.clear()
+
+
+@pytest.mark.parametrize("kwargs,what", [
+    (dict(prefix_cache=True, spec_k=0), "prefix cache"),
+    (dict(prefix_cache=False, spec_k=2), "speculative decoding"),
+])
+def test_engine_refuses_what_a_ring_cannot_offer(tiny, kwargs, what):
+    lm, params, _ = tiny
+    traits = lm.cache_traits(lm.init_cache(2, 64))
+    assert traits["state_bytes_per_slot"] == 0 and not traits["rewindable"]
+    assert not hasattr(lm, "prefill_at") and not hasattr(lm, "verify_step")
+    with pytest.raises(MXNetError, match=what + ".*ring of sliding_window"):
+        GenerationEngine(lm, params, max_slots=2, max_len=64, buckets=(16,),
+                         start=False, **kwargs)
+
+
+def test_tick_counters_against_a_host_count(tiny):
+    """With telemetry on, the engine's counters are what the decode program
+    itself routed and attended: re-derived here from the model's routing of
+    the same tokens at the same positions, and from the positions."""
+    from mxnet_tpu import telemetry
+
+    lm, params, _ = tiny
+    prev = telemetry.enabled()
+    telemetry.enable()
+    names = lm.TICK_COUNTERS
+    pre = "serving.generation."
+    try:
+        eng = _engine(lm, params, buckets=(16,), start=False)
+        assert eng._tick_counter_names == names
+        c0 = {k: telemetry.counter(pre + k).value for k in names}
+        prompts = [_tokens(n, seed=n) for n in (5, 12)]
+        streams = [eng.submit(p, max_new_tokens=3) for p in prompts]
+        for _ in range(20):
+            if all(s.done for s in streams):
+                break
+            eng._tick_once()
+        assert eng._ahead is None
+        got = {k: telemetry.counter(pre + k).value - c0[k] for k in names}
+        routed = eng.slot_snapshot(streams[0].slot)[4]
+        eng.close()
+    finally:
+        telemetry.enable(prev)
+    assert routed.shape == (8, 2) and 0 <= routed.min() and routed.max() < 8
+    # 2 sessions x 2 decoded tokens at positions n and n + 1: a full layer
+    # attends p + 1 rows, a window layer min(p + 1, 8); 2 full and 6 window
+    # layers
+    at = [5, 6, 12, 13]
+    assert got["kv_rows_live_full"] == 2 * sum(p + 1 for p in at)
+    assert got["kv_rows_live_window"] == 6 * sum(min(p + 1, 8) for p in at)
+    # every expert is held: each decoded token computes 2 pairs in 8 layers
+    assert got["expert_assignments"] == 4 * 8 * 2
+    assert 0 < got["expert_tokens_max"] <= got["experts_hit"] <= 4 * 8 * 2
+
+
+def test_tick_counters_of_a_hand_made_routing(tiny):
+    lm, _, _ = tiny
+    routed = np.full((4, 8, 2), -1, np.int32)
+    routed[0, 0] = [3, 5]
+    routed[1, 0] = [3, 7]
+    routed[2, 0] = [1, 2]                       # a dead slot's: not counted
+    routed[3, 1] = [0, 6]
+    positions = jnp.asarray([4, 9, -1, 0], jnp.int32)
+    cache = lm.init_cache(4, 64)
+    got = np.asarray(lm.tick_counters(*cache[:4], jnp.asarray(routed),
+                                      positions))
+    # pairs 4 + 2; layer 0 hits {3, 5, 7} and layer 1 {0, 6}; the fullest
+    # expert has 2 tokens in layer 0 and 1 in layer 1; rows (5 + 10 + 1) in
+    # each of 2 full layers, (5 + 8 + 1) in each of 6 window layers
+    assert got.tolist() == [6, 5, 3, 32, 84]
+
+
+def test_counters_cost_nothing_with_telemetry_off(tiny):
+    """Telemetry off: the counters' program is compiled by `warm()` (so a
+    later traced window compiles nothing) and never dispatched."""
+    lm, params, _ = tiny
+    eng = _engine(lm, params, buckets=(16,), start=False)
+    try:
+        eng.warm()
+        calls = []
+        real = eng._tick_counters_fn
+        eng._tick_counters_fn = lambda: calls.append(1) or real()
+        s = eng.submit(_tokens(7), max_new_tokens=4)
+        for _ in range(10):
+            if s.done:
+                break
+            eng._tick_once()
+        assert s.done and calls == []
+    finally:
+        eng.close()
+
+
+def test_nothing_compiles_after_warm_up(tiny):
+    from jax import monitoring
+
+    lm, params, _ = tiny
+    compiles = []
+
+    def listener(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    monitoring.register_event_duration_secs_listener(listener)
+    try:
+        eng = _engine(lm, params, buckets=(8, 32), start=False)
+        eng.warm()
+        del compiles[:]
+        prompts = [_tokens(n, seed=n) for n in (4, 8, 20, 6, 13)]
+        streams = [eng.submit(p, max_new_tokens=6) for p in prompts]
+        for _ in range(100):
+            if all(s.done for s in streams):
+                break
+            eng._tick_once()
+        assert all(s.done for s in streams) and compiles == []
+        eng.close()
+    finally:
+        monitoring.unregister_event_duration_listener(listener)
