@@ -47,6 +47,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import telemetry
 from ..parallel.spmd import model_mesh
 from .transformer import _attend_rows, _table_rows, _write_rows
 
@@ -446,7 +447,10 @@ class HybridLM:
         """Whether :meth:`decode_step` advances a recurrent-state slab of
         this shape through the Pallas kernel (``ops/pallas_ssm.py``: each
         live slot's state read once and written once, where it lies) or in
-        XLA (which reads it twice). Decided as :meth:`decode_block` is."""
+        XLA (which reads it twice). Decided as :meth:`decode_block` is;
+        which way a layer's trace went is counted
+        (`mamba.state_update.kernel` / `.xla`, once a trace, telemetry
+        on)."""
         from ..ops import pallas_attention as pa
         from ..ops import pallas_ssm
 
@@ -513,7 +517,11 @@ class HybridLM:
         with jax.named_scope("mamba.state_update"):
             f32 = jnp.float32
             decay, dtx = jnp.exp(log_a), step[:, :, None] * x.astype(f32)
-            if self.state_kernel(ssm.shape, ssm.dtype):
+            kernel = self.state_kernel(ssm.shape, ssm.dtype)
+            if telemetry._enabled:
+                telemetry.counter("mamba.state_update."
+                                  + ("kernel" if kernel else "xla")).inc()
+            if kernel:
                 from ..ops import pallas_attention as pa
                 from ..ops import pallas_ssm
 

@@ -8,14 +8,28 @@ size on lanes). One step of layer ``page`` for slot ``s`` and head ``h`` is
     S[s, page, h] = decay[s, h] * S[s, page, h] + dtx[s, h, :, None] * B[s]
     y[s, h, :]    = S[s, page, h] @ C[s]
 
-— pure bandwidth: 2 x the state's bytes a live slot, a few FLOPs a byte. XLA
-writes the update in place (a dynamic-update-slice fusion) but cannot also
-emit ``y`` from that fusion, so it reads the layer's page of every slot twice;
-this kernel makes both in one pass, takes the live slots first in its grid,
-and neither reads nor writes a dead one (the state goes back through an output
-aliased to the input). The per-head scalars and columns arrive head-minor
-(``[.., H]``: a head's column broadcasts along lanes), ``B`` and ``C`` as
-rows. `state_update_applies` is the shape test a caller makes before the call.
+XLA writes the update in place (a dynamic-update-slice fusion) but cannot
+also emit ``y`` from that fusion, so it reads the layer's page of every slot
+twice; this kernel makes both in one pass, takes the live slots first in its
+grid, and neither reads nor writes a dead one (the state goes back through an
+output aliased to the input).
+
+What bounds it is the copy of a page in and out, and that is the memory's
+own rate, not the pipeline's: on the v5e a 2.1 MB page takes 2.93 us to read
+and 3.21 us to write when nothing else runs (87% and 80% of 819 GB/s), 6.38 us
+with both in flight however deep the queue, and a body that only rewrites
+the state 6.40 (80% of the 4.19 MB at 819 GB/s; PERF.md section 5, PR 34).
+This body hides under it. The state is advanced in float32 on the VPU. ``y``
+is contracted on the MXU, which is otherwise idle: the new state of up to 128
+rows is the stationary operand and ``C`` the moving one, at
+float32-equivalent precision (``HIGHEST``: the compiler's multi-pass split of
+both operands), so no lane reduction runs per vreg of state — summed over
+lanes on the cross-lane unit, as before PR 34, the same page took 7.8 us.
+The eight rows of the moving operand each keep an eighth of ``C``'s lanes and
+are added at the end: eight short sums instead of one long one. The per-head
+scalars and columns arrive head-minor (``[.., H]``: a head's column
+broadcasts along lanes, which hides under the copy), ``B`` and ``C`` as rows.
+`state_update_applies` is the shape test a caller makes before the call.
 """
 from __future__ import annotations
 
@@ -48,23 +62,38 @@ def state_update_applies(slab_shape, dtype):
             and p % 8 == 0 and 4 * h * p * n * 4 <= _BLOCK_BUDGET_BYTES)
 
 
+def _rows_per_product(heads, hp):
+    """Heads whose rows make one stationary operand: as many as fill the
+    MXU's 128 rows, a whole number of times over the page."""
+    return max(d for d in range(1, heads + 1)
+               if heads % d == 0 and (d == 1 or d * hp <= _LANES))
+
+
 def _kernel(n_ref, slot_ref, page_ref, decay_ref, dtx_ref, b_ref, c_ref,
-            s_ref, y_ref, so_ref, *, heads):
+            s_ref, y_ref, so_ref, *, tile):
     del page_ref                                # the index maps read it
     j = pl.program_id(0)
+    heads, _, n = s_ref.shape[2:]
 
     @pl.when(j < n_ref[0])
     def _():
-        b, c = b_ref[0], c_ref[0]                               # [1, N]
-        lane = lax.broadcasted_iota(jnp.int32, y_ref.shape[1:], 1)
-        y = jnp.zeros(y_ref.shape[1:], jnp.float32)            # [P, H]
-        for h in range(heads):
-            new = (decay_ref[0][:, h:h + 1] * s_ref[0, 0, h]
-                   + dtx_ref[0][:, h:h + 1] * b)                # [P, N]
-            so_ref[0, 0, h] = new
-            y = jnp.where(lane == h,
-                          jnp.sum(new * c, axis=1, keepdims=True), y)
-        y_ref[0] = y
+        b = b_ref[0]                                            # [1, N]
+        # row r of the moving operand: the r-th eighth of C's lanes
+        row = lax.broadcasted_iota(jnp.int32, (8, n), 0) * (n // 8)
+        lane = lax.broadcasted_iota(jnp.int32, (8, n), 1)
+        c8 = jnp.where(jnp.logical_and(lane >= row, lane < row + n // 8),
+                       c_ref[0], 0.0)
+        for t in range(heads // tile):
+            new = []
+            for h in range(t * tile, (t + 1) * tile):
+                new.append(decay_ref[0][:, h:h + 1] * s_ref[0, 0, h]
+                           + dtx_ref[0][:, h:h + 1] * b)        # [P, N]
+                so_ref[0, 0, h] = new[-1]
+            part = lax.dot_general(
+                c8, jnp.concatenate(new, axis=0), (((1,), (1,)), ((), ())),
+                precision=lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)             # [8, rows]
+            y_ref[0, t:t + 1, :] = jnp.sum(part, axis=0, keepdims=True)
 
     # no live slot at all: the write-back block still goes back, unchanged
     @pl.when(jnp.logical_and(n_ref[0] == 0, j == 0))
@@ -100,8 +129,9 @@ def state_update(slab, page, decay, dtx, b, c, alive, *, interpret=False):
         return (slot_ref[j], page_ref[0], 0, 0, 0)
 
     f32 = jnp.float32
+    tile = _rows_per_product(heads, hp)
     y, slab = pl.pallas_call(
-        functools.partial(_kernel, heads=heads),
+        functools.partial(_kernel, tile=tile),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(n_slots,),
@@ -113,10 +143,11 @@ def state_update(slab, page, decay, dtx, b, c, alive, *, interpret=False):
                 pl.BlockSpec((1, 1, heads, hp, n), state),
             ],
             out_specs=[
-                pl.BlockSpec((1, hp, heads), row),
+                pl.BlockSpec((1, heads // tile, tile * hp), row),
                 pl.BlockSpec((1, 1, heads, hp, n), state),
             ]),
-        out_shape=[jax.ShapeDtypeStruct((n_slots, hp, heads), f32),
+        out_shape=[jax.ShapeDtypeStruct((n_slots, heads // tile, tile * hp),
+                                        f32),
                    jax.ShapeDtypeStruct(slab.shape, slab.dtype)],
         # operands count the scalar-prefetch ones: the slab is 7
         input_output_aliases={7: 1},
@@ -128,5 +159,5 @@ def state_update(slab, page, decay, dtx, b, c, alive, *, interpret=False):
     )(n_live[None], slot_of, jnp.asarray(page, jnp.int32).reshape(1),
       decay.astype(f32)[:, None, :], jnp.swapaxes(dtx.astype(f32), 1, 2),
       b.astype(f32)[:, None, :], c.astype(f32)[:, None, :], slab)
-    return (jnp.where(alive[:, None, None], jnp.swapaxes(y, 1, 2), 0.0),
-            slab)
+    return (jnp.where(alive[:, None, None],
+                      y.reshape(n_slots, heads, hp), 0.0), slab)

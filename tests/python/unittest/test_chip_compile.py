@@ -249,11 +249,13 @@ def test_hybrid_decode_program_updates_state_in_place_on_v5e(one_chip,
     (the K/V slab's with 4 queries a head, the recurrent state's); every
     member of the cache is aliased input to output; and no XLA op copies,
     slices, updates or re-lays the recurrent-state slab — each live slot's
-    state is read once and written once, by the kernel, where it lies."""
+    state is read once and written once, by the kernel, where it lies. The
+    trace counts which way each Mamba layer's state update went."""
     import json
     import os
 
     from mxnet_tpu import parallel as par
+    from mxnet_tpu import telemetry
     from mxnet_tpu.models import HybridLM, HybridLMConfig
 
     monkeypatch.setenv("MXNET_PALLAS_ATTENTION", "1")
@@ -289,8 +291,18 @@ def test_hybrid_decode_program_updates_state_in_place_on_v5e(one_chip,
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), tuple(cache)
 
     ints = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
-    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
-        params, cache, ints, ints).compile()
+    went = {k: telemetry.counter("mamba.state_update." + k)
+            for k in ("kernel", "xla")}
+    before = {k: c.value for k, c in went.items()}
+    was = telemetry.enabled()
+    telemetry.enable()
+    try:
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+            params, cache, ints, ints).compile()
+    finally:
+        telemetry.enable(was)
+    assert {k: c.value - before[k] for k, c in went.items()} == {
+        "kernel": 9, "xla": 0}
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 10      # 9 state + 1 K/V kernel
     assert len(re.findall(r"%mamba_state_update[.\d]* = ", text)) == 9
