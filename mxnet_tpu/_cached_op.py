@@ -237,8 +237,9 @@ def backward_program(jaxprs, wiring, wanted, heads, emit, update=None):
         # no barrier XLA carries them over in the pullback's wider
         # precision, and the step differs from its two-program form
         grads = jax.lax.optimization_barrier(grads)
-        new_ws, new_ss = update(list(weights), list(grads), list(states),
-                                lrs, wds, rescale)
+        with jax.named_scope("optimizer.update"):
+            new_ws, new_ss = update(list(weights), list(grads), list(states),
+                                    lrs, wds, rescale)
         if jax.tree_util.tree_structure(tuple(new_ss)) \
                 != jax.tree_util.tree_structure(tuple(states)):
             raise MXNetError("fused_update changed the structure of the "

@@ -330,7 +330,14 @@ class Block:
     def __call__(self, *args):
         for hook in self._forward_pre_hooks.values():
             hook(self, args)
-        out = self.forward(*args)
+        if _PARAM_OVERRIDE.get() is not None:
+            # inside a CachedOp capture (or its shape-only pass): the
+            # block's device-side scope, so nested blocks nest in the
+            # program's op_names. Never in the eager call path
+            with jax.named_scope(self._name):
+                out = self.forward(*args)
+        else:
+            out = self.forward(*args)
         for hook in self._forward_hooks.values():
             hook(self, args, out)
         return out

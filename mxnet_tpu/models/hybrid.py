@@ -222,29 +222,38 @@ class HybridLM:
 
     # -- pieces -------------------------------------------------------------
 
+    # Device-side scopes (`jax.named_scope`: in every instruction's op_name,
+    # read by benchmark/program_scopes.py): `embed`, `norm`, `mlp`, `head`,
+    # `attn.project`, `attn.prefill` | `attn.decode`, `attn.out`,
+    # `mamba.project`, `mamba.conv`, `mamba.gates`, `mamba.ssd` |
+    # `mamba.state_update`, `mamba.out`, `cache.write`.
+
     def _rms(self, x, g):
-        x32 = x.astype(jnp.float32)
-        out = x32 * lax.rsqrt((x32 * x32).mean(-1, keepdims=True)
-                              + self.cfg.rms_norm_eps)
-        return (out * g.astype(jnp.float32)).astype(x.dtype)
+        with jax.named_scope("norm"):
+            x32 = x.astype(jnp.float32)
+            out = x32 * lax.rsqrt((x32 * x32).mean(-1, keepdims=True)
+                                  + self.cfg.rms_norm_eps)
+            return (out * g.astype(jnp.float32)).astype(x.dtype)
 
     def _mlp(self, params, i, h):
         """The gated MLP sub-layer with its norm and residual."""
         u = self._rms(h, params[f"l{i}.norm2"])
-        g, v = jnp.split(u @ params[f"l{i}.w_in"], 2, axis=-1)
-        return h + self.cfg.residual_multiplier * (
-            (jax.nn.silu(g) * v) @ params[f"l{i}.w_out"])
+        with jax.named_scope("mlp"):
+            g, v = jnp.split(u @ params[f"l{i}.w_in"], 2, axis=-1)
+            return h + self.cfg.residual_multiplier * (
+                (jax.nn.silu(g) * v) @ params[f"l{i}.w_out"])
 
     def _qkv(self, params, i, u):
         """`u` [T, D] -> q [T, Hq, hd], k and v [T, Hkv, hd]."""
         c = self.cfg
         t = u.shape[0]
-        return ((u @ params[f"l{i}.wq"]).reshape(t, c.num_attention_heads,
-                                                 c.head_dim),
-                (u @ params[f"l{i}.wk"]).reshape(t, c.num_key_value_heads,
-                                                 c.head_dim),
-                (u @ params[f"l{i}.wv"]).reshape(t, c.num_key_value_heads,
-                                                 c.head_dim))
+        with jax.named_scope("attn.project"):
+            return ((u @ params[f"l{i}.wq"]).reshape(
+                        t, c.num_attention_heads, c.head_dim),
+                    (u @ params[f"l{i}.wk"]).reshape(
+                        t, c.num_key_value_heads, c.head_dim),
+                    (u @ params[f"l{i}.wv"]).reshape(
+                        t, c.num_key_value_heads, c.head_dim))
 
     def _attention_seq(self, params, i, u):
         """The attention mixer over one whole sequence `u` [L, D]: `(out
@@ -253,30 +262,35 @@ class HybridLM:
         L = u.shape[0]
         q, k, v = self._qkv(params, i, u)
         group = c.num_attention_heads // c.num_key_value_heads
-        q = q.reshape(L, c.num_key_value_heads, group, c.head_dim)
-        s = jnp.einsum("qhgd,khd->hgqk", q, k,
-                       preferred_element_type=jnp.float32) \
-            * c.attention_multiplier
-        ar = jnp.arange(L)
-        # large-negative, not -inf: see TransformerLM.prefill
-        s = s + jnp.where(ar[:, None] >= ar[None, :], 0.0, -1e9)
-        p = jax.nn.softmax(s, axis=-1).astype(u.dtype)
-        a = jnp.einsum("hgqk,khd->qhgd", p, v).reshape(L, c.hidden_size)
-        return a @ params[f"l{i}.wo"], k, v
+        with jax.named_scope("attn.prefill"):
+            q = q.reshape(L, c.num_key_value_heads, group, c.head_dim)
+            s = jnp.einsum("qhgd,khd->hgqk", q, k,
+                           preferred_element_type=jnp.float32) \
+                * c.attention_multiplier
+            ar = jnp.arange(L)
+            # large-negative, not -inf: see TransformerLM.prefill
+            s = s + jnp.where(ar[:, None] >= ar[None, :], 0.0, -1e9)
+            p = jax.nn.softmax(s, axis=-1).astype(u.dtype)
+            a = jnp.einsum("hgqk,khd->qhgd", p, v).reshape(L, c.hidden_size)
+        with jax.named_scope("attn.out"):
+            return a @ params[f"l{i}.wo"], k, v
 
     def _mamba_project(self, params, i, u):
         """`u` [T, D] -> z [T, inner], xBC [T, C], dt_raw [T, H]."""
         c = self.cfg
-        return jnp.split(u @ params[f"l{i}.m_in"],
-                         [c.mamba_inner, c.mamba_inner + c.conv_channels],
-                         axis=-1)
+        with jax.named_scope("mamba.project"):
+            return jnp.split(u @ params[f"l{i}.m_in"],
+                             [c.mamba_inner, c.mamba_inner + c.conv_channels],
+                             axis=-1)
 
     def _mamba_gates(self, params, i, dt_raw):
         """float32 step sizes `dt` [T, H] and log-decays `dt * A`."""
-        step = jax.nn.softplus(dt_raw.astype(jnp.float32)
-                               + params[f"l{i}.dt_bias"].astype(jnp.float32))
-        return step, step * -jnp.exp(params[f"l{i}.A_log"]
-                                     .astype(jnp.float32))
+        with jax.named_scope("mamba.gates"):
+            step = jax.nn.softplus(
+                dt_raw.astype(jnp.float32)
+                + params[f"l{i}.dt_bias"].astype(jnp.float32))
+            return step, step * -jnp.exp(params[f"l{i}.A_log"]
+                                         .astype(jnp.float32))
 
     def _mamba_out(self, params, i, y, x, z):
         """`y` [T, H, P] float32 (the recurrence's output) -> the mixer's
@@ -284,11 +298,14 @@ class HybridLM:
         output projection."""
         c = self.cfg
         t = y.shape[0]
-        y = y + params[f"l{i}.D"].astype(jnp.float32)[None, :, None] \
-            * x.astype(jnp.float32)
-        y = y.reshape(t, c.mamba_inner) * jax.nn.silu(z.astype(jnp.float32))
-        return self._rms(y, params[f"l{i}.m_norm"]).astype(z.dtype) \
-            @ params[f"l{i}.m_out"]
+        with jax.named_scope("mamba.out"):      # its norm nests: the
+            # outermost scope names the work
+            y = y + params[f"l{i}.D"].astype(jnp.float32)[None, :, None] \
+                * x.astype(jnp.float32)
+            y = y.reshape(t, c.mamba_inner) \
+                * jax.nn.silu(z.astype(jnp.float32))
+            return self._rms(y, params[f"l{i}.m_norm"]).astype(z.dtype) \
+                @ params[f"l{i}.m_out"]
 
     def _split_xbc(self, xbc):
         c = self.cfg
@@ -319,8 +336,10 @@ class HybridLM:
                 padded[j:j + L].astype(jnp.float32) * w[j] for j in range(k))
             x, b, cc = self._split_xbc(jax.nn.silu(conv).astype(dt_))
         step, log_a = self._mamba_gates(params, i, dt_raw)
-        real = (jnp.arange(L) < length)[:, None]
-        step, log_a = jnp.where(real, step, 0.0), jnp.where(real, log_a, 0.0)
+        with jax.named_scope("mamba.gates"):
+            real = (jnp.arange(L) < length)[:, None]
+            step = jnp.where(real, step, 0.0)
+            log_a = jnp.where(real, log_a, 0.0)
         with jax.named_scope("mamba.ssd"):
             y, state = self._ssd(x, b, cc, step, log_a)
         return self._mamba_out(params, i, y, x, z), state, tail
@@ -381,15 +400,17 @@ class HybridLM:
 
     def _logits(self, params, h):
         h = self._rms(h, params["norm_f"])
-        return (h @ params["embed"].T).astype(jnp.float32) \
-            / self.cfg.logits_scaling
+        with jax.named_scope("head"):
+            return (h @ params["embed"].T).astype(jnp.float32) \
+                / self.cfg.logits_scaling
 
     def _sequence(self, params, tokens, length):
         """One whole sequence `tokens` [L]: the hidden states [L, D] after
         the last layer and, per layer, what a cache keeps of it: `(k, v)` of
         an attention layer, `(state, conv_tail)` of a Mamba layer."""
         c = self.cfg
-        h = self._embed(jnp.take(params["embed"], tokens, axis=0))
+        with jax.named_scope("embed"):
+            h = self._embed(jnp.take(params["embed"], tokens, axis=0))
         kept = []
         for i, kind in enumerate(c.layer_types):
             u = self._rms(h, params[f"l{i}.norm1"])
@@ -482,21 +503,25 @@ class HybridLM:
         - 1, ck, cv, ssm, conv)`. `tokens` [Lb] is the prompt padded (with
         anything) to the bucket; `length` and `slot` are traced."""
         h, kept = self._sequence(params, tokens, length)
-        for kind, page, keep in zip(self.cfg.layer_types, self._page, kept):
-            at = (slot, page, 0, 0, 0)
-            if kind == "attention":
-                k, v = keep
-                ck = lax.dynamic_update_slice(
-                    ck, k.transpose(1, 0, 2)[None, None].astype(ck.dtype), at)
-                cv = lax.dynamic_update_slice(
-                    cv, v.transpose(1, 0, 2)[None, None].astype(cv.dtype), at)
-            else:
-                state, tail = keep
-                ssm = lax.dynamic_update_slice(
-                    ssm, state[None, None].astype(ssm.dtype), at)
-                conv = lax.dynamic_update_slice(
-                    conv, tail[None, None].astype(conv.dtype), at[:4])
-        last = lax.dynamic_slice_in_dim(h, length - 1, 1, axis=0)
+        with jax.named_scope("cache.write"):
+            for kind, page, keep in zip(self.cfg.layer_types, self._page,
+                                        kept):
+                at = (slot, page, 0, 0, 0)
+                if kind == "attention":
+                    k, v = keep
+                    ck = lax.dynamic_update_slice(
+                        ck, k.transpose(1, 0, 2)[None, None].astype(ck.dtype),
+                        at)
+                    cv = lax.dynamic_update_slice(
+                        cv, v.transpose(1, 0, 2)[None, None].astype(cv.dtype),
+                        at)
+                else:
+                    state, tail = keep
+                    ssm = lax.dynamic_update_slice(
+                        ssm, state[None, None].astype(ssm.dtype), at)
+                    conv = lax.dynamic_update_slice(
+                        conv, tail[None, None].astype(conv.dtype), at[:4])
+            last = lax.dynamic_slice_in_dim(h, length - 1, 1, axis=0)
         return self._logits(params, last)[0], ck, cv, ssm, conv
 
     def _mamba_step(self, params, i, u, ssm, conv, page, alive):
@@ -554,13 +579,14 @@ class HybridLM:
         block = self.decode_block(ck.shape, ck.dtype)
         positions = jnp.minimum(positions, ck.shape[3] - 1)
         alive = positions >= 0
-        h = self._embed(_table_rows(params["embed"], tokens))
+        with jax.named_scope("embed"):
+            h = self._embed(_table_rows(params["embed"], tokens))
         for i, (kind, page) in enumerate(zip(c.layer_types, self._page)):
             u = self._rms(h, params[f"l{i}.norm1"])
             if kind == "attention":
                 q, k, v = self._qkv(params, i, u)
-                k, v = k.astype(ck.dtype), v.astype(cv.dtype)
                 with jax.named_scope("attn.decode"):
+                    k, v = k.astype(ck.dtype), v.astype(cv.dtype)
                     if block is not None:
                         a, ck, cv = pd.decode_update_attend(
                             q, k, v, ck, cv, jnp.int32(page), positions,
@@ -571,8 +597,9 @@ class HybridLM:
                         cv = _write_rows(cv, page, positions, v)
                         a = _attend_rows(q, ck, cv, page, positions,
                                          scale=c.attention_multiplier)
-                mixed = a.astype(h.dtype).reshape(-1, c.hidden_size) \
-                    @ params[f"l{i}.wo"]
+                with jax.named_scope("attn.out"):
+                    mixed = a.astype(h.dtype).reshape(-1, c.hidden_size) \
+                        @ params[f"l{i}.wo"]
             else:
                 mixed, ssm, conv = self._mamba_step(params, i, u, ssm, conv,
                                                     page, alive)

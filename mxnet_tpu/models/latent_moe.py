@@ -261,11 +261,18 @@ class LatentMoELM:
 
     # -- pieces -------------------------------------------------------------
 
+    # Device-side scopes (`jax.named_scope`: in every instruction's op_name,
+    # read by benchmark/program_scopes.py; where they nest, the outermost
+    # names the work): `embed`, `norm`, `mlp`, `head`, `mla.project`,
+    # `mla.absorb`, `mla.attend`, `attn.out`, `cache.write`, `moe.route`,
+    # `moe.group`, `moe.experts`, `moe.shared`.
+
     def _rms(self, x, g):
-        x32 = x.astype(jnp.float32)
-        out = x32 * lax.rsqrt((x32 * x32).mean(-1, keepdims=True)
-                              + self.cfg.rms_norm_eps)
-        return (out * g.astype(jnp.float32)).astype(x.dtype)
+        with jax.named_scope("norm"):
+            x32 = x.astype(jnp.float32)
+            out = x32 * lax.rsqrt((x32 * x32).mean(-1, keepdims=True)
+                                  + self.cfg.rms_norm_eps)
+            return (out * g.astype(jnp.float32)).astype(x.dtype)
 
     def _rotate(self, x, positions):
         """Half-split rotary embedding of the last axis of `x` [T, ...,
@@ -329,7 +336,9 @@ class LatentMoELM:
                                               (L,) + q_rope.shape[1:])],
                     axis=-1)
                 a = _causal_attention(q, k, v, c.softmax_scale)
-        return a.reshape(L, -1).astype(u.dtype) @ params[f"l{i}.wo"], lat, k_r
+        with jax.named_scope("attn.out"):
+            return a.reshape(L, -1).astype(u.dtype) @ params[f"l{i}.wo"], \
+                lat, k_r
 
     def _attention_step(self, params, i, u, cache_c, cache_kr, page,
                         positions, block):
@@ -343,8 +352,11 @@ class LatentMoELM:
         c = self.cfg
         q_nope, q_rope, lat, k_r = self._project(
             params, i, u, jnp.maximum(positions, 0))
-        cache_c = _write_rows(cache_c, page, positions, lat[:, None, :], 2)
-        cache_kr = _write_rows(cache_kr, page, positions, k_r[:, :, None], 3)
+        with jax.named_scope("cache.write"):
+            cache_c = _write_rows(cache_c, page, positions, lat[:, None, :],
+                                  2)
+            cache_kr = _write_rows(cache_kr, page, positions,
+                                   k_r[:, :, None], 3)
         w_uk, w_uv = self._w_ukv(params, i)
         with jax.named_scope("mla.absorb"):
             qc = jnp.einsum("shd,rhd->shr", q_nope, w_uk)
@@ -360,8 +372,9 @@ class LatentMoELM:
                                    c.softmax_scale)
         with jax.named_scope("mla.absorb"):
             a = jnp.einsum("shr,rhd->shd", o.astype(u.dtype), w_uv)
-        return a.reshape(u.shape[0], -1) @ params[f"l{i}.wo"], \
-            cache_c, cache_kr
+        with jax.named_scope("attn.out"):
+            return a.reshape(u.shape[0], -1) @ params[f"l{i}.wo"], \
+                cache_c, cache_kr
 
     def _gated(self, x, w_in, w_out):
         g, v = jnp.split(x @ w_in, 2, axis=-1)
@@ -399,8 +412,9 @@ class LatentMoELM:
         local index, -1 elsewhere), None for a dense layer."""
         x = self._rms(h, params[f"l{i}.norm2"])
         if self._is_dense(i):
-            return h + self._gated(x, params[f"l{i}.w_in"],
-                                   params[f"l{i}.w_out"]), None
+            with jax.named_scope("mlp"):
+                return h + self._gated(x, params[f"l{i}.w_in"],
+                                       params[f"l{i}.w_out"]), None
         real = jnp.ones(x.shape[0], bool) if real is None else real
         y, local = self._experts(params, i, x, real)
         with jax.named_scope("moe.shared"):
@@ -411,15 +425,17 @@ class LatentMoELM:
     # -- forward ------------------------------------------------------------
 
     def _logits(self, params, h):
-        return (self._rms(h, params["norm_f"]) @ params["head"]) \
-            .astype(jnp.float32)
+        h = self._rms(h, params["norm_f"])
+        with jax.named_scope("head"):
+            return (h @ params["head"]).astype(jnp.float32)
 
     def _sequence(self, params, tokens, length):
         """One whole sequence `tokens` [L] of which the first `length` are
         real: the hidden states [L, D] after the last layer and, per layer,
         the latent rows `(c [L, R], k_r [L, rope])` a cache keeps."""
-        h = jnp.take(params["embed"], tokens, axis=0) \
-            .astype(jnp.dtype(self.cfg.dtype))
+        with jax.named_scope("embed"):
+            h = jnp.take(params["embed"], tokens, axis=0) \
+                .astype(jnp.dtype(self.cfg.dtype))
         real = jnp.arange(tokens.shape[0]) < length
         kept = []
         for i in range(self.cfg.num_hidden_layers):
@@ -520,14 +536,15 @@ class LatentMoELM:
         the prompt padded (with anything) to the bucket; `length` and
         `slot` are traced."""
         h, kept = self._sequence(params, tokens, length)
-        for i, (lat, k_r) in enumerate(kept):
-            cache_c = lax.dynamic_update_slice(
-                cache_c, lat[None, None].astype(cache_c.dtype),
-                (slot, i, 0, 0))
-            cache_kr = lax.dynamic_update_slice(
-                cache_kr, k_r.T[None, None].astype(cache_kr.dtype),
-                (slot, i, 0, 0))
-        last = lax.dynamic_slice_in_dim(h, length - 1, 1, axis=0)
+        with jax.named_scope("cache.write"):
+            for i, (lat, k_r) in enumerate(kept):
+                cache_c = lax.dynamic_update_slice(
+                    cache_c, lat[None, None].astype(cache_c.dtype),
+                    (slot, i, 0, 0))
+                cache_kr = lax.dynamic_update_slice(
+                    cache_kr, k_r.T[None, None].astype(cache_kr.dtype),
+                    (slot, i, 0, 0))
+            last = lax.dynamic_slice_in_dim(h, length - 1, 1, axis=0)
         return self._logits(params, last)[0], cache_c, cache_kr, routed
 
     def decode_step(self, params, cache_c, cache_kr, routed, tokens,
@@ -542,7 +559,9 @@ class LatentMoELM:
         block = self.decode_block(cache_c.shape, cache_c.dtype)
         positions = jnp.minimum(positions, cache_c.shape[2] - 1)
         alive = positions >= 0
-        h = _table_rows(params["embed"], tokens).astype(jnp.dtype(c.dtype))
+        with jax.named_scope("embed"):
+            h = _table_rows(params["embed"], tokens).astype(
+                jnp.dtype(c.dtype))
         chose = []
         for i in range(c.num_hidden_layers):
             mixed, cache_c, cache_kr = self._attention_step(
@@ -552,8 +571,9 @@ class LatentMoELM:
             if local is not None:
                 chose.append(local)
         if chose:
-            routed = jnp.where(alive[:, None, None],
-                               jnp.stack(chose, axis=1), routed)
+            with jax.named_scope("cache.write"):
+                routed = jnp.where(alive[:, None, None],
+                                   jnp.stack(chose, axis=1), routed)
         return self._logits(params, h), cache_c, cache_kr, routed
 
 

@@ -234,11 +234,13 @@ class TransformerLM:
     # -- forward ------------------------------------------------------------
 
     def _ln(self, x, scale, bias):
-        x32 = x.astype(jnp.float32)
-        mu = x32.mean(-1, keepdims=True)
-        var = x32.var(-1, keepdims=True)
-        out = (x32 - mu) * jax.lax.rsqrt(var + 1e-5)
-        return (out * scale.astype(jnp.float32) + bias.astype(jnp.float32)).astype(x.dtype)
+        with jax.named_scope("norm"):
+            x32 = x.astype(jnp.float32)
+            mu = x32.mean(-1, keepdims=True)
+            var = x32.var(-1, keepdims=True)
+            out = (x32 - mu) * jax.lax.rsqrt(var + 1e-5)
+            return (out * scale.astype(jnp.float32)
+                    + bias.astype(jnp.float32)).astype(x.dtype)
 
     def _local_attention(self, q, k, v, blocks):
         """One device's attention over [B, L, H, D]: the fused flash kernel
@@ -428,6 +430,50 @@ class TransformerLM:
         return (params["embed"].T if self.cfg.tie_embeddings
                 else params["lm_head"])
 
+    # The serving programs' device-side scopes (`jax.named_scope`: in every
+    # instruction's op_name, read by benchmark/program_scopes.py): `embed`,
+    # `norm`, `attn.project`, `attn.prefill` | `attn.decode`, `attn.out`,
+    # `mlp`, `head` — the names the other three models use for the same work.
+
+    def _project_qkv(self, params, i, h, rows):
+        """Layer ``i``'s q, k, v ``[rows, H, hd]`` of the normed ``h``."""
+        c = self.cfg
+        ln1 = self._ln(h, params[f"l{i}.ln1_scale"], params[f"l{i}.ln1_bias"])
+        with jax.named_scope("attn.project"):
+            q, k, v = jnp.split(ln1 @ params[f"l{i}.wqkv"], 3, axis=-1)
+            shape = (rows, c.n_heads, c.d_model // c.n_heads)
+            return q.reshape(shape), k.reshape(shape), v.reshape(shape)
+
+    def _attn_out(self, params, i, h, attn):
+        with jax.named_scope("attn.out"):
+            return h + attn @ params[f"l{i}.wo"]
+
+    def _mlp(self, params, i, h, group_axis):
+        """``h`` plus layer ``i``'s feed-forward half. An expert layer
+        routes the rows as groups along ``group_axis``: 0 is one group of
+        all rows (a prefill), 1 a group a row (a decode)."""
+        dt = h.dtype
+        ln2 = self._ln(h, params[f"l{i}.ln2_scale"], params[f"l{i}.ln2_bias"])
+        with jax.named_scope("mlp"):
+            if self._is_moe(i):
+                ff, _ = self._moe_ffn(i, params,
+                                      jnp.expand_dims(ln2, group_axis))
+                return h + jnp.squeeze(ff, group_axis)
+            ff = jax.nn.gelu(ln2 @ params[f"l{i}.w1"]
+                             + params[f"l{i}.b1"].astype(dt))
+            return h + ff @ params[f"l{i}.w2"] + params[f"l{i}.b2"].astype(dt)
+
+    def _logits(self, params, h, row=None):
+        """fp32 logits of the final-normed rows ``h`` [T, D]; with ``row``,
+        of that one row alone [1, V] — every row is normed and then the one
+        is cut, the order the prefill programs have always had."""
+        h = self._ln(h, params["ln_f_scale"], params["ln_f_bias"])
+        if row is not None:
+            h = lax.dynamic_slice_in_dim(h, row, 1, axis=0)         # [1,D]
+        with jax.named_scope("head"):
+            return (h @ self._head(params).astype(h.dtype)).astype(
+                jnp.float32)
+
     def prefill(self, params, cache_k, cache_v, tokens, length, slot):
         """Full-prompt forward for ONE session, writing its K/V into slot
         ``slot`` rows ``[0, Lb)`` of the slab and returning the logits at
@@ -450,48 +496,39 @@ class TransformerLM:
         Lb = tokens.shape[0]
         hd = c.d_model // c.n_heads
         scale = 1.0 / np.sqrt(hd)
-        h = jnp.take(params["embed"], tokens, axis=0).astype(dt)     # [Lb,D]
-        h = h + params["pos_embed"][:Lb].astype(dt)
+        with jax.named_scope("embed"):
+            h = jnp.take(params["embed"], tokens, axis=0).astype(dt)  # [Lb,D]
+            h = h + params["pos_embed"][:Lb].astype(dt)
         # additive causal mask, large-negative (not -inf: a fully-masked
         # row must softmax to harmless garbage, not NaN)
         ar = jnp.arange(Lb)
         causal = jnp.where(ar[:, None] >= ar[None, :], 0.0, -1e9)   # [Lb,Lb]
         for i in range(c.n_layers):
-            ln1 = self._ln(h, params[f"l{i}.ln1_scale"],
-                           params[f"l{i}.ln1_bias"])
-            qkv = ln1 @ params[f"l{i}.wqkv"]
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(Lb, c.n_heads, hd)
-            k = k.reshape(Lb, c.n_heads, hd)
-            v = v.reshape(Lb, c.n_heads, hd)
-            # slab write: [1, 1, H, Lb, hd] block at (slot, layer, 0, 0, 0)
-            cache_k = lax.dynamic_update_slice(
-                cache_k, k.transpose(1, 0, 2)[None, None].astype(cache_k.dtype),
-                (slot, i, 0, 0, 0))
-            cache_v = lax.dynamic_update_slice(
-                cache_v, v.transpose(1, 0, 2)[None, None].astype(cache_v.dtype),
-                (slot, i, 0, 0, 0))
-            s = jnp.einsum("qhd,khd->hqk", q, k,
-                           preferred_element_type=jnp.float32) * scale
-            p = jax.nn.softmax(s + causal[None], axis=-1).astype(dt)
-            attn = jnp.einsum("hqk,khd->qhd", p, v).reshape(Lb, c.d_model)
-            h = h + attn @ params[f"l{i}.wo"]
-            ln2 = self._ln(h, params[f"l{i}.ln2_scale"],
-                           params[f"l{i}.ln2_bias"])
-            if self._is_moe(i):
-                # batch-1 grouped dispatch; note: capacity is computed at
-                # the BUCKET length, so under heavy routing imbalance a
-                # bucket-padded prefill can keep tokens a shorter forward
-                # would have dropped (decode_step always keeps: C=1, L=1)
-                ff, _ = self._moe_ffn(i, params, ln2[None])
-                h = h + ff[0]
-            else:
-                ff = jax.nn.gelu(ln2 @ params[f"l{i}.w1"]
-                                 + params[f"l{i}.b1"].astype(dt))
-                h = h + ff @ params[f"l{i}.w2"] + params[f"l{i}.b2"].astype(dt)
-        h = self._ln(h, params["ln_f_scale"], params["ln_f_bias"])
-        last = lax.dynamic_slice_in_dim(h, length - 1, 1, axis=0)    # [1,D]
-        logits = (last @ self._head(params).astype(dt)).astype(jnp.float32)
+            q, k, v = self._project_qkv(params, i, h, Lb)
+            with jax.named_scope("attn.prefill"):
+                # slab write: [1, 1, H, Lb, hd] block at (slot, layer, 0, 0,
+                # 0)
+                cache_k = lax.dynamic_update_slice(
+                    cache_k,
+                    k.transpose(1, 0, 2)[None, None].astype(cache_k.dtype),
+                    (slot, i, 0, 0, 0))
+                cache_v = lax.dynamic_update_slice(
+                    cache_v,
+                    v.transpose(1, 0, 2)[None, None].astype(cache_v.dtype),
+                    (slot, i, 0, 0, 0))
+                s = jnp.einsum("qhd,khd->hqk", q, k,
+                               preferred_element_type=jnp.float32) * scale
+                p = jax.nn.softmax(s + causal[None], axis=-1).astype(dt)
+                attn = jnp.einsum("hqk,khd->qhd", p, v).reshape(Lb,
+                                                                c.d_model)
+            h = self._attn_out(params, i, h, attn)
+            # an expert layer dispatches the prompt as one group; note:
+            # capacity is computed at the BUCKET length, so under heavy
+            # routing imbalance a bucket-padded prefill can keep tokens a
+            # shorter forward would have dropped (decode_step always keeps:
+            # C=1, L=1)
+            h = self._mlp(params, i, h, 0)
+        logits = self._logits(params, h, row=length - 1)
         sh = self._slab_sharding()
         return (logits[0], sharding_constraint(cache_k, sh),
                 sharding_constraint(cache_v, sh))
@@ -527,56 +564,44 @@ class TransformerLM:
         hd = c.d_model // c.n_heads
         scale = 1.0 / np.sqrt(hd)
         pos = offset + jnp.arange(Lb)
-        h = jnp.take(params["embed"], tokens, axis=0).astype(dt)     # [Lb,D]
-        # jnp.take clips out-of-range positions (pad rows past the model's
-        # positional range read row max_len-1 — garbage the mask hides)
-        h = h + jnp.take(params["pos_embed"], pos, axis=0).astype(dt)
+        with jax.named_scope("embed"):
+            h = jnp.take(params["embed"], tokens, axis=0).astype(dt)  # [Lb,D]
+            # jnp.take clips out-of-range positions (pad rows past the
+            # model's positional range read row max_len-1 — garbage the mask
+            # hides)
+            h = h + jnp.take(params["pos_embed"], pos, axis=0).astype(dt)
         # suffix token i attends slab rows j <= offset + i: the cached
         # prefix plus causal-within-suffix, one mask over the whole row.
         # Large-negative, not -inf (finite garbage for fully-masked rows)
         mask = jnp.where(jnp.arange(L)[None, None, :]
                          <= pos[None, :, None], 0.0, -1e9)     # [1,Lb,L]
         for i in range(c.n_layers):
-            ln1 = self._ln(h, params[f"l{i}.ln1_scale"],
-                           params[f"l{i}.ln1_bias"])
-            qkv = ln1 @ params[f"l{i}.wqkv"]
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(Lb, c.n_heads, hd)
-            k = k.reshape(Lb, c.n_heads, hd)
-            v = v.reshape(Lb, c.n_heads, hd)
-            # slab write: [1, 1, H, Lb, hd] block at (slot, layer, 0,
-            # offset, 0) — rows [0, offset) stay the forked prefix
-            cache_k = lax.dynamic_update_slice(
-                cache_k, k.transpose(1, 0, 2)[None, None].astype(cache_k.dtype),
-                (slot, i, 0, offset, 0))
-            cache_v = lax.dynamic_update_slice(
-                cache_v, v.transpose(1, 0, 2)[None, None].astype(cache_v.dtype),
-                (slot, i, 0, offset, 0))
-            ck_i = lax.dynamic_slice(
-                cache_k, (slot, i, 0, 0, 0),
-                (1, 1, c.n_heads, L, hd))[0, 0]                # [H,L,hd]
-            cv_i = lax.dynamic_slice(
-                cache_v, (slot, i, 0, 0, 0),
-                (1, 1, c.n_heads, L, hd))[0, 0]
-            s = jnp.einsum("qhd,hkd->hqk", q, ck_i.astype(dt),
-                           preferred_element_type=jnp.float32) * scale
-            p = jax.nn.softmax(s + mask, axis=-1).astype(dt)
-            attn = jnp.einsum("hqk,hkd->qhd", p,
-                              cv_i.astype(dt)).reshape(Lb, c.d_model)
-            h = h + attn @ params[f"l{i}.wo"]
-            ln2 = self._ln(h, params[f"l{i}.ln2_scale"],
-                           params[f"l{i}.ln2_bias"])
-            if self._is_moe(i):
-                # batch-1 grouped dispatch, as in prefill
-                ff, _ = self._moe_ffn(i, params, ln2[None])
-                h = h + ff[0]
-            else:
-                ff = jax.nn.gelu(ln2 @ params[f"l{i}.w1"]
-                                 + params[f"l{i}.b1"].astype(dt))
-                h = h + ff @ params[f"l{i}.w2"] + params[f"l{i}.b2"].astype(dt)
-        h = self._ln(h, params["ln_f_scale"], params["ln_f_bias"])
-        last = lax.dynamic_slice_in_dim(h, length - 1, 1, axis=0)    # [1,D]
-        logits = (last @ self._head(params).astype(dt)).astype(jnp.float32)
+            q, k, v = self._project_qkv(params, i, h, Lb)
+            with jax.named_scope("attn.prefill"):
+                # slab write: [1, 1, H, Lb, hd] block at (slot, layer, 0,
+                # offset, 0) — rows [0, offset) stay the forked prefix
+                cache_k = lax.dynamic_update_slice(
+                    cache_k,
+                    k.transpose(1, 0, 2)[None, None].astype(cache_k.dtype),
+                    (slot, i, 0, offset, 0))
+                cache_v = lax.dynamic_update_slice(
+                    cache_v,
+                    v.transpose(1, 0, 2)[None, None].astype(cache_v.dtype),
+                    (slot, i, 0, offset, 0))
+                ck_i = lax.dynamic_slice(
+                    cache_k, (slot, i, 0, 0, 0),
+                    (1, 1, c.n_heads, L, hd))[0, 0]                # [H,L,hd]
+                cv_i = lax.dynamic_slice(
+                    cache_v, (slot, i, 0, 0, 0),
+                    (1, 1, c.n_heads, L, hd))[0, 0]
+                s = jnp.einsum("qhd,hkd->hqk", q, ck_i.astype(dt),
+                               preferred_element_type=jnp.float32) * scale
+                p = jax.nn.softmax(s + mask, axis=-1).astype(dt)
+                attn = jnp.einsum("hqk,hkd->qhd", p,
+                                  cv_i.astype(dt)).reshape(Lb, c.d_model)
+            h = self._attn_out(params, i, h, attn)
+            h = self._mlp(params, i, h, 0)      # one group, as in prefill
+        logits = self._logits(params, h, row=length - 1)
         sh = self._slab_sharding()
         return (logits[0], sharding_constraint(cache_k, sh),
                 sharding_constraint(cache_v, sh))
@@ -639,44 +664,31 @@ class TransformerLM:
         c = self.cfg
         dt = jnp.dtype(c.dtype)
         S = tokens.shape[0]
-        hd = c.d_model // c.n_heads
         block = self.decode_block(cache_k.shape, cache_k.dtype)
         positions = jnp.minimum(positions, cache_k.shape[3] - 1)
-        h = _table_rows(params["embed"], tokens).astype(dt)          # [S,D]
-        h = h + jnp.take(params["pos_embed"], jnp.maximum(positions, 0),
-                         axis=0).astype(dt)
+        with jax.named_scope("embed"):
+            h = _table_rows(params["embed"], tokens).astype(dt)      # [S,D]
+            h = h + jnp.take(params["pos_embed"], jnp.maximum(positions, 0),
+                             axis=0).astype(dt)
         for i in range(c.n_layers):
-            ln1 = self._ln(h, params[f"l{i}.ln1_scale"],
-                           params[f"l{i}.ln1_bias"])
-            qkv = ln1 @ params[f"l{i}.wqkv"]
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(S, c.n_heads, hd)
-            k = k.reshape(S, c.n_heads, hd).astype(cache_k.dtype)
-            v = v.reshape(S, c.n_heads, hd).astype(cache_v.dtype)
-            if block is not None:
-                attn, cache_k, cache_v = pd.decode_update_attend(
-                    q, k, v, cache_k, cache_v, jnp.int32(i), positions,
-                    block=block, interpret=pa.pallas_interpret())
-            else:
-                cache_k = _write_rows(cache_k, i, positions, k)
-                cache_v = _write_rows(cache_v, i, positions, v)
-                attn = _attend_rows(q, cache_k, cache_v, i, positions)
-            attn = attn.astype(dt).reshape(S, c.d_model)
-            h = h + attn @ params[f"l{i}.wo"]
-            ln2 = self._ln(h, params[f"l{i}.ln2_scale"],
-                           params[f"l{i}.ln2_bias"])
-            if self._is_moe(i):
-                # [S, 1, D]: every slot is its own routing group of one
-                # token with capacity 1, so a decoded token is ALWAYS
-                # routed (never capacity-dropped, unlike training forward)
-                ff, _ = self._moe_ffn(i, params, ln2[:, None, :])
-                h = h + ff[:, 0]
-            else:
-                ff = jax.nn.gelu(ln2 @ params[f"l{i}.w1"]
-                                 + params[f"l{i}.b1"].astype(dt))
-                h = h + ff @ params[f"l{i}.w2"] + params[f"l{i}.b2"].astype(dt)
-        h = self._ln(h, params["ln_f_scale"], params["ln_f_bias"])
-        logits = (h @ self._head(params).astype(dt)).astype(jnp.float32)
+            q, k, v = self._project_qkv(params, i, h, S)
+            with jax.named_scope("attn.decode"):
+                k, v = k.astype(cache_k.dtype), v.astype(cache_v.dtype)
+                if block is not None:
+                    attn, cache_k, cache_v = pd.decode_update_attend(
+                        q, k, v, cache_k, cache_v, jnp.int32(i), positions,
+                        block=block, interpret=pa.pallas_interpret())
+                else:
+                    cache_k = _write_rows(cache_k, i, positions, k)
+                    cache_v = _write_rows(cache_v, i, positions, v)
+                    attn = _attend_rows(q, cache_k, cache_v, i, positions)
+                attn = attn.astype(dt).reshape(S, c.d_model)
+            h = self._attn_out(params, i, h, attn)
+            # [S, 1, D]: every slot is its own routing group of one token
+            # with capacity 1, so a decoded token is ALWAYS routed (never
+            # capacity-dropped, unlike training forward)
+            h = self._mlp(params, i, h, 1)
+        logits = self._logits(params, h)
         sh = self._slab_sharding()
         return (logits, sharding_constraint(cache_k, sh),
                 sharding_constraint(cache_v, sh))

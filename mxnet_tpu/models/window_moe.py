@@ -242,11 +242,18 @@ class WindowMoELM:
 
     # -- pieces -------------------------------------------------------------
 
+    # Device-side scopes (`jax.named_scope`: in every instruction's op_name,
+    # read by benchmark/program_scopes.py): `embed`, `norm`, `head`,
+    # `attn.project`, `attn.rotary`, `attn.prefill` | `attn.decode` |
+    # `attn.window`, `attn.out`, `cache.write`, `moe.route`, `moe.group`,
+    # `moe.experts`.
+
     def _rms(self, x, g):
-        x32 = x.astype(jnp.float32)
-        out = x32 * lax.rsqrt((x32 * x32).mean(-1, keepdims=True)
-                              + self.cfg.rms_norm_eps)
-        return (out * g.astype(jnp.float32)).astype(x.dtype)
+        with jax.named_scope("norm"):
+            x32 = x.astype(jnp.float32)
+            out = x32 * lax.rsqrt((x32 * x32).mean(-1, keepdims=True)
+                                  + self.cfg.rms_norm_eps)
+            return (out * g.astype(jnp.float32)).astype(x.dtype)
 
     def _window(self, i):
         """Layer `i`'s window, None for a full layer."""
@@ -287,7 +294,9 @@ class WindowMoELM:
                     interpret=pa.pallas_interpret()).transpose(1, 0, 2)
             else:
                 a = _band_attention(q, k, v, c.head_dim ** -0.5, window)
-        return a.reshape(L, -1).astype(u.dtype) @ params[f"l{i}.wo"], k, v
+        with jax.named_scope("attn.out"):
+            return a.reshape(L, -1).astype(u.dtype) @ params[f"l{i}.wo"], \
+                k, v
 
     def _attention_step(self, params, i, u, slab_k, slab_v, page, positions,
                         block):
@@ -316,8 +325,9 @@ class WindowMoELM:
                 slab_v = _write_rows(slab_v, page, at, v.astype(slab_v.dtype))
                 a = _attend_member(q, slab_k[:, page], slab_v[:, page],
                                    positions, c.head_dim ** -0.5)
-        return a.reshape(u.shape[0], -1).astype(u.dtype) \
-            @ params[f"l{i}.wo"], slab_k, slab_v
+        with jax.named_scope("attn.out"):
+            return a.reshape(u.shape[0], -1).astype(u.dtype) \
+                @ params[f"l{i}.wo"], slab_k, slab_v
 
     def _route(self, params, i, x):
         """`x` [T, D] -> `(chosen [T, k] expert ids of the whole router,
@@ -349,15 +359,17 @@ class WindowMoELM:
     # -- forward ------------------------------------------------------------
 
     def _logits(self, params, h):
-        return (self._rms(h, params["norm_f"]) @ params["head"]) \
-            .astype(jnp.float32)
+        h = self._rms(h, params["norm_f"])
+        with jax.named_scope("head"):
+            return (h @ params["head"]).astype(jnp.float32)
 
     def _sequence(self, params, tokens, length):
         """One whole sequence `tokens` [L] of which the first `length` are
         real: the hidden states [L, D] after the last layer and, per layer,
         the keys and values `(k, v)` [H, L, hd] a cache keeps."""
-        h = jnp.take(params["embed"], tokens, axis=0) \
-            .astype(jnp.dtype(self.cfg.dtype))
+        with jax.named_scope("embed"):
+            h = jnp.take(params["embed"], tokens, axis=0) \
+                .astype(jnp.dtype(self.cfg.dtype))
         real = jnp.arange(tokens.shape[0]) < length
         kept = []
         for i in range(self.cfg.num_hidden_layers):
@@ -472,20 +484,24 @@ class WindowMoELM:
         are traced."""
         h, kept = self._sequence(params, tokens, length)
         lb, rows = tokens.shape[0], k_ring.shape[3]
-        newest = jnp.clip(length - 1 - (length - 1 - jnp.arange(rows)) % rows,
-                          0, lb - 1)
 
         def put(slab, page, x):
             return lax.dynamic_update_slice(
                 slab, x[None, None].astype(slab.dtype), (slot, page, 0, 0, 0))
 
-        for page, i in enumerate(self.full_layers):
-            k_full = put(k_full, page, kept[i][0])
-            v_full = put(v_full, page, kept[i][1])
-        for page, i in enumerate(self.window_layers):
-            k_ring = put(k_ring, page, jnp.take(kept[i][0], newest, axis=1))
-            v_ring = put(v_ring, page, jnp.take(kept[i][1], newest, axis=1))
-        last = lax.dynamic_slice_in_dim(h, length - 1, 1, axis=0)
+        with jax.named_scope("cache.write"):
+            newest = jnp.clip(
+                length - 1 - (length - 1 - jnp.arange(rows)) % rows,
+                0, lb - 1)
+            for page, i in enumerate(self.full_layers):
+                k_full = put(k_full, page, kept[i][0])
+                v_full = put(v_full, page, kept[i][1])
+            for page, i in enumerate(self.window_layers):
+                k_ring = put(k_ring, page,
+                             jnp.take(kept[i][0], newest, axis=1))
+                v_ring = put(v_ring, page,
+                             jnp.take(kept[i][1], newest, axis=1))
+            last = lax.dynamic_slice_in_dim(h, length - 1, 1, axis=0)
         return (self._logits(params, last)[0], k_full, v_full, k_ring,
                 v_ring, routed)
 
@@ -505,7 +521,9 @@ class WindowMoELM:
                   for kind, (k, _) in slabs.items()}
         positions = jnp.minimum(positions, k_full.shape[3] - 1)
         alive = positions >= 0
-        h = _table_rows(params["embed"], tokens).astype(jnp.dtype(c.dtype))
+        with jax.named_scope("embed"):
+            h = _table_rows(params["embed"], tokens).astype(
+                jnp.dtype(c.dtype))
         chose = []
         for i, kind in enumerate(c.layer_types):
             mixed, *slabs[kind] = self._attention_step(
@@ -514,8 +532,9 @@ class WindowMoELM:
             h, local = self._mlp(params, i, h + mixed, alive)
             chose.append(local)
         (k_full, v_full), (k_ring, v_ring) = slabs[FULL], slabs[WINDOW]
-        routed = jnp.where(alive[:, None, None], jnp.stack(chose, axis=1),
-                           routed)
+        with jax.named_scope("cache.write"):
+            routed = jnp.where(alive[:, None, None],
+                               jnp.stack(chose, axis=1), routed)
         return (self._logits(params, h), k_full, v_full, k_ring, v_ring,
                 routed)
 

@@ -238,6 +238,16 @@ def _op_cache(name):
     return cache
 
 
+def _scoped(op, arrays, attrs):
+    """``op.fn`` under the op's device-side scope: every instruction it
+    lowers to carries the op's name in its ``op_name``. Called only where
+    the op is being traced into a program — an outer capture, or the first
+    call of its own one-op program — so a steady eager call never enters
+    it."""
+    with jax.named_scope(op.name):
+        return op.fn(*arrays, **attrs)
+
+
 def _jitted(name, frozen_attrs, backend):
     """One-op XLA computation, cached by (op, attrs); jax caches by shapes.
     This is the eager compile cache — the role CachedOp's signature check
@@ -246,7 +256,7 @@ def _jitted(name, frozen_attrs, backend):
     def build():
         op = _OPS[name]
         attrs = dict(frozen_attrs)
-        return jax.jit(lambda *arrays: op.fn(*arrays, **attrs))
+        return jax.jit(lambda *arrays: _scoped(op, arrays, attrs))
 
     return _op_cache("op_eager").get_or_build(
         (name, frozen_attrs, backend), build)
@@ -274,7 +284,7 @@ def _vjp_fwd_jitted(name, frozen_attrs):
     def build():
         op = _OPS[name]
         attrs = dict(frozen_attrs)
-        fn = lambda *arrays: op.fn(*arrays, **attrs)
+        fn = lambda *arrays: _scoped(op, arrays, attrs)
 
         def fwd(*arrays):
             out, vjp = jax.vjp(fn, *arrays)
@@ -320,8 +330,7 @@ def invoke_with_vjp(name, *arrays, **attrs):
         return out, _PyPullback(
             lambda cts: vjp1(cts) + tuple(None for _ in rest))
     if _in_trace(arrays):
-        fn = op.fn
-        return jax.vjp(lambda *a: fn(*a, **attrs), *arrays)
+        return jax.vjp(lambda *a: _scoped(op, a, attrs), *arrays)
     jfn = _vjp_fwd_jitted(op.name, _freeze(attrs))
     return jfn(*arrays)
 
@@ -331,7 +340,9 @@ def invoke_raw(name, *arrays, **attrs):
     op = get_op(name)
     if op.wrap_kwargs is not None:
         attrs = op.wrap_kwargs(attrs)
-    if _in_trace(arrays) or op.eager_only:
+    if _in_trace(arrays):
+        return _scoped(op, arrays, attrs)
+    if op.eager_only:
         return op.fn(*arrays, **attrs)
     jfn = _jitted(op.name, _freeze(attrs), None)
     return jfn(*arrays)

@@ -1397,7 +1397,11 @@ class GenerationEngine:
             # host events, so it is sound under the overlap order (the
             # prefill_us histogram below is not: see its record site)
             waited_us = (now - sess.stream.submitted_at) * 1e6
-            bucket = self.bucket_for(n - m if node is not None else n)
+            # the prompt tokens this admission prefills, and the bucket
+            # they are padded to: both are stats of the span below, so a
+            # profiler trace says its own prefill sizes
+            suffix = n - m if node is not None else n
+            bucket = self.bucket_for(suffix)
             t_pf = time.perf_counter()
             trc = tracing._enabled and sess.span is not None
             if trc:
@@ -1408,7 +1412,7 @@ class GenerationEngine:
                                   cat="generation", parent=sess.span)
             try:
                 with tracing.span("generation.prefill", cat="generation",
-                                  bucket=bucket, slot=slot,
+                                  bucket=bucket, tokens=suffix, slot=slot,
                                   waited_us=int(waited_us)):
                     if node is not None:
                         tok = self._fork_admit(sess, slot, node, m, bucket)
@@ -1446,7 +1450,7 @@ class GenerationEngine:
                 tracing.emit_span("generation.prefill", t_pf_us,
                                   tracing.now_us() - t_pf_us,
                                   cat="generation", parent=sess.span,
-                                  bucket=bucket, slot=slot,
+                                  bucket=bucket, tokens=suffix, slot=slot,
                                   cached_prefix=sess.prefix_len)
             sess.slot = sess.stream.slot = slot
             # pinned for the session's whole life: after a swap the tick

@@ -54,10 +54,15 @@ def _dispatch_node(node, env, key, train, nidx, gate=None):
         n_pos = len(ins) - len(names)
         kw_ins = dict(zip(names, ins[n_pos:]))
         ins = ins[:n_pos]
-    if op.needs_rng:
-        out = f(jax.random.fold_in(key, nidx), *ins, **kw_ins)
-    else:
-        out = f(*ins, **kw_ins)
+    # the node's device-side scope: every instruction it lowers to carries
+    # `<op>:<name>` in its op_name (jax wraps the backward's as
+    # `transpose(jvp(<op>:<name>))`). Entered while the graph is traced into
+    # a program, never per step
+    with jax.named_scope(f"{node.op}:{node.name}"):
+        if op.needs_rng:
+            out = f(jax.random.fold_in(key, nidx), *ins, **kw_ins)
+        else:
+            out = f(*ins, **kw_ins)
     outs = out if isinstance(out, (tuple, list)) else (out,)
     for i, o in enumerate(outs):
         env[(id(node), i)] = o
@@ -484,25 +489,28 @@ class Executor:
                     # the batch-sharded sum upstream the fsdp constraint
                     # lowers to ReduceScatter (parallel/spmd.py)
                     grads = spmd.constrain_grads(names, grads)
-                if zero1 is not None:
-                    # sharded weight update: grads constrained to the
-                    # dp-sharded flat buckets (sum+constraint lowers to
-                    # ReduceScatter), 1/N-shard optimizer step, weights
-                    # allgathered back replicated — or straight back to
-                    # the spmd layouts when both compose
-                    new_ws, new_ss = zero1.traced_update(
-                        opt, list(params), list(grads), ss,
-                        lrs_, wds_, rescale,
-                        unpack_shardings=(spmd.param_shardings(names)
-                                          if spmd is not None else None))
-                else:
-                    new_ws, new_ss = opt.fused_update(
-                        list(params), list(grads), ss, lrs_, wds_, rescale)
-                    if spmd is not None:
-                        # updated weights/state persist at the planned
-                        # layouts: donation aliases, residency stays 1/N
-                        new_ws = spmd.constrain_params(names, new_ws)
-                        new_ss = spmd.constrain_state_trees(names, new_ss)
+                with jax.named_scope("optimizer.update"):
+                    if zero1 is not None:
+                        # sharded weight update: grads constrained to the
+                        # dp-sharded flat buckets (sum+constraint lowers to
+                        # ReduceScatter), 1/N-shard optimizer step, weights
+                        # allgathered back replicated — or straight back to
+                        # the spmd layouts when both compose
+                        new_ws, new_ss = zero1.traced_update(
+                            opt, list(params), list(grads), ss,
+                            lrs_, wds_, rescale,
+                            unpack_shardings=(spmd.param_shardings(names)
+                                              if spmd is not None else None))
+                    else:
+                        new_ws, new_ss = opt.fused_update(
+                            list(params), list(grads), ss, lrs_, wds_,
+                            rescale)
+                        if spmd is not None:
+                            # updated weights/state persist at the planned
+                            # layouts: donation aliases, residency stays 1/N
+                            new_ws = spmd.constrain_params(names, new_ws)
+                            new_ss = spmd.constrain_state_trees(names,
+                                                                new_ss)
                 return outputs, tuple(new_ws), new_ss, aux_new
 
             # Donate exactly what will ALIAS (the hlolint donation audit
