@@ -41,7 +41,9 @@ decode's own program). Prefill attends in the form above (up-project,
 attend). Decode uses the ABSORBED form: `qc_h = q_nope,h W_uk,h^T`, scores
 `qc . c + q_rope . k_r` against the latent rows, the weighted sum of `c`
 rows, then `W_uv,h` — each cached row is read once for all heads
-(`ops/pallas_latent.py` on one TPU chip, else the same mathematics in XLA).
+(`ops/pallas_latent.py` on one TPU chip: the kernel also takes the tick's
+new row and puts it into the slabs; else the same mathematics in XLA, the
+row written by a `dynamic_update_slice` a slot).
 The model offers no `prefill_at` / `verify_step`, and `cache_traits` says
 so.
 """
@@ -55,6 +57,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import telemetry
 from ..parallel.spmd import model_mesh
 from . import experts, rotary
 from .transformer import _table_rows
@@ -343,30 +346,41 @@ class LatentMoELM:
     def _attention_step(self, params, i, u, cache_c, cache_kr, page,
                         positions, block):
         """One token a slot through layer `i`'s attention in the ABSORBED
-        form: `u` [S, D]; writes each live slot's latent row at its
-        position, attends rows `[0, position]`. Returns `(out [S, D],
-        cache_c, cache_kr)`."""
+        form: `u` [S, D]; each live slot's latent row goes in at its
+        position and the slot attends rows `[0, position]`. With a `block`
+        the kernel does both (`pallas_latent.latent_attend` takes the row,
+        merges it into the block that holds the position and sends one tile
+        back to each slab); without one (`block is None`: a mesh, no TPU
+        kernels, a shape the kernel refuses) XLA writes the row
+        (`_write_rows`) and attends every row under a mask — the reference
+        the kernel is held to, slabs bit for bit. Which way a layer's trace
+        went is counted (`mla.attend.kernel` / `.xla`, once a trace,
+        telemetry on). Returns `(out [S, D], cache_c, cache_kr)`."""
         from ..ops import pallas_attention as pa
         from ..ops import pallas_latent
 
         c = self.cfg
         q_nope, q_rope, lat, k_r = self._project(
             params, i, u, jnp.maximum(positions, 0))
-        with jax.named_scope("cache.write"):
-            cache_c = _write_rows(cache_c, page, positions, lat[:, None, :],
-                                  2)
-            cache_kr = _write_rows(cache_kr, page, positions,
-                                   k_r[:, :, None], 3)
         w_uk, w_uv = self._w_ukv(params, i)
         with jax.named_scope("mla.absorb"):
             qc = jnp.einsum("shd,rhd->shr", q_nope, w_uk)
-        with jax.named_scope("mla.attend"):
-            if block is not None:
-                o = pallas_latent.latent_attend(
-                    qc, q_rope, cache_c, cache_kr, jnp.int32(page), positions,
-                    block=block, scale=c.softmax_scale,
+        if telemetry._enabled:
+            telemetry.counter("mla.attend."
+                              + ("xla" if block is None else "kernel")).inc()
+        if block is not None:
+            with jax.named_scope("mla.attend"):
+                o, cache_c, cache_kr = pallas_latent.latent_attend(
+                    qc, q_rope, lat, k_r, cache_c, cache_kr, jnp.int32(page),
+                    positions, block=block, scale=c.softmax_scale,
                     interpret=pa.pallas_interpret())
-            else:
+        else:
+            with jax.named_scope("cache.write"):
+                cache_c = _write_rows(cache_c, page, positions,
+                                      lat[:, None, :], 2)
+                cache_kr = _write_rows(cache_kr, page, positions,
+                                       k_r[:, :, None], 3)
+            with jax.named_scope("mla.attend"):
                 o = _attend_latent(qc, q_rope, cache_c[:, page],
                                    cache_kr[:, page], positions,
                                    c.softmax_scale)
@@ -578,11 +592,13 @@ class LatentMoELM:
 
 
 def _write_rows(slab, layer, positions, rows, axis):
-    """`slab[s, layer, ...]` takes `rows[s]` at index `positions[s]` of
-    `axis` for every slot with a position >= 0, in place on a donated slab:
-    one dynamic_update_slice a slot, which XLA performs in the slab's own
-    layout (`transformer._write_rows`). A dead slot writes back what its
-    index 0 held."""
+    """The XLA formulation's row write (on one TPU chip the decode kernel
+    takes the row itself: `_attention_step`): `slab[s, layer, ...]` takes
+    `rows[s]` at index `positions[s]` of `axis` for every slot with a
+    position >= 0, in place on a donated slab: one dynamic_update_slice a
+    slot, which XLA performs in the slab's own layout
+    (`transformer._write_rows`). A dead slot writes back what its index 0
+    held."""
     for s in range(slab.shape[0]):
         at = [s, layer, 0, 0]
         at[axis] = jnp.maximum(positions[s], 0)

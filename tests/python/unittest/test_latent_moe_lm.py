@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from mxnet_tpu import parallel as par
+from mxnet_tpu import telemetry
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.models import LatentMoELM, LatentMoELMConfig
 from mxnet_tpu.models import experts, latent_moe
@@ -205,35 +206,163 @@ def test_absorbed_decode_equals_unabsorbed(tiny):
     assert not np.asarray(cache_c[0]).any()
 
 
-@pytest.mark.parametrize("alive", [[1, 1, 1, 1], [0, 1, 0, 1], [0, 0, 0, 0]])
-def test_latent_kernel_matches_restatement(alive):
-    """The Pallas kernel (interpreted) against the XLA formulation over a
-    slab whose rows past each position are NaN, at positions on, before
-    and after a block's edge."""
+def _bits(x):
+    """An array's bit patterns, so that equality also holds NaN to NaN."""
+    x = np.asarray(x)
+    return x.view({2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
+
+
+# a slot's position, by slot (-1: dead); block 128 of L = 256 unless a
+# length is given
+KERNEL_CASES = {
+    "first_row": ([0, 0, 3, 17], 1, "float32"),
+    "block_minus_1": ([127, 127, 126, 127], 1, "float32"),
+    "block": ([128, 128, 129, 128], 1, "float32"),
+    "last_row": ([255, 255, 254, 255], 1, "float32"),
+    "edges_together": ([127, 128, 5, 255], 1, "float32"),
+    "five_rows_into_a_poisoned_block": ([133, 5, 133, 5], 1, "float32"),
+    "all_dead": ([-1, -1, -1, -1], 1, "float32"),
+    "one_dead_among_live": ([127, -1, 133, 255], 1, "float32"),
+    "two_dead_among_live": ([-1, 128, -1, 255], 1, "float32"),
+    "layer_0": ([127, 128, -1, 255], 0, "float32"),
+    "layer_2_of_3": ([0, 200, 16, -1], 2, "float32"),
+    "bf16_tiles": ([127, 128, 5, 255], 1, "bfloat16"),
+    "bf16_dead_and_edges": ([-1, 15, 16, 143], 0, "bfloat16"),
+    # blocks of 512 fetched a quarter (128 rows) a copy, L = 1,024: the last
+    # unit is fetched and scored as far as the position
+    "pieces_of_the_first_unit": ([0, 127, 128, 511], 1, "float32", 1024),
+    "pieces_of_the_second_unit": ([512, 639, 640, 1023], 1, "float32",
+                                  1024),
+    "pieces_dead_and_poisoned": ([-1, 700, 383, -1], 2, "float32", 1024),
+    "pieces_bf16": ([511, 512, 900, 5], 0, "bfloat16", 1024),
+}
+
+
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_latent_kernel_matches_restatement(case):
+    """The Pallas kernel (interpreted) against the XLA formulation
+    (`_write_rows`, then `_attend_latent`) on slabs whose rows AT and past
+    each position are what a previous occupant left — NaN in `c`, inf in
+    `k_r` — at positions on, before and after a block's edge, under a traced
+    layer index: (i) the attention, (ii) both slabs bit for bit, everywhere
+    — the other rows and lanes of the written tile, every other layer and
+    slot — and (iii) a dead slot's page as it was."""
+    positions, layer, dtype, *length = KERNEL_CASES[case]
     rng = np.random.default_rng(5)
-    slots, layers, length, rank, rope, heads = 4, 2, 256, 128, 8, 4
+    slots, layers, rank, rope, heads = 4, 3, 128, 8, 4
+    length, = length or [256]
     block = pallas_latent.latent_block((slots, layers, length, rank),
-                                       jnp.float32, target=128)
-    assert block == 128
-    positions = np.where(alive, [127, 128, 5, 255], -1).astype(np.int32)
+                                       dtype, target=length // 2)
+    assert block == length // 2
+    positions = np.asarray(positions, np.int32)
     slab_c = rng.normal(size=(slots, layers, length, rank)).astype("f4")
     slab_kr = rng.normal(size=(slots, layers, rope, length)).astype("f4")
     for s, p in enumerate(positions):
-        slab_c[s, :, max(p, 0) + 1:] = np.nan
-        slab_kr[s, :, :, max(p, 0) + 1:] = np.nan
+        slab_c[s, :, max(p, 0):] = np.nan
+        slab_kr[s, :, :, max(p, 0):] = np.inf
+    slab_c, slab_kr = jnp.asarray(slab_c, dtype), jnp.asarray(slab_kr, dtype)
     qc = jnp.asarray(rng.normal(size=(slots, heads, rank)), jnp.float32)
     qr = jnp.asarray(rng.normal(size=(slots, heads, rope)), jnp.float32)
-    want = latent_moe._attend_latent(
-        qc, qr, jnp.asarray(slab_c[:, 1]), jnp.asarray(slab_kr[:, 1]),
-        jnp.asarray(positions), 0.2)
-    got = pallas_latent.latent_attend(
-        qc, qr, jnp.asarray(slab_c), jnp.asarray(slab_kr), jnp.int32(1),
-        jnp.asarray(positions), block=block, scale=0.2, interpret=True)
+    lat = jnp.asarray(rng.normal(size=(slots, rank)), jnp.float32)
+    k_r = jnp.asarray(rng.normal(size=(slots, rope)), jnp.float32)
+    at = jnp.asarray(positions)
+    want_c = latent_moe._write_rows(slab_c, layer, at, lat[:, None, :], 2)
+    want_kr = latent_moe._write_rows(slab_kr, layer, at, k_r[:, :, None], 3)
+    want = latent_moe._attend_latent(qc, qr, want_c[:, layer],
+                                     want_kr[:, layer], at, 0.2)
+
+    @jax.jit
+    def kernel(layer):                      # the layer is traced
+        return pallas_latent.latent_attend(
+            qc, qr, lat, k_r, slab_c, slab_kr, layer, at, block=block,
+            scale=0.2, interpret=True)
+
+    got, got_c, got_kr = kernel(jnp.int32(layer))
     assert np.isfinite(np.asarray(got)).all()
-    if any(alive):
-        _close(got, want, "latent kernel")
-    else:
-        assert not np.asarray(got).any()
+    if (positions >= 0).any():
+        _close(got, want, "latent kernel",
+               TOL if dtype == "float32" else 1e-2)
+    assert not np.asarray(got)[positions < 0].any()
+    assert np.array_equal(_bits(got_c), _bits(want_c))
+    assert np.array_equal(_bits(got_kr), _bits(want_kr))
+    dead = positions < 0
+    assert np.array_equal(_bits(got_c)[dead], _bits(slab_c)[dead])
+    assert np.array_equal(_bits(got_kr)[dead], _bits(slab_kr)[dead])
+    for s in np.flatnonzero(~dead):         # ... and the row did go in
+        assert np.array_equal(_bits(got_c[s, layer, positions[s]]),
+                              _bits(lat[s].astype(dtype)))
+        assert np.array_equal(_bits(got_kr[s, layer, :, positions[s]]),
+                              _bits(k_r[s].astype(dtype)))
+
+
+def test_decode_step_kernel_and_xla_leave_the_same_cache(tiny, monkeypatch):
+    """`decode_step` through the kernel (interpreted) and through the XLA
+    formulation over four ticks that cross a block's edge (4,094 -> 4,097
+    with blocks of 2,048), one slot deep in its first block, one dead: the
+    same argmax every tick, and the same cache — the first layer's rows,
+    whose inputs are the token's alone, bit for bit; the later layers' to
+    rounding (their inputs carry the attention before them, which the two
+    sum in different orders); the dead slot's page untouched."""
+    _, params, _ = tiny
+    slots, max_len = 3, 8192
+    lm = _model(dict(CONFIG, max_position_embeddings=max_len))
+    rng = np.random.default_rng(11)
+    start = np.asarray([4094, -1, 4], np.int32)
+    cache = lm.init_cache(slots, max_len)
+    filled = []
+    for member in cache[:2]:
+        rows = rng.normal(size=member.shape).astype("f4") * 0.5
+        filled.append(rows)
+    for s, p in enumerate(start):
+        filled[0][s, :, max(p, 0):] = np.nan
+        filled[1][s, :, :, max(p, 0):] = np.nan
+    before = (jnp.asarray(filled[0]), jnp.asarray(filled[1]), cache[2])
+    tokens = rng.integers(0, VOCAB, (4, slots)).astype(np.int32)
+
+    def run(kernel):
+        monkeypatch.setenv("MXNET_PALLAS_ATTENTION", "1" if kernel else "0")
+        monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
+        assert lm.decode_block(before[0].shape, before[0].dtype) \
+            == (2048 if kernel else None)
+        step = jax.jit(lm.decode_step)
+        cache, picked = before, []
+        for t, toks in enumerate(tokens):
+            positions = np.where(start >= 0, start + t, -1).astype(np.int32)
+            logits, *cache = step(params, *cache, jnp.asarray(toks),
+                                  jnp.asarray(positions))
+            picked.append(np.asarray(jnp.argmax(logits, -1)))
+        return np.stack(picked), tuple(cache)
+
+    went = {k: telemetry.counter("mla.attend." + k)
+            for k in ("kernel", "xla")}
+    was = telemetry.enabled()
+    telemetry.enable()
+    try:
+        # once a layer a trace (jit may trace a step more than once)
+        count = {k: c.value for k, c in went.items()}
+        picked_k, cache_k = run(True)
+        assert went["kernel"].value - count["kernel"] \
+            >= lm.cfg.num_hidden_layers
+        assert went["xla"].value == count["xla"]
+        count = {k: c.value for k, c in went.items()}
+        picked_x, cache_x = run(False)
+        assert went["xla"].value - count["xla"] >= lm.cfg.num_hidden_layers
+        assert went["kernel"].value == count["kernel"]
+    finally:
+        telemetry.enable(was)
+    live = start >= 0
+    assert np.array_equal(picked_k[:, live], picked_x[:, live])
+    for got, want, was_ in zip(cache_k[:2], cache_x[:2], before[:2]):
+        assert np.array_equal(_bits(got[:, 0]), _bits(want[:, 0]))
+        assert np.array_equal(np.isnan(got), np.isnan(want))
+        _close(np.nan_to_num(got), np.nan_to_num(want), "cache rows", 1e-5)
+        assert np.array_equal(_bits(got[1]), _bits(was_[1]))
+    assert np.array_equal(cache_k[2], cache_x[2])
+    for s in np.flatnonzero(live):          # the four rows went in
+        assert np.isfinite(np.asarray(
+            cache_k[0][s, :, start[s]:start[s] + 4])).all()
+        assert np.isfinite(np.asarray(
+            cache_k[1][s, :, :, start[s]:start[s] + 4])).all()
 
 
 def test_prefill_kernel_matches_restatement():
