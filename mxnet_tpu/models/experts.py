@@ -1,7 +1,9 @@
 """The dropless expert layer that the expert models share
 (`latent_moe.LatentMoELM`, `window_moe.WindowMoELM`): the model routes —
 its own router, passed in — and this module computes the chosen experts it
-is told it holds.
+is told it holds. The two published routers (`softmax_route`,
+`sigmoid_route`) and the SiLU-gated MLP of a dense layer or a shared expert
+(`gated_mlp`) are here too, once, for the models that use them.
 
 **Dropless**: there is no capacity. The (token, expert) assignments are
 sorted by expert and the experts' two matmuls are grouped products over the
@@ -23,7 +25,8 @@ from jax import lax
 from .. import telemetry
 
 __all__ = ["expert_layer", "grouped_product", "gmm_tiling",
-           "routing_counters"]
+           "routing_counters", "sigmoid_route", "softmax_route",
+           "gated_mlp"]
 
 # tokens an expert layer groups at once in a prefill: bounds the sorted
 # copies (top_k rows a token) whatever the bucket
@@ -69,6 +72,50 @@ def gmm_tiling(m, k, n, itemsize):
     tn = divisor(n, widest)
     tk = divisor(k, max(budget // (tn * itemsize), _LANES))
     return tm, tk, tn
+
+
+def gated_mlp(x, w_in, w_out):
+    """The SiLU-gated MLP `(silu(x W_gate) * x W_up) W_down` of a dense
+    layer or a shared expert: `w_in` [D, 2 F] is gate | up fused along the
+    output axis, `w_out` [F, D]."""
+    g, v = jnp.split(x @ w_in, 2, axis=-1)
+    return (jax.nn.silu(g) * v) @ w_out
+
+
+def softmax_route(x, router, top_k, normalise=True, scale=1.0):
+    """`x` [T, D] -> `(chosen [T, k] expert ids of the whole router, weights
+    [T, k] float32)`: softmax probabilities in float32 over all the experts,
+    the `top_k` largest, normalised over the selection when `normalise`
+    (`norm_topk_prob`), times `scale` where it is not 1."""
+    with jax.named_scope("moe.route"):
+        p = jax.nn.softmax(jnp.dot(
+            x.astype(jnp.float32), router,
+            precision=lax.Precision.HIGHEST), axis=-1)
+        weights, chosen = lax.top_k(p, top_k)
+        if normalise:
+            weights = weights / weights.sum(-1, keepdims=True)
+        if scale != 1.0:
+            weights = weights * scale
+    return chosen, weights
+
+
+def sigmoid_route(x, router, bias, top_k, scale, normalise=True, eps=0.0):
+    """`x` [T, D] -> `(chosen [T, k] expert ids of the whole router, weights
+    [T, k] float32)`: sigmoid scores `s` in float32 over all the experts;
+    the `top_k` with the largest `s + bias` (the bias enters the selection
+    only); weights `s_e / (sum_chosen s + eps) * scale`, the division left
+    out without `normalise`."""
+    with jax.named_scope("moe.route"):
+        s = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), router,
+            precision=lax.Precision.HIGHEST))
+        _, chosen = lax.top_k(s + bias, top_k)
+        weights = jnp.take_along_axis(s, chosen, axis=-1)
+        if normalise:
+            total = weights.sum(-1, keepdims=True)
+            weights = weights / (total + eps if eps else total)
+        weights = weights * scale
+    return chosen, weights
 
 
 def grouped_product(rows, weights, sizes, mesh):

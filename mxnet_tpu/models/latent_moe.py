@@ -390,25 +390,17 @@ class LatentMoELM:
             return a.reshape(u.shape[0], -1) @ params[f"l{i}.wo"], \
                 cache_c, cache_kr
 
-    def _gated(self, x, w_in, w_out):
-        g, v = jnp.split(x @ w_in, 2, axis=-1)
-        return (jax.nn.silu(g) * v) @ w_out
+    _gated = staticmethod(experts.gated_mlp)
 
     def _route(self, params, i, x):
         """`x` [T, D] -> `(chosen [T, k] expert ids of the whole router,
-        weights [T, k] float32)`: sigmoid scores in float32, selection by
-        `s + b`, weights from `s` normalised over the selection."""
+        weights [T, k] float32)`: `experts.sigmoid_route` — sigmoid scores
+        in float32, selection by `s + b`, weights from `s` normalised over
+        the selection."""
         c = self.cfg
-        with jax.named_scope("moe.route"):
-            s = jax.nn.sigmoid(jnp.dot(
-                x.astype(jnp.float32), params[f"l{i}.router"],
-                precision=lax.Precision.HIGHEST))
-            _, chosen = lax.top_k(s + params[f"l{i}.router_bias"],
-                                  c.num_experts_per_tok)
-            picked = jnp.take_along_axis(s, chosen, axis=-1)
-            weights = picked / picked.sum(-1, keepdims=True) \
-                * c.routed_scaling_factor
-        return chosen, weights
+        return experts.sigmoid_route(
+            x, params[f"l{i}.router"], params[f"l{i}.router_bias"],
+            c.num_experts_per_tok, c.routed_scaling_factor)
 
     def _experts(self, params, i, x, real):
         """The held experts' part of the expert layer for `x` [T, D]:

@@ -29,8 +29,10 @@ output aliased to the input — 16 rows around it, one packed bfloat16 tile —
 so there is no XLA scatter or ``dynamic-update-slice`` on the slab. The
 ``G`` query heads of a K/V head are scored as ONE ``[G, 128] x [128,
 block]`` product on the MXU and summed as one ``[G, block] x [block, 128]``:
-8 FLOPs a cache byte at ``G`` = 8, far under the chip's ridge (240), so the
-rows' bytes bind.
+``G`` FLOPs a cache byte (8 at mellum's 32:4 heads, 6 at Trinity's 48:8; any
+``G``: Mosaic takes a ``[6, 128]`` tile as it takes ``[8, 128]``), far under
+the chip's ridge (240), so the rows' bytes bind. The block over the rows
+follows the heads (:func:`kv_block`: 1,024 rows at 4 K/V heads, 512 at 8).
 
 **The prefill** (:func:`band_prefill_attend`, kernel `swa_prefill_attend`):
 causal attention of one whole sequence with an optional window, grouped

@@ -598,3 +598,201 @@ def test_window_moe_prefill_program_compiles_for_v5e(one_chip, monkeypatch,
     live = ma.argument_size_in_bytes + ma.temp_size_in_bytes \
         + ma.output_size_in_bytes - ma.alias_size_in_bytes
     assert live < 9e9, live         # one period of two: 5.5 GB resident
+
+
+# ---------------------------------------------------------------------------
+# the same model as the afmoe block at Trinity-Large-Preview's widths (ISSUE
+# 39): 8 K/V heads with 6 queries each, rings of 4,096, experts of 3072 x 3072
+# ---------------------------------------------------------------------------
+
+def test_kv_block_halves_for_eight_heads():
+    """`kv_block`'s budget (the K and V blocks of every head, double
+    buffered, in 4 MiB) gives 8 K/V heads a block of 512 rows where 4 heads
+    take 1,024: a full member of 16,384 rows is 32 grid steps a slot, a ring
+    of 4,096 is 8."""
+    from mxnet_tpu.ops import pallas_window
+
+    assert pallas_window.kv_block((32, 1, 8, 16384, 128), jnp.bfloat16) == 512
+    assert pallas_window.kv_block((32, 4, 8, 4096, 128), jnp.bfloat16) == 512
+    assert pallas_window.kv_block((32, 1, 4, 16384, 128),
+                                  jnp.bfloat16) == 1024
+    assert pallas_window.band_block(16384, 4096) == 1024
+    assert pallas_window.band_steps(16384, 1024, 4096) == 5
+
+
+@pytest.mark.parametrize("slab", [(32, 1, 8, 16384, 128),
+                                  (32, 4, 8, 4096, 128)],
+                         ids=["full-16384", "rings-4096"])
+def test_kv128_attend_compiles_at_six_queries_a_head_for_v5e(one_chip, slab):
+    """The decode kernel at 8 K/V heads x 6 query heads (a `[6, 128]` query
+    tile: neither a power of two nor a packed bfloat16 tile) over both of
+    Trinity's members."""
+    from mxnet_tpu.ops import pallas_window
+
+    block = pallas_window.kv_block(slab, jnp.bfloat16)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(q, k, v, slab_k, slab_v, positions):
+        return pallas_window.kv_update_attend(
+            q, k, v, slab_k, slab_v, jnp.int32(0), positions, block=block,
+            scale=128 ** -0.5)
+
+    text = jax.jit(fn, donate_argnums=(3, 4)).lower(
+        sds((32, 48, 128)), sds((32, 8, 128)), sds((32, 8, 128)), sds(slab),
+        sds(slab), sds((32,), jnp.int32)).compile().as_text()
+    assert len(re.findall(r"%kv128_attend[.\d]* = ", text)) == 1
+
+
+@pytest.mark.parametrize("window", [4096, None])
+@pytest.mark.parametrize("length", [2048, 4096, 8192, 16384])
+def test_swa_prefill_attend_compiles_at_48_heads_for_v5e(one_chip, length,
+                                                         window):
+    """The prefill attention kernel at 48 query / 8 K/V heads for every
+    bucket of the cell, over the band of 4,096 and causal."""
+    from mxnet_tpu.ops import pallas_window
+
+    block = pallas_window.band_block(length, window)
+    assert block == 1024
+
+    def sds(heads):
+        return jax.ShapeDtypeStruct((heads, length, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    text = _compiled_text(
+        lambda q, k, v: pallas_window.band_prefill_attend(
+            q, k, v, block=block, scale=128 ** -0.5, window=window),
+        sds(48), sds(8), sds(8))
+    assert "swa_prefill_attend" in text
+
+
+@pytest.mark.parametrize("rows", [128, 16384])
+@pytest.mark.parametrize("n", [6144, 3072])
+def test_grouped_matmul_compiles_at_k_3072_for_v5e(one_chip, monkeypatch,
+                                                   rows, n):
+    """The grouped product over 32 held experts of 3072 x 6144 (gate | up)
+    and 3072 x 3072 (down) for a tick's 128 rows and a prefill chunk's
+    16,384: the Pallas `gmm`, tiled from the shapes, not `lax.ragged_dot`."""
+    from mxnet_tpu import parallel as par
+    from mxnet_tpu.models import experts
+
+    monkeypatch.setenv("MXNET_PALLAS_ATTENTION", "1")
+    monkeypatch.delenv("MXNET_PALLAS_INTERPRET", raising=False)
+    assert experts.gmm_tiling(rows, 3072, n, 2) == (
+        (128, 512, 3072) if rows == 128 else (256, 1024, 1024))
+    mesh = par.create_mesh(devices=[next(iter(one_chip.device_set))], dp=1)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = _compiled_text(
+        lambda x, w, sizes: experts.grouped_product(x, w, sizes, mesh),
+        sds((rows, 3072)), sds((32, 3072, n)), sds((32,), jnp.int32))
+    assert "%gmm" in text and "ragged-dot" not in text
+
+
+@pytest.fixture(scope="module")
+def trinity_share(one_chip):
+    """`(lm, params, cache)` of `trinity_large_ep8` as the benchmark runs it
+    — 5 layers, 32 of 256 experts, 25,024 vocabulary rows, 32 slots x 16,384
+    positions — as shapes on the described chip."""
+    import json
+
+    from mxnet_tpu import parallel as par
+    from mxnet_tpu.models import WindowMoELM, WindowMoELMConfig
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "trinity_large_ep8.json")) as f:
+        published = json.load(f)
+    assert len(published["layer_types"]) == 60      # carried whole
+    config = WindowMoELMConfig.from_config(published, max_len=16384)
+    assert config.layer_types == ("sliding_attention",) * 3 \
+        + ("full_attention", "sliding_attention")
+    assert (config.num_experts, config.experts_held, config.expert_first,
+            config.num_dense_layers) == (256, 32, 0, 1)
+    dev = next(iter(one_chip.device_set))
+    lm = WindowMoELM(config, par.create_mesh(devices=[dev], dp=1))
+    host_lm = WindowMoELM(config, par.create_mesh(devices=jax.devices()[:1],
+                                                  dp=1))
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = {k: sds(v) for k, v in jax.eval_shape(
+        host_lm.init_params, jax.random.PRNGKey(0)).items()}
+    assert params["l0.wqkv"].shape == (3072, 14336)     # q | k | v | gate
+    cache = tuple(sds(v) for v in jax.eval_shape(
+        lambda: host_lm.init_cache(32, 16384)))
+    assert [c.shape for c in cache] == [
+        (32, 1, 8, 16384, 128), (32, 1, 8, 16384, 128),
+        (32, 4, 8, 4096, 128), (32, 4, 8, 4096, 128), (32, 4, 4)]
+    return lm, params, cache
+
+
+def test_afmoe_decode_program_compiles_for_v5e(one_chip, monkeypatch,
+                                               trinity_share):
+    """The engine's decode program of the afmoe block: the slab kernel once
+    a layer (four rings and the full member) at 6 queries a head, the
+    grouped matmul twice an EXPERT layer (none in the dense one), every
+    member of the cache aliased input to output, no XLA op on a page."""
+    lm, params, cache = trinity_share
+    monkeypatch.setenv("MXNET_PALLAS_ATTENTION", "1")
+    monkeypatch.delenv("MXNET_PALLAS_INTERPRET", raising=False)
+    assert lm.decode_block(cache[0].shape, cache[0].dtype) == 512
+    assert lm.decode_block(cache[2].shape, cache[2].dtype) == 512
+
+    def fn(params, cache, tokens, positions):       # the engine's wrapper
+        logits, *cache = lm.decode_step(params, *cache, tokens, positions)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), tuple(cache)
+
+    ints = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, ints, ints).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%kv128_attend[.\d]* = ", text)) == 5
+    assert len(re.findall(r"%gmm[.\d]* = ", text)) == 8
+    assert "ragged-dot" not in text
+    for scope in ("attn.gate", "attn.qknorm", "moe.shared", "/mlp/"):
+        assert scope in text, scope
+    page = 32 * 8 * 4096 * 128                      # a ring's page
+    assert _page_sized_ops(text, page) == []
+    ma = compiled.memory_analysis()
+    cache_bytes = sum(int(np.prod(c.shape)) * c.dtype.itemsize
+                      for c in cache)
+    assert ma.alias_size_in_bytes >= cache_bytes    # all five, whole
+    assert ma.temp_size_in_bytes < page * 2
+    # what stands on the chip: 8.65 GB of weights + 4.29 GB of cache
+    assert 12.9e9 < ma.argument_size_in_bytes < 13.0e9
+
+
+def test_afmoe_prefill_program_compiles_for_v5e(one_chip, monkeypatch,
+                                                trinity_share):
+    """The engine's 16,384-token prefill program of the afmoe block: the
+    band kernel once a layer (a window layer's grid holds 5 key blocks of
+    1,024 a query block), the grouped matmul over chunks of 4,096 tokens,
+    the dense MLP and the shared expert a chunk at a time; its temporaries
+    fit beside the 12.95 GB that stand."""
+    lm, params, cache = trinity_share
+    monkeypatch.setenv("MXNET_PALLAS_ATTENTION", "1")
+    monkeypatch.delenv("MXNET_PALLAS_INTERPRET", raising=False)
+    assert lm.prefill_block(16384, 4096) == 1024
+    assert lm.prefill_block(16384) == 1024
+
+    def fn(params, cache, toks, length, slot):      # the engine's wrapper
+        logits, *cache = lm.prefill(params, *cache, toks, length, slot)
+        return jnp.argmax(logits).astype(jnp.int32), tuple(cache)
+
+    toks = jax.ShapeDtypeStruct((16384,), jnp.int32, sharding=one_chip)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, toks, scalar, scalar).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%swa_prefill_attend[.\d]* = ", text)) == 5
+    assert "%gmm" in text and "ragged-dot" not in text
+    ma = compiled.memory_analysis()
+    live = ma.argument_size_in_bytes + ma.temp_size_in_bytes \
+        + ma.output_size_in_bytes - ma.alias_size_in_bytes
+    assert live < 15.0e9, live      # 12.95 GB stand; temporaries 1.7 GB

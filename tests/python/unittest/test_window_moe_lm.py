@@ -8,7 +8,15 @@ model is float32 here, so it agrees with the float32 reference to rounding:
 every tolerance is 1e-4 of the compared quantity's scale. The bfloat16 model
 at the published widths is compared on the chip
 (benchmark/runners/serve_swa_moe.py).
+
+The second half holds the same model built as the `afmoe` block
+(Trinity-Large-Preview: an output gate, per-head norms, four norms a layer,
+unrotated full layers, a leading dense layer, a sigmoid router with a
+selection bias beside a shared expert, 3 query heads a K/V head) against
+`benchmark/reference/trinity_afmoe.py`, and shows of each part of that block
+that the comparison fails when it is left out.
 """
+import dataclasses
 import os
 import sys
 
@@ -30,9 +38,11 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))))
 sys.path[:0] = [os.path.join(REPO, "benchmark")]
 from reference import mellum_swa_moe as ref  # noqa: E402
-from runners import serve_swa_moe  # noqa: E402
+from reference import trinity_afmoe as aref  # noqa: E402
+from runners import serve_afmoe, serve_swa_moe  # noqa: E402
 
 ref.PAD_TO = ref.BLOCK = 16     # the chip's sizes would spend these tiny tests on padding
+aref.PAD_TO = aref.BLOCK = 16
 
 TOL = 1e-4
 VOCAB = 211
@@ -390,6 +400,10 @@ def test_grouped_kernel_is_the_plain_grouped_product(tiny, monkeypatch):
     (256, 896, 2304, (128, 896, 2304)),
     (32768, 2304, 1792, (256, 1152, 896)),
     (32768, 896, 2304, (256, 896, 768)),
+    (128, 3072, 6144, (128, 512, 3072)),        # ... and K = 3072 (PR 39):
+    (128, 3072, 3072, (128, 512, 3072)),        # a tick's 32 x 4 pairs,
+    (16384, 3072, 6144, (256, 1024, 1024)),     # a prefill chunk's 4,096 x 4
+    (16384, 3072, 3072, (256, 1024, 1024)),
     (100, 64, 64, None),                        # no whole row tile
 ])
 def test_grouped_tiles_follow_the_shapes(m, k, n, want):
@@ -419,6 +433,21 @@ def test_from_config_refuses_what_the_block_cannot_express():
     with pytest.raises(ValueError, match="held experts"):
         _model(dict(CONFIG, num_experts=4, published={"num_experts": 8},
                     share={"expert_first": 6}))
+    # the afmoe keys
+    for key, value in (("n_group", 2), ("topk_group", 2),
+                       ("num_expert_groups", 4), ("num_limited_groups", 2),
+                       ("num_shared_experts", 2), ("score_func", "tanh"),
+                       ("rope_scaling", {"type": "yarn", "factor": 4}),
+                       ("num_dense_layers", 6), ("hidden_act", "gelu"),
+                       ("attention_bias", True)):
+        with pytest.raises(ValueError, match=key):
+            WindowMoELMConfig.from_config(dict(AFMOE, **{key: value}))
+    with pytest.raises(ValueError, match="mlp_layer_types"):
+        WindowMoELMConfig.from_config(dict(
+            AFMOE, mlp_layer_types=["sparse"] * 5))
+    assert WindowMoELMConfig.from_config(dict(
+        AFMOE, mlp_layer_types=["dense"] + ["sparse"] * 4)) \
+        == WindowMoELMConfig.from_config(AFMOE)
 
 
 # -- through GenerationEngine ------------------------------------------------
@@ -614,10 +643,11 @@ def test_counters_cost_nothing_with_telemetry_off(tiny):
         eng.close()
 
 
-def test_nothing_compiles_after_warm_up(tiny):
+@pytest.mark.parametrize("family", ["tiny", "afmoe"])
+def test_nothing_compiles_after_warm_up(request, family):
     from jax import monitoring
 
-    lm, params, _ = tiny
+    lm, params, _ = request.getfixturevalue(family)
     compiles = []
 
     def listener(event, duration, **_):
@@ -639,3 +669,329 @@ def test_nothing_compiles_after_warm_up(tiny):
         eng.close()
     finally:
         monitoring.unregister_event_duration_listener(listener)
+
+
+# ===========================================================================
+# the afmoe block (Trinity-Large-Preview) in the same class
+# ===========================================================================
+
+# the published keys at a tiny size: one period and a layer more, 1 dense
+# layer, 3 query heads a K/V head (not a power of two), a window of 8
+AFMOE = dict(
+    vocab_size=VOCAB, hidden_size=64, num_hidden_layers=5,
+    num_attention_heads=6, num_key_value_heads=2, head_dim=16,
+    intermediate_size=128, moe_intermediate_size=32, num_dense_layers=1,
+    num_experts=8, num_experts_per_tok=2, num_shared_experts=1,
+    score_func="sigmoid", route_norm=True, route_scale=2.448, n_group=1,
+    topk_group=1, num_expert_groups=1, num_limited_groups=1,
+    mup_enabled=True, rms_norm_eps=1e-5, rope_theta=10000, rope_scaling=None,
+    sliding_window=8, global_attn_every_n_layers=4,
+    layer_types=[WINDOW, WINDOW, WINDOW, FULL] * 2,
+    max_position_embeddings=256, dtype="float32", hidden_act="silu",
+    tie_word_embeddings=False, model_type="afmoe")
+AFMOE_KERNEL = dict(AFMOE, head_dim=128, sliding_window=128,
+                    max_position_embeddings=512)
+
+
+def _built_afmoe(config):
+    lm = _model(config)
+    params = lm.init_params(jax.random.PRNGKey(0))
+    return lm, params, serve_afmoe.published(params, config)
+
+
+@pytest.fixture(scope="module")
+def afmoe():
+    return _built_afmoe(AFMOE)
+
+
+@pytest.fixture(scope="module")
+def afmoe_wide():
+    return _built_afmoe(AFMOE_KERNEL)
+
+
+def test_afmoe_builds_what_the_block_names(afmoe):
+    lm, params, weights = afmoe
+    c = lm.cfg
+    assert (c.attention_gate, c.qk_norm, c.post_norms, c.mup_enabled) \
+        == (True,) * 4
+    assert c.rope_full is None and dict(c.rope_window)["rope_theta"] == 1e4
+    assert (c.num_dense_layers, c.n_expert_layers, c.score_func) \
+        == (1, 4, "sigmoid")
+    assert lm.full_layers == (3,) and lm.window_layers == (0, 1, 2, 4)
+    assert params["l0.wqkv"].shape == (64, (6 + 2 * 2 + 6) * 16)
+    assert params["l0.w_in"].shape == (64, 256) and "l0.router" not in params
+    assert params["l1.router_bias"].dtype == jnp.float32
+    assert {n.rpartition(".")[2] for n in params if n.startswith("l1.")} == {
+        "norm1", "norm1_post", "norm2", "norm2_post", "wqkv", "wo", "q_norm",
+        "k_norm", "router", "router_bias", "experts_in", "experts_out",
+        "shared_in", "shared_out"}
+    assert "layers.1.self_attn.gate_proj.weight" in weights
+    assert [m.shape for m in lm.init_cache(3, 64)] == [
+        (3, 1, 2, 64, 16), (3, 1, 2, 64, 16), (3, 4, 2, 8, 16),
+        (3, 4, 2, 8, 16), (3, 4, 2)]
+
+
+def test_a_config_without_the_new_keys_builds_what_it_built(tiny):
+    """mellum's configuration: the parameter names and shapes of before this
+    block existed, and none of its scopes in the lowered decode; the afmoe
+    block's decode names them."""
+    lm, params, _ = tiny
+    assert {n.rpartition(".")[2] for n in params if n.startswith("l")} == {
+        "norm1", "norm2", "wqkv", "wo", "router", "experts_in",
+        "experts_out"}
+    assert params["l0.wqkv"].shape == (64, (4 + 2 * 2) * 16)
+    assert len(params) == 8 * 7 + 3
+
+    def lowered(model, weights):
+        cache = model.init_cache(2, 64)
+        ints = jnp.zeros(2, jnp.int32)
+        return jax.jit(model.decode_step).lower(
+            weights, *cache, ints, ints).as_text(debug_info=True)
+
+    new = ("attn.gate", "attn.qknorm", "moe.shared", "/mlp/")
+    text = lowered(lm, params)
+    assert "attn.rotary" in text and not [n for n in new if n in text]
+    alm = _model(AFMOE)
+    text = lowered(alm, alm.init_params(jax.random.PRNGKey(0)))
+    assert not [n for n in new if n not in text]
+
+
+@pytest.mark.parametrize("length", [7, 16, 29, 40])
+def test_afmoe_forward_matches_reference(afmoe, length):
+    lm, params, weights = afmoe
+    seq = _tokens(length)
+    want = aref.logits(AFMOE, weights, seq, np.arange(length))
+    _close(lm.forward(params, seq[None])[0], want, "logits")
+
+
+def _biased_weights(x, router, bias, top_k, scale, normalise=True, eps=0.0):
+    """`experts.sigmoid_route` with the fault of weighing by `s + b`."""
+    s = jax.nn.sigmoid(x.astype(jnp.float32) @ router) + bias
+    weights, chosen = jax.lax.top_k(s, top_k)
+    return chosen, weights / weights.sum(-1, keepdims=True) * scale
+
+
+@pytest.mark.parametrize("what,change", [
+    ("the gate left out", dict(attention_gate=False)),
+    ("rotary applied to a full layer",
+     dict(rope_full=(("rope_theta", 10000.0), ("rope_type", "default")))),
+    ("rotary left off a window layer", dict(rope_window=None)),
+    ("a head norm left out", dict(qk_norm=False)),
+    ("a post-norm left out", dict(post_norms=False)),
+    ("route_scale left out", dict(route_scale=1.0)),
+    ("mup left out", dict(mup_enabled=False)),
+    ("the shared expert not at all", dict(num_shared_experts=0)),
+    ("the window off by one", dict(sliding_window=7)),
+    ("the shared expert counted twice", "shared"),
+    ("the bias entering the weights", "bias"),
+    ("a head norm's weight ignored", "q_norm"),
+    ("a post-norm's weight ignored", "norm1_post"),
+])
+def test_the_comparison_sees_each_part_of_the_afmoe_block(afmoe, monkeypatch,
+                                                          what, change):
+    """The comparison this file and the benchmark make is tight enough to
+    fail for each part of the block left out or misapplied. The norm
+    weights, 1 as drawn, are scaled here so that a weight ignored shows."""
+    lm, params, _ = afmoe
+    params = {k: v * 1.5 if "norm" in k else v for k, v in params.items()}
+    weights = serve_afmoe.published(params, AFMOE)
+    seq = _tokens(40)
+    want = aref.logits(AFMOE, weights, seq, np.arange(40))
+    _close(lm.forward(params, seq[None])[0], want, "the block as it is")
+    wrong, wrong_params = lm, dict(params)
+    if isinstance(change, dict):
+        wrong = WindowMoELM(dataclasses.replace(lm.cfg, **change), lm.mesh)
+    elif change == "shared":
+        wrong_params["l2.shared_out"] = params["l2.shared_out"] * 2
+    elif change == "bias":
+        monkeypatch.setattr(experts, "sigmoid_route", _biased_weights)
+    else:
+        wrong_params[f"l2.{change}"] = jnp.ones_like(params[f"l2.{change}"])
+    got = wrong.forward(wrong_params, seq[None])[0]
+    assert _err(got, want) > 100 * TOL, what
+
+
+AFMOE_CASES = [("xla", 5, 8), ("xla", 8, 8), ("xla", 13, 16),
+               ("xla", 29, 32), ("kernel", 200, 256)]
+
+
+@pytest.mark.parametrize("path,prompt_len,bucket", AFMOE_CASES)
+def test_afmoe_prefill_then_decode_matches_full_forward(
+        afmoe, afmoe_wide, monkeypatch, path, prompt_len, bucket):
+    """As `test_prefill_then_decode_matches_full_forward`, for the afmoe
+    block: prompts shorter than, equal to and longer than the window, so
+    that the rings do and do not wrap; 3 query heads a K/V head through
+    both formulations (the kernel interpreted)."""
+    lm, params, weights = afmoe if path == "xla" else afmoe_wide
+    config = AFMOE if path == "xla" else AFMOE_KERNEL
+    steps, max_len = (20, 64) if path == "xla" else (5, 512)
+    if path == "kernel":
+        monkeypatch.setenv("MXNET_PALLAS_ATTENTION", "1")
+        monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
+        assert lm.decode_block((3, 1, 2, 512, 128), jnp.float32) == 512
+    seq = _tokens(prompt_len + steps, seed=prompt_len)
+    want, kv, _ = aref.forward(config, weights, seq,
+                               np.arange(prompt_len - 1, len(seq)))
+    logits, cache = _prefill(lm, params, _poisoned(lm, 3, max_len),
+                             seq[:prompt_len], bucket, slot=1)
+    _close(logits, want[0], "prefill logits")
+    _check_rows(lm, cache, 1, prompt_len, kv)
+    for t in range(prompt_len, len(seq)):
+        logits, cache = _decode(lm, params, cache, 1, seq[t], t)
+        _close(logits, want[t - prompt_len + 1], f"decode logits at {t}")
+    _check_rows(lm, cache, 1, len(seq), kv)
+    for member in cache[:4]:
+        assert np.isnan(np.asarray(member)[[0, 2]]).all(), \
+            "a dead slot's rows were touched"
+    assert cache[4].shape == (3, 4, 2)      # the expert layers' routing
+
+
+def test_afmoe_router_is_a_biased_sigmoid_top_k(afmoe):
+    lm, params, _ = afmoe
+    x = jnp.asarray(np.random.default_rng(7).normal(size=(40, 64)),
+                    jnp.float32)
+    s = 1 / (1 + np.exp(-np.asarray(x @ params["l1.router"], np.float64)))
+    bias = np.asarray(params["l1.router_bias"], np.float64)
+    chosen, weights = lm._route(params, 1, x)
+    want = np.argsort(-(s + bias), axis=-1)[:, :2]
+    assert np.array_equal(np.sort(np.asarray(chosen), -1), np.sort(want, -1))
+    # the bias moves the selection of this draw, and never the weights
+    assert not np.array_equal(np.sort(want, -1),
+                              np.sort(np.argsort(-s, axis=-1)[:, :2], -1))
+    picked = np.take_along_axis(s, np.asarray(chosen), -1)
+    _close(weights, picked / picked.sum(-1, keepdims=True) * 2.448,
+           "weights")
+
+
+def test_the_shares_of_an_afmoe_layer_add_up_to_the_uncut_layer():
+    """16 experts over 4 shares of 4: a share routes over all 16 and sums
+    over its own; the four shares' routed parts plus the shared expert —
+    which every share computes whole — counted ONCE are the uncut layer,
+    and the reference given a share is the model given that share."""
+    whole_cfg = dict(AFMOE, num_experts=16)
+    whole = _model(whole_cfg)
+    params = whole.init_params(jax.random.PRNGKey(3))
+    x = jnp.asarray(np.random.default_rng(11).normal(size=(24, 64)),
+                    jnp.float32)
+    real = jnp.ones(24, bool)
+    want, routing = whole._mlp_out(params, 2, x, real)
+    shared = experts.gated_mlp(x, params["l2.shared_in"],
+                               params["l2.shared_out"])
+    total, seen = shared, np.zeros((24, 2), int)
+    seq = _tokens(29, seed=5)
+    for first in (0, 4, 8, 12):
+        cfg = dict(whole_cfg, num_experts=4, published={"num_experts": 16},
+                   share={"expert_first": first})
+        chip = _model(cfg)
+        assert (chip.cfg.num_experts, chip.cfg.experts_held,
+                chip.cfg.expert_first) == (16, 4, first)
+        held = {k: v[first:first + 4] if "experts_" in k else v
+                for k, v in params.items()}
+        part, local = chip._mlp_out(held, 2, x, real)
+        total = total + (part - shared)
+        seen += np.asarray(local) >= 0
+        assert np.array_equal(
+            np.asarray(local)[np.asarray(local) >= 0] + first,
+            np.asarray(routing)[np.asarray(local) >= 0])
+        want_logits = aref.logits(cfg, serve_afmoe.published(held, cfg), seq,
+                                  np.arange(29))
+        _close(chip.forward(held, seq[None])[0], want_logits,
+               f"the share from {first} against the reference")
+    assert (seen == 1).all()            # every choice on exactly one share
+    _close(total, want, "the shares' sum")
+    twice = total + shared
+    assert _err(twice, want) > 100 * TOL
+
+
+def test_afmoe_batch_through_the_engine_equals_each_request_alone(afmoe):
+    """7 requests over 3 slots through `GenerationEngine`: every stream is
+    the reference's greedy continuation of its own prompt, and nothing
+    compiles after the first pass."""
+    lm, params, weights = afmoe
+    prompts = [_tokens(n, seed=n) for n in (3, 8, 9, 17, 5, 30, 12)]
+    with _engine(lm, params, buckets=(8, 32)) as eng:
+        assert [m.shape[1:] for m in eng._kv] == [
+            (1, 2, 64, 16), (1, 2, 64, 16), (4, 2, 8, 16), (4, 2, 8, 16),
+            (4, 2)]
+        streams = [eng.submit(p, max_new_tokens=6) for p in prompts]
+        got = [s.result(timeout=120) for s in streams]
+        misses = eng.cache.misses
+        again = eng.generate(prompts[3], max_new_tokens=6)
+        assert eng.cache.misses == misses
+    for p, g in zip(prompts, got):
+        seq = list(p)
+        for _ in range(6):
+            row = aref.logits(AFMOE, weights, np.asarray(seq),
+                              [len(seq) - 1])
+            seq.append(int(np.asarray(row)[0].argmax()))
+        assert g == seq[len(p):]
+    assert again == got[3]
+
+
+def test_afmoe_tick_counters_against_a_host_count(afmoe):
+    """With a dense layer present the routing member has a page an EXPERT
+    layer, and the counters count over those: re-derived from the
+    positions and from what the program left in the cache."""
+    from mxnet_tpu import telemetry
+
+    lm, params, _ = afmoe
+    prev = telemetry.enabled()
+    telemetry.enable()
+    names = lm.TICK_COUNTERS
+    pre = "serving.generation."
+    try:
+        eng = _engine(lm, params, buckets=(16,), start=False)
+        c0 = {k: telemetry.counter(pre + k).value for k in names}
+        prompts = [_tokens(n, seed=n) for n in (5, 12)]
+        streams = [eng.submit(p, max_new_tokens=3) for p in prompts]
+        for _ in range(20):
+            if all(s.done for s in streams):
+                break
+            eng._tick_once()
+        got = {k: telemetry.counter(pre + k).value - c0[k] for k in names}
+        routed = eng.slot_snapshot(streams[0].slot)[4]
+        eng.close()
+    finally:
+        telemetry.enable(prev)
+    assert routed.shape == (4, 2) and 0 <= routed.min() and routed.max() < 8
+    at = [5, 6, 12, 13]
+    assert got["kv_rows_live_full"] == 1 * sum(p + 1 for p in at)
+    assert got["kv_rows_live_window"] == 4 * sum(min(p + 1, 8) for p in at)
+    # every expert is held: a decoded token computes 2 pairs in each of the
+    # 4 expert layers, none in the dense one
+    assert got["expert_assignments"] == 4 * 4 * 2
+    assert 0 < got["expert_tokens_max"] <= got["experts_hit"] <= 4 * 4 * 2
+    hand = np.full((3, 4, 2), -1, np.int32)
+    hand[0, 0], hand[1, 0], hand[2, 3] = [3, 5], [3, 7], [0, 6]
+    counted = np.asarray(lm.tick_counters(
+        *lm.init_cache(3, 64)[:4], jnp.asarray(hand),
+        jnp.asarray([4, 9, 0], jnp.int32)))
+    # rows (5 + 10 + 1) in the 1 full layer, (5 + 8 + 1) in each of 4 rings
+    assert counted.tolist() == [6, 5, 3, 16, 56]
+
+
+def test_weights_can_be_drawn_wider_than_they_are_kept():
+    """jax's bfloat16 normal takes 128 values and has a mean of -0.012 (a
+    hundred standard errors of this many draws): `draw_dtype="float32"`
+    draws a leaf in float32 and keeps it in the served dtype, unbiased;
+    the default draw is what it was."""
+    lm = _model(dict(AFMOE, dtype="bfloat16", hidden_size=512,
+                     moe_intermediate_size=256))
+    key = jax.random.PRNGKey(0)
+    narrow, wide = lm.init_params(key), lm.init_params(key, "float32")
+    assert {k: (v.shape, v.dtype) for k, v in narrow.items()} \
+        == {k: (v.shape, v.dtype) for k, v in wide.items()}
+    name = "l1.experts_in"                  # 8 x 512 x 512 draws
+    assert narrow[name].dtype == jnp.bfloat16
+    scale, n = 512 ** 0.5, narrow[name].size
+    mean = {k: float(np.asarray(p[name], np.float64).mean()) * scale
+            for k, p in (("narrow", narrow), ("wide", wide))}
+    assert abs(mean["wide"]) < 4 * n ** -0.5 < 8 * n ** -0.5 \
+        < abs(mean["narrow"])
+    assert len(np.unique(np.asarray(narrow[name], np.float32))) <= 256
+    again = lm.init_params(key)
+    assert all(np.array_equal(np.asarray(narrow[k], np.float32),
+                              np.asarray(again[k], np.float32))
+               for k in narrow)
+    for k in ("l1.router", "l1.router_bias", "l1.norm1_post"):
+        assert np.array_equal(np.asarray(narrow[k]), np.asarray(wide[k]))
