@@ -38,8 +38,8 @@ around the position goes back to each slab by a copy of its own through an
 output aliased to it — ``[16, R]`` rows of ``c`` and ``[rope, 128]`` lanes
 of ``k_r`` — so the decode program holds no XLA scatter,
 ``dynamic-update-slice`` or ``dynamic-slice`` on a slab (`pallas_window.py`
-and `pallas_decode.py` are the precedents, one for each of this cache's two
-layouts). Nothing of a dead slot's page is read or written; a tick with no
+and `pallas_decode.py`, whose grid is likewise the live part alone, are the
+precedents). Nothing of a dead slot's page is read or written; a tick with no
 live slot at all takes one step that does nothing.
 
 A prefill attends in the published form — keys and values up-projected per

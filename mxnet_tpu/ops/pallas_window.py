@@ -20,13 +20,13 @@ attends its first ``min(p + 1, R)`` rows:
   left, and are selected away.
 
 Softmax does not care in which order the rows come, so the ring is never
-unrolled. As in `pallas_decode.py` the live slots are taken first in the
-grid ``(slot, row block)``, positions ride as scalar-prefetch operands, the
-block index is clamped to the slot's last live block (an index that does
-not change costs no DMA), ``pl.when`` skips what lies past it, and the new
-row is merged into its block in VMEM and goes back to the slab through an
-output aliased to the input — 16 rows around it, one packed bfloat16 tile —
-so there is no XLA scatter or ``dynamic-update-slice`` on the slab. The
+unrolled. The live slots are taken first in the grid ``(slot, row block)``,
+positions ride as scalar-prefetch operands, the block index is clamped to
+the slot's last live block (no change, no DMA), ``pl.when`` skips what lies
+past it (`pallas_decode.py`'s grid has no such step: it holds the live
+blocks only), and as there the new row is merged into its block in VMEM and
+goes back to the slab through an output aliased to the input — 16 rows, one
+packed bfloat16 tile: no XLA scatter or ``dynamic-update-slice``. The
 ``G`` query heads of a K/V head are scored as ONE ``[G, 128] x [128,
 block]`` product on the MXU and summed as one ``[G, block] x [block, 128]``:
 ``G`` FLOPs a cache byte (8 at mellum's 32:4 heads, 6 at Trinity's 48:8; any

@@ -1272,8 +1272,11 @@ class GenerationEngine:
         """Telemetry: how much of the slab this dispatch reads.
         ``slab_blocks_live`` over ``slab_blocks_total`` is the share of
         the slab's blocks the decode program had to read (1 when the model
-        keeps the XLA formulation, which reads every row). Host arithmetic
-        on positions the tick already holds."""
+        keeps the XLA formulation, which reads every row). With the slab
+        kernel ``slab_blocks_live`` IS its grid: `decode_update_attend`
+        takes one step a live block a layer (`pallas_decode.live_steps`)
+        and none for the rest. Host arithmetic on positions the tick
+        already holds."""
         from ...ops.pallas_decode import live_blocks
 
         block = self._slab_block

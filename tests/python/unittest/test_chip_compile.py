@@ -183,6 +183,31 @@ def _page_sized_ops(text, page):
     return moved
 
 
+_SLAB_KERNEL = re.compile(r"%decode_update_attend[.\d]* = .*? "
+                          r"custom-call\(([^)]*)\)")
+_HLO_OPERAND = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = .*? ([\w\-]+)\("
+                          r"(%[\w.\-]+)?", re.M)
+
+
+def _slab_kernel_calls(text):
+    """The operands of every `decode_update_attend` custom call of a compiled
+    program, by the instruction that made them: the grid's bound, the work
+    list's two members and the positions first, then the layer, the tick's
+    rows and the slabs. XLA's own copies and bitcasts are looked through (it
+    stages a list in fast memory for a later call: the same list)."""
+    made = {m.group(1): m.group(2, 3) for m in _HLO_OPERAND.finditer(text)}
+
+    def source(name):
+        while made.get(name, ("",))[0] in ("copy", "copy-start", "copy-done",
+                                           "bitcast"):
+            name = made[name][1]
+        return name
+
+    return [[source(re.sub(r"/\*.*?\*/", "", name).strip())
+             for name in m.group(1).split(",")]
+            for m in _SLAB_KERNEL.finditer(text)]
+
+
 @pytest.mark.parametrize("slots,cfg", [
     (32, dict(vocab_size=50257, d_model=1600, n_heads=25, d_ff=6400)),
     (8, dict(vocab_size=50257, d_model=768, n_heads=12, d_ff=3072)),
@@ -227,7 +252,14 @@ def test_decode_program_touches_no_slab_page_on_v5e(one_chip, monkeypatch,
         sds((slots,), jnp.int32)).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 2        # one kernel a layer
+    # ... exactly one, whose grid (a step a live block: ISSUE 41) and work
+    # list are built once a tick, not once a layer: every call takes the
+    # same bound, the same two lists and the same positions
+    calls = _slab_kernel_calls(text)
+    assert len(calls) == 2
+    assert len({tuple(operands[:4]) for operands in calls}) == 1
     page = slots * config.n_heads * 1024 * hd
+    # a dynamic-update-slice on a slab would be one of these
     assert _page_sized_ops(text, page) == []
     ma = compiled.memory_analysis()
     slab_bytes = 2 * 2 * page * 2                    # K and V, 2 layers, bf16
@@ -244,13 +276,15 @@ def test_decode_program_touches_no_slab_page_on_v5e(one_chip, monkeypatch,
 def test_hybrid_decode_program_updates_state_in_place_on_v5e(one_chip,
                                                              monkeypatch):
     """The engine's decode program for one period (10 layers: 9 Mamba-2, 1
-    grouped-query attention) of granite-4.0-h-micro at its published widths,
-    32 slots x 4,096 rows: it compiles for the v5e; both kernels are in it
-    (the K/V slab's with 4 queries a head, the recurrent state's); every
-    member of the cache is aliased input to output; and no XLA op copies,
-    slices, updates or re-lays the recurrent-state slab — each live slot's
-    state is read once and written once, by the kernel, where it lies. The
-    trace counts which way each Mamba layer's state update went."""
+    grouped-query attention) of granite-4.0-h-micro and the next period's
+    attention layer, at its published widths, 32 slots x 4,096 rows: it
+    compiles for the v5e; both kernels are in it (the K/V slab's with 4
+    queries a head, the recurrent state's); the two slab kernels share one
+    grid, built once a tick; every member of the cache is aliased input to
+    output; and no XLA op copies, slices, updates or re-lays a K/V page or
+    the recurrent-state slab — each live slot's state is read once and
+    written once, by the kernel, where it lies. The trace counts which way
+    each Mamba layer's state update went."""
     import json
     import os
 
@@ -265,7 +299,8 @@ def test_hybrid_decode_program_updates_state_in_place_on_v5e(one_chip,
     with open(os.path.join(root, "benchmark", "configs",
                            "granite_4_0_h_micro.json")) as f:
         published = json.load(f)
-    published["layer_types"] = published["layer_types"][:10]     # a period
+    published["layer_types"] = (published["layer_types"][:10]    # a period
+                                + ["attention"])
     config = HybridLMConfig.from_config(published)
     slots, rows = 32, 4096
     dev = next(iter(one_chip.device_set))
@@ -281,7 +316,7 @@ def test_hybrid_decode_program_updates_state_in_place_on_v5e(one_chip,
     cache = tuple(sds(v) for v in jax.eval_shape(
         lambda: host_lm.init_cache(slots, rows)))
     assert [c.shape for c in cache] == [
-        (32, 1, 8, 4096, 64), (32, 1, 8, 4096, 64), (32, 9, 64, 64, 128),
+        (32, 2, 8, 4096, 64), (32, 2, 8, 4096, 64), (32, 9, 64, 64, 128),
         (32, 9, 3, 4352)]
     assert lm.decode_block(cache[0].shape, cache[0].dtype) == 256
     assert lm.state_kernel(cache[2].shape, cache[2].dtype)
@@ -304,8 +339,15 @@ def test_hybrid_decode_program_updates_state_in_place_on_v5e(one_chip,
     assert {k: c.value - before[k] for k, c in went.items()} == {
         "kernel": 9, "xla": 0}
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 10      # 9 state + 1 K/V kernel
+    assert text.count("tpu_custom_call") >= 11      # 9 state + 2 K/V kernels
     assert len(re.findall(r"%mamba_state_update[.\d]* = ", text)) == 9
+    # the attention layers' kernels take the tick's rows themselves (no
+    # dynamic-update-slice, copy or re-laying of a K/V page) and one grid
+    calls = _slab_kernel_calls(text)
+    assert len(calls) == 2
+    assert len({tuple(operands[:4]) for operands in calls}) == 1
+    assert [line for line in _page_sized_ops(text, slots * 8 * rows * 64)
+            if "bf16[" in line] == []
     state = slots * 64 * 64 * 128                   # one layer's page
     moved = [line for line in _page_sized_ops(text, state)
              if "f32[" in line]

@@ -17,6 +17,8 @@ from mxnet_tpu.models import transformer as tfm
 from mxnet_tpu.ops import pallas_decode as pd
 from mxnet_tpu.serving import GenerationEngine
 
+import slab_kernel_2d
+
 S, NL, H, L, LAYER = 6, 2, 2, 512, 1
 BLOCK = 256                      # what decode_block gives for L=512
 
@@ -118,6 +120,142 @@ def test_rows_past_the_position_never_reach_the_output(path, junk):
     assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the grid of the tick's live blocks and nothing else (ISSUE 41)
+# ---------------------------------------------------------------------------
+
+LIVE_ONLY = {
+    "edges": [0, BLOCK - 1, BLOCK, L - 1, 127, 128],
+    "dead-between": [5, -1, -1, 300, -1, L - 1],
+    "dead-ends": [-1, BLOCK, -1, 0, BLOCK - 1, -1],
+    "none-live": [-1] * S,
+    "all-last": [L - 1] * S,
+    # as many slots as the cells': every one live, the edges among them
+    "all-32": [0, BLOCK - 1, BLOCK, L - 1] + list(range(3, 500, 18)),
+}
+
+
+def _restated(q, k_new, v_new, ck, cv, layer, pos, group):
+    """`old_step` for ``group`` queries a slab head: a query head reads the
+    slab head ``i // group``."""
+    n, hq, hd = q.shape
+    want = [old_step(q.reshape(n, hq // group, group, hd)[:, :, g],
+                     k_new, v_new, ck, cv, layer, pos)[0]
+            for g in range(group)]
+    return jnp.stack(want, axis=2).reshape(n, hq, hd)
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint32)
+
+
+@pytest.mark.parametrize("junk", [None, np.inf, np.nan],
+                         ids=["clean", "inf", "nan"])
+@pytest.mark.parametrize("group", [1, 4], ids=["1q", "4q"])
+@pytest.mark.parametrize("name", LIVE_ONLY)
+def test_live_only_grid(name, group, junk):
+    """A grid step a live block and no other: the kernel (interpreted)
+    against the re-stated old step, the XLA formulation and the kernel as it
+    was with its grid of every (slot, block) (`slab_kernel_2d`), with dead
+    slots between and around live ones, none live, every slot at its last
+    row, 32 live slots, and positions on both sides of a block's and a lane
+    group's edge; one query a slab head (GPT-2's) and four (granite's); inf
+    or nan in every dead page and past every position. The attention is the
+    old grid's bit for bit, and every slab row but the written ones comes
+    back bit for bit, the junk included."""
+    positions = LIVE_ONLY[name]
+    n = len(positions)
+    rng = np.random.default_rng(3)
+    draw = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    q, k_new, v_new = draw(n, H * group, 64), draw(n, H, 64), draw(n, H, 64)
+    clean_k, clean_v = draw(n, NL, H, L, 64), draw(n, NL, H, L, 64)
+    pos = jnp.asarray(positions, jnp.int32)
+    alive = np.asarray(pos) >= 0
+    ck, cv = clean_k, clean_v
+    if junk is not None:
+        # past a live slot's position and all of a dead slot's pages
+        beyond = jnp.arange(L)[None, None, None, :, None] \
+            > pos[:, None, None, None, None]
+        ck, cv = jnp.where(beyond, junk, ck), jnp.where(beyond, junk, cv)
+    block = pd.decode_block(ck.shape, ck.dtype)
+    got, got_k, got_v = pd.decode_update_attend(
+        q, k_new, v_new, ck, cv, LAYER, pos, block=block, interpret=True)
+    was, was_k, was_v = slab_kernel_2d.decode_update_attend(
+        q, k_new, v_new, ck, cv, LAYER, pos, block=block, interpret=True)
+    assert np.array_equal(_bits(got), _bits(was))
+    assert np.array_equal(_bits(got_k), _bits(was_k))
+    assert np.array_equal(_bits(got_v), _bits(was_v))
+    want = _restated(q, k_new, v_new, clean_k, clean_v, LAYER,
+                     jnp.maximum(pos, 0), group)
+    xla = tfm._attend_rows(q, tfm._write_rows(ck, LAYER, pos, k_new),
+                           tfm._write_rows(cv, LAYER, pos, v_new), LAYER, pos)
+    got = np.asarray(got)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got[alive], np.asarray(want)[alive],
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(got[alive], np.asarray(xla)[alive],
+                               rtol=2e-5, atol=2e-5)
+    assert not got[~alive].any()
+    for slab, after, new in ((ck, got_k, k_new), (cv, got_v, v_new)):
+        expect = np.array(slab)
+        for s, p in enumerate(positions):
+            if p >= 0:
+                expect[s, LAYER, :, p, :] = np.asarray(new)[s]
+        assert np.array_equal(_bits(after), _bits(expect))
+
+
+def _grid_of(positions):
+    """What the kernel's call is handed for its grid: the bound and the work
+    list, the first operands of the `pallas_call` in the function's trace
+    (before the positions and the layer), evaluated."""
+    n = len(positions)
+    row, slab = jnp.zeros((n, H, 64)), jnp.zeros((n, NL, H, L, 64))
+    args = (row, row, row, slab, slab, jnp.asarray(positions, jnp.int32))
+    closed = jax.make_jaxpr(
+        lambda q, k, v, ck, cv, pos: pd.decode_update_attend.__wrapped__(
+            q, k, v, ck, cv, LAYER, pos, block=BLOCK))(*args)
+    (at, call), = [(i, e) for i, e in enumerate(closed.jaxpr.eqns)
+                   if e.primitive.name == "pallas_call"]
+    grid = call.params["grid_mapping"]
+    assert len(grid.grid) == grid.num_dynamic_grid_bounds == 1
+    assert grid.num_index_operands == 4    # the lists, positions, the layer
+    upto = closed.jaxpr.replace(outvars=call.invars[:4],
+                                eqns=closed.jaxpr.eqns[:at])
+    bound, slot_of, block_of, pos = map(np.asarray, jax.core.eval_jaxpr(
+        upto, closed.consts, *args))
+    assert pos.tolist() == list(positions)
+    return int(bound), slot_of, block_of
+
+
+GRIDS = dict(LIVE_ONLY, **{"one": [-1, -1, 300, -1, -1, -1],
+                           "steps": [300, -1, 0, 511, -1, 256]})
+
+
+@pytest.mark.parametrize("name", GRIDS)
+def test_grid_bound_is_the_live_blocks(name):
+    """The grid's bound is what the engine's `slab_blocks_live` counts, the
+    tick's live blocks (`live_blocks(positions, block).sum()`), and 1 for a
+    tick with none; the work list walks the live slots in slot order, each
+    from block 0 to the block of its position, and past the bound, where
+    nothing steps, it stays inside the slab."""
+    positions = GRIDS[name]
+    bound, slot_of, block_of = _grid_of(positions)
+    walk = [(s, b) for s, p in enumerate(positions) if p >= 0
+            for b in range(p // BLOCK + 1)]
+    assert len(walk) == pd.live_blocks(np.asarray(positions), BLOCK).sum()
+    assert bound == max(len(walk), 1)
+    assert list(zip(slot_of.tolist(), block_of.tolist()))[:len(walk)] == walk
+    assert slot_of.shape == block_of.shape == (len(positions) * (L // BLOCK),)
+    assert 0 <= slot_of.min() and slot_of.max() < len(positions)
+    assert 0 <= block_of.min() and block_of.max() < L // BLOCK
+    if not walk:
+        assert block_of[0] == 0            # the one step: a block sent back
+    same = pd.live_steps(jnp.asarray(positions), BLOCK, L // BLOCK)
+    assert [np.asarray(x).tolist() for x in same] == [
+        bound, slot_of.tolist(), block_of.tolist()]
+
 
 
 @pytest.fixture
