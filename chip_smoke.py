@@ -636,7 +636,7 @@ def phase_serve_hybrid(seed, events, seen):
         block = eng._slab_block
         state_kernel = lm.state_kernel(ssm.shape, ssm.dtype)
         log(f"[{name}] {n_params / 1e6:.1f}M params {cfg.dtype}, "
-            f"{lm.n_mamba} mamba + {lm.n_attention} attention layers; cache "
+            f"{lm.n_recurrent} mamba + {lm.n_attention} attention layers; cache "
             f"K/V {tuple(kv.shape)} state {tuple(ssm.shape)} "
             f"{eng.kv_slab_bytes() / 2**20:.0f} MiB; warmup compiled "
             f"{warm['compiles']} programs in {warm['seconds']:.1f}s")

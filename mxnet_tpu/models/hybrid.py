@@ -1,7 +1,10 @@
-"""Hybrid state-space / attention language model (the Granite 4.0-H block,
-`model_type` granitemoehybrid without experts): Mamba-2 layers interleaved
-with grouped-query attention layers as the configuration's `layer_types`
-says, RMSNorm, a SiLU-gated MLP, and the family's four scalar multipliers.
+"""Hybrid recurrent / attention language model: layers with a recurrent
+mixer interleaved with attention layers as the configuration's `layer_types`
+says, RMSNorm, a SiLU-gated MLP. Two published blocks are built from it.
+
+**The Granite 4.0-H block** (`model_type` granitemoehybrid without experts;
+`layer_types` of "mamba" and "attention"), with the family's four scalar
+multipliers:
 
     h = embed[tokens] * embedding_multiplier
     per layer:  h += residual_multiplier * mixer(RMSNorm(h))
@@ -16,49 +19,86 @@ says, RMSNorm, a SiLU-gated MLP, and the family's four scalar multipliers.
   group: B and C are shared by all heads. `dt`, the decay and `S` are
   float32 whatever the compute dtype.
 
+**The Olmo-Hybrid block** (`model_type` olmo_hybrid; `layer_types` of
+"linear_attention" and "full_attention"): no multipliers, an untied head,
+the norms AFTER the sub-layer in a full-attention layer and before it in a
+linear one:
+
+    h = embed[tokens]
+    full_attention:    h += RMSNorm(attn(h));      h += RMSNorm(mlp(h))
+    linear_attention:  h += gdn(RMSNorm(h));       h += mlp(RMSNorm(h))
+    logits = RMSNorm(h) @ head
+
+* attention mixer — as above with `head_dim ** -0.5` for the multiplier,
+  and `q` and `k` through an RMSNorm over the WHOLE projected vector before
+  the heads are cut; still no position encoding.
+* gated-delta-rule mixer — `models/recurrent.py`, `GatedDeltaMixer`: a
+  head's matrix state `S [dk, dv]` moves by `S' = a S; u = v - S'^T k; S = S'
+  + b k u^T; o = S^T q`.
+
+What the second block adds is read from the configuration (`from_config`)
+and compiles to nothing for the first: granite's programs are the same
+operations as before it existed (tests/python/unittest/test_hybrid_lm.py
+holds their lowered text).
+
 Serving (`GenerationEngine`) sees the model through the cache protocol
 (docs/faq/perf.md, "The cache protocol"): `init_cache` returns a TUPLE of
 arrays, each with the slot as its leading axis, and `prefill` /
 `decode_step` take its members in order after `params` and return them in
 order after their result. Here the members are
 
-    K, V   [slots, attention layers, kv heads, max_len, head_dim]   dtype
-    ssm    [slots, mamba layers, heads, head_dim, d_state]          float32
-    conv   [slots, mamba layers, d_conv - 1, conv channels]         dtype
+    K, V    [slots, attention layers, kv heads, max_len, head_dim]   dtype
+    state   [slots, recurrent layers, *mixer.state_shape]            float32
+            Mamba-2: [heads, head_dim, d_state]; gated delta rule: [dk,
+            heads * dv] (the heads side by side on the lanes)
+    conv    [slots, recurrent layers, kernel - 1, conv channels]     dtype
+            Mamba-2: x | B | C; gated delta rule: q | k | v, three streams
 
-so the Mamba layers pay no rows, and their state does not grow. Prefill
-computes the recurrence in chunks of `mamba_chunk_size` (the "SSD" form:
-inside a chunk a masked-decay matmul, between chunks the carried state);
-a ragged last chunk and the padding up to the prefill bucket are steps of
-`dt = 0`, which leave the state where the prompt's true last token put it.
-Decode is one step from the stored state and the stored last `d_conv - 1`
-convolution inputs. A recurrent state cannot be rewound or extended from an
-offset, so the model offers no `prefill_at` / `verify_step` and
-`cache_traits` says so: the engine refuses the prefix cache and speculation.
+so the recurrent layers pay no rows, and their state does not grow. Prefill
+computes the recurrence in chunks (Mamba-2: the "SSD" form, inside a chunk a
+masked-decay matmul, between chunks the carried state; the delta rule: the
+WY form, a unit-lower-triangular solve a chunk); a ragged last chunk and the
+padding up to the prefill bucket are steps that leave the state where the
+prompt's true last token put it (`dt = 0`; `g = 0, beta = 0`). Decode is one
+step from the stored state and the stored last `kernel - 1` convolution
+inputs. A recurrent state cannot be rewound or extended from an offset, so
+the model offers no `prefill_at` / `verify_step` and `cache_traits` says so:
+the engine refuses the prefix cache and speculation.
 """
 from __future__ import annotations
 
 import dataclasses
-
-import numpy as np
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from .. import telemetry
 from ..parallel.spmd import model_mesh
 from .transformer import _attend_rows, _table_rows, _write_rows
 
 __all__ = ["HybridLMConfig", "HybridLM"]
+
+# `layer_types` by what a layer's mixer is
+ATTENTION_KINDS = ("attention", "full_attention")
+RECURRENT_KINDS = ("mamba", "linear_attention")
+
+
+def _need(config, key, kind):
+    """`config[key]`, or a refusal that names the key and the kind of layer
+    that needs it."""
+    if key not in config:
+        raise ValueError(f"HybridLM: the configuration lacks {key!r}, which "
+                         f"its {kind!r} layers need")
+    return config[key]
 
 
 @dataclasses.dataclass(frozen=True)
 class HybridLMConfig:
     """The published configuration's keys, under their published names
     (`from_config` reads a `config.json`-shaped dict), plus what serving
-    adds: `max_len` (positions a cache may be asked for) and `dtype`."""
+    adds: `max_len` (positions a cache may be asked for) and `dtype`. The
+    keys of a mixer matter only where `layer_types` names it."""
     vocab_size: int = 1024
     hidden_size: int = 64
     shared_intermediate_size: int = 128
@@ -77,47 +117,110 @@ class HybridLMConfig:
     mamba_chunk_size: int = 8
     max_len: int = 2048
     dtype: str = "bfloat16"
+    # the Olmo-Hybrid block (module docstring); the defaults are granite's
+    tie_word_embeddings: bool = True
+    qk_norm: bool = False               # over the whole projected q and k
+    post_norm_kinds: tuple = ()         # kinds whose norms follow a sub-layer
+    linear_num_heads: int = 4
+    linear_key_head_dim: int = 8
+    linear_value_head_dim: int = 16
+    linear_conv_kernel_dim: int = 4
+    linear_allow_neg_eigval: bool = True
+    gdn_chunk_size: int = 64
 
     @classmethod
     def from_config(cls, config, max_len=None, dtype=None):
-        """From a published `config.json` (a dict). What the block cannot
-        express is refused by name rather than ignored (experts live in
+        """From a published `config.json` (a dict): the first
+        `num_hidden_layers` of its `layer_types`. Each mixer's keys are read
+        only where `layer_types` names it; what the block cannot express is
+        refused by name rather than ignored (experts live in
         `models/experts.py`, which `LatentMoELM` and `WindowMoELM` call: a
         state-space model with experts would call it from here)."""
-        for key, want in (("mamba_n_groups", 1), ("num_local_experts", 0),
+        kinds = tuple(config["layer_types"])
+        kinds = kinds[:config.get("num_hidden_layers", len(kinds))]
+        for key, want in (("num_local_experts", 0), ("attention_bias", False),
+                          ("hidden_act", "silu")):
+            if key in config and config[key] != want:
+                raise ValueError(f"HybridLM: {key}={config[key]!r} is not "
+                                 f"supported (only {want!r})")
+        fields = dict(
+            vocab_size=config["vocab_size"],
+            hidden_size=config["hidden_size"], layer_types=kinds,
+            num_attention_heads=config["num_attention_heads"],
+            num_key_value_heads=config["num_key_value_heads"],
+            rms_norm_eps=config["rms_norm_eps"],
+            max_len=int(config["max_position_embeddings"]
+                        if max_len is None else max_len),
+            dtype=config.get("dtype", "bfloat16") if dtype is None else dtype)
+        if config.get("model_type") == "olmo_hybrid":
+            fields.update(cls._olmo_fields(config, kinds))
+        else:
+            fields.update(cls._granite_fields(config, kinds))
+        return cls(**fields)
+
+    @staticmethod
+    def _granite_fields(config, kinds):
+        for key, want in (("mamba_n_groups", 1),
                           ("position_embedding_type", "nope"),
-                          ("attention_bias", False),
                           ("mamba_proj_bias", False),
                           ("mamba_conv_bias", True),
-                          ("hidden_act", "silu"),
                           ("tie_word_embeddings", True)):
             if key in config and config[key] != want:
                 raise ValueError(f"HybridLM: {key}={config[key]!r} is not "
                                  f"supported (only {want!r})")
-        if config["mamba_expand"] * config["hidden_size"] != \
-                config["mamba_n_heads"] * config["mamba_d_head"]:
-            raise ValueError("HybridLM: mamba_expand * hidden_size must be "
-                             "mamba_n_heads * mamba_d_head")
-        return cls(
-            vocab_size=config["vocab_size"],
-            hidden_size=config["hidden_size"],
+        fields = dict(
             shared_intermediate_size=config["shared_intermediate_size"],
-            layer_types=tuple(config["layer_types"]),
-            num_attention_heads=config["num_attention_heads"],
-            num_key_value_heads=config["num_key_value_heads"],
             attention_multiplier=config["attention_multiplier"],
             embedding_multiplier=config["embedding_multiplier"],
             residual_multiplier=config["residual_multiplier"],
-            logits_scaling=config["logits_scaling"],
-            rms_norm_eps=config["rms_norm_eps"],
-            mamba_n_heads=config["mamba_n_heads"],
-            mamba_d_head=config["mamba_d_head"],
-            mamba_d_state=config["mamba_d_state"],
-            mamba_d_conv=config["mamba_d_conv"],
-            mamba_chunk_size=config["mamba_chunk_size"],
-            max_len=int(config["max_position_embeddings"]
-                        if max_len is None else max_len),
-            dtype=config.get("dtype", "bfloat16") if dtype is None else dtype)
+            logits_scaling=config["logits_scaling"])
+        if "mamba" in kinds:
+            if _need(config, "mamba_expand", "mamba") \
+                    * config["hidden_size"] \
+                    != _need(config, "mamba_n_heads", "mamba") \
+                    * _need(config, "mamba_d_head", "mamba"):
+                raise ValueError("HybridLM: mamba_expand * hidden_size must "
+                                 "be mamba_n_heads * mamba_d_head")
+            fields.update({key: _need(config, key, "mamba") for key in (
+                "mamba_n_heads", "mamba_d_head", "mamba_d_state",
+                "mamba_d_conv", "mamba_chunk_size")})
+        return fields
+
+    @staticmethod
+    def _olmo_fields(config, kinds):
+        if (config.get("rope_parameters") or {}).get("rope_theta") is not None:
+            raise ValueError("HybridLM: rope_parameters.rope_theta="
+                             f"{config['rope_parameters']['rope_theta']!r} is "
+                             "not supported (only None: the block has no "
+                             "rotary)")
+        if config.get("tie_word_embeddings", False):
+            raise ValueError("HybridLM: tie_word_embeddings=True is not "
+                             "supported for olmo_hybrid (only False: the "
+                             "block has an lm_head)")
+        hd = config["hidden_size"] // config["num_attention_heads"]
+        fields = dict(
+            shared_intermediate_size=config["intermediate_size"],
+            attention_multiplier=hd ** -0.5, embedding_multiplier=1.0,
+            residual_multiplier=1.0, logits_scaling=1.0,
+            tie_word_embeddings=False, qk_norm=True,
+            post_norm_kinds=("full_attention",))
+        if "linear_attention" in kinds:
+            kind = "linear_attention"
+            heads = _need(config, "linear_num_value_heads", kind)
+            if _need(config, "linear_num_key_heads", kind) != heads:
+                raise ValueError(
+                    "HybridLM: linear_num_key_heads="
+                    f"{config['linear_num_key_heads']!r} is not supported "
+                    f"(only linear_num_value_heads, {heads})")
+            fields.update(
+                linear_num_heads=heads,
+                linear_allow_neg_eigval=bool(
+                    config.get("linear_allow_neg_eigval", False)),
+                gdn_chunk_size=config.get("gdn_chunk_size", 64),
+                **{key: _need(config, key, kind) for key in (
+                    "linear_key_head_dim", "linear_value_head_dim",
+                    "linear_conv_kernel_dim")})
+        return fields
 
     # derived sizes
     @property
@@ -139,23 +242,35 @@ class HybridLM:
     no sharding plan yet, and its kernels run on one device."""
 
     def __init__(self, config, mesh=None):
+        from . import recurrent
+
         kinds = set(config.layer_types)
-        if not kinds <= {"mamba", "attention"}:
+        known = set(ATTENTION_KINDS + RECURRENT_KINDS)
+        if not kinds <= known:
             raise ValueError(f"HybridLM: unknown layer types "
-                             f"{sorted(kinds - {'mamba', 'attention'})}")
+                             f"{sorted(kinds - known)}")
+        if len(kinds & set(RECURRENT_KINDS)) > 1:
+            raise ValueError("HybridLM: one kind of recurrent layer a model "
+                             "(the cache has one state member), not "
+                             f"{sorted(kinds & set(RECURRENT_KINDS))}")
         if config.num_attention_heads % config.num_key_value_heads:
             raise ValueError("HybridLM: query heads must group evenly over "
                              "the K/V heads")
         self.cfg = config
         self.mesh = mesh or model_mesh()
-        # a layer's index among the layers of its own kind: its page of the
-        # K/V slabs or of the state slabs
+        # the recurrent layers' mixer (models/recurrent.py); a model with
+        # none keeps Mamba-2's shapes for its empty state members
+        self.mixer = recurrent.MIXERS[next(
+            (k for k in RECURRENT_KINDS if k in kinds), "mamba")](
+                config, self.mesh, self._rms)
+        # a layer's index among the layers that share its member of the
+        # cache: its page of the K/V slabs or of the state slabs
         self._page = []
-        seen = {"mamba": 0, "attention": 0}
+        seen = {False: 0, True: 0}
         for kind in config.layer_types:
-            self._page.append(seen[kind])
-            seen[kind] += 1
-        self.n_attention, self.n_mamba = seen["attention"], seen["mamba"]
+            self._page.append(seen[kind in ATTENTION_KINDS])
+            seen[kind in ATTENTION_KINDS] += 1
+        self.n_attention, self.n_recurrent = seen[True], seen[False]
 
     # -- parameters ---------------------------------------------------------
 
@@ -163,24 +278,20 @@ class HybridLM:
         c = self.cfg
         d, f = c.hidden_size, c.shared_intermediate_size
         shapes = {"embed": (c.vocab_size, d), "norm_f": (d,)}
+        if not c.tie_word_embeddings:
+            shapes["head"] = (d, c.vocab_size)
         for i, kind in enumerate(c.layer_types):
             shapes.update({f"l{i}.norm1": (d,), f"l{i}.norm2": (d,),
                            f"l{i}.w_in": (d, 2 * f), f"l{i}.w_out": (f, d)})
-            if kind == "attention":
+            if kind in ATTENTION_KINDS:
                 kv = c.num_key_value_heads * c.head_dim
                 shapes.update({f"l{i}.wq": (d, d), f"l{i}.wk": (d, kv),
                                f"l{i}.wv": (d, kv), f"l{i}.wo": (d, d)})
+                if c.qk_norm:
+                    shapes.update({f"l{i}.q_norm": (d,),
+                                   f"l{i}.k_norm": (kv,)})
             else:
-                shapes.update({
-                    f"l{i}.m_in": (d, 2 * c.mamba_inner
-                                   + 2 * c.mamba_d_state + c.mamba_n_heads),
-                    f"l{i}.conv_w": (c.mamba_d_conv, c.conv_channels),
-                    f"l{i}.conv_b": (c.conv_channels,),
-                    f"l{i}.dt_bias": (c.mamba_n_heads,),
-                    f"l{i}.A_log": (c.mamba_n_heads,),
-                    f"l{i}.D": (c.mamba_n_heads,),
-                    f"l{i}.m_norm": (c.mamba_inner,),
-                    f"l{i}.m_out": (c.mamba_inner, d)})
+                shapes.update(self.mixer.shapes(i))
         return shapes
 
     def param_specs(self):
@@ -188,35 +299,42 @@ class HybridLM:
         return {name: repl for name in self._shapes()}
 
     def init_params(self, key):
-        """Random weights: normal / sqrt(fan_in) for matrices (the
-        embedding's fan-in is the hidden size, which its use as the output
-        head contracts), ones for the norms and `D`, and the Mamba-2
-        reference initialisation for what decides the decays — `A_log =
-        log U[1, 16]`, `dt_bias = softplus^-1(log-uniform[1e-3, 1e-1])` — a
-        normal draw there gives degenerate decays."""
+        """Random weights, drawn in float32 and kept in `dtype`: normal /
+        sqrt(fan_in) for matrices (a tied embedding's fan-in is the hidden
+        size, which its use as the output head contracts; an untied one is
+        only looked up and is drawn at unit variance), ones for the norms
+        and `D`, and the Mamba-2 reference initialisation for what decides
+        the decays of either mixer — `A_log = log U[1, 16]`, `dt_bias =
+        softplus^-1(log-uniform[1e-3, 1e-1])` — a normal draw there gives
+        degenerate decays."""
+        from .recurrent import _softplus_inverse_steps
+
         c = self.cfg
         dt = jnp.dtype(c.dtype)
         shapes = self._shapes()
         specs = self.param_specs()
+        ones = ("norm1", "norm2", "norm_f", "q_norm", "k_norm") \
+            + self.mixer.ONES
         params = {}
         keys = jax.random.split(key, len(shapes))
         for (name, shape), k in zip(sorted(shapes.items()), keys):
             leaf = name.rpartition(".")[2]
-            if leaf in ("norm1", "norm2", "norm_f", "m_norm", "D"):
+            if leaf in ones:
                 val = jnp.ones(shape, jnp.float32)
             elif leaf == "A_log":
                 val = jnp.log(jax.random.uniform(k, shape, jnp.float32,
                                                  1.0, 16.0))
             elif leaf == "dt_bias":
-                step = jnp.exp(jax.random.uniform(
-                    k, shape, jnp.float32, np.log(1e-3), np.log(1e-1)))
-                val = step + jnp.log(-jnp.expm1(-step))
+                val = _softplus_inverse_steps(k, shape)
             else:
-                fan_in = (c.hidden_size if leaf == "embed" else
-                          c.mamba_d_conv if leaf in ("conv_w", "conv_b")
-                          else shape[0])
+                if leaf == "embed":
+                    fan_in = c.hidden_size if c.tie_word_embeddings else 1
+                elif leaf in ("conv_w", "conv_b"):  # its kernel
+                    fan_in = self.mixer.conv_shape[0] + 1
+                else:
+                    fan_in = shape[0]
                 val = jax.random.normal(k, shape, jnp.float32) \
-                    / np.sqrt(fan_in)
+                    / fan_in ** 0.5
             params[name] = jax.device_put(val.astype(dt), specs[name])
         return params
 
@@ -224,202 +342,133 @@ class HybridLM:
 
     # Device-side scopes (`jax.named_scope`: in every instruction's op_name,
     # read by benchmark/program_scopes.py): `embed`, `norm`, `mlp`, `head`,
-    # `attn.project`, `attn.prefill` | `attn.decode`, `attn.out`,
+    # `attn.project`, `attn.qknorm`, `attn.prefill` | `attn.decode`,
+    # `attn.out`, `cache.write`, and the mixer's (models/recurrent.py):
     # `mamba.project`, `mamba.conv`, `mamba.gates`, `mamba.ssd` |
-    # `mamba.state_update`, `mamba.out`, `cache.write`.
+    # `mamba.state_update`, `mamba.out`; `gdn.project`, `gdn.conv`,
+    # `gdn.gates`, `gdn.chunk` | `gdn.state_update`, `gdn.out`.
 
-    def _rms(self, x, g):
-        with jax.named_scope("norm"):
+    def _rms(self, x, g, scope="norm"):
+        with jax.named_scope(scope):
             x32 = x.astype(jnp.float32)
             out = x32 * lax.rsqrt((x32 * x32).mean(-1, keepdims=True)
                                   + self.cfg.rms_norm_eps)
             return (out * g.astype(jnp.float32)).astype(x.dtype)
 
-    def _mlp(self, params, i, h):
+    def _post(self, kind):
+        return kind in self.cfg.post_norm_kinds
+
+    def _add(self, h, x):
+        """The residual: `h + residual_multiplier * x`."""
+        m = self.cfg.residual_multiplier
+        return h + (x if m == 1 else m * x)
+
+    def _mixed(self, params, i, kind, h, mixed):
+        """The mixer's output into the residual stream, through the layer's
+        first norm where that follows the sub-layer."""
+        if self._post(kind):
+            mixed = self._rms(mixed, params[f"l{i}.norm1"])
+        return self._add(h, mixed)
+
+    def _mixer_input(self, params, i, kind, h):
+        return h if self._post(kind) \
+            else self._rms(h, params[f"l{i}.norm1"])
+
+    def _mlp(self, params, i, kind, h):
         """The gated MLP sub-layer with its norm and residual."""
-        u = self._rms(h, params[f"l{i}.norm2"])
+        post = self._post(kind)
+        u = h if post else self._rms(h, params[f"l{i}.norm2"])
         with jax.named_scope("mlp"):
             g, v = jnp.split(u @ params[f"l{i}.w_in"], 2, axis=-1)
-            return h + self.cfg.residual_multiplier * (
-                (jax.nn.silu(g) * v) @ params[f"l{i}.w_out"])
+            out = (jax.nn.silu(g) * v) @ params[f"l{i}.w_out"]
+            if not post:
+                return self._add(h, out)
+        return self._add(h, self._rms(out, params[f"l{i}.norm2"]))
 
     def _qkv(self, params, i, u):
-        """`u` [T, D] -> q [T, Hq, hd], k and v [T, Hkv, hd]."""
+        """`u` [T, D] -> q [T, Hq, hd], k and v [T, Hkv, hd]; `q` and `k`
+        through their whole-vector norms where the block has them."""
         c = self.cfg
         t = u.shape[0]
+        heads = (c.num_attention_heads, c.num_key_value_heads,
+                 c.num_key_value_heads)
         with jax.named_scope("attn.project"):
-            return ((u @ params[f"l{i}.wq"]).reshape(
-                        t, c.num_attention_heads, c.head_dim),
-                    (u @ params[f"l{i}.wk"]).reshape(
-                        t, c.num_key_value_heads, c.head_dim),
-                    (u @ params[f"l{i}.wv"]).reshape(
-                        t, c.num_key_value_heads, c.head_dim))
+            q, k, v = ((u @ params[f"l{i}.w{s}"]).reshape(t, n, c.head_dim)
+                       for s, n in zip("qkv", heads))
+        if c.qk_norm:
+            q, k = (self._rms(x.reshape(t, -1), params[f"l{i}.{s}_norm"],
+                              "attn.qknorm").reshape(x.shape)
+                    for s, x in (("q", q), ("k", k)))
+        return q, k, v
 
     def _attention_seq(self, params, i, u):
         """The attention mixer over one whole sequence `u` [L, D]: `(out
-        [L, D], k, v [L, Hkv, hd])`. Plain XLA: 4 of 40 layers."""
+        [L, D], k, v [L, Hkv, hd])`. Heads of 128 go through the prefill
+        kernel of `ops/pallas_window.py` where :meth:`prefill_block` says
+        so; else plain XLA (granite: 4 of 40 layers)."""
         c = self.cfg
         L = u.shape[0]
         q, k, v = self._qkv(params, i, u)
         group = c.num_attention_heads // c.num_key_value_heads
+        block = self.prefill_block(L)
         with jax.named_scope("attn.prefill"):
-            q = q.reshape(L, c.num_key_value_heads, group, c.head_dim)
-            s = jnp.einsum("qhgd,khd->hgqk", q, k,
-                           preferred_element_type=jnp.float32) \
-                * c.attention_multiplier
-            ar = jnp.arange(L)
-            # large-negative, not -inf: see TransformerLM.prefill
-            s = s + jnp.where(ar[:, None] >= ar[None, :], 0.0, -1e9)
-            p = jax.nn.softmax(s, axis=-1).astype(u.dtype)
-            a = jnp.einsum("hgqk,khd->qhgd", p, v).reshape(L, c.hidden_size)
+            if block is not None:
+                from ..ops import pallas_attention as pa
+                from ..ops import pallas_window as pw
+
+                a = pw.band_prefill_attend(
+                    q.transpose(1, 0, 2), k.transpose(1, 0, 2),
+                    v.transpose(1, 0, 2), block=block,
+                    scale=c.attention_multiplier,
+                    interpret=pa.pallas_interpret()) \
+                    .transpose(1, 0, 2).reshape(L, c.hidden_size)
+            else:
+                q = q.reshape(L, c.num_key_value_heads, group, c.head_dim)
+                s = jnp.einsum("qhgd,khd->hgqk", q, k,
+                               preferred_element_type=jnp.float32) \
+                    * c.attention_multiplier
+                ar = jnp.arange(L)
+                # large-negative, not -inf: see TransformerLM.prefill
+                s = s + jnp.where(ar[:, None] >= ar[None, :], 0.0, -1e9)
+                p = jax.nn.softmax(s, axis=-1).astype(u.dtype)
+                a = jnp.einsum("hgqk,khd->qhgd", p, v).reshape(
+                    L, c.hidden_size)
         with jax.named_scope("attn.out"):
             return a @ params[f"l{i}.wo"], k, v
-
-    def _mamba_project(self, params, i, u):
-        """`u` [T, D] -> z [T, inner], xBC [T, C], dt_raw [T, H]."""
-        c = self.cfg
-        with jax.named_scope("mamba.project"):
-            return jnp.split(u @ params[f"l{i}.m_in"],
-                             [c.mamba_inner, c.mamba_inner + c.conv_channels],
-                             axis=-1)
-
-    def _mamba_gates(self, params, i, dt_raw):
-        """float32 step sizes `dt` [T, H] and log-decays `dt * A`."""
-        with jax.named_scope("mamba.gates"):
-            step = jax.nn.softplus(
-                dt_raw.astype(jnp.float32)
-                + params[f"l{i}.dt_bias"].astype(jnp.float32))
-            return step, step * -jnp.exp(params[f"l{i}.A_log"]
-                                         .astype(jnp.float32))
-
-    def _mamba_out(self, params, i, y, x, z):
-        """`y` [T, H, P] float32 (the recurrence's output) -> the mixer's
-        output [T, D]: the skip `D x`, the gate, the gated RMSNorm, the
-        output projection."""
-        c = self.cfg
-        t = y.shape[0]
-        with jax.named_scope("mamba.out"):      # its norm nests: the
-            # outermost scope names the work
-            y = y + params[f"l{i}.D"].astype(jnp.float32)[None, :, None] \
-                * x.astype(jnp.float32)
-            y = y.reshape(t, c.mamba_inner) \
-                * jax.nn.silu(z.astype(jnp.float32))
-            return self._rms(y, params[f"l{i}.m_norm"]).astype(z.dtype) \
-                @ params[f"l{i}.m_out"]
-
-    def _split_xbc(self, xbc):
-        c = self.cfg
-        x, b, cc = jnp.split(xbc, [c.mamba_inner,
-                                   c.mamba_inner + c.mamba_d_state], axis=-1)
-        return (x.reshape(x.shape[0], c.mamba_n_heads, c.mamba_d_head), b, cc)
-
-    def _mamba_seq(self, params, i, u, length):
-        """The Mamba-2 mixer over one whole sequence `u` [L, D] of which the
-        first `length` tokens are real. Returns `(out [L, D], state [H, P,
-        N] float32, conv_tail [d_conv - 1, C])`: the recurrent state after
-        token `length - 1` and the last `d_conv - 1` convolution inputs up
-        to it (zeros before the sequence's start). Rows at and past
-        `length` are steps of `dt = 0`: they neither move the state nor
-        enter the tail, and their outputs are garbage nobody reads."""
-        c = self.cfg
-        L, dt_ = u.shape[0], u.dtype
-        k = c.mamba_d_conv
-        z, xbc, dt_raw = self._mamba_project(params, i, u)
-        with jax.named_scope("mamba.conv"):
-            padded = jnp.concatenate(
-                [jnp.zeros((k - 1, xbc.shape[1]), xbc.dtype), xbc], axis=0)
-            # padded row t + k - 1 is token t: the tail is tokens
-            # [length - (k - 1), length)
-            tail = lax.dynamic_slice_in_dim(padded, length, k - 1, axis=0)
-            w = params[f"l{i}.conv_w"].astype(jnp.float32)
-            conv = params[f"l{i}.conv_b"].astype(jnp.float32) + sum(
-                padded[j:j + L].astype(jnp.float32) * w[j] for j in range(k))
-            x, b, cc = self._split_xbc(jax.nn.silu(conv).astype(dt_))
-        step, log_a = self._mamba_gates(params, i, dt_raw)
-        with jax.named_scope("mamba.gates"):
-            real = (jnp.arange(L) < length)[:, None]
-            step = jnp.where(real, step, 0.0)
-            log_a = jnp.where(real, log_a, 0.0)
-        with jax.named_scope("mamba.ssd"):
-            y, state = self._ssd(x, b, cc, step, log_a)
-        return self._mamba_out(params, i, y, x, z), state, tail
-
-    def _ssd(self, x, b, c, step, log_a):
-        """The recurrence over a whole sequence from a zero state, in chunks
-        of `mamba_chunk_size`: x [L, H, P], b and c [L, N], step and log_a
-        [L, H] float32. Returns `(y [L, H, P] float32, state [H, P, N]
-        float32)`. A ragged last chunk is padded with steps of `dt = 0`.
-
-        Inside a chunk, with `cs` the running sum of `log_a`: `y_t = sum_{s
-        <= t} exp(cs_t - cs_s) dt_s (C_t . B_s) x_s + exp(cs_t) (S_in C_t)`
-        and `S_out = exp(cs_end) S_in + sum_s exp(cs_end - cs_s) dt_s x_s
-        (x) B_s`. What touches the carried state runs at matmul precision
-        `highest`: the state is float32 and stays so."""
-        q = self.cfg.mamba_chunk_size
-        L, nh, hp = x.shape
-        n = b.shape[1]
-        pad = -L % q
-        if pad:
-            x, b, c, step, log_a = (jnp.pad(t, ((0, pad),) + ((0, 0),)
-                                            * (t.ndim - 1))
-                                    for t in (x, b, c, step, log_a))
-        nc = (L + pad) // q
-        f32 = jnp.float32
-        chunks = (x.reshape(nc, q, nh, hp), b.reshape(nc, q, n),
-                  c.reshape(nc, q, n), step.reshape(nc, q, nh),
-                  log_a.reshape(nc, q, nh))
-        causal = jnp.tril(jnp.ones((q, q), bool))[:, :, None]
-
-        def chunk(state, xs):
-            x_c, b_c, c_c, dt_c, la_c = xs
-            cs = jnp.cumsum(la_c, axis=0)                        # [q, H]
-            # exp(cs_t - cs_s) for s <= t; masked before the exp
-            decay = jnp.exp(jnp.where(causal, cs[:, None, :] - cs[None, :, :],
-                                      -jnp.inf))                 # [t, s, H]
-            g = jnp.einsum("tn,sn->ts", c_c, b_c,
-                           preferred_element_type=f32)
-            m = g[:, :, None] * decay * dt_c[None, :, :]
-            y = jnp.einsum("tsh,shp->thp", m, x_c.astype(f32))
-            y = y + jnp.exp(cs)[:, :, None] * jnp.einsum(
-                "tn,hpn->thp", c_c.astype(f32), state,
-                precision=lax.Precision.HIGHEST)
-            to_end = jnp.exp(cs[-1][None, :] - cs) * dt_c        # [s, H]
-            state = jnp.exp(cs[-1])[:, None, None] * state + jnp.einsum(
-                "shp,sn->hpn", to_end[:, :, None] * x_c.astype(f32),
-                b_c.astype(f32), precision=lax.Precision.HIGHEST)
-            return state, y
-
-        state, y = lax.scan(chunk, jnp.zeros((nh, hp, n), f32), chunks)
-        return y.reshape(nc * q, nh, hp)[:L], state
 
     # -- forward ------------------------------------------------------------
 
     def _embed(self, rows):
-        return (rows * self.cfg.embedding_multiplier).astype(
+        m = self.cfg.embedding_multiplier
+        return (rows if m == 1 else rows * m).astype(
             jnp.dtype(self.cfg.dtype))
 
     def _logits(self, params, h):
+        c = self.cfg
         h = self._rms(h, params["norm_f"])
         with jax.named_scope("head"):
+            if not c.tie_word_embeddings:
+                return (h @ params["head"]).astype(jnp.float32)
             return (h @ params["embed"].T).astype(jnp.float32) \
-                / self.cfg.logits_scaling
+                / c.logits_scaling
 
     def _sequence(self, params, tokens, length):
         """One whole sequence `tokens` [L]: the hidden states [L, D] after
         the last layer and, per layer, what a cache keeps of it: `(k, v)` of
-        an attention layer, `(state, conv_tail)` of a Mamba layer."""
+        an attention layer, `(state, conv_tail)` of a recurrent layer."""
         c = self.cfg
         with jax.named_scope("embed"):
             h = self._embed(jnp.take(params["embed"], tokens, axis=0))
         kept = []
         for i, kind in enumerate(c.layer_types):
-            u = self._rms(h, params[f"l{i}.norm1"])
-            if kind == "attention":
+            u = self._mixer_input(params, i, kind, h)
+            if kind in ATTENTION_KINDS:
                 mixed, *keep = self._attention_seq(params, i, u)
             else:
-                mixed, *keep = self._mamba_seq(params, i, u, length)
+                mixed, *keep = self.mixer.seq(params, i, u, length)
             kept.append(keep)
-            h = self._mlp(params, i, h + c.residual_multiplier * mixed)
+            h = self._mlp(params, i, kind,
+                          self._mixed(params, i, kind, h, mixed))
         return h, kept
 
     def forward(self, params, tokens):
@@ -434,10 +483,10 @@ class HybridLM:
     # -- the cache protocol (serving/generation) ------------------------------
 
     def init_cache(self, max_slots, max_len=None):
-        """The serving cache: `(K, V, ssm, conv)`, zeroed, each with the slot
-        as its leading axis (module docstring). K/V rows are paid by the
-        attention layers only; the state of the Mamba layers has one size
-        whatever `max_len`."""
+        """The serving cache: `(K, V, state, conv)`, zeroed, each with the
+        slot as its leading axis (module docstring). K/V rows are paid by
+        the attention layers only; the state of the recurrent layers has one
+        size whatever `max_len`."""
         c = self.cfg
         max_len = c.max_len if max_len is None else int(max_len)
         if max_len > c.max_len:
@@ -447,52 +496,86 @@ class HybridLM:
         sh = NamedSharding(self.mesh, P())
         kv = (s, self.n_attention, c.num_key_value_heads, max_len, c.head_dim)
         shapes = ((kv, dt), (kv, dt),
-                  ((s, self.n_mamba, c.mamba_n_heads, c.mamba_d_head,
-                    c.mamba_d_state), jnp.float32),
-                  ((s, self.n_mamba, c.mamba_d_conv - 1, c.conv_channels),
-                   dt))
+                  ((s, self.n_recurrent) + self.mixer.state_shape,
+                   jnp.float32),
+                  ((s, self.n_recurrent) + self.mixer.conv_shape, dt))
         return tuple(jax.device_put(jnp.zeros(shape, t), sh)
                      for shape, t in shapes)
 
     def decode_block(self, slab_shape, dtype):
         """As `TransformerLM.decode_block`: the Pallas decode kernel's block
-        over the K/V slab's rows, or None for the XLA formulation."""
+        over the K/V slab's rows, or None for the XLA formulation. A slab
+        whose heads are the lane width lies `hd`-minor on the chip and goes
+        to `ops/pallas_window.py`'s kernel, any other to
+        `ops/pallas_decode.py`'s (:meth:`decode_step` tells them apart the
+        same way)."""
         from ..ops import pallas_attention as pa
-        from ..ops import pallas_decode as pd
 
         if self.mesh.size > 1 or not pa.pallas_enabled():
             return None
+        if slab_shape[-1] == pa._LANES:
+            from ..ops import pallas_window as pw
+
+            return pw.kv_block(slab_shape, dtype)
+        from ..ops import pallas_decode as pd
+
         return pd.decode_block(slab_shape, dtype)
+
+    def prefill_block(self, length):
+        """The prefill attention kernel's block over a sequence of `length`
+        positions (`ops/pallas_window.band_prefill_attend`, heads of 128
+        only), or None for the XLA formulation; decided as
+        :meth:`decode_block` is."""
+        from ..ops import pallas_attention as pa
+
+        if self.mesh.size > 1 or not pa.pallas_enabled() \
+                or self.cfg.head_dim != pa._LANES:
+            return None
+        from ..ops import pallas_window as pw
+
+        return pw.band_block(length)
 
     def state_kernel(self, slab_shape, dtype):
         """Whether :meth:`decode_step` advances a recurrent-state slab of
-        this shape through the Pallas kernel (``ops/pallas_ssm.py``: each
-        live slot's state read once and written once, where it lies) or in
-        XLA (which reads it twice). Decided as :meth:`decode_block` is;
-        which way a layer's trace went is counted
-        (`mamba.state_update.kernel` / `.xla`, once a trace, telemetry
-        on)."""
-        from ..ops import pallas_attention as pa
-        from ..ops import pallas_ssm
+        this shape through the mixer's Pallas kernel (``ops/pallas_ssm.py``:
+        each live slot's state read once and written once, where it lies)
+        or in XLA (which reads it more than once). Decided as
+        :meth:`decode_block` is; which way a layer's trace went is counted
+        (`mamba.state_update.kernel` / `.xla`, `gdn.state_update.kernel` /
+        `.xla`, once a trace, telemetry on)."""
+        return self.mixer.kernel(slab_shape, dtype)
 
-        return (self.mesh.size == 1 and pa.pallas_enabled()
-                and pallas_ssm.state_update_applies(slab_shape, dtype))
+    TICK_COUNTERS = ("kv_rows_live_full",)
 
     def cache_traits(self, cache):
         """What the engine may ask about a cache it otherwise only carries
-        (docs/faq/perf.md, "The cache protocol")."""
+        (docs/faq/perf.md, "The cache protocol"). A model with
+        `full_attention` layers counts the rows their live slots attend a
+        tick (`tick_counters`), as `WindowMoELM` does under the same
+        name."""
         _, _, ssm, conv = cache
         slots = ssm.shape[0]
-        return {
+        traits = {
             "block": self.decode_block(cache[0].shape, cache[0].dtype),
             "state_bytes_per_slot": (int(ssm.nbytes) + int(conv.nbytes))
             // slots,
             "rewindable": False,
             "why_not_rewindable":
-                "the Mamba layers' recurrent and convolution state holds "
+                "the recurrent layers' state and convolution window hold "
                 "only the last token's value: it cannot be extended from a "
                 "row offset (prefix reuse) nor rolled back (speculation) "
                 "without snapshots, which this cache does not keep"}
+        if "full_attention" in self.cfg.layer_types:
+            traits["tick_counters"] = self.TICK_COUNTERS
+        return traits
+
+    def tick_counters(self, ck, cv, state, conv, positions):
+        """int32 `[len(TICK_COUNTERS)]` of ONE decode step: the K/V rows the
+        live slots attend, summed over the attention layers."""
+        del cv, state, conv
+        rows = jnp.where(positions >= 0,
+                         jnp.minimum(positions + 1, ck.shape[3]), 0)
+        return (rows.sum(dtype=jnp.int32) * self.n_attention)[None]
 
     def prefill(self, params, ck, cv, ssm, conv, tokens, length, slot):
         """Full-prompt forward for ONE session into slot `slot`: writes the
@@ -507,7 +590,7 @@ class HybridLM:
             for kind, page, keep in zip(self.cfg.layer_types, self._page,
                                         kept):
                 at = (slot, page, 0, 0, 0)
-                if kind == "attention":
+                if kind in ATTENTION_KINDS:
                     k, v = keep
                     ck = lax.dynamic_update_slice(
                         ck, k.transpose(1, 0, 2)[None, None].astype(ck.dtype),
@@ -518,62 +601,25 @@ class HybridLM:
                 else:
                     state, tail = keep
                     ssm = lax.dynamic_update_slice(
-                        ssm, state[None, None].astype(ssm.dtype), at)
+                        ssm, state[None, None].astype(ssm.dtype),
+                        at[:ssm.ndim])
                     conv = lax.dynamic_update_slice(
                         conv, tail[None, None].astype(conv.dtype), at[:4])
             last = lax.dynamic_slice_in_dim(h, length - 1, 1, axis=0)
         return self._logits(params, last)[0], ck, cv, ssm, conv
 
-    def _mamba_step(self, params, i, u, ssm, conv, page, alive):
-        """One token for every slot through Mamba layer `i`: `u` [S, D],
-        the slot-major state slabs, `alive` [S]. The state update happens on
-        the layer's page of the slab where it lies; a dead slot's state and
-        convolution window stay bit-for-bit what they were."""
-        z, xbc, dt_raw = self._mamba_project(params, i, u)
-        with jax.named_scope("mamba.conv"):
-            window = jnp.concatenate([conv[:, page], xbc[:, None, :]], axis=1)
-            w = params[f"l{i}.conv_w"].astype(jnp.float32)
-            out = params[f"l{i}.conv_b"].astype(jnp.float32) + jnp.einsum(
-                "skc,kc->sc", window.astype(jnp.float32), w)
-            conv = conv.at[:, page].set(jnp.where(
-                alive[:, None, None], window[:, 1:], conv[:, page]))
-            x, b, cc = self._split_xbc(jax.nn.silu(out).astype(u.dtype))
-        step, log_a = self._mamba_gates(params, i, dt_raw)
-        with jax.named_scope("mamba.state_update"):
-            f32 = jnp.float32
-            decay, dtx = jnp.exp(log_a), step[:, :, None] * x.astype(f32)
-            kernel = self.state_kernel(ssm.shape, ssm.dtype)
-            if telemetry._enabled:
-                telemetry.counter("mamba.state_update."
-                                  + ("kernel" if kernel else "xla")).inc()
-            if kernel:
-                from ..ops import pallas_attention as pa
-                from ..ops import pallas_ssm
-
-                y, ssm = pallas_ssm.state_update(
-                    ssm, jnp.int32(page), decay, dtx, b, cc, alive,
-                    interpret=pa.pallas_interpret())
-            else:
-                old = ssm[:, page]                              # [S,H,P,N]
-                new = decay[:, :, None, None] * old + (
-                    dtx[:, :, :, None] * b.astype(f32)[:, None, None, :])
-                y = jnp.sum(new * cc.astype(f32)[:, None, None, :], axis=-1)
-                ssm = ssm.at[:, page].set(
-                    jnp.where(alive[:, None, None, None], new, old))
-        return self._mamba_out(params, i, y, x, z), ssm, conv
-
     def decode_step(self, params, ck, cv, ssm, conv, tokens, positions):
         """One fused incremental step over every slot: a live slot consumes
         one token, writes its K/V row at `positions[s]` in each attention
         layer, attends rows `[0, positions[s]]`, and advances the state of
-        each Mamba layer by one step. A NEGATIVE position marks a dead slot:
-        nothing of it is written, attended or advanced. Returns `(logits
-        [S, V] fp32, ck, cv, ssm, conv)`; jit with the cache donated. How
-        the cache is touched is decided from shapes, policy and mesh before
-        the call (:meth:`decode_block`, :meth:`state_kernel`): the Pallas
-        kernels on one TPU chip, else the same mathematics in XLA."""
+        each recurrent layer by one step. A NEGATIVE position marks a dead
+        slot: nothing of it is written, attended or advanced. Returns
+        `(logits [S, V] fp32, ck, cv, ssm, conv)`; jit with the cache
+        donated. How the cache is touched is decided from shapes, policy and
+        mesh before the call (:meth:`decode_block`, :meth:`state_kernel`):
+        the Pallas kernels on one TPU chip, else the same mathematics in
+        XLA."""
         from ..ops import pallas_attention as pa
-        from ..ops import pallas_decode as pd
 
         c = self.cfg
         block = self.decode_block(ck.shape, ck.dtype)
@@ -582,26 +628,36 @@ class HybridLM:
         with jax.named_scope("embed"):
             h = self._embed(_table_rows(params["embed"], tokens))
         for i, (kind, page) in enumerate(zip(c.layer_types, self._page)):
-            u = self._rms(h, params[f"l{i}.norm1"])
-            if kind == "attention":
+            u = self._mixer_input(params, i, kind, h)
+            if kind in ATTENTION_KINDS:
                 q, k, v = self._qkv(params, i, u)
                 with jax.named_scope("attn.decode"):
                     k, v = k.astype(ck.dtype), v.astype(cv.dtype)
-                    if block is not None:
-                        a, ck, cv = pd.decode_update_attend(
-                            q, k, v, ck, cv, jnp.int32(page), positions,
-                            block=block, scale=c.attention_multiplier,
-                            interpret=pa.pallas_interpret())
-                    else:
+                    if block is None:
                         ck = _write_rows(ck, page, positions, k)
                         cv = _write_rows(cv, page, positions, v)
                         a = _attend_rows(q, ck, cv, page, positions,
                                          scale=c.attention_multiplier)
+                    elif c.head_dim == pa._LANES:
+                        from ..ops import pallas_window as pw
+
+                        a, ck, cv = pw.kv_update_attend(
+                            q, k, v, ck, cv, jnp.int32(page), positions,
+                            block=block, scale=c.attention_multiplier,
+                            interpret=pa.pallas_interpret())
+                    else:
+                        from ..ops import pallas_decode as pd
+
+                        a, ck, cv = pd.decode_update_attend(
+                            q, k, v, ck, cv, jnp.int32(page), positions,
+                            block=block, scale=c.attention_multiplier,
+                            interpret=pa.pallas_interpret())
                 with jax.named_scope("attn.out"):
                     mixed = a.astype(h.dtype).reshape(-1, c.hidden_size) \
                         @ params[f"l{i}.wo"]
             else:
-                mixed, ssm, conv = self._mamba_step(params, i, u, ssm, conv,
-                                                    page, alive)
-            h = self._mlp(params, i, h + c.residual_multiplier * mixed)
+                mixed, ssm, conv = self.mixer.step(params, i, u, ssm, conv,
+                                                   page, alive)
+            h = self._mlp(params, i, kind,
+                          self._mixed(params, i, kind, h, mixed))
         return self._logits(params, h), ck, cv, ssm, conv

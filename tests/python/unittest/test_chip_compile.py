@@ -838,3 +838,206 @@ def test_afmoe_prefill_program_compiles_for_v5e(one_chip, monkeypatch,
     live = ma.argument_size_in_bytes + ma.temp_size_in_bytes \
         + ma.output_size_in_bytes - ma.alias_size_in_bytes
     assert live < 15.0e9, live      # 12.95 GB stand; temporaries 1.7 GB
+
+
+# ---------------------------------------------------------------------------
+# the hybrid model as the Olmo-Hybrid block at Olmo-Hybrid-7B's widths (ISSUE
+# 42): 30 linear heads of a 96 x 192 float32 state, 30 K/V heads of 128 with
+# one query each, 32 slots x 2,048 positions
+# ---------------------------------------------------------------------------
+
+def test_gdn_state_update_compiles_at_its_bytes_for_v5e(one_chip):
+    """The delta rule's state kernel over the cell's whole state slab: it
+    compiles for the v5e, the slab is aliased input to output, and its bytes
+    on the chip are the count's — `[.., 96, 30 x 192]` float32 is 45 whole
+    lane rows a sublane row, so nothing is padded: within 2% of 849 MB."""
+    from mxnet_tpu.ops import pallas_ssm
+
+    slots, layers, heads, dk, dv = 32, 12, 30, 96, 192
+    slab = (slots, layers, dk, heads * dv)
+    assert pallas_ssm.gdn_update_applies(slab, jnp.float32, heads)
+
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(slab, alpha, beta, q, k, v, alive):
+        return pallas_ssm.gdn_state_update(slab, jnp.int32(5), alpha, beta,
+                                           q, k, v, alive)
+
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(
+        sds(*slab), sds(slots, heads), sds(slots, heads),
+        sds(slots, heads, dk), sds(slots, heads, dk), sds(slots, heads, dv),
+        sds(slots, dtype=jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%gdn_state_update[.\d]* = ", text)) == 1
+    assert "f32[32,12,96,5760]{3,2,1,0:T(8,128)}" in text
+    count = int(np.prod(slab)) * 4
+    assert abs(count / 1e6 - 849.3) < 0.1
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= count
+    # the slab and the tick's few small operands, nothing else: a padded
+    # layout (256 lanes a head) would be 1.13 GB
+    assert count <= ma.argument_size_in_bytes <= 1.02 * count
+    assert ma.temp_size_in_bytes < count // (slots * layers)    # no page
+
+
+def test_kv128_attend_compiles_at_one_query_a_head_for_v5e(one_chip):
+    """The decode kernel at 30 K/V heads with ONE query each (a `[1, 128]`
+    query tile, a `(30, 1, 1)` scratch) over the cell's full member, whose
+    block `kv_block`'s budget puts at 128 rows."""
+    from mxnet_tpu.ops import pallas_window
+
+    slab = (32, 4, 30, 2048, 128)
+    block = pallas_window.kv_block(slab, jnp.bfloat16)
+    assert block == 128
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(q, k, v, slab_k, slab_v, positions):
+        return pallas_window.kv_update_attend(
+            q, k, v, slab_k, slab_v, jnp.int32(2), positions, block=block,
+            scale=128 ** -0.5)
+
+    text = jax.jit(fn, donate_argnums=(3, 4)).lower(
+        sds((32, 30, 128)), sds((32, 30, 128)), sds((32, 30, 128)),
+        sds(slab), sds(slab), sds((32,), jnp.int32)).compile().as_text()
+    assert len(re.findall(r"%kv128_attend[.\d]* = ", text)) == 1
+
+
+@pytest.mark.parametrize("length", [256, 512, 1024])
+def test_swa_prefill_attend_compiles_at_30_heads_for_v5e(one_chip, length):
+    """The prefill attention kernel at 30 heads, no window, group 1, for
+    every bucket of the cell."""
+    from mxnet_tpu.ops import pallas_window
+
+    block = pallas_window.band_block(length)
+    assert block == length
+
+    def sds():
+        return jax.ShapeDtypeStruct((30, length, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    text = _compiled_text(
+        lambda q, k, v: pallas_window.band_prefill_attend(
+            q, k, v, block=block, scale=128 ** -0.5),
+        sds(), sds(), sds())
+    assert "swa_prefill_attend" in text
+
+
+@pytest.fixture(scope="module")
+def olmo_period(one_chip):
+    """`(lm, params, cache)` of one period of `olmo_hybrid_7b_l16` (three
+    linear layers and a full one) at its published widths, 32 slots x 2,048
+    positions, as shapes on the described chip."""
+    import json
+
+    from mxnet_tpu import parallel as par
+    from mxnet_tpu.models import HybridLM, HybridLMConfig
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "olmo_hybrid_7b_l16.json")) as f:
+        published = json.load(f)
+    assert len(published["layer_types"]) == 32      # carried whole
+    published["num_hidden_layers"] = 4
+    config = HybridLMConfig.from_config(published, max_len=2048)
+    assert config.layer_types == ("linear_attention",) * 3 \
+        + ("full_attention",)
+    dev = next(iter(one_chip.device_set))
+    lm = HybridLM(config, par.create_mesh(devices=[dev], dp=1))
+    host_lm = HybridLM(config, par.create_mesh(devices=jax.devices()[:1],
+                                               dp=1))
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = {k: sds(v) for k, v in jax.eval_shape(
+        host_lm.init_params, jax.random.PRNGKey(0)).items()}
+    cache = tuple(sds(v) for v in jax.eval_shape(
+        lambda: host_lm.init_cache(32, 2048)))
+    assert [c.shape for c in cache] == [
+        (32, 1, 30, 2048, 128), (32, 1, 30, 2048, 128), (32, 3, 96, 5760),
+        (32, 3, 3, 11520)]
+    return lm, params, cache
+
+
+def test_olmo_decode_program_compiles_for_v5e(one_chip, monkeypatch,
+                                              olmo_period):
+    """The engine's decode program of one period: it compiles for the v5e;
+    the state kernel is in it once a linear layer and the `hd`-minor slab
+    kernel once a full layer (not `decode_update_attend`, whose slabs lie
+    `L`-minor, and not the XLA formulation); every member of the cache is
+    aliased input to output; no XLA op copies, slices, updates or re-lays a
+    K/V page or a layer's page of the state slab. The trace counts which way
+    each linear layer's state update went."""
+    from mxnet_tpu import telemetry
+
+    lm, params, cache = olmo_period
+    monkeypatch.setenv("MXNET_PALLAS_ATTENTION", "1")
+    monkeypatch.delenv("MXNET_PALLAS_INTERPRET", raising=False)
+    assert lm.decode_block(cache[0].shape, cache[0].dtype) == 128
+    assert lm.state_kernel(cache[2].shape, cache[2].dtype)
+
+    def fn(params, cache, tokens, positions):       # the engine's wrapper
+        logits, *cache = lm.decode_step(params, *cache, tokens, positions)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), tuple(cache)
+
+    ints = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
+    went = {k: telemetry.counter("gdn.state_update." + k)
+            for k in ("kernel", "xla")}
+    before = {k: c.value for k, c in went.items()}
+    was = telemetry.enabled()
+    telemetry.enable()
+    try:
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+            params, cache, ints, ints).compile()
+    finally:
+        telemetry.enable(was)
+    assert {k: c.value - before[k] for k, c in went.items()} == {
+        "kernel": 3, "xla": 0}
+    text = compiled.as_text()
+    assert len(re.findall(r"%gdn_state_update[.\d]* = ", text)) == 3
+    assert len(re.findall(r"%kv128_attend[.\d]* = ", text)) == 1
+    assert "decode_update_attend" not in text
+    assert [line for line in _page_sized_ops(text, 32 * 30 * 2048 * 128)
+            if "bf16[" in line] == []
+    state = 32 * 96 * 5760                          # one layer's page
+    assert [line for line in _page_sized_ops(text, state)
+            if "f32[" in line] == []
+    ma = compiled.memory_analysis()
+    cache_bytes = sum(int(np.prod(c.shape)) * c.dtype.itemsize
+                      for c in cache)
+    assert ma.alias_size_in_bytes >= cache_bytes    # all four, whole
+    assert ma.temp_size_in_bytes < state * 4        # no temporary of a page
+
+
+@pytest.mark.parametrize("bucket", [256, 1024])
+def test_olmo_prefill_program_compiles_for_v5e(one_chip, monkeypatch,
+                                               olmo_period, bucket):
+    """The engine's prefill program of one period at the cell's smallest and
+    largest bucket: it compiles for the v5e (the chunked delta rule's
+    triangular solve and scan included), the full layer's attention is the
+    prefill kernel, and every member of the cache is aliased."""
+    lm, params, cache = olmo_period
+    monkeypatch.setenv("MXNET_PALLAS_ATTENTION", "1")
+    monkeypatch.delenv("MXNET_PALLAS_INTERPRET", raising=False)
+    assert lm.prefill_block(bucket) == bucket
+
+    def fn(params, cache, tokens, length, slot):    # the engine's wrapper
+        logits, *cache = lm.prefill(params, *cache, tokens, length, slot)
+        return jnp.argmax(logits).astype(jnp.int32), tuple(cache)
+
+    one = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, jax.ShapeDtypeStruct((bucket,), jnp.int32,
+                                           sharding=one_chip),
+        one, one).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%swa_prefill_attend[.\d]* = ", text)) == 1
+    ma = compiled.memory_analysis()
+    cache_bytes = sum(int(np.prod(c.shape)) * c.dtype.itemsize
+                      for c in cache)
+    assert ma.alias_size_in_bytes >= cache_bytes
+    assert ma.temp_size_in_bytes < 2 ** 30

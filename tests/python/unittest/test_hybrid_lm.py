@@ -223,7 +223,7 @@ def test_decode_kernel_grouped_queries(group):
 def test_state_kernel_matches_restatement(alive, dims):
     """The kernel against a float64 restatement: the written state is the
     float32 formula's to an ulp of its terms, `y` is no further from float64
-    than twice what the XLA formulation of `_mamba_step` (float32, summed
+    than twice what the XLA formulation of `Mamba2Mixer.step` (float32, summed
     over lanes) is, dead slots and the other pages stay bit for bit."""
     (NL, H, P, N), S, page = dims, 4, 1
     rng = np.random.default_rng(1)
