@@ -474,6 +474,7 @@ class WindowMoELM:
         with jax.named_scope("attn.window" if self._window(i)
                              else "attn.decode"):
             if block is not None:
+                pw.count_body(q, slab_k)
                 a, slab_k, slab_v = pw.kv_update_attend(
                     q, k, v, slab_k, slab_v, jnp.int32(page), positions,
                     block=block, scale=c.head_dim ** -0.5,
@@ -600,7 +601,10 @@ class WindowMoELM:
     def decode_block(self, slab_shape, dtype):
         """The decode kernel's block over the rows of a member of this
         shape, or None for the XLA formulation; decided from shapes, policy
-        and mesh before the call, as `TransformerLM.decode_block`."""
+        and mesh before the call, as `TransformerLM.decode_block`. Which
+        body of the kernel a layer's trace took is counted
+        (`attn.decode.kv128.grouped` / `.one_query`, once a trace, telemetry
+        on)."""
         from ..ops import pallas_attention as pa
         from ..ops import pallas_window as pw
 

@@ -34,6 +34,19 @@ block]`` product on the MXU and summed as one ``[G, block] x [block, 128]``:
 the chip's ridge (240), so the rows' bytes bind. The block over the rows
 follows the heads (:func:`kv_block`: 1,024 rows at 4 K/V heads, 512 at 8).
 
+At ONE query a K/V head (``G = 1``: Olmo-Hybrid's full layers, 30 heads, a
+block of 128 rows) that body is a softmax chain a HEAD, and it left a live
+step at 4.65 us where its 1.97 MB of tiles take 2.4 (v5e, PR 44) — not for
+the MXU's sake (a tile is 8 pushes of 16 rows whether it is loaded for one
+row or streamed past the queries, and the four MXUs already share them) but
+for the chains': 30 of them on ``[1, 128]`` vectors, one sublane of a vreg's
+eight, each with two cross-lane reductions and three read-modify-writes of
+one-row scratch. :func:`_kernel_one_query` keeps the two products a head and
+lets the step's score rows meet in a ``[H, block]`` scratch: ONE chain on 4
+vregs, one update of the accumulator, 644 bundles where there were 1,357,
+and a live step of 2.75 us against 2.71 for the kernel's DMA alone. The
+group is static in the trace; every ``G > 1`` lowers to what it lowered to.
+
 **The prefill** (:func:`band_prefill_attend`, kernel `swa_prefill_attend`):
 causal attention of one whole sequence with an optional window, grouped
 queries, key blocks streamed through the grid as `pallas_latent.
@@ -54,10 +67,11 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import telemetry
 from .pallas_attention import _NEG_INF, _LANES, _divisor_block
 
-__all__ = ["kv_block", "kv_update_attend", "band_block", "band_steps",
-           "band_prefill_attend"]
+__all__ = ["kv_block", "kv_update_attend", "count_body", "band_block",
+           "band_steps", "band_prefill_attend"]
 
 # the K and V blocks of every head, double-buffered by the pipeline: a
 # quarter of Mosaic's 16 MiB scoped-VMEM limit on a v5e
@@ -170,6 +184,110 @@ def _kernel(n_ref, slot_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref, k_ref,
         vo_ref[0, 0] = v_ref[0, 0, :, pl.ds(0, _WRITE_ROWS), :]
 
 
+def _kernel_one_query(n_ref, slot_ref, pos_ref, layer_ref, q_ref, kn_ref,
+                      vn_ref, k_ref, v_ref, o_ref, ko_ref, vo_ref, m_sc, l_sc,
+                      acc_sc, s_sc, pv_sc, *, scale, heads, rows, block):
+    """:func:`_kernel`'s grid step when a K/V head has ONE query: the same
+    two products a head, but the step's ``heads`` score rows meet in
+    ``s_sc`` and run through ONE softmax chain ``[heads, block]``, and the
+    ``heads`` rows of ``p @ v`` meet in ``pv_sc`` for one update of the
+    accumulator (module docstring: what a chain a head cost). A function
+    of its own, so that :func:`_kernel` traces what it traced."""
+    del layer_ref                               # the index maps read it
+    j, b = pl.program_id(0), pl.program_id(1)
+    pos = pos_ref[slot_ref[j]]
+    at = pos % rows                             # the new row
+    n_live = jnp.minimum(pos + 1, rows)         # rows the slot attends
+    live = j < n_ref[0]
+    nt = (((1,), (1,)), ((), ()))
+
+    @pl.when(jnp.logical_and(live, b == 0))
+    def _():
+        m_sc[...] = jnp.full(m_sc.shape, _NEG_INF, jnp.float32)
+        l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    def attend(k_of, v_of, seen):
+        """Every head's tiles (``k_of(h)``, ``v_of(h)`` [block, 128]) into
+        the running softmax of the step's ``heads`` queries; ``seen`` as in
+        :func:`_kernel`."""
+        for h in range(heads):
+            s_sc[h:h + 1] = lax.dot_general(
+                q_ref[0, h], k_of(h), nt, preferred_element_type=jnp.float32)
+        s = s_sc[...] * scale                               # [heads, block]
+        if seen is not None:
+            s = jnp.where(seen, s, _NEG_INF)
+        m_prev = m_sc[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        s_sc[...] = p = jnp.exp(s - m_new)
+        l_sc[...] = alpha * l_sc[...] + jnp.sum(p, axis=1, keepdims=True)
+        m_sc[...] = m_new
+        for h in range(heads):
+            v = v_of(h)
+            pv_sc[h:h + 1] = jnp.dot(s_sc[h:h + 1].astype(v.dtype), v,
+                                     preferred_element_type=jnp.float32)
+        acc_sc[...] = alpha * acc_sc[...] + pv_sc[...]
+
+    first = b * block
+    holds_new = jnp.logical_and(first <= at, at < first + block)
+
+    # every row of the block is live and the new row lies elsewhere
+    @pl.when(jnp.logical_and(live, jnp.logical_and(
+        first + block <= n_live, jnp.logical_not(holds_new))))
+    def _():
+        attend(lambda h: k_ref[0, 0, h], lambda h: v_ref[0, 0, h], None)
+
+    @pl.when(jnp.logical_and(live, holds_new))
+    def _():
+        row = first + lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+        seen = first + lax.broadcasted_iota(jnp.int32, (1, block), 1) \
+            < n_live
+        # the 16 rows around the new one, as they go back to the slab
+        group = pl.multiple_of((at - first) // _WRITE_ROWS * _WRITE_ROWS,
+                               _WRITE_ROWS)
+        near = first + group + lax.broadcasted_iota(
+            jnp.int32, (_WRITE_ROWS, 1), 0)
+        for h in range(heads):
+            ko_ref[0, 0, h] = jnp.where(
+                near == at, kn_ref[0, h],
+                k_ref[0, 0, h, pl.ds(group, _WRITE_ROWS), :])
+            vo_ref[0, 0, h] = jnp.where(
+                near == at, vn_ref[0, h],
+                v_ref[0, 0, h, pl.ds(group, _WRITE_ROWS), :])
+
+        def v_of(h):
+            # rows past the live ones: selected away, as in `_kernel`
+            vn = vn_ref[0, h]
+            return jnp.where(row == at, vn,
+                             jnp.where(row < n_live, v_ref[0, 0, h],
+                                       jnp.zeros_like(vn)))
+
+        attend(lambda h: jnp.where(row == at, kn_ref[0, h], k_ref[0, 0, h]),
+               v_of, seen)
+
+    @pl.when(jnp.logical_and(live, b == pl.num_programs(1) - 1))
+    def _():
+        o_ref[0, 0] = acc_sc[...] / l_sc[...]
+
+    # no live slot at all: the write-back block still goes back, unchanged
+    @pl.when(jnp.logical_and(n_ref[0] == 0,
+                             jnp.logical_and(j == 0, b == 0)))
+    def _():
+        ko_ref[0, 0] = k_ref[0, 0, :, pl.ds(0, _WRITE_ROWS), :]
+        vo_ref[0, 0] = v_ref[0, 0, :, pl.ds(0, _WRITE_ROWS), :]
+
+
+def count_body(q, slab_k):
+    """With telemetry on, count which body :func:`kv_update_attend` takes
+    for these operands: `attn.decode.kv128.one_query` or `.grouped`. For
+    the caller's trace, once a layer: the kernel's own is shared by every
+    layer of a member and may be older than the caller's."""
+    if telemetry._enabled:
+        telemetry.counter("attn.decode.kv128." + (
+            "one_query" if q.shape[1] == slab_k.shape[2] else "grouped")).inc()
+
+
 @functools.partial(jax.jit, static_argnames=("block", "scale", "interpret"))
 def kv_update_attend(q, k_new, v_new, slab_k, slab_v, layer, positions, *,
                      block, scale=None, interpret=False):
@@ -181,7 +299,9 @@ def kv_update_attend(q, k_new, v_new, slab_k, slab_v, layer, positions, *,
     a full member, ``R = sliding_window`` a ring); a slot with a negative
     position is dead — nothing of it is read or written, and its attention
     is 0. ``q`` is ``[S, Hq, 128]`` with ``Hq`` a multiple of ``H``: query
-    head ``i`` reads K/V head ``i // (Hq // H)``. ``scale`` multiplies the
+    head ``i`` reads K/V head ``i // (Hq // H)``; ``Hq == H`` takes
+    :func:`_kernel_one_query`'s body, any other :func:`_kernel`'s (the
+    same mathematics at the same precision). ``scale`` multiplies the
     scores (None: ``1/sqrt(hd)``). Returns ``(attention [S, Hq, 128] fp32,
     slab_k, slab_v)``. ``block`` comes from :func:`kv_block`. ``layer`` is
     an int32 scalar and TRACED: every layer's call on a member shares one
@@ -217,9 +337,15 @@ def kv_update_attend(q, k_new, v_new, slab_k, slab_v, layer, positions, *,
         return (slot_ref[j], layer_ref[0], 0,
                 jnp.maximum(pos_ref[slot_ref[j]], 0) % rows // _WRITE_ROWS, 0)
 
+    # one query a head (a static fact of the trace): the body that runs the
+    # step's heads as one chain, its state a row a head
+    one = group == 1
     kernel = functools.partial(
-        _kernel, scale=1.0 / math.sqrt(hd) if scale is None else scale,
+        _kernel_one_query if one else _kernel,
+        scale=1.0 / math.sqrt(hd) if scale is None else scale,
         heads=heads, rows=rows, block=block)
+    state = (heads,) if one else (heads, group)
+    attended = (1, heads, hd) if one else (heads, group, hd)
     dt = slab_k.dtype
     out, slab_k, slab_v = pl.pallas_call(
         kernel,
@@ -234,17 +360,20 @@ def kv_update_attend(q, k_new, v_new, slab_k, slab_v, layer, positions, *,
                 pl.BlockSpec((1, 1, heads, block, hd), page),
             ],
             out_specs=[
-                pl.BlockSpec((1, heads, group, hd), row),
+                pl.BlockSpec((1,) + attended, row),
                 pl.BlockSpec((1, 1, heads, _WRITE_ROWS, hd), written),
                 pl.BlockSpec((1, 1, heads, _WRITE_ROWS, hd), written),
             ],
             scratch_shapes=[
-                pltpu.VMEM((heads, group, 1), jnp.float32),     # running max
-                pltpu.VMEM((heads, group, 1), jnp.float32),     # running sum
-                pltpu.VMEM((heads, group, hd), jnp.float32),    # p @ v
-            ]),
+                pltpu.VMEM(state + (1,), jnp.float32),          # running max
+                pltpu.VMEM(state + (1,), jnp.float32),          # running sum
+                pltpu.VMEM(state + (hd,), jnp.float32),         # p @ v
+            ] + ([
+                pltpu.VMEM((heads, block), jnp.float32),    # scores, then p
+                pltpu.VMEM((heads, hd), jnp.float32),       # the step's p @ v
+            ] if one else [])),
         out_shape=[
-            jax.ShapeDtypeStruct((n_slots, heads, group, hd), jnp.float32),
+            jax.ShapeDtypeStruct((n_slots,) + attended, jnp.float32),
             jax.ShapeDtypeStruct(slab_k.shape, slab_k.dtype),
             jax.ShapeDtypeStruct(slab_v.shape, slab_v.dtype),
         ],

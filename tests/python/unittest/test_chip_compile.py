@@ -8,6 +8,7 @@ Also pins the shape tests that decide, before the call, whether a kernel
 applies: lengths no accepted block divides and K/V beyond the fast-memory
 budget go to the XLA path instead of to a compiler refusal.
 """
+import contextlib
 import os
 import re
 
@@ -51,6 +52,26 @@ def one_chip():
 
 def _compiled_text(fn, *avals):
     return jax.jit(fn).lower(*avals).compile().as_text()
+
+
+@contextlib.contextmanager
+def _counted(prefix, kinds):
+    """Telemetry on around a trace: yields a dict that holds, once the
+    block has ended, how far each counter `prefix + kind` moved in it."""
+    from mxnet_tpu import telemetry
+
+    counters = {k: telemetry.counter(prefix + k) for k in kinds}
+    before = {k: c.value for k, c in counters.items()}
+    was, moved = telemetry.enabled(), {}
+    telemetry.enable()
+    try:
+        yield moved
+    finally:
+        telemetry.enable(was)
+        moved.update({k: c.value - before[k] for k, c in counters.items()})
+
+
+KV128_BODIES = ("attn.decode.kv128.", ("one_query", "grouped"))
 
 
 @pytest.mark.parametrize("shape,dtype", [
@@ -595,8 +616,11 @@ def test_window_moe_decode_program_compiles_for_v5e(one_chip, monkeypatch,
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), tuple(cache)
 
     ints = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
-    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
-        params, cache, ints, ints).compile()
+    with _counted(*KV128_BODIES) as bodies:
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+            params, cache, ints, ints).compile()
+    # 8 queries a K/V head: every layer's trace took the grouped body
+    assert bodies == {"one_query": 0, "grouped": 4}
     text = compiled.as_text()
     assert len(re.findall(r"%kv128_attend[.\d]* = ", text)) == 4
     assert len(re.findall(r"%gmm[.\d]* = ", text)) == 8
@@ -883,7 +907,9 @@ def test_gdn_state_update_compiles_at_its_bytes_for_v5e(one_chip):
 
 def test_kv128_attend_compiles_at_one_query_a_head_for_v5e(one_chip):
     """The decode kernel at 30 K/V heads with ONE query each (a `[1, 128]`
-    query tile, a `(30, 1, 1)` scratch) over the cell's full member, whose
+    query tile a head, the step's 30 score rows one `(30, 128)` scratch:
+    ISSUE 44's body, which hands back `[S, 1, 30, 128]` where the grouped
+    body hands back `[S, H, G, 128]`) over the cell's full member, whose
     block `kv_block`'s budget puts at 128 rows."""
     from mxnet_tpu.ops import pallas_window
 
@@ -903,6 +929,8 @@ def test_kv128_attend_compiles_at_one_query_a_head_for_v5e(one_chip):
         sds((32, 30, 128)), sds((32, 30, 128)), sds((32, 30, 128)),
         sds(slab), sds(slab), sds((32,), jnp.int32)).compile().as_text()
     assert len(re.findall(r"%kv128_attend[.\d]* = ", text)) == 1
+    call = re.search(r"%kv128_attend[.\d]* = \((\S+)", text).group(1)
+    assert call.startswith("f32[32,1,30,128]"), call
 
 
 @pytest.mark.parametrize("length", [256, 512, 1024])
@@ -971,9 +999,8 @@ def test_olmo_decode_program_compiles_for_v5e(one_chip, monkeypatch,
     `L`-minor, and not the XLA formulation); every member of the cache is
     aliased input to output; no XLA op copies, slices, updates or re-lays a
     K/V page or a layer's page of the state slab. The trace counts which way
-    each linear layer's state update went."""
-    from mxnet_tpu import telemetry
-
+    each linear layer's state update went, and which body of the slab
+    kernel the full layer took."""
     lm, params, cache = olmo_period
     monkeypatch.setenv("MXNET_PALLAS_ATTENTION", "1")
     monkeypatch.delenv("MXNET_PALLAS_INTERPRET", raising=False)
@@ -985,18 +1012,13 @@ def test_olmo_decode_program_compiles_for_v5e(one_chip, monkeypatch,
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), tuple(cache)
 
     ints = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
-    went = {k: telemetry.counter("gdn.state_update." + k)
-            for k in ("kernel", "xla")}
-    before = {k: c.value for k, c in went.items()}
-    was = telemetry.enabled()
-    telemetry.enable()
-    try:
+    with _counted("gdn.state_update.", ("kernel", "xla")) as went, \
+            _counted(*KV128_BODIES) as bodies:
         compiled = jax.jit(fn, donate_argnums=(1,)).lower(
             params, cache, ints, ints).compile()
-    finally:
-        telemetry.enable(was)
-    assert {k: c.value - before[k] for k, c in went.items()} == {
-        "kernel": 3, "xla": 0}
+    assert went == {"kernel": 3, "xla": 0}
+    # one query a K/V head: the full layer's trace took the one-chain body
+    assert bodies == {"one_query": 1, "grouped": 0}
     text = compiled.as_text()
     assert len(re.findall(r"%gdn_state_update[.\d]* = ", text)) == 3
     assert len(re.findall(r"%kv128_attend[.\d]* = ", text)) == 1

@@ -407,6 +407,106 @@ def test_kv128_kernel_at_one_query_a_head(alive):
     _close(got, want, "decode kernel")
 
 
+ONE_QUERY_ROWS = 256                    # two blocks of 128
+ONE_QUERY_POSITIONS = {
+    # both sides of a block's edge, row 0, the last row
+    "full": [127, 128, 0, ONE_QUERY_ROWS - 1],
+    # a ring that has wrapped (p >= R): rows 127, 0, 128 and 255 again
+    "ring": [ONE_QUERY_ROWS + 127, ONE_QUERY_ROWS, 3 * ONE_QUERY_ROWS + 128,
+             4 * ONE_QUERY_ROWS - 1]}
+
+
+@pytest.mark.parametrize("alive", [[1, 1, 1, 1], [0, 1, 1, 0], [0, 0, 0, 0]],
+                         ids=["all", "some", "none"])
+@pytest.mark.parametrize("heads", [3, 30])
+@pytest.mark.parametrize("member", ["full", "ring"])
+def test_kv128_one_query_body(member, heads, alive):
+    """The body `kv_update_attend` takes at ONE query a K/V head — the
+    step's heads as one softmax chain `[heads, block]` (ISSUE 44) —
+    interpreted, against the XLA formulation: positions on both sides of a
+    block's edge, row 0 and the last row of a full member, a ring that has
+    wrapped; 3 heads and 30 (no multiple of the 8 sublanes of a vreg: what
+    pads the chain's last vreg must reach no max and no sum); all, some and
+    no slot alive (a dead slot's rows, `inf` and `nan`, go back as they
+    came, and with no live slot the write-back block does); `inf` in the K
+    rows and `nan` in the V rows past a live slot's position and in the row
+    it writes; a scale that is not `1/sqrt(128)`. The slabs bit-equal, the
+    attention at the file's tolerance."""
+    from mxnet_tpu.models import window_moe
+
+    rng = np.random.default_rng(11)
+    slots, layers, rows, hd = 4, 2, ONE_QUERY_ROWS, 128
+    positions = np.where(alive, ONE_QUERY_POSITIONS[member],
+                         -1).astype(np.int32)
+    slab_k, slab_v = (rng.normal(size=(slots, layers, heads, rows, hd))
+                      .astype("f4") for _ in range(2))
+    for s, p in enumerate(positions):
+        for slab, left in ((slab_k, np.inf), (slab_v, np.nan)):
+            if p < 0:
+                slab[s] = left                  # a dead slot: all of it
+            else:
+                slab[s, :, :, min(p + 1, rows):] = left
+                slab[s, :, :, p % rows] = left  # the row to write
+    q, k_new, v_new = (jnp.asarray(rng.normal(size=(slots, heads, hd)),
+                                   jnp.float32) for _ in range(3))
+    at = jnp.asarray(np.where(positions >= 0, positions % rows, -1))
+    want_k = tfm._write_rows(jnp.asarray(slab_k), 1, at, k_new)
+    want_v = tfm._write_rows(jnp.asarray(slab_v), 1, at, v_new)
+    want = window_moe._attend_member(q, want_k[:, 1], want_v[:, 1],
+                                     jnp.asarray(positions), 0.37)
+    got, got_k, got_v = pallas_window.kv_update_attend(
+        q, k_new, v_new, jnp.asarray(slab_k), jnp.asarray(slab_v),
+        jnp.int32(1), jnp.asarray(positions), block=128, scale=0.37,
+        interpret=True)
+    np.testing.assert_array_equal(np.asarray(got_k), np.asarray(want_k))
+    np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v))
+    if any(alive):
+        _close(got, want, "decode kernel, one query a head")
+    assert not np.asarray(got)[positions < 0].any()
+
+
+GROUPED_LOWERED = {     # sha256 of the lowered text at commit 7b7cd92 (PR 42)
+    # mellum2_12b_l8: 32 queries over 4 K/V heads, 2 full layers, 6 rings
+    ((32, 2, 4, 16384, 128), 32):
+        "b0c08eacc2a827880f4f1b9b857f7fcb68de5e9c460ed5090df194ecf862ee9c",
+    ((32, 6, 4, 1024, 128), 32):
+        "27506d1d51315436b0e0416e366ef7394174984c921087c2914409aaf25bf2a1",
+    # trinity_large_ep8: 48 queries over 8 K/V heads, a full layer, 4 rings
+    ((32, 1, 8, 16384, 128), 48):
+        "07c5c3d4ecce73a86dd47412ae8973dda69e2bf3979ce9ac9bdcd22d30a6593e",
+    ((32, 4, 8, 4096, 128), 48):
+        "71d9a83b92202a3d1ad5113294c9d2cc6147c7b812b5e439797d283eec8536c5"}
+
+
+@pytest.mark.parametrize("slab,q_heads", list(GROUPED_LOWERED), ids=[
+    "mellum-full", "mellum-rings", "trinity-full", "trinity-rings"])
+def test_kv128_grouped_lowers_what_it_did(slab, q_heads):
+    """The one-query body is a branch on a static fact of the trace: at
+    mellum's 8 and Trinity's 6 queries a K/V head, over the members of
+    their cells, `kv_update_attend` lowers to the text it lowered to before
+    the branch was there (its sha256, taken on the parent commit; the
+    kernel interpreted, so the text is the kernel's own operations, the
+    grid, the block specs and the aliasing — a Mosaic lowering carries the
+    source's line numbers and moves with any edit above the kernel)."""
+    block = pallas_window.kv_block(slab, jnp.bfloat16)
+    assert block == {4: 1024, 8: 512}[slab[2]]
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    def fn(q, k, v, slab_k, slab_v, positions):
+        return pallas_window.kv_update_attend(
+            q, k, v, slab_k, slab_v, jnp.int32(0), positions, block=block,
+            scale=128 ** -0.5, interpret=True)
+
+    text = jax.jit(fn, donate_argnums=(3, 4)).lower(
+        sds((32, q_heads, 128)), sds((32, slab[2], 128)),
+        sds((32, slab[2], 128)), sds(slab), sds(slab),
+        sds((32,), jnp.int32)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == GROUPED_LOWERED[slab, q_heads]
+
+
 GRANITE_LOWERED = {     # sha256 of the lowered text at commit eef6210 (PR 41)
     ("xla", "decode"):
         "3d4c97a630722d1d0cf1191e443c763ca2eb783cc2399c21741a353ef06d79c7",
