@@ -20,13 +20,20 @@ attends its first ``min(p + 1, R)`` rows:
   left, and are selected away.
 
 Softmax does not care in which order the rows come, so the ring is never
-unrolled. The live slots are taken first in the grid ``(slot, row block)``,
-positions ride as scalar-prefetch operands, the block index is clamped to
-the slot's last live block (no change, no DMA), ``pl.when`` skips what lies
-past it (`pallas_decode.py`'s grid has no such step: it holds the live
-blocks only), and as there the new row is merged into its block in VMEM and
-goes back to the slab through an output aliased to the input — 16 rows, one
-packed bfloat16 tile: no XLA scatter or ``dynamic-update-slice``. The
+unrolled. The grid is `pallas_decode.py`'s: ONE axis with a step for every
+LIVE block of the tick and no other — the live slots in slot order, each
+from block 0 to its last live block ``(min(p + 1, R) - 1) // block``, the
+full member's ``p // block`` and a wrapped ring's last (`pallas_decode.
+live_steps` of the positions clamped to ``R - 1``; the bound is dynamic,
+and the step's slot and block ride as scalar-prefetch operands beside the
+positions). A dead slot and a block past a position have no step: stepped
+over under ``pl.when`` they cost no DMA but 0.45-0.49 us each on a v5e,
+half of the 2,048 steps a tick of the Olmo block's four full layers (PR
+44 measured, PR 45 took them out). A tick with no live slot takes one
+step, which sends its write-back block back as it came. As there, the new
+row is merged into its block in VMEM and goes back to the slab through an
+output aliased to the input — 16 rows, one packed bfloat16 tile: no XLA
+scatter or ``dynamic-update-slice``. The
 ``G`` query heads of a K/V head are scored as ONE ``[G, 128] x [128,
 block]`` product on the MXU and summed as one ``[G, block] x [block, 128]``:
 ``G`` FLOPs a cache byte (8 at mellum's 32:4 heads, 6 at Trinity's 48:8; any
@@ -69,6 +76,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .. import telemetry
 from .pallas_attention import _NEG_INF, _LANES, _divisor_block
+from .pallas_decode import live_steps
 
 __all__ = ["kv_block", "kv_update_attend", "count_body", "band_block",
            "band_steps", "band_prefill_attend"]
@@ -98,19 +106,23 @@ def kv_block(slab_shape, dtype, target=1024):
     return block
 
 
-def _kernel(n_ref, slot_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref, k_ref,
-            v_ref, o_ref, ko_ref, vo_ref, m_sc, l_sc, acc_sc, *, scale, heads,
-            rows, block):
-    """One (slot, row block) grid step: every K/V head's ``[block, 128]`` K
-    and V tiles against the slot's ``G`` queries of that head, streamed
-    into a running softmax (fp32); the block that holds the slot's row takes
-    the new row, and the 16 rows around it go back to the slab."""
+def _kernel(slot_ref, block_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref,
+            k_ref, v_ref, o_ref, ko_ref, vo_ref, m_sc, l_sc, acc_sc, *, scale,
+            heads, rows, block):
+    """One grid step, which is one LIVE block ``block_ref[t]`` of slot
+    ``slot_ref[t]`` (`pallas_decode.live_steps`: a slot's steps follow one
+    another, block 0 first): every K/V head's ``[block, 128]`` K and V
+    tiles against the slot's ``G`` queries of that head, streamed into a
+    running softmax (fp32); the block that holds the slot's row takes the
+    new row, and the 16 rows around it go back to the slab."""
     del layer_ref                               # the index maps read it
-    j, b = pl.program_id(0), pl.program_id(1)
-    pos = pos_ref[slot_ref[j]]
+    t = pl.program_id(0)
+    b = block_ref[t]
+    pos = pos_ref[slot_ref[t]]
     at = pos % rows                             # the new row
     n_live = jnp.minimum(pos + 1, rows)         # rows the slot attends
-    live = j < n_ref[0]
+    # only the one step of a tick with no live slot has a dead one
+    live = pos >= 0
     nt = (((1,), (1,)), ((), ()))
 
     @pl.when(jnp.logical_and(live, b == 0))
@@ -171,20 +183,20 @@ def _kernel(n_ref, slot_ref, pos_ref, layer_ref, q_ref, kn_ref, vn_ref, k_ref,
                 near == at, vn, v_ref[0, 0, h, pl.ds(group, _WRITE_ROWS), :])
             attend(h, k, v, seen)
 
-    @pl.when(jnp.logical_and(live, b == pl.num_programs(1) - 1))
+    # the slot's last live block (in a wrapped ring not the new row's)
+    @pl.when(jnp.logical_and(live, b == (n_live - 1) // block))
     def _():
         for h in range(heads):
             o_ref[0, h] = acc_sc[h] / l_sc[h]
 
     # no live slot at all: the write-back block still goes back, unchanged
-    @pl.when(jnp.logical_and(n_ref[0] == 0,
-                             jnp.logical_and(j == 0, b == 0)))
+    @pl.when(jnp.logical_not(live))
     def _():
         ko_ref[0, 0] = k_ref[0, 0, :, pl.ds(0, _WRITE_ROWS), :]
         vo_ref[0, 0] = v_ref[0, 0, :, pl.ds(0, _WRITE_ROWS), :]
 
 
-def _kernel_one_query(n_ref, slot_ref, pos_ref, layer_ref, q_ref, kn_ref,
+def _kernel_one_query(slot_ref, block_ref, pos_ref, layer_ref, q_ref, kn_ref,
                       vn_ref, k_ref, v_ref, o_ref, ko_ref, vo_ref, m_sc, l_sc,
                       acc_sc, s_sc, pv_sc, *, scale, heads, rows, block):
     """:func:`_kernel`'s grid step when a K/V head has ONE query: the same
@@ -194,11 +206,13 @@ def _kernel_one_query(n_ref, slot_ref, pos_ref, layer_ref, q_ref, kn_ref,
     accumulator (module docstring: what a chain a head cost). A function
     of its own, so that :func:`_kernel` traces what it traced."""
     del layer_ref                               # the index maps read it
-    j, b = pl.program_id(0), pl.program_id(1)
-    pos = pos_ref[slot_ref[j]]
+    t = pl.program_id(0)
+    b = block_ref[t]
+    pos = pos_ref[slot_ref[t]]
     at = pos % rows                             # the new row
     n_live = jnp.minimum(pos + 1, rows)         # rows the slot attends
-    live = j < n_ref[0]
+    # only the one step of a tick with no live slot has a dead one
+    live = pos >= 0
     nt = (((1,), (1,)), ((), ()))
 
     @pl.when(jnp.logical_and(live, b == 0))
@@ -266,13 +280,13 @@ def _kernel_one_query(n_ref, slot_ref, pos_ref, layer_ref, q_ref, kn_ref,
         attend(lambda h: jnp.where(row == at, kn_ref[0, h], k_ref[0, 0, h]),
                v_of, seen)
 
-    @pl.when(jnp.logical_and(live, b == pl.num_programs(1) - 1))
+    # the slot's last live block (in a wrapped ring not the new row's)
+    @pl.when(jnp.logical_and(live, b == (n_live - 1) // block))
     def _():
         o_ref[0, 0] = acc_sc[...] / l_sc[...]
 
     # no live slot at all: the write-back block still goes back, unchanged
-    @pl.when(jnp.logical_and(n_ref[0] == 0,
-                             jnp.logical_and(j == 0, b == 0)))
+    @pl.when(jnp.logical_not(live))
     def _():
         ko_ref[0, 0] = k_ref[0, 0, :, pl.ds(0, _WRITE_ROWS), :]
         vo_ref[0, 0] = v_ref[0, 0, :, pl.ds(0, _WRITE_ROWS), :]
@@ -305,7 +319,9 @@ def kv_update_attend(q, k_new, v_new, slab_k, slab_v, layer, positions, *,
     scores (None: ``1/sqrt(hd)``). Returns ``(attention [S, Hq, 128] fp32,
     slab_k, slab_v)``. ``block`` comes from :func:`kv_block`. ``layer`` is
     an int32 scalar and TRACED: every layer's call on a member shares one
-    trace and lowering."""
+    trace and lowering. The grid is the tick's live blocks (module
+    docstring): the work list is a function of ``positions``, ``block`` and
+    ``R`` alone, so XLA computes it once for the layers of a member."""
     n_slots, _, heads, rows, hd = slab_k.shape
     if hd != _LANES or rows % block or block % _LANES:
         raise ValueError(f"kv_update_attend: block {block} does not tile "
@@ -317,25 +333,21 @@ def kv_update_attend(q, k_new, v_new, slab_k, slab_v, layer, positions, *,
     group = q_heads // heads
     positions = positions.astype(jnp.int32)
     alive = positions >= 0
-    # live slots first, in slot order; the steps past them stay on the last
-    # live slot's last block (no DMA, no compute, nothing written)
-    n_live = jnp.sum(alive, dtype=jnp.int32)
-    order = jnp.argsort(jnp.logical_not(alive), stable=True).astype(jnp.int32)
-    slot_of = order[jnp.minimum(jnp.arange(n_slots, dtype=jnp.int32),
-                                jnp.maximum(n_live - 1, 0))]
+    # a step a live block: min(p + 1, R) rows are `min(p, R - 1) // block + 1`
+    # blocks, the full member's `p // block + 1` and a wrapped ring's all
+    n_steps, slot_of, block_of = live_steps(
+        jnp.where(alive, jnp.minimum(positions, rows - 1), -1), block,
+        rows // block)
 
-    def row(j, b, n_ref, slot_ref, pos_ref, layer_ref):
-        return (slot_ref[j], 0, 0, 0)
+    def row(t, slot_ref, block_ref, pos_ref, layer_ref):
+        return (slot_ref[t], 0, 0, 0)
 
-    def page(j, b, n_ref, slot_ref, pos_ref, layer_ref):
-        pos = jnp.maximum(pos_ref[slot_ref[j]], 0)
-        last = (jnp.minimum(pos + 1, rows) - 1) // block
-        return (slot_ref[j], layer_ref[0], 0,
-                jnp.where(j < n_ref[0], jnp.minimum(b, last), last), 0)
+    def page(t, slot_ref, block_ref, pos_ref, layer_ref):
+        return (slot_ref[t], layer_ref[0], 0, block_ref[t], 0)
 
-    def written(j, b, n_ref, slot_ref, pos_ref, layer_ref):
-        return (slot_ref[j], layer_ref[0], 0,
-                jnp.maximum(pos_ref[slot_ref[j]], 0) % rows // _WRITE_ROWS, 0)
+    def written(t, slot_ref, block_ref, pos_ref, layer_ref):
+        return (slot_ref[t], layer_ref[0], 0,
+                jnp.maximum(pos_ref[slot_ref[t]], 0) % rows // _WRITE_ROWS, 0)
 
     # one query a head (a static fact of the trace): the body that runs the
     # step's heads as one chain, its state a row a head
@@ -351,7 +363,7 @@ def kv_update_attend(q, k_new, v_new, slab_k, slab_v, layer, positions, *,
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(n_slots, rows // block),
+            grid=(n_steps,),
             in_specs=[
                 pl.BlockSpec((1, heads, group, hd), row),
                 pl.BlockSpec((1, heads, 1, hd), row),
@@ -380,10 +392,10 @@ def kv_update_attend(q, k_new, v_new, slab_k, slab_v, layer, positions, *,
         # operands count the scalar-prefetch ones: the slabs are 7 and 8
         input_output_aliases={7: 1, 8: 2},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         name="kv128_attend",
         interpret=interpret,
-    )(n_live[None], slot_of, positions,
+    )(slot_of, block_of, positions,
       jnp.asarray(layer, jnp.int32).reshape(1),
       q.astype(dt).reshape(n_slots, heads, group, hd),
       k_new.astype(dt)[:, :, None, :], v_new.astype(dt)[:, :, None, :],

@@ -1275,7 +1275,10 @@ class GenerationEngine:
         keeps the XLA formulation, which reads every row). With the slab
         kernel ``slab_blocks_live`` IS its grid: `decode_update_attend`
         takes one step a live block a layer (`pallas_decode.live_steps`)
-        and none for the rest. Host arithmetic on positions the tick
+        and none for the rest — and so does `pallas_window.
+        kv_update_attend` on a FULL member, whose block the models that use
+        it give as the trait (a ring's grid is its own rows' blocks, which
+        nothing here counts). Host arithmetic on positions the tick
         already holds."""
         from ...ops.pallas_decode import live_blocks
 

@@ -18,6 +18,7 @@ from mxnet_tpu.ops import pallas_decode as pd
 from mxnet_tpu.serving import GenerationEngine
 
 import slab_kernel_2d
+from pallas_grid import grid_of_call
 
 S, NL, H, L, LAYER = 6, 2, 2, 512, 1
 BLOCK = 256                      # what decode_block gives for L=512
@@ -212,19 +213,10 @@ def _grid_of(positions):
     (before the positions and the layer), evaluated."""
     n = len(positions)
     row, slab = jnp.zeros((n, H, 64)), jnp.zeros((n, NL, H, L, 64))
-    args = (row, row, row, slab, slab, jnp.asarray(positions, jnp.int32))
-    closed = jax.make_jaxpr(
+    bound, slot_of, block_of, pos = grid_of_call(
         lambda q, k, v, ck, cv, pos: pd.decode_update_attend.__wrapped__(
-            q, k, v, ck, cv, LAYER, pos, block=BLOCK))(*args)
-    (at, call), = [(i, e) for i, e in enumerate(closed.jaxpr.eqns)
-                   if e.primitive.name == "pallas_call"]
-    grid = call.params["grid_mapping"]
-    assert len(grid.grid) == grid.num_dynamic_grid_bounds == 1
-    assert grid.num_index_operands == 4    # the lists, positions, the layer
-    upto = closed.jaxpr.replace(outvars=call.invars[:4],
-                                eqns=closed.jaxpr.eqns[:at])
-    bound, slot_of, block_of, pos = map(np.asarray, jax.core.eval_jaxpr(
-        upto, closed.consts, *args))
+            q, k, v, ck, cv, LAYER, pos, block=BLOCK),
+        row, row, row, slab, slab, jnp.asarray(positions, jnp.int32))
     assert pos.tolist() == list(positions)
     return int(bound), slot_of, block_of
 

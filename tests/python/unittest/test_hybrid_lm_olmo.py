@@ -465,17 +465,17 @@ def test_kv128_one_query_body(member, heads, alive):
     assert not np.asarray(got)[positions < 0].any()
 
 
-GROUPED_LOWERED = {     # sha256 of the lowered text at commit 7b7cd92 (PR 42)
+GROUPED_LOWERED = {     # sha256 of the lowered text on PR 45's tree (see below)
     # mellum2_12b_l8: 32 queries over 4 K/V heads, 2 full layers, 6 rings
     ((32, 2, 4, 16384, 128), 32):
-        "b0c08eacc2a827880f4f1b9b857f7fcb68de5e9c460ed5090df194ecf862ee9c",
+        "e87e78d5f7f524f37be2c083244315ab813d993b514f58ec448ca6638e00b7e2",
     ((32, 6, 4, 1024, 128), 32):
-        "27506d1d51315436b0e0416e366ef7394174984c921087c2914409aaf25bf2a1",
+        "d485ec629f8b761e6ac9a1915b9e90a3b20e8a73f54e9516dae89f7a8b8c680e",
     # trinity_large_ep8: 48 queries over 8 K/V heads, a full layer, 4 rings
     ((32, 1, 8, 16384, 128), 48):
-        "07c5c3d4ecce73a86dd47412ae8973dda69e2bf3979ce9ac9bdcd22d30a6593e",
+        "e345e72c91197899442e6644869c4ce8e50fb0fc364c991e0d349a724b631096",
     ((32, 4, 8, 4096, 128), 48):
-        "71d9a83b92202a3d1ad5113294c9d2cc6147c7b812b5e439797d283eec8536c5"}
+        "c2e169cabd47f8562f259c7b5b26a771a150c6c2fa2196a026fd852289ba2a4e"}
 
 
 @pytest.mark.parametrize("slab,q_heads", list(GROUPED_LOWERED), ids=[
@@ -483,11 +483,17 @@ GROUPED_LOWERED = {     # sha256 of the lowered text at commit 7b7cd92 (PR 42)
 def test_kv128_grouped_lowers_what_it_did(slab, q_heads):
     """The one-query body is a branch on a static fact of the trace: at
     mellum's 8 and Trinity's 6 queries a K/V head, over the members of
-    their cells, `kv_update_attend` lowers to the text it lowered to before
-    the branch was there (its sha256, taken on the parent commit; the
-    kernel interpreted, so the text is the kernel's own operations, the
+    their cells, `kv_update_attend` lowers to a pinned text (its sha256;
+    the kernel interpreted, so the text is the kernel's own operations, the
     grid, the block specs and the aliasing — a Mosaic lowering carries the
-    source's line numbers and moves with any edit above the kernel)."""
+    source's line numbers and moves with any edit above the kernel).
+    The values are of the commit of PR 45 (on top of 231f04e, PR 44), which
+    made the grid one axis over the tick's live blocks: the text carries
+    the grid and the block specs, so it moved by design. The grouped
+    body's arithmetic did not change — a step learns its slot and block
+    from the work list, no more — which is held numerically, not by this
+    text: `test_window_moe_lm.py::test_kv128_grid_is_the_live_blocks` and
+    `test_decode_kernel_matches_restatement`."""
     block = pallas_window.kv_block(slab, jnp.bfloat16)
     assert block == {4: 1024, 8: 512}[slab[2]]
 

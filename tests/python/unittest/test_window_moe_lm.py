@@ -41,6 +41,8 @@ from reference import mellum_swa_moe as ref  # noqa: E402
 from reference import trinity_afmoe as aref  # noqa: E402
 from runners import serve_afmoe, serve_swa_moe  # noqa: E402
 
+from pallas_grid import grid_of_call  # noqa: E402
+
 ref.PAD_TO = ref.BLOCK = 16     # the chip's sizes would spend these tiny tests on padding
 aref.PAD_TO = aref.BLOCK = 16
 
@@ -334,6 +336,123 @@ def test_decode_kernel_matches_restatement(alive, rows):
         _close(got, want, "decode kernel")
     else:
         assert not np.asarray(got).any()
+
+
+GRID_ROWS, GRID_BLOCK, GRID_LAYER = 512, 128, 1
+# (K/V heads, queries a head): Trinity's 6, mellum's 8, the Olmo block's one
+GRID_BODIES = {"grouped6": (2, 6), "grouped8": (2, 8), "one_query": (3, 1)}
+GRID_POSITIONS = {
+    # a dead slot between live ones; both sides of a block's edge; the last
+    # row; and an empty block after every live slot but the full one
+    "full": [300, -1, 127, GRID_ROWS - 1, 128, -1],
+    # a ring the positions have not filled: the rows past them are the
+    # previous occupant's; one slot on the row that fills it
+    "ring_filling": [5, -1, 255, GRID_ROWS - 1, 256, -1],
+    # a ring wrapped once and several times, the new row in a MIDDLE block
+    # (1, 2, 1, 2): the slot's last step is block 3, not the new row's
+    "ring_wrapped": [GRID_ROWS + 200, -1, 3 * GRID_ROWS + 300,
+                     GRID_ROWS + 130, -1, 2 * GRID_ROWS + 383]}
+
+
+def _grid_operands(body, positions, seed=13):
+    """Operands of one `kv_update_attend` call on a member of `GRID_ROWS`
+    rows (4 blocks) with `inf` in K and `nan` in V wherever the tick may
+    not look: past a live slot's rows, in the row it writes, and all of a
+    dead slot."""
+    heads, group = GRID_BODIES[body]
+    rng = np.random.default_rng(seed)
+    slots, layers, hd = len(positions), 2, 128
+    slab_k, slab_v = (rng.normal(size=(slots, layers, heads, GRID_ROWS, hd))
+                      .astype("f4") for _ in range(2))
+    for s, p in enumerate(positions):
+        for slab, left in ((slab_k, np.inf), (slab_v, np.nan)):
+            if p < 0:
+                slab[s] = left
+            else:
+                slab[s, :, :, min(p + 1, GRID_ROWS):] = left
+                slab[s, :, :, p % GRID_ROWS] = left
+    q = jnp.asarray(rng.normal(size=(slots, heads * group, hd)), jnp.float32)
+    k_new, v_new = (jnp.asarray(rng.normal(size=(slots, heads, hd)),
+                                jnp.float32) for _ in range(2))
+    return (q, k_new, v_new, jnp.asarray(slab_k), jnp.asarray(slab_v),
+            jnp.asarray(positions, jnp.int32))
+
+
+def _kv128_grid(*operands):
+    """What `kv128_attend`'s call is handed for its grid: the bound and the
+    work list, evaluated."""
+    bound, slot_of, block_of, pos = grid_of_call(
+        lambda q, k, v, sk, sv, pos: pallas_window.kv_update_attend
+        .__wrapped__(q, k, v, sk, sv, GRID_LAYER, pos, block=GRID_BLOCK,
+                     interpret=True), *operands)
+    assert pos.tolist() == np.asarray(operands[-1]).tolist()
+    return int(bound), slot_of.tolist(), block_of.tolist()
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint32)
+
+
+@pytest.mark.parametrize("member", list(GRID_POSITIONS))
+@pytest.mark.parametrize("body", list(GRID_BODIES))
+def test_kv128_grid_is_the_live_blocks(body, member):
+    """`kv128_attend`'s grid is ONE axis with a step a LIVE block (ISSUE
+    45; `pallas_decode.live_steps`), interpreted, for both bodies: the
+    bound handed to the call is the sum over the slots of `(min(p + 1, R)
+    - 1) // block + 1` — a full member's `p // block + 1`, a wrapped ring's
+    every block — and the work list walks the live slots in slot order,
+    each from block 0; `inf` and `nan` in the blocks past a position and in
+    every block of a dead slot reach neither the attention nor the slab;
+    a live slot changes ONE row of the slab (so one 16-row group), a dead
+    slot none; the attention is the plain `jax.numpy` formulation's and the
+    slabs are its, bit for bit."""
+    positions = GRID_POSITIONS[member]
+    operands = _grid_operands(body, positions)
+    q, k_new, v_new, slab_k, slab_v, pos = operands
+    walk = [(s, b) for s, p in enumerate(positions) if p >= 0
+            for b in range((min(p + 1, GRID_ROWS) - 1) // GRID_BLOCK + 1)]
+    assert len(walk) == {"full": 10, "ring_filling": 10,
+                         "ring_wrapped": 16}[member]
+    bound, slot_of, block_of = _kv128_grid(*operands)
+    assert bound == len(walk)
+    assert list(zip(slot_of, block_of))[:bound] == walk
+    assert len(slot_of) == len(positions) * (GRID_ROWS // GRID_BLOCK)
+    assert 0 <= min(slot_of) and max(slot_of) < len(positions)
+    assert 0 <= min(block_of) and max(block_of) < GRID_ROWS // GRID_BLOCK
+
+    at = jnp.where(pos >= 0, pos % GRID_ROWS, -1)
+    want_k = _write_rows(slab_k, GRID_LAYER, at, k_new)
+    want_v = _write_rows(slab_v, GRID_LAYER, at, v_new)
+    want = window_moe._attend_member(q, want_k[:, GRID_LAYER],
+                                     want_v[:, GRID_LAYER], pos, 0.31)
+    got, got_k, got_v = pallas_window.kv_update_attend(
+        *operands[:5], jnp.int32(GRID_LAYER), pos, block=GRID_BLOCK,
+        scale=0.31, interpret=True)
+    _close(got, want, "the live-only grid")
+    assert not np.asarray(got)[np.asarray(positions) < 0].any()
+    for before, after, wanted in ((slab_k, got_k, want_k),
+                                  (slab_v, got_v, want_v)):
+        assert np.array_equal(_bits(after), _bits(wanted))
+        moved = (_bits(after) != _bits(before)).any(axis=(2, 4))
+        for s, p in enumerate(positions):   # [layers, rows] a slot
+            rows = np.argwhere(moved[s]).tolist()
+            assert rows == ([] if p < 0 else [[GRID_LAYER, p % GRID_ROWS]])
+
+
+@pytest.mark.parametrize("body", list(GRID_BODIES))
+def test_kv128_grid_of_a_tick_with_no_live_slot(body):
+    """No live slot: the grid is one step, which sends its write-back block
+    back as it came — the slabs (every row `inf` or `nan`) return bit for
+    bit and the attention is zeros."""
+    operands = _grid_operands(body, [-1, -1, -1])
+    bound, slot_of, block_of = _kv128_grid(*operands)
+    assert bound == 1 and block_of[0] == 0
+    got, got_k, got_v = pallas_window.kv_update_attend(
+        *operands[:5], jnp.int32(GRID_LAYER), operands[5], block=GRID_BLOCK,
+        interpret=True)
+    assert not np.asarray(got).any()
+    assert np.array_equal(_bits(got_k), _bits(operands[3]))
+    assert np.array_equal(_bits(got_v), _bits(operands[4]))
 
 
 @pytest.mark.parametrize("window", [None, 128, 100, 300])
