@@ -203,14 +203,15 @@ unit() {
   # attributed, not as a flaky assertion inside an unrelated suite
   log "health suite (SLO tracker, liveness/readiness, stall watchdog + capture, router drain, chaos acceptance)"
   python -m pytest tests/python/unittest/test_health.py -q
-  # overlap gate, standalone: these tests flip MXNET_OVERLAP / the
-  # telemetry registry, spin the DeviceStager staging thread and pin
-  # N-step BIT-EXACT parameter parity vs the MXNET_OVERLAP=0 lockstep
-  # reference (SGD+Adam across fused/zero1/spmd), staged-buffer donation
-  # safety under in-flight reuse, serving flush parity with zero
-  # steady-state compiles, and pad-buffer identity stability — a
-  # pipeline-ordering or staging regression fails HERE, attributed
-  log "overlap suite (async dispatch pipeline parity, staged donation safety, deferred metric lane)"
+  # overlap gate, standalone: these tests flip the telemetry registry,
+  # spin the DeviceStager staging thread and pin N-step BIT-EXACT
+  # parameter and epoch-metric parity of `fit` vs a lockstep reference
+  # loop written over the module's public calls (SGD+Adam across
+  # fused/zero1/spmd, a short last batch, a bucket switch between steps),
+  # staged-buffer donation safety under in-flight reuse, serving flush
+  # parity with zero steady-state compiles, and pad-buffer identity
+  # stability — an ordering or staging regression fails HERE, attributed
+  log "overlap suite (fit vs lockstep reference loop, staged donation safety, the metric's one step of lag)"
   python -m pytest tests/python/unittest/test_overlap.py -q
   # spmd gate, standalone: these tests flip MXNET_SPMD / MXNET_ZERO1 /
   # MXNET_PIPELINE_* and pin sharded-vs-replicated whole-run parity,

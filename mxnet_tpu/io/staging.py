@@ -8,10 +8,10 @@ critical path while the device sits idle.  :class:`DeviceStager` closes
 the input half of that gap: while step *t* executes, a staging thread
 pads/casts/places batch *t+1* into a bounded ring of pre-placed buffers,
 so the consuming step finds device-resident arrays instead of host
-numpy.  The consumer side (``Module.fit``'s deferred metric lane, the
-serving batcher's stage-ahead, the generation tick's
-dispatch-then-bookkeep reorder) lives with each loop; this module owns
-only the buffer discipline.
+numpy.  The consumer side (``Module.fit``'s dispatch-then-prepare order,
+the serving batcher's stage-ahead) lives with each loop, and the metric's
+one step of lag with ``mxnet_tpu.metric``; this module owns only the
+buffer discipline.
 
 Correctness invariants, in order of importance:
 
@@ -32,10 +32,8 @@ Correctness invariants, in order of importance:
   staging thread into the lock-order/blocking-hazard analysis like every
   other subsystem.
 
-``MXNET_OVERLAP=0`` disables every overlap lane at once (fit, serving,
-generation) and restores bit-exact lockstep — the reference semantics the
-parity tests pin against.  ``MXNET_STAGING_BUFFERS`` sizes the ring
-(default 2 = classic double buffering: one in flight, one staging).
+The ring holds two slots (classic double buffering: one in flight, one
+staging).
 """
 from __future__ import annotations
 
@@ -43,20 +41,6 @@ import threading
 import time as _time
 
 from .. import telemetry
-from ..base import getenv, register_env
-
-register_env("MXNET_OVERLAP", 1,
-             "async dispatch pipeline: overlap host work (batch staging, "
-             "deferred metric reads, serving/generation bookkeeping) with "
-             "in-flight device execution; 0 = bit-exact lockstep reference")
-register_env("MXNET_STAGING_BUFFERS", 2,
-             "DeviceStager ring depth: staged-but-unretired batches the "
-             "input pipeline may hold on device (min 2 = double buffer)")
-
-
-def overlap_enabled():
-    """One switch for every overlap lane (fit / serving / generation)."""
-    return bool(int(getenv("MXNET_OVERLAP") or 0))
 
 
 class _Slot:
@@ -95,9 +79,7 @@ class DeviceStager:
     the consumer's placement context changed between stage and consume.
     """
 
-    def __init__(self, name="io.stager", depth=None):
-        if depth is None:
-            depth = int(getenv("MXNET_STAGING_BUFFERS") or 2)
+    def __init__(self, name="io.stager", depth=2):
         self._depth = max(2, int(depth))
         # analysis-tracked so MXNET_DEBUG_SYNC sees the staging thread
         from .. import analysis

@@ -17,7 +17,8 @@ subclass's own ``update`` on the kept arrays, so the numbers are those of
 lockstep. Every read (``get``, ``get_name_value``, ``reset``, ``str``,
 ``sum_metric``, ``num_inst``, pickling and copying) settles first. The lag is
 one call, never more. All-numpy arguments, and everything under
-``MXNET_OVERLAP=0``, settle at once.
+:func:`immediate`, settle at once. This module is the lag's one owner:
+``Module.fit`` and a gluon loop both call ``update`` plainly.
 
 Counters ``metric.deferred`` (updates kept), ``metric.settled_late`` (settled
 by the next ``update``) and ``metric.settled_on_read``; the fetch is under the
@@ -35,7 +36,6 @@ import numpy
 from . import telemetry
 from . import tracing
 from .base import numeric_types, string_types
-from .io.staging import overlap_enabled
 from .ndarray.ndarray import NDArray
 
 __all__ = ["EvalMetric", "CompositeEvalMetric", "Accuracy", "TopKAccuracy", "F1", "MCC",
@@ -95,9 +95,9 @@ _lockstep = _Lockstep()
 
 @contextlib.contextmanager
 def immediate():
-    """Updates made inside are applied at once. For a caller that already
-    applies a step's update one step late itself (``fit``'s deferred lane):
-    deferring again would let the host run two steps ahead."""
+    """Updates made inside are applied at once: lockstep numbers at every
+    call (``with mx.metric.immediate(): mod.fit(...)``), at the price of
+    draining the step just launched. Settling itself runs under it."""
     _lockstep.depth += 1
     try:
         yield
@@ -125,7 +125,7 @@ def _keep(x):
 def _deferring(update):
     @functools.wraps(update)
     def deferred(self, labels, preds):
-        defer = (not _lockstep.depth and overlap_enabled()
+        defer = (not _lockstep.depth
                  and (_holds_ndarray(labels) or _holds_ndarray(preds)))
         kept = (_keep(labels), _keep(preds)) if defer else None
         try:

@@ -19,7 +19,6 @@ from .. import tracing
 from ..base import MXNetError
 from .. import metric as _metric
 from .. import ndarray as nd
-from ..io import staging as _staging
 from ..io.io import DataDesc
 
 
@@ -186,11 +185,11 @@ class BaseModule:
             return output_list2
         return output_list
 
-    def _fetch_next_batch(self, data_iter, sparse_row_id_fn, stage):
+    def _fetch_next_batch(self, data_iter, sparse_row_id_fn):
         """`fit`'s data phase as one live span: ``step.data`` holding
         ``step.data.next`` (the iterator) and ``step.data.stage``
-        (``prepare``, and ``stage_batch`` where the overlap order stages
-        the batch while the step runs). None when the epoch is over."""
+        (``prepare``, and ``stage_batch``: the batch is staged while the
+        step runs). None when the epoch is over."""
         with tracing.span("step.data", cat="train"):
             with tracing.span("step.data.next", cat="train"):
                 try:
@@ -199,8 +198,7 @@ class BaseModule:
                     return None
             with tracing.span("step.data.stage", cat="train"):
                 self.prepare(batch, sparse_row_id_fn=sparse_row_id_fn)
-                if stage:
-                    self.stage_batch(batch)
+                self.stage_batch(batch)
         return batch
 
     def fit(self, train_data, eval_data=None, eval_metric="acc",
@@ -253,13 +251,6 @@ class BaseModule:
                 end_of_batch = False
                 data_iter = iter(train_data)
                 next_data_batch = next(data_iter)
-                # async overlap lane (MXNET_OVERLAP=1): metric reads become
-                # deferred thunks applied one step late, so the host never
-                # blocks on the step it just dispatched; sync points land
-                # only at epoch boundaries (and wherever a consumer pulls
-                # quantiles). `pending_metric` holds step t-1's thunk.
-                overlap = _staging.overlap_enabled()
-                pending_metric = None
                 while not end_of_batch:
                     data_batch = next_data_batch
                     if monitor is not None:
@@ -301,80 +292,37 @@ class BaseModule:
                         t_up = time.perf_counter() if tele else 0.0
                         if tele:
                             telemetry.gauge("step.fused").set(1 if fused else 0)
-                        # deferred-metric capture: under overlap, step t's
-                        # metric read becomes a thunk holding t's still-live
-                        # lazy outputs; it is applied NEXT iteration, while
-                        # step t+1 is in flight. None = this step cannot
-                        # defer (overlap off, list batch, module without
-                        # captured outputs) -> eager lockstep reference.
-                        capture = None
-                        if overlap and not isinstance(data_batch, list):
-                            capture = self.capture_metric_update(
-                                data_batch.label)
-                        if capture is None:
-                            with tracing.span("step.sync", cat="train"), \
-                                    _metric.immediate():
-                                if pending_metric is not None:
-                                    # mixed-mode seam: settle the deferred
-                                    # step before the eager one updates the
-                                    # metric
-                                    pending_metric(eval_metric)
-                                    pending_metric = None
-                                    self.retire_staged()
-                                if isinstance(data_batch, list):
-                                    self.update_metric(
-                                        eval_metric,
-                                        [db.label for db in data_batch],
-                                        pre_sliced=True)
-                                else:
-                                    self.update_metric(eval_metric,
-                                                       data_batch.label)
-                            t_sync = time.perf_counter() if tele else 0.0
-                            fetched = self._fetch_next_batch(
-                                data_iter, sparse_row_id_fn, stage=False)
-                            if fetched is None:
-                                end_of_batch = True
-                            else:
-                                next_data_batch = fetched
-                            t_end = t_data = time.perf_counter() if tele \
-                                else 0.0
-                            marks = (("fwdbwd", t0, t_fb),
-                                     ("update", t_fb, t_up),
-                                     ("sync", t_up, t_sync),
-                                     ("data", t_sync, t_data))
+                        # dispatch-then-prepare: fetch + device-stage
+                        # batch t+1 while step t executes
+                        fetched = self._fetch_next_batch(
+                            data_iter, sparse_row_id_fn)
+                        if fetched is None:
+                            end_of_batch = True
                         else:
-                            # dispatch-then-prepare: fetch + device-stage
-                            # batch t+1 while step t executes, then apply
-                            # step t-1's metric thunk (its outputs finished
-                            # at least one step ago, so this rarely blocks)
-                            fetched = self._fetch_next_batch(
-                                data_iter, sparse_row_id_fn, stage=True)
-                            if fetched is None:
-                                end_of_batch = True
+                            next_data_batch = fetched
+                        t_data = time.perf_counter() if tele else 0.0
+                        # `mx.metric` keeps step t's buffers and settles
+                        # step t-1 inside this call (its outputs finished
+                        # at least one step ago, so this rarely blocks);
+                        # the epoch's last step settles where the metric
+                        # is read. The outputs are still step t's, sliced
+                        # by step t's pad: the next batch's pad and bucket
+                        # apply only inside the next fused_step/forward.
+                        with tracing.span("step.sync", cat="train"):
+                            if isinstance(data_batch, list):
+                                self.update_metric(
+                                    eval_metric,
+                                    [db.label for db in data_batch],
+                                    pre_sliced=True)
                             else:
-                                next_data_batch = fetched
-                            t_data = time.perf_counter() if tele else 0.0
-                            with tracing.span("step.sync", cat="train"), \
-                                    _metric.immediate():
-                                if pending_metric is not None:
-                                    pending_metric(eval_metric)
-                                    self.retire_staged()
-                                pending_metric = capture
-                                if end_of_batch:
-                                    # epoch boundary is a sync point: flush
-                                    # so epoch-end metrics match lockstep
-                                    # bit-exact
-                                    pending_metric(eval_metric)
-                                    pending_metric = None
-                                    self.retire_staged()
-                            t_end = t_sync = time.perf_counter() if tele \
-                                else 0.0
-                            marks = (("fwdbwd", t0, t_fb),
-                                     ("update", t_fb, t_up),
-                                     ("data", t_up, t_data),
-                                     ("sync", t_data, t_sync))
-                            if tele:
-                                telemetry.counter("overlap.steps").inc()
+                                self.update_metric(eval_metric,
+                                                   data_batch.label)
+                            self.retire_staged()
+                        t_sync = time.perf_counter() if tele else 0.0
+                        marks = (("fwdbwd", t0, t_fb),
+                                 ("update", t_fb, t_up),
+                                 ("data", t_up, t_data),
+                                 ("sync", t_data, t_sync))
                         step_span.set(fused=fused)
                     if trc:
                         tracing.flight_recorder.observe(step_span.tree())
@@ -384,7 +332,7 @@ class BaseModule:
                         for seg, a, b in marks:
                             telemetry.histogram(
                                 f"step.{seg}_us").record((b - a) * 1e6)
-                        total_us = (t_end - t0) * 1e6
+                        total_us = (t_sync - t0) * 1e6
                         total_h.record(total_us)
                         # wall-clock denominator for the derived pipeline
                         # stall ratio (prefetch wait + stage wait over wall)
@@ -411,11 +359,6 @@ class BaseModule:
                         # completed — the watchdog's rolling median learns
                         # the step cadence from these
                         fit_beacon.touch()
-                if pending_metric is not None:  # pragma: no cover — safety
-                    with _metric.immediate():
-                        pending_metric(eval_metric)
-                    pending_metric = None
-                    self.retire_staged()
                 if fit_beacon is not None:
                     fit_beacon.idle()
                 for name, val in eval_metric.get_name_value():
@@ -443,16 +386,9 @@ class BaseModule:
     def prepare(self, data_batch, sparse_row_id_fn=None):
         pass
 
-    # -- async overlap lane hooks (MXNET_OVERLAP) ----------------------------
-    # Subclasses that can defer their sync points override these; the base
-    # defaults make every module a valid lockstep participant, so `fit`
-    # degrades to the bit-exact reference order wherever a hook opts out.
-
-    def capture_metric_update(self, labels):
-        """A thunk ``f(eval_metric)`` that applies THIS step's metric
-        update later (from outputs captured now), or None when this step
-        must update eagerly (the lockstep reference path)."""
-        return None
+    # -- batch staging hooks -------------------------------------------------
+    # Subclasses that can stage the next batch on the device override
+    # these; under the base defaults every batch takes host-side feed prep.
 
     def stage_batch(self, data_batch):
         """Hand ``data_batch`` to the device-staging thread so its
@@ -461,8 +397,8 @@ class BaseModule:
         return False
 
     def retire_staged(self):
-        """Release the oldest staged buffer whose step finished — called
-        by ``fit`` right after the deferred metric for that step lands."""
+        """Release the oldest staged buffer in flight — called by ``fit``
+        once a step's metric update has been made."""
         return False
 
     def _overlap_teardown(self):

@@ -213,17 +213,10 @@ class BucketingModule(BaseModule):
     def update_metric(self, eval_metric, labels, pre_sliced=False):
         self._curr_module.update_metric(eval_metric, labels, pre_sliced)
 
-    # -- async overlap lane (MXNET_OVERLAP) ----------------------------------
-    # The deferred-metric thunk closes over the bucket module that ran the
-    # step, so bucket switches between capture and apply stay correct.
+    # -- batch staging -------------------------------------------------------
     # Batch staging is NOT delegated: the next batch's bucket module isn't
     # switched in until its own fused_step, so its executor shapes aren't
-    # knowable here — bucketed fits keep lockstep feed prep.
-
-    def capture_metric_update(self, labels):
-        if self._curr_module is None:
-            return None
-        return self._curr_module.capture_metric_update(labels)
+    # knowable here — bucketed fits keep host-side feed prep.
 
     def retire_staged(self):
         if self._curr_module is None:

@@ -89,7 +89,7 @@ class Module(BaseModule):
         self._pipeline_failed = False  # plan/trace failed — stay unpipelined
         self._spmd = None  # SPMD sharding plan (MXNET_SPMD)
         self._spmd_failed = False  # plan/trace failed — stay replicated
-        self._stager = None  # DeviceStager ring (MXNET_OVERLAP, lazy)
+        self._stager = None  # DeviceStager ring (lazy)
         self._staged_meta = []  # [(batch, pad/hysteresis meta)] FIFO
 
     # -- properties ----------------------------------------------------------
@@ -684,30 +684,15 @@ class Module(BaseModule):
             dict(zip(self._label_names, labels)),
             dict(zip(self._output_names, self.get_outputs())))
 
-    # -- async overlap lane (MXNET_OVERLAP) ----------------------------------
-
-    def capture_metric_update(self, labels):
-        """Defer this step's metric read: the returned thunk holds the
-        CURRENT outputs (lazily sliced by the current pad state, which the
-        next step's feed prep will overwrite) and applies them whenever
-        `fit` settles the deferred lane."""
-        if labels is None or not (self.binded and self.params_initialized):
-            return None
-        label_map = dict(zip(self._label_names, labels))
-        out_map = dict(zip(self._output_names, self.get_outputs()))
-
-        def apply(eval_metric):
-            eval_metric.update_dict(label_map, out_map)
-
-        return apply
+    # -- batch staging -------------------------------------------------------
 
     def stage_batch(self, data_batch):
         """Decide stageability on the MAIN thread (executor shapes + the
         pad-vs-reshape hysteresis state are only coherent here), then hand
         the pad/cast/device-placement to the staging thread. Mirrors
         `_make_feed`'s decision tree exactly: a reshape-bound batch is not
-        staged — the lockstep path owns rebinds."""
-        if not _staging.overlap_enabled() or not self._fused_step_ready():
+        staged — host-side feed prep owns rebinds."""
+        if not self._fused_step_ready():
             return False
         if isinstance(data_batch, list) or data_batch.data is None:
             return False

@@ -39,7 +39,6 @@ from .. import analysis
 from .. import ndarray as nd
 from .. import telemetry
 from .. import tracing
-from ..io import staging as _staging
 from ..base import getenv, register_env
 from ..log import get_logger
 from ..resilience import retry_call
@@ -222,12 +221,11 @@ class DynamicBatcher:
         return fut
 
     def _loop(self):
-        # overlap lane (MXNET_OVERLAP=1): while a flush executes on
-        # device, the worker preps the NEXT one — `_execute_prep` calls
-        # `_stage_next` between forward dispatch and drain, so the staged
-        # prep's concat/pad/placement rides under the in-flight compute.
-        # A staged prep is executed on the next loop turn (after a
-        # deadline re-sweep); MXNET_OVERLAP=0 never stages.
+        # while a flush executes on device, the worker preps the NEXT one
+        # — `_execute_prep` calls `_stage_next` between forward dispatch
+        # and drain, so the staged prep's concat/pad/placement rides under
+        # the in-flight compute. A staged prep is executed on the next
+        # loop turn (after a deadline re-sweep).
         staged = None
         while True:
             if staged is not None:
@@ -445,8 +443,7 @@ class DynamicBatcher:
         # with `_run` patched over (test gates, wrappers) keeps the
         # lockstep call so the patch still sees every forward
         stage_fn = self._stage_next if (
-            stage and _staging.overlap_enabled()
-            and "_run" not in self._predictor.__dict__) else None
+            stage and "_run" not in self._predictor.__dict__) else None
         state = {"first": stage_fn is not None}
         with tracing.span("serving.batch", cat="serving", rows=rows,
                           bucket=bucket, reason=reason,

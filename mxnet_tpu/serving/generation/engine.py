@@ -76,10 +76,10 @@ model with per-slot state that is not rows, ``prefill_tokens``, plus ``prefix.{h
 evictions}``/``prefix.cached_tokens`` and ``spec.{proposed,accepted,
 rolled_back,committed}`` with derived ``spec.acceptance_ratio``).
 ``queue_wait_us`` is submit → start of the admission (host events at both
-ends). ``prefill_us`` is prefill dispatch → first token on the HOST: under
-``MXNET_OVERLAP=1`` (the default) the tick dispatches its decode first and
-the prefill queues behind it on the device, so it reads one decode plus
-the prefill — the prefill's own device time is only in a device trace.
+ends). ``prefill_us`` is prefill dispatch → first token on the HOST: the
+tick dispatches its decode first and the prefill queues behind it on the
+device, so it reads one decode plus the prefill — the prefill's own
+device time is only in a device trace.
 Tracing: every tick is one live span tree (``generation.tick`` → sweep /
 decode / admit → prefill → prefill.fetch / commit → commit.fetch, each
 also ``mx:<name>`` in any ``jax.profiler`` trace), nothing per session per
@@ -104,7 +104,6 @@ from ... import telemetry
 from ... import tracing
 from ...base import MXNetError, getenv, register_env
 from ...compile_cache import CompileCache
-from ...io import staging as _staging
 from ...log import get_logger
 from .. import qos
 from ..admission import AdmissionQueue, DeadlineExceededError, Request
@@ -821,7 +820,7 @@ class GenerationEngine:
 
     def _version_params(self, version):
         """The placed param dict pinned for ``version`` (the cohort
-        dispatch in _decode/_spec_decode)."""
+        dispatch in _decode_dispatch/_spec_dispatch)."""
         return self._param_sets[version][0]
 
     def _cohorts(self):
@@ -1087,9 +1086,10 @@ class GenerationEngine:
                 self._tick()
 
     def _tick(self):
-        """One scheduler tick (tick lock held): sweep deadlines, admit
-        prefills into free slots, run ONE fused decode over the slab,
-        evict finished sessions. A tick never raises — an executable
+        """One scheduler tick (tick lock held): dispatch ONE fused decode
+        over the slab, sweep deadlines and admit prefills into free slots
+        while it runs, then commit its tokens and evict finished sessions.
+        A tick never raises — an executable
         failure fails the live sessions (never-strand, the batcher's
         guard) and reallocates the possibly-donated slab."""
         tele = telemetry._enabled
@@ -1104,49 +1104,42 @@ class GenerationEngine:
                                  live=self._live, queued=len(self._queue))
         with tick_span:
             try:
-                if _staging.overlap_enabled():
-                    # overlap order: dispatch the decode FIRST, do the
-                    # host bookkeeping (queue expiry, deadline sweep,
-                    # admission scan) while the executable runs, THEN
-                    # block and commit — the tick's host work hides
-                    # behind device time instead of serializing ahead of
-                    # it. Sessions evicted or replaced inside that window
-                    # are identity-guarded at commit (their tokens are
-                    # discarded; the stale slab rows are masked garbage
-                    # the next occupant's prefill overwrites). Admitted
-                    # prefills chain on the still-lazy decode cache
-                    # outputs, so they join the NEXT tick's decode —
-                    # per-session token streams stay bit-exact with the
-                    # lockstep order below.
-                    # the decode this tick commits is the one the last
-                    # tick dispatched AHEAD, else one dispatched now; where
-                    # it may (_lookahead_ok) the decode after it goes out
-                    # before the commit, so the device has its next program
-                    # queued while the host fetches, commits and dispatches
-                    pending, self._ahead = self._ahead, None
-                    first = pending is None
-                    if first:
-                        pending = self._decode_dispatch()
-                    if pending is not None and self._lookahead_ok(pending):
-                        # (a tick that dispatched its own decode above has
-                        # its span already: a tick shows one decode child)
-                        self._ahead = self._dispatch_ahead(pending,
-                                                           span=not first)
-                    self._sweep()
-                    # a live slot is parked only BETWEEN decodes: the one in
-                    # flight advances its slot, and a recurrent state is
-                    # not advanced twice by the same token the way a K/V
-                    # row is rewritten (the parked copy would hold a token
-                    # that the identity guard then discards at commit)
-                    self._admit(preempt=pending is None)
-                    if pending is not None:
-                        self._decode_commit(pending)
-                        if self._qos is not None:
-                            self._admit()
-                else:
-                    self._sweep()
-                    self._admit()
-                    self._decode()
+                # dispatch the decode FIRST, do the host bookkeeping
+                # (queue expiry, deadline sweep, admission scan) while the
+                # executable runs, THEN block and commit — the tick's host
+                # work hides behind device time instead of serializing
+                # ahead of it. Sessions evicted or replaced inside that
+                # window are identity-guarded at commit (their tokens are
+                # discarded; the stale slab rows are masked garbage the
+                # next occupant's prefill overwrites). Admitted prefills
+                # chain on the still-lazy decode cache outputs, so they
+                # join the NEXT tick's decode — per-session token streams
+                # are those of one request generated alone.
+                # the decode this tick commits is the one the last
+                # tick dispatched AHEAD, else one dispatched now; where
+                # it may (_lookahead_ok) the decode after it goes out
+                # before the commit, so the device has its next program
+                # queued while the host fetches, commits and dispatches
+                pending, self._ahead = self._ahead, None
+                first = pending is None
+                if first:
+                    pending = self._decode_dispatch()
+                if pending is not None and self._lookahead_ok(pending):
+                    # (a tick that dispatched its own decode above has
+                    # its span already: a tick shows one decode child)
+                    self._ahead = self._dispatch_ahead(pending,
+                                                       span=not first)
+                self._sweep()
+                # a live slot is parked only BETWEEN decodes: the one in
+                # flight advances its slot, and a recurrent state is
+                # not advanced twice by the same token the way a K/V
+                # row is rewritten (the parked copy would hold a token
+                # that the identity guard then discards at commit)
+                self._admit(preempt=pending is None)
+                if pending is not None:
+                    self._decode_commit(pending)
+                    if self._qos is not None:
+                        self._admit()
                 if len(self._param_sets) > 1:
                     # a swap transition is draining: release versions
                     # whose last session just finished
@@ -1477,12 +1470,11 @@ class GenerationEngine:
                     n - sess.prefix_len)
                 telemetry.histogram(
                     "serving.generation.queue_wait_us").record(waited_us)
-                # prefill dispatch -> first token on the host. Under
-                # MXNET_OVERLAP=1 (the default) the tick dispatched its
-                # decode first and the prefill queues behind it on the
-                # device, so this reads ONE DECODE PLUS the prefill; the
-                # prefill's own device time is the trace's (the
-                # benchmark's prefill_ms_p50)
+                # prefill dispatch -> first token on the host. The tick
+                # dispatched its decode first and the prefill queues
+                # behind it on the device, so this reads ONE DECODE PLUS
+                # the prefill; the prefill's own device time is the
+                # trace's (the benchmark's prefill_ms_p50)
                 telemetry.histogram("serving.generation.prefill_us").record(
                     (time.perf_counter() - t_pf) * 1e6)
             # cache the full prompt's KV for future sessions while a free
@@ -1535,36 +1527,23 @@ class GenerationEngine:
                 "serving.generation.prefix.cached_tokens_served").inc(m)
         return tok
 
-    def _decode(self):
-        """ONE fused step over the whole slab; every live session
-        advances one token (plain) or up to ``spec_k + 1`` (speculative
-        verify). Dead slots ride along unread and unwritten — that fixed
-        shape is exactly what makes mid-stream admit/evict free.
+    def _decode_dispatch(self):
+        """Dispatch ONE fused step over the whole slab WITHOUT
+        materializing the token output; :meth:`_decode_commit` blocks and
+        delivers. Every live session advances one token (plain) or up to
+        ``spec_k + 1`` (speculative verify); dead slots ride along unread
+        and unwritten — that fixed shape is what makes mid-stream
+        admit/evict free.
 
         During a weight-swap transition (live sessions pinned to more
         than one version) the SAME executable runs once per version
         cohort with that cohort's pinned params, other cohorts' slots
-        marked dead — N dispatches, zero new programs, and
-        every session's output stays bit-exact with an unswapped engine
-        on its own weights.
-
-        Split into :meth:`_decode_dispatch` (launch the executables,
-        tokens still lazy) and :meth:`_decode_commit` (block + deliver)
-        so the overlap tick can do its host bookkeeping between the two;
-        this method is the back-to-back composition."""
-        pending = self._decode_dispatch()
-        if pending is not None:
-            self._decode_commit(pending)
-
-    def _decode_dispatch(self):
-        """Dispatch the decode (or verify) executable once per version
-        cohort WITHOUT materializing the token output. Cohort dispatch
-        order and inputs are identical to the fused path: a later
-        cohort's call only reads the earlier ones' cache outputs (pure
-        lazy dataflow) and every non-member slot is dead to it, so
-        committing before or after the remaining dispatches is
-        bit-equivalent. Returns the pending state for
-        :meth:`_decode_commit`, or None when no slot is live."""
+        marked dead — N dispatches, zero new programs. A later cohort's
+        call only reads the earlier ones' cache outputs (pure lazy
+        dataflow) and every non-member slot is dead to it, so every
+        session's output stays bit-exact with an unswapped engine on its
+        own weights. Returns the pending state for :meth:`_decode_commit`,
+        or None when no slot is live."""
         import jax.numpy as jnp
 
         if self._live == 0:

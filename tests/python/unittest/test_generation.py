@@ -469,14 +469,12 @@ def test_generation_telemetry_and_report(lm48, tele, tmp_path, capsys):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("overlap", ["0", "1"], ids=["lockstep", "overlap"])
-def test_tick_span_tree(lm48, monkeypatch, overlap):
+def test_tick_span_tree(lm48):
     """A tick that decodes a live session and admits a queued one is ONE
     live span tree: tick > sweep, decode, admit > prefill > prefill.fetch,
     commit > commit.fetch, in the order the tick did them. Nothing is
     emitted per session per tick (generation.decode_tick is gone); the
     session root carries its token count instead."""
-    monkeypatch.setenv("MXNET_OVERLAP", overlap)
     lm, params = lm48
     a, b = _prompts(2, seed=6)
     prev = tracing.enabled()
@@ -511,11 +509,8 @@ def test_tick_span_tree(lm48, monkeypatch, overlap):
                     for k in kids(t) if k["name"] == "generation.admit")]
     assert both, [names(kids(t)) for t in ticks]
     tick = both[0]
-    order = (["generation.sweep", "generation.admit", "generation.decode",
-              "generation.commit"] if overlap == "0" else
-             ["generation.decode", "generation.sweep", "generation.admit",
-              "generation.commit"])
-    assert names(kids(tick)) == order
+    assert names(kids(tick)) == ["generation.decode", "generation.sweep",
+                                 "generation.admit", "generation.commit"]
     by = {e["name"]: e for e in kids(tick)}
     (prefill,) = kids(by["generation.admit"])
     assert {"bucket", "slot", "waited_us"} <= set(prefill["args"])

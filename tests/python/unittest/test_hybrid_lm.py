@@ -31,6 +31,8 @@ sys.path[:0] = [os.path.join(REPO, "benchmark")]
 from reference import granite_hybrid as ref  # noqa: E402
 from runners.serve_closed_loop import granite_published  # noqa: E402
 
+from lm_jit import jitted  # noqa: E402
+
 ref.PAD_TO = 32     # the chip's 512 would spend these tiny tests on padding
 
 TOL = 1e-4
@@ -73,9 +75,9 @@ def _poisoned(lm, slots, max_len):
 def _prefill(lm, params, cache, prompt, bucket, slot):
     padded = np.full(bucket, 7, np.int32)       # padded "with anything"
     padded[:len(prompt)] = prompt
-    out = jax.jit(lm.prefill)(params, *cache, jnp.asarray(padded),
-                              jnp.asarray(len(prompt), jnp.int32),
-                              jnp.asarray(slot, jnp.int32))
+    out = jitted(lm, "prefill")(params, *cache, jnp.asarray(padded),
+                                 jnp.asarray(len(prompt), jnp.int32),
+                                 jnp.asarray(slot, jnp.int32))
     return out[0], tuple(out[1:])
 
 
@@ -84,8 +86,8 @@ def _decode(lm, params, cache, slot, token, position):
     tokens = np.zeros(slots, np.int32)
     positions = np.full(slots, -1, np.int32)
     tokens[slot], positions[slot] = token, position
-    out = jax.jit(lm.decode_step)(params, *cache, jnp.asarray(tokens),
-                                  jnp.asarray(positions))
+    out = jitted(lm, "decode_step")(params, *cache, jnp.asarray(tokens),
+                                     jnp.asarray(positions))
     return out[0][slot], tuple(out[1:])
 
 

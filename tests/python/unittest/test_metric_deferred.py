@@ -1,7 +1,7 @@
 """`mx.metric` settles an update whose arguments are NDArrays one call late.
 
 The contract (`mxnet_tpu/metric.py`): the numbers are those of lockstep
-(`MXNET_OVERLAP=0`) bit for bit, call *k* fetches the arguments of call
+(every update under `mx.metric.immediate()`) bit for bit, call *k* fetches the arguments of call
 *k - 1* and nothing else, every read settles first, and the kept values are
 those the arrays held at call time.
 """
@@ -120,10 +120,9 @@ def fetches(monkeypatch):
 
 
 @pytest.fixture
-def lockstep(monkeypatch):
+def lockstep():
     def run(fn):
-        with monkeypatch.context() as m:
-            m.setenv("MXNET_OVERLAP", "0")
+        with mx.metric.immediate():
             return fn()
     return run
 
@@ -424,13 +423,29 @@ class Watched(mx.metric.Accuracy):
         super().update(labels, preds)
 
 
-def test_fit_keeps_its_one_step_of_lag(lockstep):
-    """`fit` applies step t's thunk at step t + 1 (and the epoch's last at
-    once). Its thunks go through the metric's immediate path, so the metric
-    adds no lag of its own: at the end of batch t, t updates have applied."""
+def _fit_watched(on_batch=None, num_epoch=1):
+    """A five-step `fit` of a one-layer net; returns the counters it moved
+    and the metric's final state."""
     rng = np.random.RandomState(0)
     X = rng.uniform(-1, 1, (40, 6)).astype(np.float32)
     Y = rng.randint(0, CLASSES, (40,)).astype(np.float32)
+    data = mx.sym.Variable("data")
+    fc = mx.sym.FullyConnected(data, num_hidden=CLASSES, name="fc")
+    mx.random.seed(7)
+    metric = Watched()
+    before = counters()
+    mod = mx.mod.Module(mx.sym.SoftmaxOutput(fc, name="softmax"),
+                        context=mx.cpu())
+    mod.fit(mx.io.NDArrayIter(X, Y, batch_size=8), num_epoch=num_epoch,
+            eval_metric=metric, batch_end_callback=on_batch,
+            optimizer_params=(("learning_rate", 0.1),))
+    return moved(before), state(metric)
+
+
+def test_fit_keeps_its_one_step_of_lag(lockstep):
+    """`fit` calls the metric plainly and the metric lags: at the end of
+    batch t, t updates have applied and step t's is the one kept. The
+    epoch's last settles where `fit` reads the metric."""
     steps = 5
 
     def run():
@@ -438,27 +453,28 @@ def test_fit_keeps_its_one_step_of_lag(lockstep):
 
         def on_batch(param):
             metric = param.eval_metric
-            seen.append((param.nbatch, metric.applied, metric._pending))
+            seen.append((param.nbatch, metric.applied,
+                         metric._pending is not None))
 
-        data = mx.sym.Variable("data")
-        fc = mx.sym.FullyConnected(data, num_hidden=CLASSES, name="fc")
-        mx.random.seed(7)
-        metric = Watched()
-        before = counters()
-        mod = mx.mod.Module(mx.sym.SoftmaxOutput(fc, name="softmax"),
-                            context=mx.cpu())
-        mod.fit(mx.io.NDArrayIter(X, Y, batch_size=8), num_epoch=1,
-                eval_metric=metric, batch_end_callback=on_batch,
-                optimizer_params=(("learning_rate", 0.1),))
-        return seen, moved(before), state(metric)
+        return (seen,) + _fit_watched(on_batch)
 
     seen, counted, final = run()
-    assert seen == [(t, t if t < steps - 1 else steps, None)
-                    for t in range(steps)]
-    assert counted == (0, 0, 0)
-    ref_seen, _, ref_final = lockstep(run)
-    assert ref_seen == [(t, t + 1, None) for t in range(steps)]
+    assert seen == [(t, t, True) for t in range(steps)]
+    assert counted == (steps, steps - 1, 1)
+    ref_seen, ref_counted, ref_final = lockstep(run)
+    assert ref_seen == [(t, t + 1, False) for t in range(steps)]
+    assert ref_counted == (0, 0, 0)
     assert final == ref_final
+
+
+def test_fit_defers_every_step_and_settles_all_but_the_last_late():
+    """The lag has one owner: over N steps an epoch `fit` defers N updates
+    through `mx.metric` and N - 1 of them settle inside the next step's
+    call; only the epoch's last waits for a read (`fit`'s own, of the
+    epoch's value)."""
+    steps, epochs = 5, 3
+    counted, _ = _fit_watched(num_epoch=epochs)
+    assert counted == (epochs * steps, epochs * (steps - 1), epochs)
 
 
 def test_the_gluon_loop_of_the_benchmark_for_four_steps(fetches, lockstep):

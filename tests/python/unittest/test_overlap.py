@@ -1,13 +1,13 @@
-"""Async dispatch pipeline (`MXNET_OVERLAP`, `mxnet_tpu/io/staging.py`).
+"""Async dispatch pipeline (`mxnet_tpu/io/staging.py`, `BaseModule.fit`).
 
-Pins the host-overlap PR's correctness contract:
+Pins the host-overlap correctness contract:
 
-* **N-step bit-exact parity** — `fit` under `MXNET_OVERLAP=1` (staged
-  device feeds, deferred metric lane) produces BITWISE identical trained
-  parameters AND identical epoch-end metric values to the
-  `MXNET_OVERLAP=0` eager lockstep reference, across SGD+Adam and the
-  fused / ZeRO-1 / SPMD execution modes. Overlap reorders host work
-  only — it must never change a bit of the device program's output.
+* **N-step bit-exact parity** — `fit` (staged device feeds, the metric one
+  step late) produces BITWISE identical trained parameters AND identical
+  epoch-end metric values to `_reference_loop`, a lockstep loop over the
+  module's public calls, across SGD+Adam and the fused / ZeRO-1 / SPMD
+  execution modes. `fit` reorders host work only — it must never change a
+  bit of the device program's output.
 * **Staged-buffer donation safety** — the `DeviceStager` ring refuses
   new work rather than recycle a buffer an in-flight step may still
   read; `take` matches batch identity; guards drop stale slots.
@@ -47,11 +47,10 @@ DIM, CLASSES = 8, 4
 
 
 class _env:
-    """Scoped env toggles: overlap switch x execution mode."""
+    """Scoped env toggles: the execution mode."""
 
-    def __init__(self, overlap, mode="fused"):
-        self.vals = {"MXNET_OVERLAP": "1" if overlap else "0",
-                     "MXNET_FUSED_STEP": "1",
+    def __init__(self, mode="fused"):
+        self.vals = {"MXNET_FUSED_STEP": "1",
                      "MXNET_ZERO1": "1" if mode == "zero1" else "",
                      "MXNET_ZERO1_NDEV": "2" if mode == "zero1" else "",
                      "MXNET_SPMD": "dp=2" if mode == "spmd" else ""}
@@ -81,30 +80,61 @@ def _mlp_symbol():
     return mx.sym.SoftmaxOutput(fc2, name="softmax")
 
 
-def _fit(overlap, mode="fused", optimizer="sgd", opt_kw=None, num_epoch=2,
-         batch=8, n=40, seed=7):
-    """One fit run; returns (params, per-epoch final metric values)."""
+_XAVIER = dict(rnd_type="gaussian", magnitude=2)
+
+
+def _params(mod):
+    arg_p, _ = mod.get_params()
+    return {k: v.asnumpy() for k, v in arg_p.items()}
+
+
+def _reference_loop(mod, it, metric, optimizer, opt_kw, num_epoch, tail_at):
+    """The lockstep reference over the module's public calls: no stager,
+    and each step's metric update applied where it is made."""
+    mod.bind(data_shapes=it.provide_data, label_shapes=it.provide_label,
+             for_training=True)
+    mod.init_params(initializer=mx.init.Xavier(**_XAVIER))
+    mod.init_optimizer(optimizer=optimizer,
+                       optimizer_params=tuple(opt_kw.items()))
+    metric_tail = []
+    for _ in range(num_epoch):
+        metric.reset()
+        for nbatch, batch in enumerate(it):
+            if not mod.fused_step(batch):
+                mod.forward_backward(batch)
+                mod.update()
+            with mx.metric.immediate():
+                mod.update_metric(metric, batch.label)
+            if nbatch == tail_at:
+                metric_tail.append(metric.get_name_value())
+        it.reset()
+    return metric_tail
+
+
+def _fit(mode="fused", optimizer="sgd", opt_kw=None, num_epoch=2,
+         batch=8, n=40, seed=7, reference=False):
+    """One run, through `fit` or through `_reference_loop`; returns (params,
+    per-epoch final metric values)."""
     opt_kw = opt_kw or {"learning_rate": 0.1}
     rng = np.random.RandomState(0)
     X = rng.uniform(-1, 1, (n, DIM)).astype(np.float32)
     Y = rng.randint(0, CLASSES, (n,)).astype(np.float32)
-    steps = n // batch
-    metric_tail = []
-
-    def on_batch(param):
-        if param.nbatch == steps - 1:
-            metric_tail.append(param.eval_metric.get_name_value())
-
-    with _env(overlap, mode):
+    tail_at = n // batch - 1
+    with _env(mode):
         mx.random.seed(seed)
         it = mx.io.NDArrayIter(X, Y, batch_size=batch, shuffle=False)
         m = mx.mod.Module(_mlp_symbol(), context=mx.cpu())
-        m.fit(it, num_epoch=num_epoch, optimizer=optimizer,
-              optimizer_params=tuple(opt_kw.items()),
-              initializer=mx.init.Xavier(rnd_type="gaussian", magnitude=2),
-              batch_end_callback=on_batch)
-        arg_p, _ = m.get_params()
-        return {k: v.asnumpy() for k, v in arg_p.items()}, metric_tail
+        if reference:
+            tail = _reference_loop(m, it, mx.metric.create("acc"), optimizer,
+                                   opt_kw, num_epoch, tail_at)
+        else:
+            tail = []
+            m.fit(it, num_epoch=num_epoch, optimizer=optimizer,
+                  optimizer_params=tuple(opt_kw.items()),
+                  initializer=mx.init.Xavier(**_XAVIER),
+                  batch_end_callback=lambda p: p.nbatch == tail_at
+                  and tail.append(p.eval_metric.get_name_value()))
+        return _params(m), tail
 
 
 @pytest.fixture
@@ -145,44 +175,128 @@ _ADAM = ("adam", {"learning_rate": 0.01, "wd": 1e-4})
 ])
 def test_fit_overlap_bit_exact_parity(optimizer, opt_kw, mode):
     """2 epochs x 5 steps: trained params BITWISE equal and epoch-end
-    metric values identical between overlap and lockstep — per optimizer
-    per execution mode (fused / ZeRO-1 sharded update / SPMD dp mesh)."""
-    w_on, m_on = _fit(True, mode, optimizer, opt_kw)
-    w_off, m_off = _fit(False, mode, optimizer, opt_kw)
+    metric values identical between `fit` and the reference loop — per
+    optimizer per execution mode (fused / ZeRO-1 sharded update / SPMD dp
+    mesh)."""
+    w_on, m_on = _fit(mode, optimizer, opt_kw)
+    w_off, m_off = _fit(mode, optimizer, opt_kw, reference=True)
     assert w_on.keys() == w_off.keys()
     for k in w_on:
         assert w_on[k].dtype == w_off[k].dtype, k
         assert np.array_equal(w_on[k], w_off[k]), k
-    # the deferred lane settles at the epoch boundary: end-of-epoch
-    # metrics are the lockstep values exactly, not one step behind
+    # a read settles the step the metric kept: end-of-epoch metrics are
+    # the lockstep values exactly, not one step behind
     assert m_on == m_off and len(m_on) == 2
 
 
 def test_fit_overlap_runs_overlapped(tele):
-    """The parity above must not pass vacuously: under MXNET_OVERLAP=1
-    the loop actually takes the deferred lane and consumes staged
-    device batches, and the derived pipeline ratios come out."""
-    steps0 = _counter("overlap.steps")
+    """The parity above must not pass vacuously: `fit` consumes staged
+    device batches and leaves each step's metric update to lag, and the
+    derived pipeline ratios come out."""
     staged0 = _counter("overlap.staged_batches")
-    _fit(True)
-    assert _counter("overlap.steps") > steps0
+    deferred0 = _counter("metric.deferred")
+    _fit()
     assert _counter("overlap.staged_batches") > staged0
+    assert _counter("metric.deferred") > deferred0
     snap = telemetry.snapshot()
     assert 0.0 <= snap["derived"]["io.stage_wait_ratio"] <= 1.0
     assert 0.0 <= snap["derived"]["io.pipeline_stall_ratio"] <= 1.0
-    # and under =0, no overlap lane is taken at all
-    s1 = _counter("overlap.steps")
-    _fit(False)
-    assert _counter("overlap.steps") == s1
 
 
 def test_fit_overlap_partial_last_batch_parity():
     """n not divisible by batch: the short final batch rides the staged
-    pad path (pad_arrays on the staging thread) — still bit-exact."""
-    w_on, _ = _fit(True, n=44)
-    w_off, _ = _fit(False, n=44)
+    pad path (pad_arrays on the staging thread) — still bit-exact, and
+    the step before it is settled after the short batch was fetched, by
+    its own pad."""
+    w_on, m_on = _fit(n=44)
+    w_off, m_off = _fit(n=44, reference=True)
     for k in w_on:
         assert np.array_equal(w_on[k], w_off[k]), k
+    assert m_on == m_off
+
+
+class _BucketIter(mx.io.DataIter):
+    """Batches whose bucket (the sequence length) differs between every
+    two consecutive steps."""
+
+    KEYS = (6, 3, 6, 3, 3, 6)
+
+    def __init__(self, batch=4, seed=3):
+        super().__init__(batch)
+        rng = np.random.RandomState(seed)
+        self.default_bucket_key = max(self.KEYS)
+        self._batches = [mx.io.DataBatch(
+            data=[mx.nd.array(rng.uniform(-1, 1, (batch, k, DIM))
+                              .astype(np.float32))],
+            label=[mx.nd.array(rng.randint(0, CLASSES, (batch,))
+                               .astype(np.float32))],
+            bucket_key=k,
+            provide_data=[DataDesc("data", (batch, k, DIM))],
+            provide_label=[DataDesc("softmax_label", (batch,))])
+            for k in self.KEYS]
+        self.provide_data = self._batches[0].provide_data
+        self.provide_label = self._batches[0].provide_label
+        self._at = 0
+
+    def reset(self):
+        self._at = 0
+
+    def next(self):
+        if self._at == len(self._batches):
+            raise StopIteration
+        self._at += 1
+        return self._batches[self._at - 1]
+
+
+def _bucket_sym(key):
+    # the mean over the bucket's length keeps every parameter's shape
+    data = mx.sym.Variable("data")
+    pooled = mx.sym.mean(data, axis=1)
+    fc1 = mx.sym.FullyConnected(pooled, num_hidden=16, name="fc1")
+    act = mx.sym.Activation(fc1, act_type="relu")
+    fc2 = mx.sym.FullyConnected(act, num_hidden=CLASSES, name="fc2")
+    return (mx.sym.SoftmaxOutput(fc2, name="softmax"), ("data",),
+            ("softmax_label",))
+
+
+def test_fit_bucket_switch_between_steps_parity():
+    """A `BucketingModule.fit` whose bucket changes between consecutive
+    steps: step t's metric update is made after batch t + 1 was fetched and
+    settles inside step t + 1's, when another bucket's module is current —
+    parameters and epoch metric are the reference loop's bit for bit, and
+    no switch forces a settle."""
+    steps, epochs = len(_BucketIter.KEYS), 2
+    opt_kw = {"learning_rate": 0.1, "momentum": 0.9}
+
+    def bucketing():
+        mx.random.seed(7)
+        return mx.mod.BucketingModule(
+            _bucket_sym, default_bucket_key=_BucketIter().default_bucket_key,
+            context=mx.cpu())
+
+    with _env():
+        metric = mx.metric.create("acc")
+        tail = []
+        deferred0 = _counter("metric.deferred")
+        late0 = _counter("metric.settled_late")
+        mod = bucketing()
+        mod.fit(_BucketIter(), num_epoch=epochs, eval_metric=metric,
+                optimizer="sgd", optimizer_params=tuple(opt_kw.items()),
+                initializer=mx.init.Xavier(**_XAVIER),
+                batch_end_callback=lambda p: p.nbatch == steps - 1
+                and tail.append(p.eval_metric.get_name_value()))
+        assert _counter("metric.deferred") - deferred0 == epochs * steps
+        assert _counter("metric.settled_late") - late0 == \
+            epochs * (steps - 1)
+        ref = bucketing()
+        ref_tail = _reference_loop(ref, _BucketIter(),
+                                   mx.metric.create("acc"), "sgd", opt_kw,
+                                   epochs, steps - 1)
+    w, w_ref = _params(mod), _params(ref)
+    assert w.keys() == w_ref.keys()
+    for k in w:
+        assert np.array_equal(w[k], w_ref[k]), k
+    assert tail == ref_tail and len(tail) == epochs
 
 
 # ---------------------------------------------------------------------------
@@ -296,10 +410,8 @@ def _predictor(seed=7):
 
 @pytest.mark.slow
 def test_batcher_overlap_flush_parity_zero_compiles(tele):
-    """Stage-ahead batching: concurrent mixed-size requests under
-    MXNET_OVERLAP=1 are bit-exact vs eager predict AND vs the
-    MXNET_OVERLAP=0 lockstep batcher, with ZERO new serving compiles
-    after warmup in both modes."""
+    """Stage-ahead batching: concurrent mixed-size requests are bit-exact
+    vs eager predict, with ZERO new serving compiles after warmup."""
     pred = _predictor()
     serving.warmup(pred)
     rng = np.random.RandomState(42)
@@ -308,46 +420,42 @@ def test_batcher_overlap_flush_parity_zero_compiles(tele):
                 for s in sizes]
     refs = [pred.predict(p).asnumpy() for p in payloads]
 
-    got = {}
-    for overlap in (True, False):
-        with _env(overlap):
-            ledger0 = compile_cache.named_stats("serving")["misses"]
-            results = [None] * len(payloads)
-            errors = []
-            with DynamicBatcher(pred, max_wait_ms=2) as srv:
-                def client(t):
-                    try:
-                        futs = [(i, srv.submit(payloads[i]))
-                                for i in range(t, len(payloads), 4)]
-                        for i, f in futs:
-                            results[i] = f.result(timeout=60).asnumpy()
-                    except Exception as e:  # noqa: BLE001
-                        errors.append(e)
+    ledger0 = compile_cache.named_stats("serving")["misses"]
+    results = [None] * len(payloads)
+    errors = []
+    with DynamicBatcher(pred, max_wait_ms=2) as srv:
+        def client(t):
+            try:
+                futs = [(i, srv.submit(payloads[i]))
+                        for i in range(t, len(payloads), 4)]
+                for i, f in futs:
+                    results[i] = f.result(timeout=60).asnumpy()
+            except Exception as e:  # noqa: BLE001
+                errors.append(e)
 
-                threads = [threading.Thread(target=client, args=(t,))
-                           for t in range(4)]
-                for th in threads:
-                    th.start()
-                for th in threads:
-                    th.join()
-            assert not errors, errors
-            assert compile_cache.named_stats("serving")["misses"] == ledger0
-            got[overlap] = results
+        threads = [threading.Thread(target=client, args=(t,))
+                   for t in range(4)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join()
+    assert not errors, errors
+    assert compile_cache.named_stats("serving")["misses"] == ledger0
     for i, ref in enumerate(refs):
-        assert np.array_equal(got[True][i], ref), i
-        assert np.array_equal(got[False][i], ref), i
+        assert np.array_equal(results[i], ref), i
 
 
 # ---------------------------------------------------------------------------
-# generation: overlapped tick token parity
+# generation: tick token parity
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.slow
 def test_generation_overlap_token_parity():
     """The dispatch-then-bookkeep tick emits the SAME token streams as
-    the lockstep tick: overlap moves the deadline sweep and admission
-    scan inside the dispatch->commit window, never the math."""
+    each request generated alone: the deadline sweep and admission scan
+    inside the dispatch->commit window, and sessions sharing a slab,
+    never move the math."""
     mesh = par.create_mesh(devices=jax.devices()[:1], dp=1)
     cfg = TransformerLMConfig(vocab_size=32, d_model=16, n_heads=2,
                               d_ff=32, n_layers=1, max_len=32,
@@ -355,14 +463,12 @@ def test_generation_overlap_token_parity():
     lm = TransformerLM(cfg, mesh)
     params = lm.init_params(jax.random.PRNGKey(0))
     prompts = [[1, 2, 3, 4], [5, 6], [7, 8, 9]]
-    out = {}
-    for overlap in (True, False):
-        with _env(overlap):
-            with GenerationEngine(lm, params, max_slots=2, max_len=32,
-                                  buckets=(8,)) as eng:
-                streams = [eng.submit(p, max_new_tokens=8) for p in prompts]
-                out[overlap] = [s.result(timeout=300) for s in streams]
-    assert out[True] == out[False]
+    with GenerationEngine(lm, params, max_slots=2, max_len=32,
+                          buckets=(8,)) as eng:
+        alone = [eng.generate(p, max_new_tokens=8) for p in prompts]
+        streams = [eng.submit(p, max_new_tokens=8) for p in prompts]
+        together = [s.result(timeout=300) for s in streams]
+    assert together == alone
 
 
 # ---------------------------------------------------------------------------
@@ -371,14 +477,14 @@ def test_generation_overlap_token_parity():
 
 
 def test_overlap_debug_sync_clean():
-    """analysis enabled BEFORE any stager exists: a full overlapped fit
-    (staging thread live, deferred metric lane on) must record ZERO
+    """analysis enabled BEFORE any stager exists: a full fit (staging
+    thread live, the metric one step late) must record ZERO
     lock-order inversions and ZERO blocking hazards."""
     was = analysis._enabled
     analysis.enable()
     analysis.reset()
     try:
-        w_on, _ = _fit(True)
+        w_on, _ = _fit()
         assert w_on  # the run trained
         rep = analysis.report()
         assert rep["inversions"] == [], rep["inversions"]

@@ -525,13 +525,11 @@ def test_fit_step_span_trees(trc):
         assert "fused.dispatch" in names  # nested through the contextvar
 
 
-@pytest.mark.parametrize("overlap", ["0", "1"], ids=["lockstep", "overlap"])
-def test_fit_live_children_keep_the_marks_order(trc, monkeypatch, overlap):
-    """The four phase children are live spans in the order the perf marks
-    had in each branch of the loop (lockstep: sync before data; overlap:
-    data before sync), fused.dispatch nests under step.fwdbwd, step.data
-    holds next + stage, and the step.*_us histograms still record."""
-    monkeypatch.setenv("MXNET_OVERLAP", overlap)
+def test_fit_live_children_keep_the_marks_order(trc):
+    """The four phase children are live spans in the order of the loop's
+    perf marks (data before sync), fused.dispatch nests under step.fwdbwd,
+    step.data holds next + stage, and the step.*_us histograms still
+    record."""
     prev = telemetry.enabled()
     telemetry.enable()
     names = ("fwdbwd", "update", "data", "sync", "total")
@@ -555,9 +553,7 @@ def test_fit_live_children_keep_the_marks_order(trc, monkeypatch, overlap):
              if e["args"].get("parent_id") == parent["args"]["span_id"]),
             key=lambda e: e["ts"])]
 
-    order = (["step.fwdbwd", "step.update", "step.sync", "step.data"]
-             if overlap == "0" else
-             ["step.fwdbwd", "step.update", "step.data", "step.sync"])
+    order = ["step.fwdbwd", "step.update", "step.data", "step.sync"]
     for i, root in enumerate(steps):
         assert kids(root) == order
         assert root["args"]["fused"] is True

@@ -42,6 +42,7 @@ from reference import trinity_afmoe as aref  # noqa: E402
 from runners import serve_afmoe, serve_swa_moe  # noqa: E402
 
 from pallas_grid import grid_of_call  # noqa: E402
+from lm_jit import jitted  # noqa: E402
 
 ref.PAD_TO = ref.BLOCK = 16     # the chip's sizes would spend these tiny tests on padding
 aref.PAD_TO = aref.BLOCK = 16
@@ -113,22 +114,10 @@ def _poisoned(lm, slots, max_len):
                  for c in lm.init_cache(slots, max_len))
 
 
-_JITTED = {}
-
-
-def _jit(lm, method):
-    """One jit wrapper a (model, method, kernel policy): a fresh wrapper a
-    call would trace and compile every decode step again."""
-    key = (id(lm), method, os.environ.get("MXNET_PALLAS_ATTENTION"))
-    if key not in _JITTED:
-        _JITTED[key] = jax.jit(getattr(lm, method))
-    return _JITTED[key]
-
-
 def _prefill(lm, params, cache, prompt, bucket, slot):
     padded = np.full(bucket, 7, np.int32)       # padded "with anything"
     padded[:len(prompt)] = prompt
-    out = _jit(lm, "prefill")(params, *cache, jnp.asarray(padded),
+    out = jitted(lm, "prefill")(params, *cache, jnp.asarray(padded),
                               jnp.asarray(len(prompt), jnp.int32),
                               jnp.asarray(slot, jnp.int32))
     return out[0], tuple(out[1:])
@@ -139,7 +128,7 @@ def _decode(lm, params, cache, slot, token, position):
     tokens = np.zeros(slots, np.int32)
     positions = np.full(slots, -1, np.int32)
     tokens[slot], positions[slot] = token, position
-    out = _jit(lm, "decode_step")(params, *cache, jnp.asarray(tokens),
+    out = jitted(lm, "decode_step")(params, *cache, jnp.asarray(tokens),
                                   jnp.asarray(positions))
     return out[0][slot], tuple(out[1:])
 

@@ -132,11 +132,12 @@ def main(argv=None):
             "this process are INCOMPLETE. Raise profiler max_events / "
             "MXNET_TRACING_MAX_EVENTS or dump more often.\n")
     staged = counters.get("overlap.staged_batches", 0)
-    overlap_steps = counters.get("overlap.steps", 0)
-    if staged or overlap_steps:
+    steps = (snap.get("histograms", {}).get("step.total_us")
+             or {}).get("count", 0)
+    if staged or steps:
         derived = snap.get("derived", {})
         line = (f"\nstage: {staged} batches device-staged over "
-                f"{overlap_steps} overlapped steps")
+                f"{steps} steps")
         fb = counters.get("overlap.fallback_batches", 0)
         full = counters.get("io.stage_ring_full", 0)
         if fb or full:
