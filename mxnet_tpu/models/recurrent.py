@@ -1,21 +1,23 @@
-"""The recurrent mixers of `HybridLM`: two implementations behind one seam.
+"""The recurrent mixers of `HybridLM`: three implementations behind one seam.
 
 A mixer owns what a recurrent layer adds to the block — its weights, its
 projection, its causal convolution, the sequence form of its recurrence
-(prefill), one step of it (decode) and the shapes of what the serving cache
-keeps a slot a layer: a float32 state page (the cache's third member) and the
-last `kernel - 1` convolution inputs (the fourth). `HybridLM` asks
+(prefill), one step of it (decode) — and STATES WHICH MEMBERS of the serving
+cache it keeps a slot a layer: a float32 state page (`state_shape`; None for
+a mixer that has no recurrent state, and the cache then has no such member)
+and the last `kernel - 1` convolution inputs (`conv_shape`). `HybridLM` asks
 
     shapes(i)                       the layer's weights, name -> shape
     ONES                            the leaves `init_params` sets to one
-    state_shape, conv_shape         one slot's page of each member
+    state_shape, conv_shape         one slot's page of each member it keeps
     seq(params, i, u, length)       -> (out [L, D], state page, conv tail)
     step(params, i, u, state, conv, page, alive)
                                     -> (out [S, D], state slab, conv slab)
     kernel(slab_shape, dtype)       whether `step` takes the Pallas kernel
 
 and nothing else: the norms around the mixer, the residual and the cache
-plumbing are the block's.
+plumbing are the block's. A mixer without a state takes and returns None
+where the others take and return theirs.
 
 * :class:`Mamba2Mixer` (`layer_types` "mamba") — module docstring of
   `hybrid.py`; state `[heads, head_dim, d_state]`.
@@ -28,6 +30,12 @@ plumbing are the block's.
   dv]`: the heads side by side on the lanes, so that a page's bytes on the
   chip are its count (a `[.., dk, dv]` page with `dv` 192 would be padded to
   256 lanes).
+* :class:`ShortConvMixer` ("conv") — the gated short convolution of LFM2:
+  `[B, C, x] = u W_in`; `y = C * conv(B * x)`, a causal depthwise
+  convolution over time of `conv_L_cache` taps a channel, no bias, no
+  activation; `out = y W_out`. NO recurrent state: what a decode step needs
+  of the past is the window alone, the last `conv_L_cache - 1` values of `B
+  * x` a channel.
 """
 from __future__ import annotations
 
@@ -39,7 +47,8 @@ from jax import lax
 
 from .. import telemetry
 
-__all__ = ["Mamba2Mixer", "GatedDeltaMixer", "MIXERS"]
+__all__ = ["Mamba2Mixer", "GatedDeltaMixer", "ShortConvMixer",
+           "MIXERS"]
 
 
 def _softplus_inverse_steps(key, shape):
@@ -482,6 +491,70 @@ class GatedDeltaMixer:
         return self._out(params, i, o, gate), state, conv
 
 
+class ShortConvMixer:
+    """The gated short convolution (module docstring). Weights of layer `i`:
+
+        c_in    [D, 3 D]        B | C | x, fused in the published order
+        conv_w  [kernel, D]     one tap vector a channel, no bias
+        c_out   [D, D]
+
+    The product `B * x` is taken in the served dtype (it is what the window
+    keeps), the taps' sum and the gate `C *` in float32. Scopes:
+    `shortconv.project`, `shortconv.conv`, `shortconv.out`."""
+
+    kind = "conv"
+    ONES = ()
+    state_shape = None      # no recurrent state: the window is all it keeps
+
+    def __init__(self, cfg, mesh, rms):
+        del mesh, rms
+        self.cfg = cfg
+        self.conv_shape = (cfg.conv_L_cache - 1, cfg.hidden_size)
+
+    def shapes(self, i):
+        d = self.cfg.hidden_size
+        return {f"l{i}.c_in": (d, 3 * d),
+                f"l{i}.conv_w": (self.cfg.conv_L_cache, d),
+                f"l{i}.c_out": (d, d)}
+
+    def kernel(self, slab_shape, dtype):
+        return False
+
+    def _project(self, params, i, u):
+        """`u` [T, D] -> the gate `C` [T, D] and the convolution's input `B
+        * x` [T, D]."""
+        with jax.named_scope("shortconv.project"):
+            b, c, x = jnp.split(u @ params[f"l{i}.c_in"], 3, axis=-1)
+            return c, b * x
+
+    def _out(self, params, i, c, conv):
+        """`(C * conv) W_out` for the taps' sums `conv` [T, D] float32."""
+        with jax.named_scope("shortconv.out"):
+            y = c.astype(jnp.float32) * conv
+            return y.astype(c.dtype) @ params[f"l{i}.c_out"]
+
+    def seq(self, params, i, u, length):
+        """The mixer over one whole sequence `u` [L, D] of which the first
+        `length` tokens are real: `(out [L, D], None, window [kernel - 1,
+        D])` — the window holds the last inputs up to token `length - 1`
+        (zeros before the sequence's start), so a bucket's padding never
+        enters it."""
+        c, bx = self._project(params, i, u)
+        with jax.named_scope("shortconv.conv"):
+            conv, tail = _conv_seq(bx, params[f"l{i}.conv_w"], None, length)
+        return self._out(params, i, c, conv), None, tail
+
+    def step(self, params, i, u, state, conv, page, alive):
+        """One token for every slot through layer `i`: `u` [S, D], the
+        slot-major slab of windows. A dead slot's window stays bit-for-bit
+        what it was."""
+        c, bx = self._project(params, i, u)
+        with jax.named_scope("shortconv.conv"):
+            out, conv = _conv_step(conv, page, bx, params[f"l{i}.conv_w"],
+                                   None, alive)
+        return self._out(params, i, c, out), state, conv
+
+
 def gdn_step_xla(old, alpha, beta, q, k, v):
     """One step of the gated delta rule in XLA, the mathematics of
     `pallas_ssm.gdn_state_update`: `old` [S, dk, H dv] float32, `alpha` and
@@ -499,4 +572,5 @@ def gdn_step_xla(old, alpha, beta, q, k, v):
     return o, new.reshape(old.shape)
 
 
-MIXERS = {m.kind: m for m in (Mamba2Mixer, GatedDeltaMixer)}
+MIXERS = {m.kind: m
+          for m in (Mamba2Mixer, GatedDeltaMixer, ShortConvMixer)}

@@ -1075,3 +1075,133 @@ def test_olmo_prefill_program_compiles_for_v5e(one_chip, monkeypatch,
                       for c in cache)
     assert ma.alias_size_in_bytes >= cache_bytes
     assert ma.temp_size_in_bytes < 2 ** 30
+
+
+# ---------------------------------------------------------------------------
+# the LFM2 expert block at its published widths and the cell's depth (ISSUE 47)
+# ---------------------------------------------------------------------------
+
+HBM_BUDGET = 14.5e9     # of a v5e's 16 GB: what a program and its cache may take
+
+
+@pytest.fixture(scope="module")
+def lfm2_stage(one_chip):
+    """`(lm, params, cache)` of `lfm2_8b_a1b_l12` whole — the 12 layers of
+    the stage at their published widths, 64 slots x 8,192 positions — as
+    shapes on the described chip."""
+    import json
+
+    from mxnet_tpu import parallel as par
+    from mxnet_tpu.models import HybridLM, HybridLMConfig
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "lfm2_8b_a1b_l12.json")) as f:
+        published = json.load(f)
+    assert len(published["layer_types"]) == 24      # carried whole
+    config = HybridLMConfig.from_config(published, max_len=8192)
+    assert config.layer_types.count("conv") == 9 \
+        and config.layer_types.count("full_attention") == 3
+    dev = next(iter(one_chip.device_set))
+    lm = HybridLM(config, par.create_mesh(devices=[dev], dp=1))
+    host_lm = HybridLM(config, par.create_mesh(devices=jax.devices()[:1],
+                                               dp=1))
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = {k: sds(v) for k, v in jax.eval_shape(
+        host_lm.init_params, jax.random.PRNGKey(0)).items()}
+    cache = tuple(sds(v) for v in jax.eval_shape(
+        lambda: host_lm.init_cache(64, 8192)))
+    assert [c.shape for c in cache] == [
+        (64, 3, 8, 8192, 64), (64, 3, 8, 8192, 64), (64, 9, 2, 2048),
+        (64, 10, 4)]
+    return lm, params, cache
+
+
+def _resident(ma):
+    """Bytes a compiled program needs on the chip while it runs: its
+    arguments (weights and cache), its temporaries, and what of its result
+    is not an argument's own buffer."""
+    return ma.argument_size_in_bytes + ma.temp_size_in_bytes \
+        + ma.output_size_in_bytes - ma.alias_size_in_bytes
+
+
+def test_lfm2_decode_program_compiles_for_v5e(one_chip, monkeypatch,
+                                              lfm2_stage):
+    """The engine's 64-slot decode program of the whole stage: it compiles
+    for the v5e; the experts' two products a layer are jax's `gmm` (256
+    sorted rows: two row tiles of 128) and not `lax.ragged_dot`; the slab
+    kernel `decode_update_attend` is in it once an attention layer and the
+    three calls share one grid; every member of the cache is aliased input
+    to output; no XLA op copies, slices, updates or re-lays a K/V page; and
+    weights, cache and temporaries fit 14.5 GB."""
+    lm, params, cache = lfm2_stage
+    monkeypatch.setenv("MXNET_PALLAS_ATTENTION", "1")
+    monkeypatch.delenv("MXNET_PALLAS_INTERPRET", raising=False)
+    assert lm.decode_block(cache[0].shape, cache[0].dtype) == 256
+    assert not lm.state_kernel(cache[2].shape, cache[2].dtype)
+
+    def fn(params, cache, tokens, positions):       # the engine's wrapper
+        logits, *cache = lm.decode_step(params, *cache, tokens, positions)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), tuple(cache)
+
+    ints = jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_chip)
+    with _counted("moe.grouped_product.", ("gmm", "ragged_dot")) as went:
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+            params, cache, ints, ints).compile()
+    assert went == {"gmm": 20, "ragged_dot": 0}
+    text = compiled.as_text()
+    assert len(re.findall(r"%gmm[.\d]* = ", text)) == 20
+    assert "ragged-dot" not in text
+    calls = _slab_kernel_calls(text)
+    assert len(calls) == 3
+    assert len({tuple(operands[:4]) for operands in calls}) == 1
+    assert [line for line in _page_sized_ops(text, 64 * 8 * 8192 * 64)
+            if "bf16[" in line] == []
+    ma = compiled.memory_analysis()
+    cache_bytes = sum(int(np.prod(c.shape)) * c.dtype.itemsize
+                      for c in cache)
+    assert ma.alias_size_in_bytes >= cache_bytes    # all four, whole
+    assert ma.temp_size_in_bytes < 2 ** 26
+    assert 11.0e9 < _resident(ma) < HBM_BUDGET
+
+
+@pytest.mark.parametrize("bucket,blockwise", [(2048, False), (8192, True)])
+def test_lfm2_prefill_program_compiles_for_v5e(one_chip, monkeypatch,
+                                               lfm2_stage, bucket,
+                                               blockwise):
+    """The engine's prefill program of the whole stage at the cell's
+    smallest and largest bucket: it compiles for the v5e with the cache
+    resident and fits 14.5 GB — the attention of 32 heads of 64 as one score
+    matrix at 2,048 (0.5 GB) and blockwise at 8,192, where the matrix would
+    be 8.6 GB —, the experts' products are `gmm`, and every member of the
+    cache is aliased."""
+    lm, params, cache = lfm2_stage
+    monkeypatch.setenv("MXNET_PALLAS_ATTENTION", "1")
+    monkeypatch.delenv("MXNET_PALLAS_INTERPRET", raising=False)
+    assert lm.prefill_block(bucket) is None
+    assert lm.prefill_blockwise(bucket) == blockwise
+
+    def fn(params, cache, tokens, length, slot):    # the engine's wrapper
+        logits, *cache = lm.prefill(params, *cache, tokens, length, slot)
+        return jnp.argmax(logits).astype(jnp.int32), tuple(cache)
+
+    one = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    with _counted("moe.grouped_product.", ("gmm", "ragged_dot")) as went:
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+            params, cache, jax.ShapeDtypeStruct((bucket,), jnp.int32,
+                                               sharding=one_chip),
+            one, one).compile()
+    assert went == {"gmm": 20, "ragged_dot": 0}
+    text = compiled.as_text()
+    assert "ragged-dot" not in text
+    assert len(re.findall(r"%gmm[.\d]* = ", text)) == 20
+    ma = compiled.memory_analysis()
+    cache_bytes = sum(int(np.prod(c.shape)) * c.dtype.itemsize
+                      for c in cache)
+    assert ma.alias_size_in_bytes >= cache_bytes
+    assert ma.temp_size_in_bytes < 2 ** 30
+    assert _resident(ma) < HBM_BUDGET
