@@ -702,9 +702,9 @@ class HybridLM:
         whose heads are the lane width lies `hd`-minor on the chip and goes
         to `ops/pallas_window.py`'s kernel, any other to
         `ops/pallas_decode.py`'s (:meth:`decode_step` tells them apart the
-        same way, and counts which body of the first a layer's trace took:
-        `attn.decode.kv128.one_query` / `.grouped`, once a trace, telemetry
-        on)."""
+        same way, and counts which body of either a layer's trace took:
+        `attn.decode.kv128.one_query` / `.grouped`, `attn.decode.slab.
+        one_query` / `.grouped`, once a trace, telemetry on)."""
         from ..ops import pallas_attention as pa
 
         if self.mesh.size > 1 or not pa.pallas_enabled():
@@ -872,6 +872,7 @@ class HybridLM:
                     else:
                         from ..ops import pallas_decode as pd
 
+                        pd.count_body(q, ck)
                         a, ck, cv = pd.decode_update_attend(
                             q, k, v, ck, cv, jnp.int32(page), positions,
                             block=block, scale=c.attention_multiplier,

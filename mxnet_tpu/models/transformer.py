@@ -675,6 +675,7 @@ class TransformerLM:
             with jax.named_scope("attn.decode"):
                 k, v = k.astype(cache_k.dtype), v.astype(cache_v.dtype)
                 if block is not None:
+                    pd.count_body(q, cache_k)
                     attn, cache_k, cache_v = pd.decode_update_attend(
                         q, k, v, cache_k, cache_v, jnp.int32(i), positions,
                         block=block, interpret=pa.pallas_interpret())

@@ -72,6 +72,7 @@ def _counted(prefix, kinds):
 
 
 KV128_BODIES = ("attn.decode.kv128.", ("one_query", "grouped"))
+SLAB_BODIES = ("attn.decode.slab.", ("one_query", "grouped"))
 
 
 @pytest.mark.parametrize("shape,dtype", [
@@ -271,9 +272,12 @@ def test_decode_program_touches_no_slab_page_on_v5e(one_chip, monkeypatch,
         logits, ck, cv = lm.decode_step(params, ck, cv, tokens, positions)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), ck, cv
 
-    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
-        params, slab, slab, sds((slots,), jnp.int32),
-        sds((slots,), jnp.int32)).compile()
+    with _counted(*SLAB_BODIES) as went:
+        compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
+            params, slab, slab, sds((slots,), jnp.int32),
+            sds((slots,), jnp.int32)).compile()
+    # one query a head: the per-lane body, once a layer's trace (ISSUE 48)
+    assert went == {"one_query": 2, "grouped": 0}
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 2        # one kernel a layer
     # ... exactly one, whose grid (a step a live block: ISSUE 41) and work
@@ -313,7 +317,6 @@ def test_hybrid_decode_program_updates_state_in_place_on_v5e(one_chip,
     import os
 
     from mxnet_tpu import parallel as par
-    from mxnet_tpu import telemetry
     from mxnet_tpu.models import HybridLM, HybridLMConfig
 
     monkeypatch.setenv("MXNET_PALLAS_ATTENTION", "1")
@@ -342,7 +345,7 @@ def test_hybrid_decode_program_updates_state_in_place_on_v5e(one_chip,
     assert [c.shape for c in cache] == [
         (32, 2, 8, 4096, 64), (32, 2, 8, 4096, 64), (32, 9, 64, 64, 128),
         (32, 9, 3, 4352)]
-    assert lm.decode_block(cache[0].shape, cache[0].dtype) == 256
+    assert lm.decode_block(cache[0].shape, cache[0].dtype) == 512
     assert lm.state_kernel(cache[2].shape, cache[2].dtype)
 
     def fn(params, cache, tokens, positions):       # the engine's wrapper
@@ -350,18 +353,13 @@ def test_hybrid_decode_program_updates_state_in_place_on_v5e(one_chip,
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), tuple(cache)
 
     ints = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
-    went = {k: telemetry.counter("mamba.state_update." + k)
-            for k in ("kernel", "xla")}
-    before = {k: c.value for k, c in went.items()}
-    was = telemetry.enabled()
-    telemetry.enable()
-    try:
+    with _counted("mamba.state_update.", ("kernel", "xla")) as went, \
+            _counted(*SLAB_BODIES) as body:
         compiled = jax.jit(fn, donate_argnums=(1,)).lower(
             params, cache, ints, ints).compile()
-    finally:
-        telemetry.enable(was)
-    assert {k: c.value - before[k] for k, c in went.items()} == {
-        "kernel": 9, "xla": 0}
+    assert went == {"kernel": 9, "xla": 0}
+    # 32 queries over 8 K/V heads: the grouped body, on the MXU (ISSUE 48)
+    assert body == {"one_query": 0, "grouped": 2}
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 11      # 9 state + 2 K/V kernels
     assert len(re.findall(r"%mamba_state_update[.\d]* = ", text)) == 9
@@ -1141,7 +1139,7 @@ def test_lfm2_decode_program_compiles_for_v5e(one_chip, monkeypatch,
     lm, params, cache = lfm2_stage
     monkeypatch.setenv("MXNET_PALLAS_ATTENTION", "1")
     monkeypatch.delenv("MXNET_PALLAS_INTERPRET", raising=False)
-    assert lm.decode_block(cache[0].shape, cache[0].dtype) == 256
+    assert lm.decode_block(cache[0].shape, cache[0].dtype) == 1024
     assert not lm.state_kernel(cache[2].shape, cache[2].dtype)
 
     def fn(params, cache, tokens, positions):       # the engine's wrapper
@@ -1149,10 +1147,12 @@ def test_lfm2_decode_program_compiles_for_v5e(one_chip, monkeypatch,
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), tuple(cache)
 
     ints = jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_chip)
-    with _counted("moe.grouped_product.", ("gmm", "ragged_dot")) as went:
+    with _counted("moe.grouped_product.", ("gmm", "ragged_dot")) as went, \
+            _counted(*SLAB_BODIES) as body:
         compiled = jax.jit(fn, donate_argnums=(1,)).lower(
             params, cache, ints, ints).compile()
     assert went == {"gmm": 20, "ragged_dot": 0}
+    assert body == {"one_query": 0, "grouped": 3}
     text = compiled.as_text()
     assert len(re.findall(r"%gmm[.\d]* = ", text)) == 20
     assert "ragged-dot" not in text
@@ -1205,3 +1205,95 @@ def test_lfm2_prefill_program_compiles_for_v5e(one_chip, monkeypatch,
     assert ma.alias_size_in_bytes >= cache_bytes
     assert ma.temp_size_in_bytes < 2 ** 30
     assert _resident(ma) < HBM_BUDGET
+
+
+# ---------------------------------------------------------------------------
+# the slab kernel's two bodies (ISSUE 48): a group of queries on the MXU
+# ---------------------------------------------------------------------------
+
+def _gpt2xl():
+    from mxnet_tpu.models import TransformerLM, TransformerLMConfig
+
+    return TransformerLM, TransformerLMConfig(
+        vocab_size=50257, d_model=1600, n_heads=25, d_ff=6400, n_layers=48,
+        max_len=1024, dtype="bfloat16"), (32, 1024)
+
+
+def _published(name, **more):
+    import json
+
+    from mxnet_tpu.models import HybridLM, HybridLMConfig
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))))
+    with open(os.path.join(root, "benchmark", "configs", name)) as f:
+        return HybridLM, HybridLMConfig.from_config(json.load(f), **more)
+
+
+@pytest.mark.parametrize("cell,build,want", [
+    ("lfm2moe_workers64", lambda: _published(
+        "lfm2_8b_a1b_l12.json", max_len=8192) + ((64, 8192),), (3, 0)),
+    ("granite4h_workers32", lambda: _published(
+        "granite_4_0_h_micro.json") + ((32, 4096),), (4, 0)),
+    ("gpt2xl_chat", _gpt2xl, (0, 48)),
+], ids=["lfm2", "granite", "gpt2xl"])
+def test_slab_kernel_body_follows_the_heads(monkeypatch, cell, build, want):
+    """Which body of `decode_update_attend` a cell's whole decode program
+    takes, from the counter a layer's trace moves (telemetry on only):
+    `attn.decode.slab.grouped | one_query` reads 3 | 0 in LFM2's stage and
+    4 | 0 in granite (32 queries over 8 K/V heads: two products a pair of
+    heads), 0 | 48 in GPT-2 XL (25 over 25: the per-lane body it had). The
+    body follows the operands' shapes and nothing else. A trace is enough
+    to count; no chip is described."""
+    from mxnet_tpu import parallel as par
+
+    monkeypatch.setenv("MXNET_PALLAS_ATTENTION", "1")
+    monkeypatch.delenv("MXNET_PALLAS_INTERPRET", raising=False)
+    cls, config, (slots, rows) = build()
+    lm = cls(config, par.create_mesh(devices=jax.devices()[:1], dp=1))
+    params = jax.eval_shape(lm.init_params, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: lm.init_cache(slots, rows))
+    assert lm.decode_block(cache[0].shape, cache[0].dtype) is not None
+    ints = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    with _counted(*SLAB_BODIES) as went:
+        jax.eval_shape(lm.decode_step, params, *cache, ints, ints)
+    assert (went["grouped"], went["one_query"]) == want
+
+
+@pytest.mark.parametrize("slab,q_heads", [
+    ((64, 3, 8, 8192, 64), 32),         # lfm2moe_workers64
+    ((32, 4, 8, 4096, 64), 32),         # granite4h_workers32
+    ((32, 2, 6, 2048, 64), 12),         # 3 pairs of heads, 2 queries each
+    ((32, 2, 5, 2048, 64), 20),         # heads that do not pair off
+    ((32, 2, 4, 2048, 32), 16),         # four heads of 32 a product
+], ids=["lfm2", "granite", "6x2", "5x4", "hd32"])
+def test_slab_kernel_grouped_compiles_for_v5e(one_chip, slab, q_heads):
+    """The grouped body alone at the two cells' slabs and at shapes that
+    stack another number of heads a product: Mosaic takes it at the block
+    `decode_block` chooses (the K and V blocks of every head, double
+    buffered, stay inside the budget that keeps the kernel's scoped VMEM
+    under Mosaic's 16 MiB), under the name the benchmark's readers look
+    for, with both slabs aliased and no copy of a page around it."""
+    from mxnet_tpu.ops import pallas_decode as pd
+
+    n, _, heads, rows, hd = slab
+    block = pd.decode_block(slab, jnp.bfloat16)
+    assert block is not None and rows % block == 0
+    assert 4 * heads * hd * block * 2 <= pd._BLOCK_BUDGET_BYTES
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(q, k, v, ck, cv, pos):
+        return pd.decode_update_attend(q, k, v, ck, cv, jnp.int32(1), pos,
+                                       block=block)
+
+    compiled = jax.jit(fn, donate_argnums=(3, 4)).lower(
+        sds((n, q_heads, hd)), sds((n, heads, hd)), sds((n, heads, hd)),
+        sds(slab), sds(slab), sds((n,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert len(_slab_kernel_calls(text)) == 1
+    assert _page_sized_ops(text, n * heads * rows * hd) == []
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= 2 * int(np.prod(slab)) * 2
+    assert ma.temp_size_in_bytes < n * heads * rows * hd

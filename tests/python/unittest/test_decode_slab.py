@@ -152,9 +152,16 @@ def _bits(x):
     return np.asarray(x).view(np.uint32)
 
 
+def _within(got, was):
+    """The largest difference is at most 1e-5 of the output's scale."""
+    got, was = np.asarray(got, np.float32), np.asarray(was, np.float32)
+    return np.abs(got - was).max() <= 1e-5 * max(np.abs(was).max(), 1e-30)
+
+
 @pytest.mark.parametrize("junk", [None, np.inf, np.nan],
                          ids=["clean", "inf", "nan"])
-@pytest.mark.parametrize("group", [1, 4], ids=["1q", "4q"])
+@pytest.mark.parametrize("group", [1, 2, 4, 8],
+                         ids=["1q", "2q", "4q", "8q"])
 @pytest.mark.parametrize("name", LIVE_ONLY)
 def test_live_only_grid(name, group, junk):
     """A grid step a live block and no other: the kernel (interpreted)
@@ -162,10 +169,14 @@ def test_live_only_grid(name, group, junk):
     was with its grid of every (slot, block) (`slab_kernel_2d`), with dead
     slots between and around live ones, none live, every slot at its last
     row, 32 live slots, and positions on both sides of a block's and a lane
-    group's edge; one query a slab head (GPT-2's) and four (granite's); inf
-    or nan in every dead page and past every position. The attention is the
-    old grid's bit for bit, and every slab row but the written ones comes
-    back bit for bit, the junk included."""
+    group's edge; one query a slab head (GPT-2's), four (granite's, LFM2's),
+    two and eight; inf or nan in every dead page and past every position. At
+    one query a head the attention is the old grid's bit for bit: the same
+    body. A group of queries goes through the MXU (ISSUE 48) where
+    `slab_kernel_2d` still streams each query per lane: the same products
+    and float32 weights in another order of sums, held to 1e-5 of the
+    output's scale. Every slab row but the written ones comes back bit for
+    bit either way, the junk included."""
     positions = LIVE_ONLY[name]
     n = len(positions)
     rng = np.random.default_rng(3)
@@ -185,7 +196,10 @@ def test_live_only_grid(name, group, junk):
         q, k_new, v_new, ck, cv, LAYER, pos, block=block, interpret=True)
     was, was_k, was_v = slab_kernel_2d.decode_update_attend(
         q, k_new, v_new, ck, cv, LAYER, pos, block=block, interpret=True)
-    assert np.array_equal(_bits(got), _bits(was))
+    if group == 1:
+        assert np.array_equal(_bits(got), _bits(was))
+    else:
+        assert np.isfinite(np.asarray(got)).all() and _within(got, was)
     assert np.array_equal(_bits(got_k), _bits(was_k))
     assert np.array_equal(_bits(got_v), _bits(was_v))
     want = _restated(q, k_new, v_new, clean_k, clean_v, LAYER,
@@ -205,6 +219,60 @@ def test_live_only_grid(name, group, junk):
             if p >= 0:
                 expect[s, LAYER, :, p, :] = np.asarray(new)[s]
         assert np.array_equal(_bits(after), _bits(expect))
+
+
+GROUPED = [(group, dt, name) for group in (2, 4, 8)
+           for dt in ("float32", "bfloat16") for name in POSITIONS]
+
+
+@pytest.mark.parametrize("group,dt,name", GROUPED,
+                         ids=["-".join(map(str, c)) for c in GROUPED])
+def test_grouped_body_matches_the_per_lane_body(group, dt, name):
+    """ISSUE 48: a slab head's group of queries as two MXU products and one
+    running softmax (`pallas_decode._kernel`) against the body it replaced,
+    which `slab_kernel_2d` still is — each query streamed per lane on the
+    vector unit in float32 — and against the XLA formulation; 2, 4 and 8
+    queries a head, float32 and bfloat16 slabs, every entry of `POSITIONS`,
+    inf in K and nan in V past every position and all through a dead
+    slot's pages. The products take the operands as they are stored and the
+    weights stay float32 (a bfloat16 slab takes them as two bfloat16
+    terms), so the two bodies differ by float32's rounding and the order of
+    the sums: at most 1e-5 of the output's scale. Exactly one row a live
+    slot goes back to each slab; every other row returns bit for bit."""
+    positions = POSITIONS[name]
+    rng = np.random.default_rng(group)
+    dtype = jnp.dtype(dt)
+    draw = lambda *shape: jnp.asarray(rng.standard_normal(shape), dtype)
+    q, k_new, v_new = draw(S, H * group, 64), draw(S, H, 64), draw(S, H, 64)
+    clean_k, clean_v = draw(S, NL, H, L, 64), draw(S, NL, H, L, 64)
+    pos = jnp.asarray(positions, jnp.int32)
+    alive = np.asarray(pos) >= 0
+    beyond = jnp.arange(L)[None, None, None, :, None] \
+        > pos[:, None, None, None, None]
+    ck = jnp.where(beyond, jnp.inf, clean_k).astype(dtype)
+    cv = jnp.where(beyond, jnp.nan, clean_v).astype(dtype)
+    got, got_k, got_v = pd.decode_update_attend(
+        q, k_new, v_new, ck, cv, LAYER, pos, block=BLOCK, interpret=True)
+    was, _, _ = slab_kernel_2d.decode_update_attend(
+        q, k_new, v_new, ck, cv, LAYER, pos, block=BLOCK, interpret=True)
+    got = np.asarray(got)
+    assert got.dtype == np.float32 and got.shape == (S, H * group, 64)
+    assert np.isfinite(got).all() and not got[~alive].any()
+    assert _within(got, was)
+    xla = tfm._attend_rows(q, tfm._write_rows(ck, LAYER, pos, k_new),
+                           tfm._write_rows(cv, LAYER, pos, v_new), LAYER, pos)
+    tol = 2e-5 if dt == "float32" else 2e-2    # XLA's bf16 softmax weights
+    np.testing.assert_allclose(got[alive], np.asarray(xla, np.float32)[alive],
+                               rtol=tol, atol=tol)
+    for slab, after, new in ((ck, got_k, k_new), (cv, got_v, v_new)):
+        expect = np.array(slab.astype(jnp.float32))
+        for s, p in enumerate(positions):
+            if p >= 0:
+                expect[s, LAYER, :, p, :] = np.asarray(
+                    new.astype(jnp.float32))[s]
+        assert after.dtype == dtype
+        assert np.array_equal(_bits(after.astype(jnp.float32)),
+                              _bits(expect))
 
 
 def _grid_of(positions):
@@ -310,6 +378,43 @@ def test_decode_step_kernel_and_xla_agree(tiny_lm, monkeypatch):
                                    rtol=1e-5, atol=1e-5)
 
 
+ONE_QUERY_LOWERED = {   # sha256 of the lowered text at commit f6fb760 (PR 47)
+    "tiny": (dict(vocab_size=61, d_model=128, n_heads=2, d_ff=128, n_layers=2,
+                  max_len=256, dtype="float32"), 5,
+             "c68d8e0b7bfaa6f53aa4dbdac7f4b377e5b2bef1b562270e479f0a218edc4c8e"),
+    # GPT-2 XL's widths, two of its 48 layers: the chat cell's 32 slots
+    "xl": (dict(vocab_size=50257, d_model=1600, n_heads=25, d_ff=6400,
+                n_layers=2, max_len=1024, dtype="bfloat16"), 32,
+           "011997c9914a39acb01e9a1c8b0c80c644b53c44463c8f5cad41e335938e926d")}
+
+
+@pytest.mark.parametrize("name", ONE_QUERY_LOWERED)
+def test_one_query_decode_lowers_what_it_did(monkeypatch, name):
+    """One query a slab head keeps its body (ISSUE 48: the grouped body is
+    a branch on a static fact of the trace, `Hq // H`): with the kernels on
+    `TransformerLM.decode_step` lowers to the text it lowered to on the
+    parent commit — its sha256, taken there before the change; the kernel
+    interpreted, so the text is the kernel's own operations, the grid, the
+    block specs and the aliasing (a Mosaic lowering carries the source's
+    line numbers); the installation is pinned, so the text is a function of
+    the program alone."""
+    import hashlib
+
+    monkeypatch.setenv("MXNET_PALLAS_ATTENTION", "1")
+    monkeypatch.setenv("MXNET_PALLAS_INTERPRET", "1")
+    config, slots, pinned = ONE_QUERY_LOWERED[name]
+    cfg = TransformerLMConfig(**config)
+    lm = TransformerLM(cfg, par.create_mesh(devices=jax.devices()[:1], dp=1))
+    slab = jax.ShapeDtypeStruct(
+        (slots, cfg.n_layers, cfg.n_heads, cfg.max_len, 64), cfg.dtype)
+    assert lm.decode_block(slab.shape, slab.dtype) == BLOCK
+    ints = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    lowered = jax.jit(lm.decode_step).lower(
+        jax.eval_shape(lm.init_params, jax.random.PRNGKey(0)), slab, slab,
+        ints, ints)
+    assert hashlib.sha256(lowered.as_text().encode()).hexdigest() == pinned
+
+
 def test_live_blocks_counts_what_the_kernel_reads():
     pos = np.array([-1, 0, 255, 256, 511, -7])
     assert pd.live_blocks(pos, 256).tolist() == [0, 1, 1, 2, 2, 0]
@@ -325,7 +430,14 @@ def test_live_blocks_counts_what_the_kernel_reads():
     ((4, 2, 100, 1024, 64), "bfloat16", 128),     # the block budget halves it
     ((4, 2, 200, 1024, 64), "float32", None),     # ... and gives up
     ((4, 2, 8, 1000, 64), "bfloat16", None),      # no lane-aligned block
-], ids=["xl", "small", "hd12", "L64", "hd128", "wide", "wider", "L1000"])
+    # the block follows the rows a slot: an eighth, from 256 to 1,024
+    ((32, 4, 8, 4096, 64), "bfloat16", 512),      # granite4h_workers32
+    ((64, 3, 8, 8192, 64), "bfloat16", 1024),     # lfm2moe_workers64
+    ((4, 2, 8, 2048, 64), "bfloat16", 256),
+    ((4, 2, 8, 32768, 64), "bfloat16", 1024),
+    ((4, 2, 20, 8192, 64), "bfloat16", 512),      # ... under the budget
+], ids=["xl", "small", "hd12", "L64", "hd128", "wide", "wider", "L1000",
+        "granite", "lfm2", "L2048", "L32768", "L8192-wide"])
 def test_decode_block_shape_test(shape, dtype, want):
     assert pd.decode_block(shape, dtype) == want
 
