@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as _np
 from jax import lax
 
+from .. import telemetry
 from .registry import register
 from ._utils import as_tuple, parse_bool
 
@@ -418,33 +419,91 @@ def _softmax_output(data, label, grad_scale=1.0, ignore_label=-1, multi_output=F
 # ---------------------------------------------------------------------------
 
 
+def _bn_axes(data, axis):
+    """The reduced axes, the broadcast shape of a per-channel vector and the
+    number of elements a channel."""
+    return (tuple(i for i in range(data.ndim) if i != axis),
+            tuple(-1 if i == axis else 1 for i in range(data.ndim)),
+            data.size // data.shape[axis])
+
+
+@_partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _batch_norm_train(data, gamma, beta, pivot, axis, eps):
+    """BatchNorm's training step in closed form: `(out, mean, var)` with the
+    batch's biased statistics in float32. Both sums are ONE pass over the
+    activation — each depends on nothing but `data` and the pivot, so XLA
+    carries them in the fusion that produces `data` — where `jnp.var` reads
+    it a second time, after the mean. `pivot` is a per-channel guess of the
+    mean known before the pass (the moving mean): the sums are taken of
+    `data - pivot`, so `E[d^2] - E[d]^2` does not cancel where a channel's
+    mean is large against its spread. `mean` / `var` feed the moving
+    statistics only and carry no gradient (reference `batch_norm.cc`)."""
+    return _batch_norm_train_fwd(data, gamma, beta, pivot, axis, eps)[0]
+
+
+def _batch_norm_train_fwd(data, gamma, beta, pivot, axis, eps):
+    red, shape, n = _bn_axes(data, axis)
+    xf = data.astype(jnp.float32)
+    d = xf - pivot.reshape(shape)
+    m1 = jnp.sum(d, axis=red) / n
+    m2 = jnp.sum(d * d, axis=red) / n
+    mean = pivot + m1
+    var = jnp.maximum(m2 - m1 * m1, 0.0)
+    inv = lax.rsqrt(var + eps)
+    scale = inv * gamma.astype(jnp.float32)
+    out = (xf - mean.reshape(shape)) * scale.reshape(shape) \
+        + beta.astype(jnp.float32).reshape(shape)
+    return (out.astype(data.dtype), mean, var), (data, gamma, beta, mean, inv)
+
+
+def _batch_norm_train_bwd(axis, eps, res, cts):
+    """`dbeta = sum(dy)` and `dgamma = sum(dy * xhat)` are siblings over the
+    same two operands — one pass — and `dx` is one elementwise expression of
+    them; autodiff of the forward reads the activation at three more
+    dependent levels (the transposes of the mean inside the variance, which
+    are algebraically zero). `xhat` is recomputed, not stored."""
+    data, gamma, beta, mean, inv = res
+    red, shape, n = _bn_axes(data, axis)
+    dy = cts[0].astype(jnp.float32)
+    xhat = (data.astype(jnp.float32) - mean.reshape(shape)) * inv.reshape(shape)
+    dbeta = jnp.sum(dy, axis=red)
+    dgamma = jnp.sum(dy * xhat, axis=red)
+    dx = (gamma.astype(jnp.float32) * inv).reshape(shape) * (
+        dy - (dbeta / n).reshape(shape) - xhat * (dgamma / n).reshape(shape))
+    return (dx.astype(data.dtype), dgamma.astype(gamma.dtype),
+            dbeta.astype(beta.dtype), jnp.zeros_like(mean))
+
+
+_batch_norm_train.defvjp(_batch_norm_train_fwd, _batch_norm_train_bwd)
+
+
 @register("BatchNorm", aliases=["BatchNorm_v1"], needs_mode=True, num_outputs=3, mutate_aux=(3, 4))
 def _batch_norm(data, gamma, beta, moving_mean, moving_var, eps=1e-3, momentum=0.9,
                 fix_gamma=True, use_global_stats=False, output_mean_var=False, axis=1,
                 cudnn_off=False, _train=False, **kw):
     """Pure-functional BatchNorm: returns (out, new_moving_mean, new_moving_var).
     The frontend writes outputs 1,2 back into the aux NDArrays (mutate_aux),
-    matching the reference's in-place moving-stat update (`batch_norm.cc`)."""
+    matching the reference's in-place moving-stat update (`batch_norm.cc`).
+    With telemetry on, a trace of the training step counts
+    `batchnorm.train.closed_form` once a node."""
     axis = int(axis) % data.ndim
     eps, momentum = float(eps), float(momentum)
     if parse_bool(fix_gamma):
         gamma = jnp.ones_like(gamma)
-    red = tuple(i for i in range(data.ndim) if i != axis)
-    shape = [1] * data.ndim
-    shape[axis] = data.shape[axis]
-    xf = data.astype(jnp.float32)
     if parse_bool(_train) and not parse_bool(use_global_stats):
-        mean = jnp.mean(xf, axis=red)
-        var = jnp.var(xf, axis=red)
+        if telemetry._enabled:
+            telemetry.counter("batchnorm.train.closed_form").inc()
+        out, mean, var = _batch_norm_train(data, gamma, beta,
+                                           moving_mean.astype(jnp.float32), axis, eps)
         new_mean = momentum * moving_mean + (1 - momentum) * mean.astype(moving_mean.dtype)
         new_var = momentum * moving_var + (1 - momentum) * var.astype(moving_var.dtype)
-    else:
-        mean, var = moving_mean.astype(jnp.float32), moving_var.astype(jnp.float32)
-        new_mean, new_var = moving_mean, moving_var
+        return out, new_mean, new_var
+    _, shape, _ = _bn_axes(data, axis)
+    mean, var = moving_mean.astype(jnp.float32), moving_var.astype(jnp.float32)
     inv = lax.rsqrt(var + eps)
-    out = (xf - mean.reshape(shape)) * inv.reshape(shape)
+    out = (data.astype(jnp.float32) - mean.reshape(shape)) * inv.reshape(shape)
     out = out * gamma.astype(jnp.float32).reshape(shape) + beta.astype(jnp.float32).reshape(shape)
-    return out.astype(data.dtype), new_mean, new_var
+    return out.astype(data.dtype), moving_mean, moving_var
 
 
 @register("LayerNorm")
